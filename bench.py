@@ -9,6 +9,12 @@ no reference-published number (the reference is an orchestrator —
 BASELINE.md), so the first recorded run is persisted to
 ``BENCH_BASELINE.json`` and later runs report ``vs_baseline`` against it.
 
+``python bench.py`` needs the chip: ``main()`` fails at once on any other
+platform, runs one fixed configuration per phase, and the first phase that
+fails ends the run non-zero — a number is never printed for a device or a
+size it was not measured on.  (The ``run_*`` functions stay importable on
+the CPU; ``scripts/ci.sh`` gates their keys at ``small=True``.)
+
 Prints exactly one JSON line on stdout.
 """
 
@@ -27,9 +33,10 @@ import jax
 import jax.numpy as jnp
 
 from dstack_tpu.models import llama, train
-# v5e peak bf16 matmul throughput per chip — the single definition, shared
-# with TrainTelemetry's MFU gauge so the two can never diverge.
-from dstack_tpu.telemetry.training import V5E_PEAK_BF16_FLOPS
+# per-chip bf16 peaks keyed by device_kind — the single table, shared with
+# TrainTelemetry's MFU gauge so the two can never diverge.
+from dstack_tpu.telemetry.training import PEAK_BF16_FLOPS
+from dstack_tpu.utils.jax_runtime import device_report, enable_persistent_cache
 
 
 def log(*a):
@@ -74,38 +81,37 @@ def _measure(cfg, batch: int, seq: int, steps: int, warmup: int,
 
     n_chips = max(len(jax.devices()), 1)
     tok_per_sec_chip = batch * seq * steps / dt / n_chips
-    mfu = (6 * cfg.num_params() * batch * seq * steps / dt / n_chips
-           / V5E_PEAK_BF16_FLOPS)
+    # MFU only against the peak of the device this ran on: None (not the
+    # v5e's figure) where the table does not know the device
+    peak = PEAK_BF16_FLOPS.get(jax.devices()[0].device_kind)
+    mfu = (None if peak is None else
+           6 * cfg.num_params() * batch * seq * steps / dt / n_chips / peak)
     log(f"{steps} steps in {dt:.3f}s -> {tok_per_sec_chip:,.0f} tok/s/chip, "
-        f"MFU≈{mfu*100:.1f}% (v5e peak)")
+        + ("MFU not computed (no peak for this device)" if mfu is None
+           else f"MFU≈{mfu*100:.1f}% ({jax.devices()[0].device_kind} peak)"))
 
     telemetry = None
     if not capture_telemetry:
         return tok_per_sec_chip, mfu, telemetry
-    try:
-        from dstack_tpu.telemetry.training import TrainTelemetry
+    from dstack_tpu.telemetry.recorder import percentiles_from_snapshot
+    from dstack_tpu.telemetry.training import TrainTelemetry
 
-        tel = TrainTelemetry(log_every=0)
-        # wrapping an already-warm step: the cache baseline keeps these
-        # from reading as recompiles
-        tel_step = tel.wrap(step_fn, cfg, n_devices=n_chips)
-        for _ in range(3):
-            state, metrics = tel_step(state, batch_d)
-        from dstack_tpu.telemetry.recorder import percentiles_from_snapshot
-
-        p = percentiles_from_snapshot(tel.step_seconds.snapshot())
-        telemetry = {
-            "step_time_p50_ms": round(p["p50"] * 1e3, 2),
-            "step_time_p99_ms": round(p["p99"] * 1e3, 2),
-            "tokens_per_sec": round(tel.tokens_per_sec.value, 1),
-            "mfu": round(tel.mfu.value, 4),
-            "recompiles": int(tel.recompiles_total.value),
-        }
-        log(f"telemetry: step p50 {telemetry['step_time_p50_ms']}ms "
-            f"MFU {telemetry['mfu']*100:.1f}% "
-            f"recompiles {telemetry['recompiles']}")
-    except Exception as e:  # pragma: no cover — bench must not die on this
-        log(f"train-step telemetry capture failed: {type(e).__name__}: {e}")
+    tel = TrainTelemetry(log_every=0)
+    # wrapping an already-warm step: the cache baseline keeps these
+    # from reading as recompiles
+    tel_step = tel.wrap(step_fn, cfg, n_devices=n_chips)
+    for _ in range(3):
+        state, metrics = tel_step(state, batch_d)
+    p = percentiles_from_snapshot(tel.step_seconds.snapshot())
+    telemetry = {
+        "step_time_p50_ms": round(p["p50"] * 1e3, 2),
+        "step_time_p99_ms": round(p["p99"] * 1e3, 2),
+        "tokens_per_sec": round(tel.tokens_per_sec.value, 1),
+        "mfu": round(tel.mfu.value, 4) if tel.peak_flops else None,
+        "recompiles": int(tel.recompiles_total.value),
+    }
+    log(f"telemetry: step p50 {telemetry['step_time_p50_ms']}ms "
+        f"MFU {telemetry['mfu']} recompiles {telemetry['recompiles']}")
     return tok_per_sec_chip, mfu, telemetry
 
 
@@ -136,8 +142,11 @@ def run_bench_8b(steps: int = 3, warmup: int = 2):
         # for 3 more blocking 8B-shape steps whose result nobody reads
         tok_s, mfu, _ = _measure(cfg, batch, seq, steps, warmup,
                                  capture_telemetry=False)
+        if mfu is None:
+            return tok_s, None, None
         full = llama.LlamaConfig.llama3_8b()
-        projected = mfu * V5E_PEAK_BF16_FLOPS / (6 * full.num_params())
+        projected = (mfu * PEAK_BF16_FLOPS[jax.devices()[0].device_kind]
+                     / (6 * full.num_params()))
         log(f"projected full-8B @ this MFU: {projected:,.0f} tok/s/chip")
         return tok_s, mfu, projected
     finally:
@@ -193,10 +202,9 @@ def run_serving_bench(steps_budget: float = 60.0, quantize=None,
         engine.step()
     if not all(r.done.is_set() for r in warm):
         # unfinished warm requests would occupy slots and contaminate the
-        # timed round with queueing — flag it rather than underreport
-        log(f"serving warm round did not finish within {steps_budget}s; "
-            "measurement skipped")
-        return 0.0
+        # timed round with queueing — fail rather than underreport
+        raise RuntimeError(
+            f"serving warm round did not finish within {steps_budget}s")
     reqs = submit_all()
     engine.step()  # prefill outside the timed window
     t0 = time.perf_counter()
@@ -254,7 +262,7 @@ def run_ttft_bench(quantize="int8"):
     return ttft, bg_rate
 
 
-def run_decode_bench(steps_budget: float = 30.0, small=None):
+def run_decode_bench(steps_budget: float = 30.0, small: bool = False):
     """Decode hot-loop arms, one workload each (PR 18 raw-speed pass).
 
     Four paged-engine arms over the same greedy prompts: the dense-paged
@@ -268,15 +276,15 @@ def run_decode_bench(steps_budget: float = 30.0, small=None):
     batch TTFT (admission -> last first-token) for the baseline and int8
     arms — the acceptance pair for "faster at equal or better TTFT".
 
-    ``small=None``: auto — the bench model (llama3_1b, 32-way) on TPU, a
-    scaled-down config on CPU so CI's gate stage finishes in seconds.
+    ``small``: the caller's explicit choice, never guessed from the backend —
+    False is the bench model (llama3_1b, 32-way) for the chip; True is a
+    scaled-down config whose keys CI's CPU gate stage checks in seconds
+    (its numbers are CPU numbers and never enter the bench payload).
     """
     import dataclasses
 
     from dstack_tpu.serving.engine import InferenceEngine, Request
 
-    if small is None:
-        small = jax.default_backend() != "tpu"
     if small:
         # prompts long enough that KV reads are a visible share of the
         # step (the int8-vs-bf16 arm difference IS those bytes), max_len
@@ -338,7 +346,7 @@ def run_decode_bench(steps_budget: float = 30.0, small=None):
 
 
 def run_decode_overlap_sweep(ks=(2, 4, 6, 8), chunks=(128, 256, 512),
-                             small=None):
+                             small: bool = False):
     """Speculation-k x prefill-chunk overlap sweep (PR 18 tentpole knob 4).
 
     The two features fight over the same windows: a bigger speculative
@@ -359,8 +367,6 @@ def run_decode_overlap_sweep(ks=(2, 4, 6, 8), chunks=(128, 256, 512),
 
     from dstack_tpu.serving.engine import InferenceEngine, Request
 
-    if small is None:
-        small = jax.default_backend() != "tpu"
     if small:
         # probe longer than the largest chunk so EVERY config actually
         # chunks the arrival (a probe under the chunk size would make the
@@ -493,10 +499,8 @@ def run_provision_bench():
     shim = native / "build" / "dstack-tpu-shim"
     runner = native / "build" / "dstack-tpu-runner"
     if not (shim.exists() and runner.exists()):
-        r = subprocess.run(["make", "-C", str(native)], capture_output=True)
-        if r.returncode != 0 or not shim.exists():
-            log("provision bench skipped: native agents not buildable")
-            return None
+        subprocess.run(["make", "-C", str(native)], capture_output=True,
+                       check=True)
 
     async def run():
         from dstack_tpu.core.models.backends import BackendType
@@ -563,13 +567,10 @@ def run_provision_bench():
         await close_sessions()
         return latency
 
-    try:
-        latency = asyncio.run(run())
-    except Exception as e:  # pragma: no cover — bench must not die on this
-        log(f"provision bench failed: {type(e).__name__}: {e}")
-        return None
-    if latency is not None:
-        log(f"provision -> first step (local backend): {latency:.2f}s")
+    latency = asyncio.run(run())
+    if latency is None:
+        raise RuntimeError("provision bench: the job never reached RUNNING")
+    log(f"provision -> first step (local backend): {latency:.2f}s")
     return latency
 
 
@@ -822,20 +823,18 @@ def run_coldstart_bench(config: str = "tiny"):
 
 
 def main():
-    # Shrink until it fits (single v5e-lite chip has 16 GB HBM).
-    train_telemetry = None
-    for batch, seq in ((14, 1024), (8, 1024), (4, 1024), (2, 1024), (1, 512)):
-        try:
-            value, train_telemetry = run_bench(batch, seq)
-            break
-        except Exception as e:  # XlaRuntimeError OOM etc.
-            log(f"bench config batch={batch} seq={seq} failed: {type(e).__name__}: {e}")
-    else:
-        print(json.dumps({
-            "metric": METRIC,
-            "value": 0.0, "unit": "tokens/sec/chip", "vs_baseline": 0.0,
-        }))
-        return
+    device = device_report()
+    if device["platform"] != "tpu":
+        # a CPU (or any other) run is not a smaller benchmark, it is a
+        # different measurement: refuse instead of printing its numbers
+        # under the chip's key names
+        raise SystemExit(
+            f"bench.py measures the TPU; this process runs on {device}. "
+            "Unset JAX_PLATFORMS (or set it to tpu) on a machine with a chip.")
+    enable_persistent_cache()
+    # one fixed configuration (r03-r05's: the largest batch that fit the
+    # 16 GB chip); no shrinking — a size that does not fit is a failure
+    value, train_telemetry = run_bench(14, 1024)
 
     extra = {}
     if train_telemetry is not None:
@@ -843,230 +842,170 @@ def main():
         # the perf trajectory carries measured MFU, not just throughput
         extra["train_step_telemetry"] = train_telemetry
     if os.environ.get("DSTACK_BENCH_TRAIN_ONLY") != "1":
-        try:
-            tok_s_8b, mfu_8b, projected = run_bench_8b()
-            extra["llama3_8b_shape_tokens_per_sec_per_chip"] = round(tok_s_8b, 1)
+        tok_s_8b, mfu_8b, projected = run_bench_8b()
+        extra["llama3_8b_shape_tokens_per_sec_per_chip"] = round(tok_s_8b, 1)
+        if mfu_8b is not None:  # a TPU whose peak the table does not hold
             extra["llama3_8b_shape_mfu"] = round(mfu_8b, 4)
-            extra["llama3_8b_projected_full_depth_tokens_per_sec_per_chip"] = \
-                round(projected, 1)
-        except Exception as e:
-            log(f"8B-shape bench failed: {type(e).__name__}: {e}")
-        try:
-            serving = run_serving_bench()
-            extra["serving_tokens_per_sec"] = round(serving, 1)
-        except Exception as e:
-            log(f"serving bench failed: {type(e).__name__}: {e}")
-        try:
-            serving_q = run_serving_bench(quantize="int8")
-            extra["serving_tokens_per_sec_int8"] = round(serving_q, 1)
-        except Exception as e:
-            log(f"int8 serving bench failed: {type(e).__name__}: {e}")
-        try:
-            serving_32 = run_serving_bench(quantize="int8", concurrency=32)
-            extra["serving_tokens_per_sec_int8_32way"] = round(serving_32, 1)
-        except Exception as e:
-            log(f"32-way serving bench failed: {type(e).__name__}: {e}")
-        try:
-            ttft, bg_rate = run_ttft_bench()
-            extra["serving_ttft_mixed_load_ms"] = round(ttft * 1e3, 1)
-            extra["serving_decode_during_prefill_tokens_per_sec"] = \
-                round(bg_rate, 1)
-        except Exception as e:
-            log(f"TTFT bench failed: {type(e).__name__}: {e}")
-        try:
-            # decode hot-loop arms: dense-paged baseline vs ragged buckets
-            # vs quantized KV, plus the TTFT pair (PR 18)
-            extra.update(run_decode_bench())
-        except Exception as e:
-            log(f"decode bench failed: {type(e).__name__}: {e}")
-        try:
-            sweep = run_decode_overlap_sweep()
-            extra["serving_decode_overlap_best_k"] = sweep["k"]
-            extra["serving_decode_overlap_best_chunk"] = sweep["chunk"]
-            extra["serving_decode_overlap_tok_s"] = sweep["tok_s"]
-        except Exception as e:
-            log(f"decode overlap sweep failed: {type(e).__name__}: {e}")
-        try:
-            # routing comparison keys: gateway_routing_<policy>_<metric>
-            # (short policy names keep the payload readable)
-            short = {"round_robin": "rr", "least_loaded": "p2c",
-                     "least_loaded_affinity": "affinity"}
-            for policy, m in run_gateway_routing_bench().items():
-                p = short.get(policy, policy)
-                extra[f"gateway_routing_{p}_p95_wait_ms"] = m["p95_wait_ms"]
-                extra[f"gateway_routing_{p}_p95_ttft_ms"] = m["p95_ttft_ms"]
-                extra[f"gateway_routing_{p}_cache_hit_rate"] = \
-                    m["cache_hit_rate"]
-        except Exception as e:
-            log(f"gateway routing bench failed: {type(e).__name__}: {e}")
-        try:
-            # grey-failure defense keys: one 20x-slow replica out of
-            # four — no-breaker baseline vs breaker vs breaker+hedge
-            # (gateway/routing_sim.py simulate_degraded drives the real
-            # tracker/breaker/hedge-budget logic)
-            from dstack_tpu.gateway.routing_sim import degraded_comparison
+            extra["llama3_8b_projected_full_depth_tokens_per_sec_per_chip"] \
+                = round(projected, 1)
+        extra["serving_tokens_per_sec"] = round(run_serving_bench(), 1)
+        extra["serving_tokens_per_sec_int8"] = round(
+            run_serving_bench(quantize="int8"), 1)
+        extra["serving_tokens_per_sec_int8_32way"] = round(
+            run_serving_bench(quantize="int8", concurrency=32), 1)
+        ttft, bg_rate = run_ttft_bench()
+        extra["serving_ttft_mixed_load_ms"] = round(ttft * 1e3, 1)
+        extra["serving_decode_during_prefill_tokens_per_sec"] = \
+            round(bg_rate, 1)
+        # decode hot-loop arms: dense-paged baseline vs ragged buckets
+        # vs quantized KV, plus the TTFT pair (PR 18)
+        extra.update(run_decode_bench())
+        sweep = run_decode_overlap_sweep()
+        extra["serving_decode_overlap_best_k"] = sweep["k"]
+        extra["serving_decode_overlap_best_chunk"] = sweep["chunk"]
+        extra["serving_decode_overlap_tok_s"] = sweep["tok_s"]
+        # routing comparison keys: gateway_routing_<policy>_<metric>
+        # (short policy names keep the payload readable)
+        short = {"round_robin": "rr", "least_loaded": "p2c",
+                 "least_loaded_affinity": "affinity"}
+        for policy, m in run_gateway_routing_bench().items():
+            p = short.get(policy, policy)
+            extra[f"gateway_routing_{p}_p95_wait_ms"] = m["p95_wait_ms"]
+            extra[f"gateway_routing_{p}_p95_ttft_ms"] = m["p95_ttft_ms"]
+            extra[f"gateway_routing_{p}_cache_hit_rate"] = \
+                m["cache_hit_rate"]
+        # grey-failure defense keys: one 20x-slow replica out of four —
+        # no-breaker baseline vs breaker vs breaker+hedge
+        # (gateway/routing_sim.py simulate_degraded drives the real
+        # tracker/breaker/hedge-budget logic)
+        from dstack_tpu.gateway.routing_sim import (
+            degraded_comparison,
+            tracing_overhead,
+        )
 
-            deg = degraded_comparison()
-            extra["gateway_breaker_baseline_p99_ms"] = \
-                deg["baseline"]["p99_ms"]
-            extra["gateway_breaker_p99_ms"] = deg["breaker"]["p99_ms"]
-            extra["gateway_breaker_opened"] = deg["breaker"]["breaker_opened"]
-            extra["gateway_breaker_deadline_misses"] = \
-                deg["breaker"]["deadline_misses"]
-            extra["gateway_hedge_p99_ms"] = deg["breaker_hedge"]["p99_ms"]
-            extra["gateway_hedge_max_ms"] = deg["breaker_hedge"]["max_ms"]
-            extra["gateway_hedge_issued"] = \
-                deg["breaker_hedge"]["hedges_issued"]
-            log(f"degraded-replica sim: p99 baseline "
-                f"{deg['baseline']['p99_ms']:,.0f} ms -> breaker "
-                f"{deg['breaker']['p99_ms']:,.0f} ms -> breaker+hedge "
-                f"{deg['breaker_hedge']['p99_ms']:,.0f} ms "
-                f"(max {deg['breaker_hedge']['max_ms']:,.0f} ms, "
-                f"{deg['breaker_hedge']['hedges_issued']:.0f} hedges)")
-        except Exception as e:
-            log(f"degraded-replica sim failed: {type(e).__name__}: {e}")
-        try:
-            # tracing overhead, sim side: REAL span recording charged into
-            # the seeded routing sim's service times — pins the <2% p95
-            # TTFT claim with numbers in the payload
-            from dstack_tpu.gateway.routing_sim import tracing_overhead
+        deg = degraded_comparison()
+        extra["gateway_breaker_baseline_p99_ms"] = deg["baseline"]["p99_ms"]
+        extra["gateway_breaker_p99_ms"] = deg["breaker"]["p99_ms"]
+        extra["gateway_breaker_opened"] = deg["breaker"]["breaker_opened"]
+        extra["gateway_breaker_deadline_misses"] = \
+            deg["breaker"]["deadline_misses"]
+        extra["gateway_hedge_p99_ms"] = deg["breaker_hedge"]["p99_ms"]
+        extra["gateway_hedge_max_ms"] = deg["breaker_hedge"]["max_ms"]
+        extra["gateway_hedge_issued"] = deg["breaker_hedge"]["hedges_issued"]
+        log(f"degraded-replica sim: p99 baseline "
+            f"{deg['baseline']['p99_ms']:,.0f} ms -> breaker "
+            f"{deg['breaker']['p99_ms']:,.0f} ms -> breaker+hedge "
+            f"{deg['breaker_hedge']['p99_ms']:,.0f} ms "
+            f"(max {deg['breaker_hedge']['max_ms']:,.0f} ms, "
+            f"{deg['breaker_hedge']['hedges_issued']:.0f} hedges)")
+        # tracing overhead, sim side: REAL span recording charged into
+        # the seeded routing sim's service times — pins the <2% p95
+        # TTFT claim with numbers in the payload
+        ov = tracing_overhead()
+        extra["serving_tracing_overhead_p95_ttft_ms_off"] = \
+            ov["p95_ttft_ms_off"]
+        extra["serving_tracing_overhead_p95_ttft_ms_on"] = \
+            ov["p95_ttft_ms_on"]
+        extra["serving_tracing_overhead_p95_ttft_pct"] = \
+            ov["p95_ttft_overhead_pct"]
+        extra["serving_tracing_overhead_span_us"] = ov["span_us_per_request"]
+        log(f"tracing overhead (sim): p95 TTFT "
+            f"{ov['p95_ttft_ms_off']:,.1f} -> "
+            f"{ov['p95_ttft_ms_on']:,.1f} ms "
+            f"({ov['p95_ttft_overhead_pct']:+.3f}%, "
+            f"{ov['span_us_per_request']:.1f} us/req)")
+        # tracing overhead, engine side: telemetry-on vs telemetry+
+        # tracer tok/s on the real decode loop
+        tok_tel = run_serving_bench(telemetry="on")
+        tok_trace = run_serving_bench(telemetry="trace")
+        extra["serving_tracing_overhead_tok_s_off"] = round(tok_tel, 1)
+        extra["serving_tracing_overhead_tok_s_on"] = round(tok_trace, 1)
+        extra["serving_tracing_overhead_tok_s_pct"] = round(
+            (tok_tel - tok_trace) / tok_tel * 100.0, 2)
+        # robustness cost, train side: checkpoint cadence overhead +
+        # emergency-flush/restore latency (docs/concepts/resilience.md
+        # quotes these keys)
+        ro = run_resume_overhead_bench()
+        extra["train_resume_overhead_step_pct"] = ro["step_overhead_pct"]
+        extra["train_resume_overhead_emergency_flush_ms"] = \
+            ro["emergency_flush_ms"]
+        extra["train_resume_overhead_restore_ms"] = ro["restore_ms"]
+        # robustness cost, control-plane side: intent-journal recovery
+        # machinery — orphan-sweep latency, crash->restart convergence
+        # and the planted-orphan count (docs/concepts/resilience.md
+        # "Crash consistency" quotes these keys)
+        from dstack_tpu.server.recovery_bench import control_recovery_metrics
 
-            ov = tracing_overhead()
-            extra["serving_tracing_overhead_p95_ttft_ms_off"] = \
-                ov["p95_ttft_ms_off"]
-            extra["serving_tracing_overhead_p95_ttft_ms_on"] = \
-                ov["p95_ttft_ms_on"]
-            extra["serving_tracing_overhead_p95_ttft_pct"] = \
-                ov["p95_ttft_overhead_pct"]
-            extra["serving_tracing_overhead_span_us"] = \
-                ov["span_us_per_request"]
-            log(f"tracing overhead (sim): p95 TTFT "
-                f"{ov['p95_ttft_ms_off']:,.1f} -> "
-                f"{ov['p95_ttft_ms_on']:,.1f} ms "
-                f"({ov['p95_ttft_overhead_pct']:+.3f}%, "
-                f"{ov['span_us_per_request']:.1f} us/req)")
-        except Exception as e:
-            log(f"tracing overhead sim failed: {type(e).__name__}: {e}")
-        try:
-            # tracing overhead, engine side: telemetry-on vs telemetry+
-            # tracer tok/s on the real decode loop
-            tok_tel = run_serving_bench(telemetry="on")
-            tok_trace = run_serving_bench(telemetry="trace")
-            extra["serving_tracing_overhead_tok_s_off"] = round(tok_tel, 1)
-            extra["serving_tracing_overhead_tok_s_on"] = round(tok_trace, 1)
-            if tok_tel > 0 and tok_trace > 0:
-                extra["serving_tracing_overhead_tok_s_pct"] = round(
-                    (tok_tel - tok_trace) / tok_tel * 100.0, 2)
-        except Exception as e:
-            log(f"tracing overhead serving bench failed: "
-                f"{type(e).__name__}: {e}")
-        try:
-            # robustness cost, train side: checkpoint cadence overhead +
-            # emergency-flush/restore latency (docs/concepts/resilience.md
-            # quotes these keys)
-            ro = run_resume_overhead_bench()
-            extra["train_resume_overhead_step_pct"] = ro["step_overhead_pct"]
-            extra["train_resume_overhead_emergency_flush_ms"] = \
-                ro["emergency_flush_ms"]
-            extra["train_resume_overhead_restore_ms"] = ro["restore_ms"]
-        except Exception as e:
-            log(f"resume overhead bench failed: {type(e).__name__}: {e}")
-        try:
-            # robustness cost, control-plane side: intent-journal recovery
-            # machinery — orphan-sweep latency, crash->restart convergence
-            # and the planted-orphan count (docs/concepts/resilience.md
-            # "Crash consistency" quotes these keys)
-            from dstack_tpu.server.recovery_bench import (
-                control_recovery_metrics,
-            )
+        cr = control_recovery_metrics()
+        extra["control_recovery_orphan_sweep_ms"] = cr["orphan_sweep_ms"]
+        extra["control_recovery_restart_converge_ms"] = \
+            cr["restart_converge_ms"]
+        extra["control_recovery_orphans_swept"] = cr["orphans_swept"]
+        log(f"control recovery: sweep {cr['orphan_sweep_ms']:.1f} ms, "
+            f"restart-converge {cr['restart_converge_ms']:.1f} ms, "
+            f"{cr['orphans_swept']} orphans swept")
+        # scale, control-plane side: N server replicas over one DB
+        # under submit/preempt churn — cycle latency, scheduling
+        # throughput per replica count, and kill-one-of-two failover
+        # convergence (docs/concepts/resilience.md "Running N server
+        # replicas" quotes these keys)
+        from dstack_tpu.server.scale_bench import control_scale_metrics
 
-            cr = control_recovery_metrics()
-            extra["control_recovery_orphan_sweep_ms"] = cr["orphan_sweep_ms"]
-            extra["control_recovery_restart_converge_ms"] = \
-                cr["restart_converge_ms"]
-            extra["control_recovery_orphans_swept"] = cr["orphans_swept"]
-            log(f"control recovery: sweep {cr['orphan_sweep_ms']:.1f} ms, "
-                f"restart-converge {cr['restart_converge_ms']:.1f} ms, "
-                f"{cr['orphans_swept']} orphans swept")
-        except Exception as e:
-            log(f"control recovery bench failed: {type(e).__name__}: {e}")
-        try:
-            # scale, control-plane side: N server replicas over one DB
-            # under submit/preempt churn — cycle latency, scheduling
-            # throughput per replica count, and kill-one-of-two failover
-            # convergence (docs/concepts/resilience.md "Running N server
-            # replicas" quotes these keys)
-            from dstack_tpu.server.scale_bench import control_scale_metrics
+        cs = control_scale_metrics()
+        extra["control_scale_pipeline_cycle_ms"] = cs["pipeline_cycle_ms"]
+        extra["control_scale_runs_per_s"] = cs["runs_per_s"]
+        extra["control_scale_converge_ms"] = cs["converge_ms"]
+        extra["control_scale_converge_bound_ms"] = cs["converge_bound_ms"]
+        for n, m in cs["per_replicas"].items():
+            extra[f"control_scale_runs_per_s_{n}r"] = m["runs_per_s"]
+            extra[f"control_scale_pipeline_cycle_ms_{n}r"] = \
+                m["pipeline_cycle_ms"]
+        log(f"control scale: {cs['runs_per_s']:,.0f} runs/s @2r, "
+            f"cycle {cs['pipeline_cycle_ms']:.1f} ms, kill-converge "
+            f"{cs['converge_ms']:.0f} ms "
+            f"(bound {cs['converge_bound_ms']:.0f} ms)")
+        # observability cost, control-plane side: one SLO evaluator
+        # cycle (burn-rate math over timeseries window queries) at a
+        # 10k-series store load, plus the raw->1m->10m rollup fold
+        # (docs/concepts/observability.md "SLOs & alerting" quotes
+        # these keys)
+        from dstack_tpu.server.slo_bench import slo_eval_metrics
 
-            cs = control_scale_metrics()
-            extra["control_scale_pipeline_cycle_ms"] = cs["pipeline_cycle_ms"]
-            extra["control_scale_runs_per_s"] = cs["runs_per_s"]
-            extra["control_scale_converge_ms"] = cs["converge_ms"]
-            extra["control_scale_converge_bound_ms"] = cs["converge_bound_ms"]
-            for n, m in cs["per_replicas"].items():
-                extra[f"control_scale_runs_per_s_{n}r"] = m["runs_per_s"]
-                extra[f"control_scale_pipeline_cycle_ms_{n}r"] = \
-                    m["pipeline_cycle_ms"]
-            log(f"control scale: {cs['runs_per_s']:,.0f} runs/s @2r, "
-                f"cycle {cs['pipeline_cycle_ms']:.1f} ms, kill-converge "
-                f"{cs['converge_ms']:.0f} ms "
-                f"(bound {cs['converge_bound_ms']:.0f} ms)")
-        except Exception as e:
-            log(f"control scale bench failed: {type(e).__name__}: {e}")
-        try:
-            # observability cost, control-plane side: one SLO evaluator
-            # cycle (burn-rate math over timeseries window queries) at a
-            # 10k-series store load, plus the raw->1m->10m rollup fold
-            # (docs/concepts/observability.md "SLOs & alerting" quotes
-            # these keys)
-            from dstack_tpu.server.slo_bench import slo_eval_metrics
-
-            se = slo_eval_metrics()
-            extra["slo_eval_cycle_ms"] = se["slo_eval_cycle_ms"]
-            extra["slo_eval_series"] = se["slo_eval_series"]
-            extra["slo_eval_alerts_checked"] = se["slo_eval_alerts_checked"]
-            extra["slo_rollup_ms"] = se["slo_rollup_ms"]
-            log(f"slo eval: cycle {se['slo_eval_cycle_ms']:.1f} ms over "
-                f"{se['slo_eval_series']:,} series "
-                f"({se['slo_eval_alerts_checked']} objectives checked), "
-                f"rollup {se['slo_rollup_ms']:.1f} ms")
-        except Exception as e:
-            log(f"slo bench failed: {type(e).__name__}: {e}")
-        try:
-            # robustness cost, serving side: drain-and-migrate dead time
-            # and the zero-drop invariant as a measured number
-            dm = run_drain_migrate_bench()
-            extra["serving_drain_migrate_drain_ms"] = dm["drain_ms"]
-            extra["serving_drain_migrate_successor_gap_ms"] = \
-                dm["successor_gap_ms"]
-            extra["serving_drain_migrate_dropped_streams"] = \
-                dm["dropped_streams"]
-        except Exception as e:
-            log(f"drain-migrate bench failed: {type(e).__name__}: {e}")
-        try:
-            # elasticity cost: cold start vs compile-cache hit vs
-            # pre-warmed standby activation, decomposed into the
-            # weights/compile/warmup legs (docs/concepts/elasticity.md
-            # quotes these keys)
-            extra.update(run_coldstart_bench())
-        except Exception as e:
-            log(f"coldstart bench failed: {type(e).__name__}: {e}")
-        try:
-            # digital-twin replay: golden-workload percentiles + wall
-            # cost, and the defended-vs-baseline grey-slow ordering on
-            # replayed load (docs/concepts/simulation.md quotes these)
-            extra.update(run_twin_bench())
-        except Exception as e:
-            log(f"twin bench failed: {type(e).__name__}: {e}")
-        provision = run_provision_bench()
-        if provision is not None:
-            extra["provision_to_first_step_sec"] = round(provision, 2)
+        se = slo_eval_metrics()
+        extra["slo_eval_cycle_ms"] = se["slo_eval_cycle_ms"]
+        extra["slo_eval_series"] = se["slo_eval_series"]
+        extra["slo_eval_alerts_checked"] = se["slo_eval_alerts_checked"]
+        extra["slo_rollup_ms"] = se["slo_rollup_ms"]
+        log(f"slo eval: cycle {se['slo_eval_cycle_ms']:.1f} ms over "
+            f"{se['slo_eval_series']:,} series "
+            f"({se['slo_eval_alerts_checked']} objectives checked), "
+            f"rollup {se['slo_rollup_ms']:.1f} ms")
+        # robustness cost, serving side: drain-and-migrate dead time
+        # and the zero-drop invariant as a measured number
+        dm = run_drain_migrate_bench()
+        extra["serving_drain_migrate_drain_ms"] = dm["drain_ms"]
+        extra["serving_drain_migrate_successor_gap_ms"] = \
+            dm["successor_gap_ms"]
+        extra["serving_drain_migrate_dropped_streams"] = \
+            dm["dropped_streams"]
+        # elasticity cost: cold start vs compile-cache hit vs
+        # pre-warmed standby activation, decomposed into the
+        # weights/compile/warmup legs (docs/concepts/elasticity.md
+        # quotes these keys)
+        extra.update(run_coldstart_bench())
+        # digital-twin replay: golden-workload percentiles + wall
+        # cost, and the defended-vs-baseline grey-slow ordering on
+        # replayed load (docs/concepts/simulation.md quotes these)
+        extra.update(run_twin_bench())
+        extra["provision_to_first_step_sec"] = round(
+            run_provision_bench(), 2)
 
     out = {
         "metric": METRIC,
         "value": round(value, 1),
         "unit": "tokens/sec/chip",
         "vs_baseline": _vs_baseline(value),
+        "device": device,
     }
     if extra:
         out["extra"] = extra

@@ -68,7 +68,6 @@ DEFAULT_AXIS_NAMES: FrozenSet[str] = frozenset(
 TRACER_NAMES = frozenset({
     "shard_map", "jax.shard_map",
     "jax.experimental.shard_map.shard_map", "jax.experimental.shard_map",
-    "jax_compat.shard_map", "dstack_tpu.utils.jax_compat.shard_map",
     "pmap", "jax.pmap",
 })
 

@@ -41,9 +41,8 @@ SCOPE_PREFIXES = (
 
 TRACER_ENTRY_POINTS = {
     "jax.jit", "jit", "pjit", "jax.pmap", "pmap",
-    "shard_map", "jax.experimental.shard_map.shard_map",
-    "jax.experimental.shard_map", "jax_compat.shard_map",
-    "dstack_tpu.utils.jax_compat.shard_map",
+    "shard_map", "jax.shard_map", "jax.experimental.shard_map.shard_map",
+    "jax.experimental.shard_map",
 }
 
 #: attribute reads on a traced array that are static at trace time

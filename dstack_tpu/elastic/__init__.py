@@ -1,7 +1,7 @@
 """Instant elasticity: kill the three legs of replica cold start.
 
-Every bench log shows 11.8-17.4 s of XLA compile+warmup per replica
-(BENCH_r05), and a real scale-up additionally pays provision + image
+An earlier v5e run (2026-08-01) showed 11.8-17.4 s of XLA compile+warmup
+per replica, and a real scale-up additionally pays provision + image
 pull + cold GCS weight load.  This package makes each leg skippable:
 
 ``compile_cache``

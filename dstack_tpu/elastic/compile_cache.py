@@ -2,8 +2,8 @@
 
 The compile leg of a replica cold start is pure waste after the first
 replica: every peer lowers the *same* HLO on the *same* topology and
-pays the same 11.8-17.4 s (BENCH_r05) to get the byte-identical
-executable.  This cache serializes the executable once
+pays the same 11.8-17.4 s (an earlier v5e run, 2026-08-01) to get the
+byte-identical executable.  This cache serializes the executable once
 (``jax.experimental.serialize_executable``) and keys it by content —
 ``sha256(HLO text + topology fingerprint + jax/jaxlib versions)`` — so
 a hit is correct by construction: any input that would compile

@@ -36,7 +36,6 @@ from dstack_tpu.ops.attention import KVCache, causal_attention, decode_step_atte
 from dstack_tpu.ops.ring_attention import ring_attention_sharded
 from dstack_tpu.ops.rmsnorm import rms_norm
 from dstack_tpu.ops.rotary import RopeScaling, apply_rope, rope_frequencies
-from dstack_tpu.utils.jax_compat import get_abstract_mesh, shard_map
 
 Params = dict[str, Any]
 
@@ -259,7 +258,7 @@ def _constrain(x, mesh: Optional[Mesh], spec: P):
     # constraints must be built on the ambient abstract mesh (the concrete
     # mesh's all-Auto axis types no longer match and the backward pass
     # rejects the mismatch); the spec itself only names Auto axes either way.
-    cur = get_abstract_mesh()
+    cur = jax.sharding.get_abstract_mesh()
     if cur.axis_names:
         mesh = cur
     return lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
@@ -291,7 +290,7 @@ def _embed_lookup(embed, tokens, mesh: Optional[Mesh], policy: ShardingPolicy):
         x = emb[jnp.clip(ids, 0, vlocal - 1)]
         return lax.psum(jnp.where(valid[..., None], x, 0), t)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(t, None), P(policy.batch_axes, policy.seq_axis)),
         out_specs=P(policy.batch_axes, policy.seq_axis, None),

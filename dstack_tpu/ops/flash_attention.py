@@ -22,7 +22,9 @@ Shapes and constraints:
   :func:`supports`), which caps S at ~8k for D=64 bf16 — long-context goes
   through ring attention (:mod:`dstack_tpu.ops.ring_attention`).
 
-Off-TPU (tests run on a CPU mesh) the kernels run in interpreter mode.
+On the CPU backend (the tests' virtual mesh) the kernels run in interpreter
+mode; every other backend compiles them, so a device the kernels were not
+written for fails at compile time instead of silently interpreting.
 """
 
 from __future__ import annotations
@@ -35,13 +37,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 import os as _os
-from dstack_tpu.utils.jax_compat import get_abstract_mesh, shard_map
 
 _NEG_INF = -1e30
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _block_sizes(seq: int) -> tuple[int, int]:
@@ -664,14 +665,14 @@ def flash_attention_sharded(mesh, q, k, v, *, batch_axes=("dcn", "data", "fsdp")
     from jax.sharding import PartitionSpec as P
     spec = P(batch_axes, None, head_axis, None)
     kwargs = {}
-    cur = get_abstract_mesh()
+    cur = jax.sharding.get_abstract_mesh()
     if cur.axis_names:
         # nested inside a manual region: use the ambient mesh and only
         # manualize this wrapper's own axes (top-level calls keep the
         # default all-axes-manual form)
         mesh = cur
         kwargs["axis_names"] = {a for a in (*batch_axes, head_axis) if a}
-    fn = shard_map(
+    fn = jax.shard_map(
         flash_attention, mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False, **kwargs,
@@ -688,11 +689,19 @@ def flash_attention_sharded(mesh, q, k, v, *, batch_axes=("dcn", "data", "fsdp")
 # scalar-prefetched block tables.  The XLA paged path first gathers each
 # slot's blocks into a dense [B, span, Hkv, D] view — at a 4k span that
 # gather IS the decode step's non-weight HBM bill, and it reads padding for
-# every slot shorter than the span.  Here the grid walks (slot, kv head,
-# table column) and the BlockSpec index_map turns the table entry into the
-# page address, so only owned pages cross HBM, exactly once, with no
+# every slot shorter than the span.  Here the grid walks (slot, table
+# column) and the BlockSpec index_map turns the table entry into the page
+# address, so only owned pages cross HBM, exactly once, with no
 # intermediate view.  int8 KV pages ({"q","s"} per serving/quant.py)
 # dequantize in-kernel after the page load — packed bytes are what stream.
+#
+# A block is one WHOLE page, all kv heads: the pool's [BS, Hkv, D] page is
+# viewed as [BS, Hkv*D] (a free reshape of contiguous memory), so the
+# block's last two dims equal the array's — the only shape the TPU
+# lowering accepts here (one head of a page would put 1 of Hkv in the
+# second-to-last dim, which is neither full nor a multiple of 8; the
+# described-v5e compile tests in tests/compute/test_tpu_compile.py hold
+# this).  The kernel walks the heads with static lane slices.
 #
 # Returns a NORMALIZED output plus the softmax logsumexp so the caller can
 # merge other attention pieces (the engine's in-window KV buffer) without
@@ -703,12 +712,13 @@ def flash_attention_sharded(mesh, q, k, v, *, batch_axes=("dcn", "data", "fsdp")
 def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *rest,
                          scale, bs, nbk, quant):
     del tables_ref  # consumed by the index maps
+    _, hkv, _, d = q_ref.shape
     if quant:
         k_ref, ks_ref, v_ref, vs_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
     else:
         k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -720,39 +730,40 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *rest,
 
     @pl.when(i * bs < length)
     def _compute():
-        q = q_ref[0, 0]            # [G, D]
-        k = k_ref[0, :, 0, :]      # [BS, D]
-        v = v_ref[0, :, 0, :]
-        if quant:
-            k = (k.astype(jnp.float32)
-                 * ks_ref[0, :, 0][:, None]).astype(q.dtype)
-            v = (v.astype(jnp.float32)
-                 * vs_ref[0, :, 0][:, None]).astype(q.dtype)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                  # [G, BS]
         kpos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(kpos < length, s, _NEG_INF)
-        # at least one column is valid here (i*bs < length), so m_new is
-        # finite and the m_prev = -inf first block gives alpha = 0 cleanly
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc[...] = acc[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = m_new
+        for h in range(hkv):
+            q = q_ref[0, h]                       # [G, D]
+            k = k_ref[0, :, h * d:(h + 1) * d]    # [BS, D]
+            v = v_ref[0, :, h * d:(h + 1) * d]
+            if quant:
+                k = (k.astype(jnp.float32)
+                     * ks_ref[0, :, h:h + 1]).astype(q.dtype)
+                v = (v.astype(jnp.float32)
+                     * vs_ref[0, :, h:h + 1]).astype(q.dtype)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale              # [G, BS]
+            s = jnp.where(kpos < length, s, _NEG_INF)
+            # at least one column is valid here (i*bs < length), so m_new
+            # is finite and the m_prev = -inf first block gives alpha = 0
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc[h] = acc[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[h] = m_new
 
     @pl.when(i == nbk - 1)
     def _flush():
         l = l_scr[...]
         safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = (acc[...] / safe_l).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(
+        o_ref[0] = (acc[...] / safe_l).astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(
             l > 0, m_scr[...] + jnp.log(safe_l), _NEG_INF)
 
 
@@ -771,6 +782,9 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
     length == 0, with o = 0) for logsumexp-merging window/new-token
     attention on the caller side.  int4 pages are not supported — the
     engine keeps those on the XLA gather path.
+
+    The kernel runs per device: under a mesh, call it inside ``shard_map``
+    with the kv-head dim sharded (the engine does).
     """
     quant = isinstance(k_pages, dict)
     if quant and "q4" in k_pages:
@@ -779,31 +793,30 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
             "use the XLA gather path")
     b, hkv, group, d = q.shape
     nbk = tables.shape[1]
-    kq = k_pages["q"] if quant else k_pages
-    bs = kq.shape[1]
+    kq, vq = (k_pages["q"], v_pages["q"]) if quant else (k_pages, v_pages)
+    num_blocks, bs = kq.shape[:2]
     if scale is None:
         scale = d ** -0.5
 
-    def page(block, prev=None):
-        del prev
-        # the table entry IS the page index; h walks kv heads in place
-        return pl.BlockSpec(
-            block, lambda bb, h, i, tables, lengths: (tables[bb, i], 0, h)
-            + (0,) * (len(block) - 3),
-            memory_space=pltpu.VMEM)
+    def whole(bb, i, tables, lengths):
+        return (bb, 0, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, group, d),
-                     lambda bb, h, i, tables, lengths: (bb, h, 0, 0),
-                     memory_space=pltpu.VMEM),
-    ]
+    def page(bb, i, tables, lengths):
+        return (tables[bb, i], 0, 0)  # the table entry IS the page index
+
+    def spec(block, index_map):
+        return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+
+    flat = (num_blocks, bs, hkv * d)
+    k_spec = spec((1, bs, hkv * d), page)
     if quant:
-        inputs = (q, kq, k_pages["s"], v_pages["q"], v_pages["s"])
-        in_specs += [page((1, bs, 1, d)), page((1, bs, 1)),
-                     page((1, bs, 1, d)), page((1, bs, 1))]
+        s_spec = spec((1, bs, hkv), page)
+        inputs = (q, kq.reshape(flat), k_pages["s"],
+                  vq.reshape(flat), v_pages["s"])
+        in_specs = [k_spec, s_spec, k_spec, s_spec]
     else:
-        inputs = (q, k_pages, v_pages)
-        in_specs += [page((1, bs, 1, d)), page((1, bs, 1, d))]
+        inputs = (q, kq.reshape(flat), vq.reshape(flat))
+        in_specs = [k_spec, k_spec]
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale, bs=bs,
                                nbk=nbk, quant=quant)
@@ -811,20 +824,14 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, hkv, nbk),
-            in_specs=in_specs,
-            out_specs=[
-                pl.BlockSpec((1, 1, group, d),
-                             lambda bb, h, i, tables, lengths: (bb, h, 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1, group, 1),
-                             lambda bb, h, i, tables, lengths: (bb, h, 0, 0),
-                             memory_space=pltpu.VMEM),
-            ],
+            grid=(b, nbk),
+            in_specs=[spec((1, hkv, group, d), whole)] + in_specs,
+            out_specs=[spec((1, hkv, group, d), whole),
+                       spec((1, hkv, group, 1), whole)],
             scratch_shapes=[
-                pltpu.VMEM((group, d), jnp.float32),
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((hkv, group, d), jnp.float32),
+                pltpu.VMEM((hkv, group, 1), jnp.float32),
+                pltpu.VMEM((hkv, group, 1), jnp.float32),
             ],
         ),
         out_shape=[

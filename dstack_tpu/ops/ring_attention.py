@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from dstack_tpu.utils.jax_compat import shard_map
 
 _NEG_INF = jnp.float32(-1e30)
 
@@ -124,7 +123,7 @@ def ring_attention_sharded(
     ``head_axis``, sequence over ``seq_axis``.
     """
     spec = P(batch_axes, seq_axis, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name=seq_axis),
         mesh=mesh,
         in_specs=(spec, spec, spec),

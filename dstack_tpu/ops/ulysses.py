@@ -34,7 +34,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dstack_tpu.ops import flash_attention as flash
 from dstack_tpu.ops.attention import causal_attention
-from dstack_tpu.utils.jax_compat import shard_map
 
 
 def ulysses_attention(
@@ -87,7 +86,7 @@ def ulysses_attention_sharded(
     ``head_axis`` (tensor parallelism composes — the all-to-all then swaps
     the *remaining* head slice), sequence over ``seq_axis``."""
     spec = P(batch_axes, seq_axis, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(ulysses_attention, axis_name=seq_axis),
         mesh=mesh,
         in_specs=(spec, spec, spec),

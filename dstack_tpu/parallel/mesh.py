@@ -181,12 +181,8 @@ def build_mesh(
         )
     shape = tuple(spec.sizes[a] for a in AXIS_ORDER)
     dev_array = np.asarray(devices).reshape(shape)
-    if hasattr(jax.sharding, "AxisType"):
-        axis_types = (jax.sharding.AxisType.Auto,) * len(AXIS_ORDER)
-        return Mesh(dev_array, AXIS_ORDER, axis_types=axis_types)
-    # older jax (< 0.5): meshes have no axis_types — Auto is the only
-    # semantics, so the plain constructor is equivalent
-    return Mesh(dev_array, AXIS_ORDER)
+    axis_types = (jax.sharding.AxisType.Auto,) * len(AXIS_ORDER)
+    return Mesh(dev_array, AXIS_ORDER, axis_types=axis_types)
 
 
 def local_mesh(spec: Optional[MeshSpec] = None) -> Mesh:

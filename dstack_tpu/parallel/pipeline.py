@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from dstack_tpu.utils.jax_compat import shard_map
 
 Carry = Any  # activation pytree flowing through the layer stack
 
@@ -125,7 +124,7 @@ def pipeline_layers(
         return outs.reshape(x.shape)
 
     layer_specs = jax.tree.map(lambda _: P(stage_axis), layers)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(layer_specs, P()),
         out_specs=P(),

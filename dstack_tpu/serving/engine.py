@@ -56,7 +56,8 @@ def _paged_kernel_default() -> bool:
     kernel (ops/flash_attention.py paged_decode_attention) instead of the
     XLA gather path.  ``DSTACK_TPU_PAGED_ATTN_KERNEL``: "auto" (default —
     on for a real TPU backend, off for CPU/interpret where the XLA path
-    wins), "1"/"0" to force."""
+    wins), "1"/"0" to force.  Whichever is chosen is the only path: a
+    kernel the compiler refuses fails the decode, nothing falls back."""
     v = os.environ.get("DSTACK_TPU_PAGED_ATTN_KERNEL", "auto")
     if v == "auto":
         return jax.default_backend() == "tpu"
@@ -429,7 +430,7 @@ class InferenceEngine:
         consulted before every jit lowering — a scaling-up replica whose
         programs a peer already compiled deserializes them in
         milliseconds instead of paying the 11.8-17.4 s compile leg
-        (BENCH_r05).  Defaults to the env-configured cache
+        (an earlier v5e run, 2026-08-01).  Defaults to the env-configured cache
         (``DSTACK_COMPILE_CACHE`` / ``DSTACK_COMPILE_CACHE_PEERS``);
         both unset → no caching, the plain jit path.  Hit/miss counters
         surface on ``/load`` and ``/stats``.
@@ -1572,6 +1573,26 @@ class InferenceEngine:
         else:
             view_k, view_v = cache_k, cache_v
 
+        if use_kernel:
+            from dstack_tpu.ops.flash_attention import (
+                paged_decode_attention as paged_attn,
+            )
+
+            if self.mesh is not None:
+                # a Pallas call is opaque to GSPMD: run it per device over
+                # the kv-head shards the cache already has (_kv_sharding)
+                from jax.sharding import PartitionSpec as P
+
+                t = self._policy.tensor_axis
+                heads = P(None, t, None, None)    # q, o: [B, Hkv, G, D]
+                pages = P(None, None, t, None)    # [NUM_BLOCKS, BS, Hkv, D]
+                if self.kv_quant:
+                    pages = {"q": pages, "s": P(None, None, t)}
+                paged_attn = jax.shard_map(
+                    paged_attn, mesh=self.mesh,
+                    in_specs=(heads, pages, pages, P(), P()),
+                    out_specs=(heads, P(None, t, None)), check_vma=False)
+
         win_shape = (cfg.num_layers, w, b, hkv, cfg.head_dim)
         win_k0 = jnp.zeros(win_shape, cfg.dtype)
         win_v0 = jnp.zeros(win_shape, cfg.dtype)
@@ -1600,12 +1621,8 @@ class InferenceEngine:
                     # o + logsumexp per slot), window half in XLA, merged
                     # by logsumexp — numerically the same attention set,
                     # reduction order aside
-                    from dstack_tpu.ops.flash_attention import (
-                        paged_decode_attention,
-                    )
-
-                    o_c, lse_c = paged_decode_attention(
-                        qg, layer_k, layer_v, tables, base_len, scale=scale)
+                    o_c, lse_c = paged_attn(
+                        qg, layer_k, layer_v, tables, base_len)
                     s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
                     s_w = jnp.where(win_mask, s_w,
                                     -1e30).astype(jnp.float32)

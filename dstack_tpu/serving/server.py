@@ -28,6 +28,11 @@ from dstack_tpu.serving.tokenizer import load_tokenizer
 from dstack_tpu.serving.wire import PD_PHASE_HEADER
 from dstack_tpu.telemetry import tracing
 from dstack_tpu.telemetry.serving import load_headers
+from dstack_tpu.utils.jax_runtime import (
+    device_memory,
+    device_report,
+    enable_persistent_cache,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -87,6 +92,10 @@ class ServingApp:
         #: still compiling/warming — reported on /load as ``warming`` so
         #: routers and admission never count this replica as capacity
         self.warming = False
+        #: set when the warmup raised: the replica never starts serving and
+        #: /health answers 503 with this text (it also stays ``warming``,
+        #: so /v1 keeps refusing and no router counts it as capacity)
+        self.warmup_error: Optional[str] = None
         self._activated_at: Optional[float] = None
         #: request tracer (telemetry/tracing.py) — rides the engine's
         #: telemetry so the scheduler spans and the HTTP spans share one
@@ -102,7 +111,10 @@ class ServingApp:
         request on a background thread (compiling every needed program,
         or pulling it from the compile cache) with ``warming`` visible on
         ``/load`` the whole time, then starts the loop.  The warmup runs
-        BEFORE the engine thread so the two never race ``step()``."""
+        BEFORE the engine thread so the two never race ``step()``.  A
+        warmup that raises (a program the compiler refuses, no memory) is
+        what every request would hit next: the loop is never started and
+        ``/health`` reports the error instead of ``ok``."""
         if not warm:
             self._thread.start()
             return
@@ -111,11 +123,12 @@ class ServingApp:
         def _warm() -> None:
             try:
                 self.engine.warmup()
-            except Exception:  # noqa: BLE001 — warming must not wedge
-                logger.exception("standby warmup failed")
-            finally:
-                self.warming = False
-                self._thread.start()
+            except Exception as e:  # noqa: BLE001 — reported on /health
+                logger.exception("warmup failed; this replica will not serve")
+                self.warmup_error = f"{type(e).__name__}: {e}"
+                return
+            self.warming = False
+            self._thread.start()
 
         threading.Thread(target=_warm, daemon=True,
                          name="engine-warm").start()
@@ -512,11 +525,16 @@ class ServingApp:
         wedged = self._wedged_response()
         if wedged is not None:
             return wedged
-        status = ("warming" if (self.warming or self.standby)
+        status = ("error" if self.warmup_error is not None
+                  else "warming" if (self.warming or self.standby)
                   else "draining"
                   if getattr(self.engine, "draining", False)
                   else "ok")
-        out = {"status": status, "model": self.model_name}
+        out = {"status": status, "model": self.model_name,
+               "device": device_report()}
+        if self.warmup_error is not None:
+            out["error"] = self.warmup_error
+            return web.json_response(out, status=503)
         if self.engine.speculation:
             # snapshot once: the engine thread mutates these, and the rate
             # must equal accepted/steps OF THIS RESPONSE
@@ -595,6 +613,10 @@ class ServingApp:
             out["compile_cache"] = cache.snapshot()
         out["warming"] = bool(self.warming)
         out["standby"] = bool(self.standby)
+        out["device_memory"] = device_memory()
+        if getattr(self.engine, "prefix_cache", False):
+            # the allocator's own counters: lookups, hit_blocks, evictions
+            out["prefix_cache"] = dict(self.engine._alloc.stats)
         if self.engine.speculation:
             steps = self.engine.spec_stats["steps"]
             accepted = self.engine.spec_stats["accepted"]
@@ -1014,6 +1036,9 @@ def main() -> None:
     args = parser.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    jax_cache_dir = enable_persistent_cache()
+    logger.info("device %s; jax compilation cache %s",
+                json.dumps(device_report()), jax_cache_dir)
     params = None
     model_name = args.model_name or args.config
     if args.checkpoint:

@@ -15,8 +15,10 @@ serving set):
   percentile; it is counted in ``recompiles_total`` instead)
 - ``steps_total`` / ``tokens_total`` / ``recompiles_total`` counters
 - ``tokens_per_sec`` / ``mfu`` gauges — from the last measured step;
-  MFU = 6 * params * tokens / wall / peak (the ROOFLINE.md convention,
-  peak defaulting to the v5e 197 TF/s bf16 figure)
+  MFU = 6 * params * tokens / wall / peak (the ROOFLINE.md convention),
+  the peak being that of the device the step runs on
+  (:data:`PEAK_BF16_FLOPS`); on a device the table does not know the
+  gauge stays 0 rather than borrowing another chip's peak
 """
 
 from __future__ import annotations
@@ -29,9 +31,13 @@ from dstack_tpu.telemetry.recorder import MetricsRecorder
 
 logger = logging.getLogger(__name__)
 
-#: v5e per-chip bf16 matmul peak (ROOFLINE.md; bench.py uses the same
-#: constant for its MFU column)
+#: v5e per-chip bf16 matmul peak (Google Cloud documentation, "TPU v5e")
 V5E_PEAK_BF16_FLOPS = 197e12
+
+#: per-chip bf16 matmul peaks keyed by ``jax.Device.device_kind`` — the one
+#: table bench.py's MFU column and this module's gauge divide by.  A device
+#: that is not here has NO peak: MFU is then not computed.
+PEAK_BF16_FLOPS = {"TPU v5 lite": V5E_PEAK_BF16_FLOPS}
 
 #: step-time buckets: 10 ms .. 120 s (covers tiny CPU test shapes through
 #: full-depth multi-chip steps)
@@ -45,9 +51,11 @@ class TrainTelemetry:
     """Recorder + the ``wrap()`` factory that instruments a jitted step."""
 
     def __init__(self, num_params: Optional[int] = None,
-                 peak_flops: float = V5E_PEAK_BF16_FLOPS,
+                 peak_flops: Optional[float] = None,
                  log_every: int = 50) -> None:
         self.num_params = num_params
+        #: None until :meth:`wrap` looks the running device up in
+        #: :data:`PEAK_BF16_FLOPS` (and still None if it is not there)
         self.peak_flops = peak_flops
         self.log_every = log_every
         self.recorder = MetricsRecorder()
@@ -65,12 +73,16 @@ class TrainTelemetry:
         """Wrap a (jitted) ``(state, batch) -> (state, metrics)`` step.
 
         ``cfg`` supplies ``num_params()`` when the telemetry was built
-        without an explicit parameter count; without either, MFU stays 0
-        and the timing metrics still record.  ``n_devices`` divides the
-        model FLOPs for per-chip MFU under a mesh.
+        without an explicit parameter count; without either — or on a
+        device with no known peak — MFU stays 0 and the timing metrics
+        still record.  ``n_devices`` divides the model FLOPs for per-chip
+        MFU under a mesh.
         """
         import jax
 
+        if self.peak_flops is None:
+            self.peak_flops = PEAK_BF16_FLOPS.get(
+                jax.devices()[0].device_kind)
         if self.num_params is None and cfg is not None:
             try:
                 self.num_params = int(cfg.num_params())
@@ -124,7 +136,7 @@ class TrainTelemetry:
         if wall > 0 and tokens:
             per_chip = tokens / wall / max(n_devices, 1)
             self.tokens_per_sec.set(tokens / wall)
-            if self.num_params:
+            if self.num_params and self.peak_flops:
                 self.mfu.set(6.0 * self.num_params * per_chip
                              / self.peak_flops)
         n = int(self.steps_total.value)

@@ -242,7 +242,7 @@ def orderings_ok(out):
             and out["serving_decode_int8_ttft_ms"]
             <= 1.05 * out["serving_decode_dense_ttft_ms"])
 
-out = run_decode_bench()
+out = run_decode_bench(small=True)
 for arm in TOK:
     assert f"serving_decode_{arm}_tok_s" in out, (arm, out)
 for arm in TTFT:
@@ -250,7 +250,7 @@ for arm in TTFT:
 for attempt in range(2):
     if orderings_ok(out):
         break
-    rerun = run_decode_bench()
+    rerun = run_decode_bench(small=True)
     for arm in TOK:
         k = f"serving_decode_{arm}_tok_s"
         out[k] = max(out[k], rerun[k])

@@ -3,6 +3,7 @@ reported distinct from draining on /load, the compile-cache and weight
 seed routes serve peers, and the standby lifecycle runs over HTTP."""
 
 import json
+import threading
 
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
@@ -236,5 +237,59 @@ async def test_standby_activation_over_http(setup):
         # idempotent: a second activate succeeds but reports no flip
         r = await client.post("/elastic/standby/activate")
         assert (await r.json())["activated"] is False
+    finally:
+        await client.close()
+
+
+async def test_failed_warmup_never_reports_ok(setup):
+    """A warmup that raises (a program the chip's compiler refuses) is what
+    every request would hit next: the replica must not log it and serve.
+    /health answers 503 with the error, /v1 keeps refusing, /load keeps it
+    out of routable capacity, and the engine loop is never started."""
+    from dstack_tpu.serving.server import ServingApp
+
+    cfg, params = setup
+    engine = _make_engine(cfg, params)
+
+    def refused(*a, **kw):
+        raise ValueError("Pallas TPU lowering: block shape refused")
+
+    engine.warmup = refused
+    app = ServingApp(engine, _Tok())
+    app.start_engine(warm=True)
+    for t in threading.enumerate():
+        if t.name == "engine-warm":
+            t.join(timeout=30)
+    client = await _serve(app)
+    try:
+        r = await client.get("/health")
+        assert r.status == 503
+        body = await r.json()
+        assert body["status"] == "error"
+        assert "block shape refused" in body["error"]
+        assert not app._thread.is_alive()
+        r = await client.post("/v1/completions",
+                              json={"prompt": "hi", "max_tokens": 1})
+        assert r.status == 503
+        assert (await (await client.get("/load")).json())["warming"] == 1
+    finally:
+        await client.close()
+
+
+async def test_health_names_the_device(setup):
+    """/health carries the device the serving process computes on, as JAX
+    reports it — what chip_smoke.py and the benchmark read."""
+    import jax
+
+    from dstack_tpu.serving.server import ServingApp
+
+    cfg, params = setup
+    app = ServingApp(_make_engine(cfg, params), _Tok())
+    client = await _serve(app)
+    try:
+        body = await (await client.get("/health")).json()
+        d = jax.devices()
+        assert body["device"] == {"platform": d[0].platform,
+                                  "kind": d[0].device_kind, "count": len(d)}
     finally:
         await client.close()

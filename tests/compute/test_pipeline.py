@@ -14,20 +14,11 @@ from dstack_tpu.models import llama, train
 from dstack_tpu.parallel.mesh import MeshSpec, build_mesh
 from dstack_tpu.parallel.pipeline import pipeline_layers
 
-#: the partial-manual stage region lowers axis_index -> PartitionId, which
-#: jaxlib < 0.5's SPMD partitioner rejects as UNIMPLEMENTED (same gate as
-#: __graft_entry__.dryrun_multichip); validation-only tests still run
-_NEEDS_MODERN_SHARD_MAP = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map needs jax >= 0.5 (PartitionId UNIMPLEMENTED)",
-)
-
 
 def _mesh(stage=4, fsdp=2):
     return build_mesh(MeshSpec(stage=stage, fsdp=fsdp), jax.devices("cpu")[: stage * fsdp])
 
 
-@_NEEDS_MODERN_SHARD_MAP
 def test_pipeline_layers_matches_scan():
     mesh = _mesh()
     d, L, B, S = 16, 8, 8, 4
@@ -45,7 +36,6 @@ def test_pipeline_layers_matches_scan():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
 
 
-@_NEEDS_MODERN_SHARD_MAP
 def test_pipeline_layers_grad_matches():
     mesh = _mesh()
     d, L, B, S = 8, 4, 4, 2
@@ -133,7 +123,6 @@ def test_pipeline_combined_with_ring_attention_rejected():
                       mesh=mesh, policy=policy)
 
 
-@_NEEDS_MODERN_SHARD_MAP
 def test_pipeline_with_flash_attention_matches_unpipelined(monkeypatch):
     """The fused flash kernel nests inside the pipeline's manual region
     (its shard_map resolves the ambient mesh and manualizes only its own

@@ -134,6 +134,25 @@ def test_kernel_path_int8_matches_xla_int8(setup):
     assert kern == xla
 
 
+@pytest.mark.parametrize("kv_quantize", [None, "int8"])
+def test_kernel_path_under_tensor_parallel_mesh(setup, kv_quantize):
+    """Under a mesh the kernel runs per device inside shard_map over the
+    kv-head shards (a Pallas call cannot be partitioned by GSPMD): same
+    tokens as the single-device kernel path."""
+    import jax
+
+    from dstack_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    cfg, params = setup  # tiny: 8 q heads / 4 kv heads
+    env = {"DSTACK_TPU_PAGED_ATTN_KERNEL": "1"}
+    want = run_greedy(cfg, params, PROMPTS[:2], 4, env=env,
+                      kv_quantize=kv_quantize)
+    mesh = build_mesh(MeshSpec(tensor=2), jax.devices("cpu")[:2])
+    got = run_greedy(cfg, params, PROMPTS[:2], 4, env=env,
+                     kv_quantize=kv_quantize, mesh=mesh)
+    assert got == want
+
+
 # -- quantized KV ------------------------------------------------------------
 
 

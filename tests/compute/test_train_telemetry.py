@@ -40,8 +40,29 @@ def test_train_step_counters_advance(setup):
     # the compile step is excluded from the step-time histogram
     assert tel.step_seconds.count >= 2
     assert tel.tokens_per_sec.value > 0
-    assert 0 < tel.mfu.value < 1  # 6*N*tok/wall against the 197 TF/s peak
+    # the CPU has no entry in the peak table: no MFU, never the v5e's
+    assert tel.peak_flops is None and tel.mfu.value == 0
     assert losses[-1] < losses[0]  # the wrapper does not break training
+
+
+def test_mfu_uses_the_running_devices_peak(setup, monkeypatch):
+    """MFU divides by the peak of the device_kind the step runs on."""
+    import jax
+    from dstack_tpu.models import train
+    from dstack_tpu.telemetry import training
+
+    cfg, opt, batch = setup
+    kind = jax.devices()[0].device_kind
+    assert training.PEAK_BF16_FLOPS["TPU v5 lite"] == 197e12
+    assert kind not in training.PEAK_BF16_FLOPS
+    monkeypatch.setitem(training.PEAK_BF16_FLOPS, kind, 1e12)
+    tel = training.TrainTelemetry(log_every=0)
+    step = train.make_train_step(cfg, opt, telemetry=tel)
+    state = train.create_state(jax.random.PRNGKey(0), cfg, opt)
+    for _ in range(2):
+        state, _ = step(state, batch)
+    assert tel.peak_flops == 1e12
+    assert 0 < tel.mfu.value < 1
 
 
 def test_wrapping_a_warm_step_records_no_recompile(setup):

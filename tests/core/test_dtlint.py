@@ -902,7 +902,7 @@ def test_dt601_literal_bogus_axis():
     bad = """
         import jax
         from jax import lax
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def kernel(x):
             return lax.psum(x, "bogus")
@@ -932,7 +932,7 @@ def test_dt601_axis_through_partial_module_constant_and_default():
         from functools import partial
         from dstack_tpu.ops.kernel import ring
         from dstack_tpu.parallel import mesh
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def sharded(m, x, seq_axis=mesh.SEQ):
             fn = shard_map(partial(ring, axis_name=seq_axis), mesh=m,
@@ -966,7 +966,7 @@ def test_dt602_unmapped_collective_and_transitive_reachability():
     good = """
         import jax
         from jax import lax
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def helper(x):
             return lax.pmean(x, "data")
@@ -992,7 +992,7 @@ def test_dt602_cross_module_reachability():
     """
     wrapper = """
         from dstack_tpu.ops.helper import all_reduce
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def body(x):
             return all_reduce(x) * 2
@@ -1012,7 +1012,7 @@ def test_dt602_cross_module_reachability():
 def test_dt603_mixed_axis_ring_perm():
     bad = """
         from jax import lax
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def ring(x, *, axis_name="seq"):
             n = lax.psum(1, "tensor")
@@ -1034,7 +1034,7 @@ def test_dt603_perm_through_closure_in_nested_body():
     good = """
         import jax
         from jax import lax
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def ring(x, *, axis_name="seq"):
             n = lax.psum(1, axis_name)
@@ -1120,7 +1120,7 @@ def test_dt605_in_specs_arity_vs_signature():
     bad = """
         from jax import lax
         from jax.sharding import PartitionSpec as P
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def kernel(q, k, v):
             return q + k + v
@@ -1134,7 +1134,7 @@ def test_dt605_in_specs_arity_vs_signature():
     good = """
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def kernel(q, k, v, *, axis_name="seq"):
             return q + k + v
@@ -1150,7 +1150,7 @@ def test_dt605_in_specs_arity_vs_signature():
 def test_dt606_collective_under_axis_index_branch():
     bad = """
         from jax import lax
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def kernel(x):
             rank = lax.axis_index("stage")
@@ -1166,7 +1166,7 @@ def test_dt606_collective_under_axis_index_branch():
     good = """
         import jax.numpy as jnp
         from jax import lax
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def kernel(x):
             rank = lax.axis_index("stage")
@@ -1188,7 +1188,7 @@ def test_dt601_partial_alias_with_extra_positional_args():
     bad = """
         from functools import partial
         from jax import lax
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def kernel(x):
             swap = partial(lax.all_to_all, axis_name="seqq", tiled=True)
@@ -1324,7 +1324,7 @@ def test_dt6xx_axis_set_falls_back_without_mesh_module():
     still validates against the documented canonical set."""
     src = """
         from jax import lax
-        from dstack_tpu.utils.jax_compat import shard_map
+        from jax import shard_map
 
         def kernel(x):
             return lax.psum(x, "bogus")

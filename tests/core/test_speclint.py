@@ -1135,8 +1135,9 @@ def test_shipped_examples_scan_clean():
         # TP exceeding the slice on the tensor-parallel service
         ("serving-tensor-parallel",
          ("--tensor-parallel 4", "--tensor-parallel 8"), "SP201"),
-        # HBM overcommit: 70B onto the 8B service's slice
-        ("serving-llama8b", ("--config llama3-8b", "--config llama3-70b"),
+        # HBM overcommit: the 8B service's bf16 weights on ONE chip of
+        # its slice (what the example asked for before it sharded)
+        ("serving-llama8b", ("--quantize int8 --tensor-parallel 8", ""),
          "SP301"),
         # port mismatch on the serving example
         ("serving-llama8b", ("port: 8000", "port: 9000"), "SP401"),
@@ -1321,10 +1322,10 @@ class TestReviewRegressions:
         probe = (
             "import sys\n"
             "class B:\n"
-            "    def find_module(self, n, p=None):\n"
-            "        return self if n in ('yaml','pydantic') else None\n"
-            "    def load_module(self, n):\n"
-            "        raise ModuleNotFoundError('blocked: '+n, name=n)\n"
+            "    def find_spec(self, n, path=None, target=None):\n"
+            "        if n.partition('.')[0] in ('yaml', 'pydantic'):\n"
+            "            raise ModuleNotFoundError('blocked: '+n, name=n)\n"
+            "        return None\n"
             "sys.meta_path.insert(0, B())\n"
             "from dstack_tpu.analysis.__main__ import main\n"
             "rc = main(['dstack_tpu/analysis/core.py', '--no-baseline'])\n"
