@@ -39,7 +39,15 @@ DEFINING = ("dstack_tpu/elastic/compile_cache.py",)
 #: call shapes that produce a compile-cache-routed (or plain jitted)
 #: callable
 _JIT_CONSTRUCTORS = ("jit", "pjit", "CachedJit", "maybe_cached",
-                     "_jit_cached")
+                     "_jit_cached", "_named_jit")
+#: constructors that take the plain function and the jit keywords
+#: themselves (``_named_jit(fn, name, static_argnums=...)``), so the static
+#: spec is read off their own call
+_JIT_KEYWORD_CONSTRUCTORS = ("_jit_cached", "_named_jit")
+#: helpers that call a cached-jit callable for their caller: name ->
+#: index of the first traced leaf (``_run_program(table, key, make,
+#: *leaves)`` builds ``table[key]`` on first use and calls it on the leaves)
+_JIT_RUNNERS = {"_run_program": 3}
 #: numpy host-array constructors — uncommitted until device_put/jnp wraps
 _NP_HOST = ("array", "zeros", "ones", "full", "asarray", "arange",
             "frombuffer", "load", "empty")
@@ -71,7 +79,7 @@ def _inner_jit(call: ast.Call, mod: Module) -> Optional[ast.Call]:
     """The ``jax.jit(...)`` call inside ``maybe_cached(jax.jit(f), ...)``/
     ``CachedJit(jax.jit(f), ...)`` (or the call itself if it IS jax.jit)."""
     last = _last_part(call.func)
-    if last in ("jit", "pjit"):
+    if last in ("jit", "pjit") + _JIT_KEYWORD_CONSTRUCTORS:
         return call
     for a in call.args[:1]:
         if isinstance(a, ast.Call) and _is_jit_construct(a, mod):
@@ -273,6 +281,11 @@ def compile_stability(mod: Module) -> List[Finding]:
                 continue
             nums, names = _static_spec(node.func, mod)
             key = "<immediate jit>"
+            leaves = node.args
+        elif _last_part(node.func) in _JIT_RUNNERS:
+            key = _last_part(node.func)
+            nums, names = set(), set()
+            leaves = node.args[_JIT_RUNNERS[key]:]
         else:
             key = _call_key(node.func)
             if key is None or key not in bindings:
@@ -280,8 +293,9 @@ def compile_stability(mod: Module) -> List[Finding]:
             if _is_jit_construct(node, mod):
                 continue  # the construction itself, not a traced call
             nums, names = bindings[key]
+            leaves = node.args
         fn = mod.func_of.get(node)
-        for i, arg in enumerate(node.args):
+        for i, arg in enumerate(leaves):
             if i in nums:
                 continue
             why = _leaf_violation(arg, mod, fn)

@@ -43,6 +43,9 @@ TRACER_ENTRY_POINTS = {
     "jax.jit", "jit", "pjit", "jax.pmap", "pmap",
     "shard_map", "jax.shard_map", "jax.experimental.shard_map.shard_map",
     "jax.experimental.shard_map",
+    # serving/engine.py's naming wrappers around jax.jit: their first
+    # argument is the function that gets traced
+    "_named_jit", "self._jit_cached",
 }
 
 #: attribute reads on a traced array that are static at trace time

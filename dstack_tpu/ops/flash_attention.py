@@ -146,6 +146,7 @@ def _fwd(q3, k3, v3, scale):
             jax.ShapeDtypeStruct((bh, seq, d), q3.dtype),
             jax.ShapeDtypeStruct((bh, seq, 1), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=_interpret(),
     )(q3, k3, v3)
 
@@ -245,6 +246,7 @@ def _bwd_merged(q3, k3, v3, do3, lse, delta, scale):
             jax.ShapeDtypeStruct((bh, seq, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((seq, d), jnp.float32)],
+        name="flash_bwd",
         interpret=_interpret(),
     )(q3, k3, v3, do3, lse, delta)
 
@@ -472,6 +474,7 @@ def _fwd_packed(qp, kp, vp, scale):
             jax.ShapeDtypeStruct((ph, seq, 1), jnp.float32),
             jax.ShapeDtypeStruct((ph, seq, 1), jnp.float32),
         ],
+        name="flash_fwd_packed",
         interpret=_interpret(),
     )(qp, kp, vp)
 
@@ -582,6 +585,7 @@ def _bwd_packed_merged(qp, kp, vp, dop, lse0, lse1, delta0, delta1, scale):
             jax.ShapeDtypeStruct((ph, seq, dd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((seq, dd), jnp.float32)],
+        name="flash_bwd_packed",
         interpret=_interpret(),
     )(qp, kp, vp, dop, lse0, lse1, delta0, delta1)
 
@@ -838,6 +842,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
             jax.ShapeDtypeStruct((b, hkv, group, d), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32),
         ],
+        name="paged_decode_attention",
         interpret=_interpret(),
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *inputs)
     return o, lse[..., 0]

@@ -64,6 +64,18 @@ def _paged_kernel_default() -> bool:
     return v not in ("0", "false", "off")
 
 
+def _named_jit(fn, name: str, **jit_kwargs):
+    """``jax.jit`` of ``fn`` under ``name``: the compiled program shows as
+    ``jit_<name>`` on a profiler trace's ``XLA Modules`` line and in HLO
+    dumps (a ``functools.partial`` or a local ``fn`` would read
+    ``jit__unknown`` / ``jit_fn``).  The only ``jax.jit`` in this module."""
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, **jit_kwargs)
+
+
 class EngineDraining(RuntimeError):
     """Raised by :meth:`InferenceEngine.submit` once the engine is in
     drain mode: in-flight requests finish, new ones must go elsewhere
@@ -91,6 +103,7 @@ class Request:
     output: List[int] = dataclasses.field(default_factory=list)
     done: threading.Event = dataclasses.field(default_factory=threading.Event)
     finish_reason: str = ""
+    #: wall clock; the later stamps are this plus monotonic time (now())
     submitted_at: float = dataclasses.field(default_factory=time.time)
     #: when the request claimed a slot (queue wait = admitted - submitted)
     admitted_at: Optional[float] = None
@@ -109,6 +122,17 @@ class Request:
     #: stamps at finish and attaches the trace id as a histogram exemplar
     trace_id: Optional[str] = None
     parent_span_id: Optional[str] = None
+    #: ``perf_counter`` reading taken with ``submitted_at`` (see :meth:`now`)
+    _submitted_perf: float = dataclasses.field(
+        default_factory=time.perf_counter, repr=False)
+
+    def now(self) -> float:
+        """The stamp for every scheduler event after submission:
+        ``submitted_at`` (wall clock, the anchor cross-process traces
+        line up on) plus the MONOTONIC time elapsed since, so differences
+        of two stamps never go negative when the wall clock is stepped."""
+        return self.submitted_at + (time.perf_counter()
+                                    - self._submitted_perf)
 
     def cancel(self, reason: str = "cancelled") -> None:
         """Stop generating for this request as soon as the engine next
@@ -119,6 +143,13 @@ class Request:
         self.cancelled = True
 
 
+# Device-side regions carry a jax.named_scope so that a profiler trace and an
+# HLO dump say which part of a program an operation belongs to: qkv, attn,
+# paged_attn, mlp, lm_head, sample, kv_insert (prefill's write of a prompt's
+# K/V), kv_window_write (the decode window's one write at its end).
+
+
+@jax.named_scope("mlp")
 def _mlp_block(h, lp, cfg: LlamaConfig, token_mask=None):
     """Dense SwiGLU or routed-expert MLP on [B, S, D] normed hiddens.
 
@@ -158,15 +189,7 @@ def _layer_kv(params, cfg: LlamaConfig, x, positions, inv_freqs,
 
     def layer(carry, lp):
         x = carry
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q = qmatmul(h, lp["wq"], cfg.dtype).reshape(
-            b, s, cfg.num_heads, cfg.head_dim)
-        k = qmatmul(h, lp["wk"], cfg.dtype).reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim)
-        v = qmatmul(h, lp["wv"], cfg.dtype).reshape(
-            b, s, cfg.num_kv_heads, cfg.head_dim)
-        q = apply_rope(q, positions, inv_freqs)
-        k = apply_rope(k, positions, inv_freqs)
+        q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, b, s)
         attn = _masked_attention(q, k, v, positions, positions)
         x = x + qmatmul(attn.reshape(b, s, cfg.q_dim),
                        lp["wo"], cfg.dtype)
@@ -176,6 +199,16 @@ def _layer_kv(params, cfg: LlamaConfig, x, positions, inv_freqs,
 
     x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
     return x, ks, vs  # ks/vs: [L, B, S, Hkv, D]
+
+
+def _last_logits(params, cfg: LlamaConfig, x, length):
+    """Logits at the last of ``length`` real positions of a [1, S, D]
+    prefill activation."""
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        head = output_head(params, cfg)
+        return qmatmul(x[0, length - 1, :], head, cfg.dtype,
+                       preferred=jnp.float32)
 
 
 def _prompt_forward(params, cfg: LlamaConfig, padded, length, bucket: int):
@@ -188,19 +221,16 @@ def _prompt_forward(params, cfg: LlamaConfig, padded, length, bucket: int):
     x = params["embed"].astype(cfg.dtype)[padded][None, :, :]
     token_mask = (jnp.arange(bucket)[None, :] < length)
     x, ks, vs = _layer_kv(params, cfg, x, positions, inv_freqs, token_mask)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    head = output_head(params, cfg)
-    logits = qmatmul(x[0, length - 1, :], head, cfg.dtype,
-                     preferred=jnp.float32)
-    return logits, ks, vs
+    return _last_logits(params, cfg, x, length), ks, vs
 
 
+@jax.named_scope("qkv")
 def _decode_qkv(x, lp, cfg: LlamaConfig, positions, inv_freqs, b: int,
                 m: int = 1):
-    """Per-token projections + RoPE for the decode window — factored out
-    so the dense and paged branches of the buffered decode can never
-    diverge numerically.  ``m`` is the tokens-per-slot-per-step width
-    (1 for plain decode, draft_k+1 for speculative verification)."""
+    """Per-token projections + RoPE — factored out so the dense and paged
+    branches of the buffered decode (and the prefill programs) can never
+    diverge numerically.  ``m`` is the tokens per row: 1 for plain decode,
+    draft_k+1 for speculative verification, the bucket for a prefill."""
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     q = qmatmul(h, lp["wq"], cfg.dtype).reshape(
         b, m, cfg.num_heads, cfg.head_dim)
@@ -255,6 +285,7 @@ def _kv_map(cache, rows, fn):
     return fn(cache, rows)
 
 
+@jax.named_scope("kv_window_write")
 def _dense_window_insert(cache, win, widx, in_window):
     """End-of-window bulk insert for the DENSE cache: cache position (b, s)
     takes window column ``widx[b, s]`` wherever ``in_window[b, s]`` — the
@@ -282,17 +313,10 @@ def _suffix_layer(x, lp, cfg: LlamaConfig, positions, inv_freqs, kv_pos,
     prefill (block scatter/gather) and the dense chunked prefill (row
     slice) — both share this body."""
     sbucket = x.shape[1]
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = qmatmul(h, lp["wq"], cfg.dtype).reshape(
-        1, sbucket, cfg.num_heads, cfg.head_dim)
-    k = qmatmul(h, lp["wk"], cfg.dtype).reshape(
-        1, sbucket, cfg.num_kv_heads, cfg.head_dim)
-    v = qmatmul(h, lp["wv"], cfg.dtype).reshape(
-        1, sbucket, cfg.num_kv_heads, cfg.head_dim)
-    q = apply_rope(q, positions, inv_freqs)
-    k = apply_rope(k, positions, inv_freqs)
-    layer_k = _kv_map(layer_k, k, insert)
-    layer_v = _kv_map(layer_v, v, insert)
+    q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, 1, sbucket)
+    with jax.named_scope("kv_insert"):
+        layer_k = _kv_map(layer_k, k, insert)
+        layer_v = _kv_map(layer_v, v, insert)
     kv_k = _kv_mat(gather(layer_k), cfg.dtype)
     kv_v = _kv_mat(gather(layer_v), cfg.dtype)
     attn = _masked_attention(q, kv_k, kv_v, positions, kv_pos)
@@ -302,6 +326,7 @@ def _suffix_layer(x, lp, cfg: LlamaConfig, positions, inv_freqs, kv_pos,
     return x, layer_k, layer_v
 
 
+@jax.named_scope("attn")
 def _masked_attention(q, k, v, q_pos, kv_pos):
     """Causal GQA attention with explicit position masks (prefill)."""
     b, s, hq, d = q.shape
@@ -556,8 +581,9 @@ class InferenceEngine:
                 init = moe_init if isinstance(cfg, MoEConfig) else init_params
                 shapes = jax.eval_shape(
                     lambda: init(jax.random.PRNGKey(0), cfg))
-                params = jax.jit(
+                params = _named_jit(
                     lambda: init(jax.random.PRNGKey(rng_seed), cfg),
+                    "init_params",
                     out_shardings=self._param_shardings(shapes),
                 )()
             else:
@@ -712,8 +738,9 @@ class InferenceEngine:
             # device.  The jitted allocator is cached: a rebuild per
             # decode-failure recovery would re-trace for nothing.
             if getattr(self, "_cache_alloc", None) is None:
-                self._cache_alloc = jax.jit(
-                    mk_zeros, out_shardings=self._kv_sharding())
+                self._cache_alloc = _named_jit(
+                    mk_zeros, "kv_cache_alloc",
+                    out_shardings=self._kv_sharding())
             self._cache_k = self._cache_alloc()
             self._cache_v = self._cache_alloc()
         else:
@@ -776,7 +803,8 @@ class InferenceEngine:
         while not self._stop:
             if not self.has_work():
                 try:
-                    req = self._queue.get(timeout=0.05)
+                    with jax.profiler.TraceAnnotation("engine.wait_for_work"):
+                        req = self._queue.get(timeout=0.05)
                     self._queue.put(req)
                 except queue.Empty:
                     continue
@@ -794,7 +822,7 @@ class InferenceEngine:
                     if req is not None:
                         self._release_host(slot_id)
                         req.finish_reason = "error"
-                        req.finished_at = time.time()
+                        req.finished_at = req.now()
                         req.done.set()
                         if self.telemetry is not None:
                             self.telemetry.record_preemption("engine_error")
@@ -882,11 +910,8 @@ class InferenceEngine:
         """
         advanced = False
         if self._pending is not None:
-            want_admit = (
-                (self._stalled is not None or not self._queue.empty())
-                and any(s is None for s in self._slots))
             nxt = None
-            if not want_admit:
+            if not self._can_admit():
                 self._advance_chunks()  # chains before nxt on device
                 advanced = True
                 nxt = self._dispatch_window(self._pending["remaining_after"])
@@ -919,48 +944,49 @@ class InferenceEngine:
                 if req is not None:
                     self._release(slot_id)
                     req.finish_reason = req.finish_reason or "cancelled"
-                    req.finished_at = time.time()
+                    req.finished_at = req.now()
                     req.done.set()
                     if self.telemetry is not None:
                         self.telemetry.record_finished(req)
                 continue
-            tokens, done = st["tokens"], st["done"]
-            chunk = tokens[done:done + self.prefill_chunk]
-            cbucket = self._bucket(len(chunk))
-            padded = np.zeros((cbucket,), np.int32)
-            padded[:len(chunk)] = chunk
-            if self.paged:
-                # paged chunks ride the suffix-prefill program (block
-                # scatter + gathered-span attention) with prefix_len = rows
-                # already in the slot's blocks
-                key = ("prefix", cbucket)
-                if key not in self._prefill_jit:
-                    self._prefill_jit[key] = self._prefill_fn_prefix(cbucket)
-                logits, self._cache_k, self._cache_v = \
-                    self._prefill_jit[key](
-                        self.params, jnp.asarray(padded),
-                        jnp.int32(len(chunk)), jnp.int32(done),
-                        self._cache_k, self._cache_v,
-                        jnp.asarray(self._tables_host[slot_id]))
-            else:
-                key = ("chunk", cbucket)
-                if key not in self._prefill_jit:
-                    self._prefill_jit[key] = self._prefill_fn_chunk(cbucket)
-                logits, self._cache_k, self._cache_v = \
-                    self._prefill_jit[key](
-                        self.params, jnp.asarray(padded),
-                        jnp.int32(len(chunk)), jnp.int32(done),
-                        self._cache_k, self._cache_v, jnp.int32(slot_id))
-            st["done"] = done + len(chunk)
-            if self.telemetry is not None:
-                self.telemetry.record_prefill(len(chunk), cbucket)
-                # keep the backlog gauge fresh even when every slot is
-                # chunking (no decode window dispatches then)
-                self.telemetry.record_prefill_backlog(self._chunk_backlog())
-            if st["done"] >= len(tokens):
-                st["logits"] = logits
-                st["n"] = len(tokens)
+            with jax.profiler.TraceAnnotation("engine.chunk"):
+                self._dispatch_chunk(slot_id, st)
             return
+
+    def _dispatch_chunk(self, slot_id: int, st: dict) -> None:
+        """Dispatch the next prefill chunk of one mid-chunking slot."""
+        tokens, done = st["tokens"], st["done"]
+        chunk = tokens[done:done + self.prefill_chunk]
+        cbucket = self._bucket(len(chunk))
+        padded = np.zeros((cbucket,), np.int32)
+        padded[:len(chunk)] = chunk
+        if self.paged:
+            # paged chunks ride the suffix-prefill program (block
+            # scatter + gathered-span attention) with prefix_len = rows
+            # already in the slot's blocks
+            logits, self._cache_k, self._cache_v = self._run_program(
+                self._prefill_jit, ("prefix", cbucket),
+                functools.partial(self._prefill_fn_prefix, cbucket),
+                self.params, jnp.asarray(padded),
+                jnp.int32(len(chunk)), jnp.int32(done),
+                self._cache_k, self._cache_v,
+                jnp.asarray(self._tables_host[slot_id]))
+        else:
+            logits, self._cache_k, self._cache_v = self._run_program(
+                self._prefill_jit, ("chunk", cbucket),
+                functools.partial(self._prefill_fn_chunk, cbucket),
+                self.params, jnp.asarray(padded),
+                jnp.int32(len(chunk)), jnp.int32(done),
+                self._cache_k, self._cache_v, jnp.int32(slot_id))
+        st["done"] = done + len(chunk)
+        if self.telemetry is not None:
+            self.telemetry.record_prefill(len(chunk), cbucket)
+            # keep the backlog gauge fresh even when every slot is
+            # chunking (no decode window dispatches then)
+            self.telemetry.record_prefill_backlog(self._chunk_backlog())
+        if st["done"] >= len(tokens):
+            st["logits"] = logits
+            st["n"] = len(tokens)
 
     def _finish_chunked(self) -> None:
         """Activate slots whose final prefill chunk has completed: sample
@@ -981,16 +1007,30 @@ class InferenceEngine:
                 for i, bkey in enumerate(self._slot_prefix[slot_id][1]):
                     if (i + 1) * self._block_size <= n and i < len(blocks):
                         self._alloc.register(bkey, blocks[i])
-            first = self._sample_first(st["logits"], req)
-            self._slots_gen += 1
-            self._lengths = self._lengths.at[slot_id].set(n)
-            self._host_lengths[slot_id] = n
-            self._last_token = self._last_token.at[slot_id].set(first)
-            self._active = self._active.at[slot_id].set(True)
-            self._record_history(slot_id, st["tokens"], first)
-            self._emit(slot_id, req, first)
+            with jax.profiler.TraceAnnotation("engine.chunk"):
+                first = self._sample_first(st["logits"], req)
+                self._slots_gen += 1
+                self._lengths = self._lengths.at[slot_id].set(n)
+                self._host_lengths[slot_id] = n
+                self._last_token = self._last_token.at[slot_id].set(first)
+                self._active = self._active.at[slot_id].set(True)
+                self._record_history(slot_id, st["tokens"], first)
+                self._emit(slot_id, req, first)
+
+    def _can_admit(self) -> bool:
+        """A waiting request could take a free slot."""
+        return ((self._stalled is not None or not self._queue.empty())
+                and any(s is None for s in self._slots))
 
     def _admit(self) -> None:
+        """Admit queued requests into free slots, under one
+        ``engine.admit`` span per call that has both."""
+        if not self._can_admit():
+            return
+        with jax.profiler.TraceAnnotation("engine.admit"):
+            self._admit_into_free_slots()
+
+    def _admit_into_free_slots(self) -> None:
         for slot_id in range(self.batch_size):
             if self._slots[slot_id] is not None:
                 continue
@@ -1014,7 +1054,7 @@ class InferenceEngine:
                 if req.cancelled:
                     # cancelled while queued: finish without taking the slot
                     req.finish_reason = req.finish_reason or "cancelled"
-                    req.finished_at = time.time()
+                    req.finished_at = req.now()
                     req.done.set()
                     if self.telemetry is not None:
                         self.telemetry.record_finished(req)
@@ -1029,14 +1069,21 @@ class InferenceEngine:
                         # stalled
                         req._stall_counted = True
                         # stall start for the engine.kv_wait trace span
-                        req._kv_stalled_at = time.time()
+                        req._kv_stalled_at = req.now()
                         self.telemetry.record_preemption(
                             "kv_blocks_exhausted")
                     self._stalled = req
                     return
+                if self.paged and self.telemetry is not None:
+                    # the pool grows here, not at dispatch: a request that
+                    # comes and goes between two windows still shows in
+                    # the peak
+                    self.telemetry.record_kv_utilization(
+                        self._kv_used_fraction())
                 try:
                     if req.prefill is not None:
-                        self._insert_prefilled(slot_id, req)
+                        with jax.profiler.TraceAnnotation("engine.prefill"):
+                            self._insert_prefilled(slot_id, req)
                     elif (self.prefill_chunk is not None
                           and self._prompt_len(req) > self.prefill_chunk):
                         # long prompt: claim the slot now, prefill one chunk
@@ -1055,7 +1102,8 @@ class InferenceEngine:
                         self._chunking[slot_id] = {"tokens": tokens,
                                                    "done": done}
                     else:
-                        self._prefill(slot_id, req)
+                        with jax.profiler.TraceAnnotation("engine.prefill"):
+                            self._prefill(slot_id, req)
                 except Exception:
                     # claim the slot so the crash handler (run_forever)
                     # fails this request and releases its KV-block
@@ -1072,7 +1120,7 @@ class InferenceEngine:
         """Stamp slot admission and record the queue wait (once — retried
         admissions after a device error keep the first stamp)."""
         if req.admitted_at is None:
-            req.admitted_at = time.time()
+            req.admitted_at = req.now()
             if self.telemetry is not None:
                 self.telemetry.record_admitted(
                     req.admitted_at - req.submitted_at,
@@ -1139,10 +1187,28 @@ class InferenceEngine:
             bucket = max(bucket, self._block_size)
         return bucket
 
-    def _jit_cached(self, jitted, tag: str):
-        """Route one jitted program through the persistent compile cache
-        (no-op passthrough when the cache is disabled)."""
-        return maybe_cached(jitted, self.compile_cache, tag=tag)
+    def _jit_cached(self, fn, tag: str, **jit_kwargs):
+        """Jit ``fn`` under its compile-cache tag (so a device trace names
+        the program ``jit_<tag>``) and route it through the persistent
+        compile cache (no-op passthrough when the cache is disabled)."""
+        return maybe_cached(_named_jit(fn, tag, **jit_kwargs),
+                            self.compile_cache, tag=tag)
+
+    def _run_program(self, table: dict, key, make, *args):
+        """Call the program ``table[key]`` on ``args``, building it with
+        ``make()`` on first use.  A jitted function traces, lowers and
+        compiles (or loads from a cache) inside its first call, so the
+        build and that call sit under one ``engine.build_program`` span:
+        inside serving it is a live request that hit a new shape."""
+        fn = table.get(key)
+        if fn is not None:
+            return fn(*args)
+        with jax.profiler.TraceAnnotation("engine.build_program"):
+            fn = table[key] = make()
+            if self.telemetry is not None:
+                self.telemetry.record_program_built(
+                    "decode" if table is self._decode_jit else "prefill")
+            return fn(*args)
 
     def _prefill_fn(self, bucket: int):
         cfg = self.cfg
@@ -1158,12 +1224,13 @@ class InferenceEngine:
                 return jax.lax.dynamic_update_slice(
                     leaf, rows[:, None], start)
 
-            cache_k = _kv_map(cache_k, ks[:, 0], insert)
-            cache_v = _kv_map(cache_v, vs[:, 0], insert)
+            with jax.named_scope("kv_insert"):
+                cache_k = _kv_map(cache_k, ks[:, 0], insert)
+                cache_v = _kv_map(cache_v, vs[:, 0], insert)
             return logits, cache_k, cache_v
 
-        return self._jit_cached(jax.jit(fn, donate_argnums=(3, 4)),
-                                f"prefill_b{bucket}")
+        return self._jit_cached(fn, f"prefill_b{bucket}",
+                                donate_argnums=(3, 4))
 
     def _prefill_fn_prefix(self, sbucket: int):
         """Suffix prefill against a cached prefix (prefix-cache mode).
@@ -1210,14 +1277,11 @@ class InferenceEngine:
 
             x, (cache_k, cache_v) = jax.lax.scan(
                 layer, x, (params["layers"], cache_k, cache_v))
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            head = output_head(params, cfg)
-            logits = qmatmul(x[0, suffix_len - 1, :], head, cfg.dtype,
-                             preferred=jnp.float32)
+            logits = _last_logits(params, cfg, x, suffix_len)
             return logits, cache_k, cache_v
 
-        return self._jit_cached(jax.jit(fn, donate_argnums=(4, 5)),
-                                f"prefill_prefix_b{sbucket}")
+        return self._jit_cached(fn, f"prefill_prefix_b{sbucket}",
+                                donate_argnums=(4, 5))
 
     def _prefill_fn_chunk(self, cbucket: int):
         """One chunk of a long prompt against the DENSE cache: computes the
@@ -1264,14 +1328,11 @@ class InferenceEngine:
 
             x, (cache_k, cache_v) = jax.lax.scan(
                 layer, x, (params["layers"], cache_k, cache_v))
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            head = output_head(params, cfg)
-            logits = qmatmul(x[0, chunk_len - 1, :], head, cfg.dtype,
-                             preferred=jnp.float32)
+            logits = _last_logits(params, cfg, x, chunk_len)
             return logits, cache_k, cache_v
 
-        return self._jit_cached(jax.jit(fn, donate_argnums=(4, 5)),
-                                f"prefill_chunk_b{cbucket}")
+        return self._jit_cached(fn, f"prefill_chunk_b{cbucket}",
+                                donate_argnums=(4, 5))
 
     def _prefill_fn_paged(self, bucket: int):
         cfg = self.cfg
@@ -1288,12 +1349,13 @@ class InferenceEngine:
                     (cfg.num_layers, nblk, bs) + rows.shape[2:])
                 return leaf.at[:, bids].set(blocked)
 
-            cache_k = _kv_map(cache_k, ks[:, 0], insert)
-            cache_v = _kv_map(cache_v, vs[:, 0], insert)
+            with jax.named_scope("kv_insert"):
+                cache_k = _kv_map(cache_k, ks[:, 0], insert)
+                cache_v = _kv_map(cache_v, vs[:, 0], insert)
             return logits, cache_k, cache_v
 
-        return self._jit_cached(jax.jit(fn, donate_argnums=(3, 4)),
-                                f"prefill_paged_b{bucket}")
+        return self._jit_cached(fn, f"prefill_paged_b{bucket}",
+                                donate_argnums=(3, 4))
 
     def _prefill(self, slot_id: int, req: Request) -> None:
         # keep the newest prompt tokens so generation fits the cache
@@ -1305,12 +1367,11 @@ class InferenceEngine:
         if prefix_len > 0:
             # suffix-only prefill over the reused prefix KV
             sbucket = self._bucket(n - prefix_len)
-            key = ("prefix", sbucket)
-            if key not in self._prefill_jit:
-                self._prefill_jit[key] = self._prefill_fn_prefix(sbucket)
             padded = np.zeros((sbucket,), np.int32)
             padded[:n - prefix_len] = tokens[prefix_len:prefix_len + sbucket]
-            logits, self._cache_k, self._cache_v = self._prefill_jit[key](
+            logits, self._cache_k, self._cache_v = self._run_program(
+                self._prefill_jit, ("prefix", sbucket),
+                functools.partial(self._prefill_fn_prefix, sbucket),
                 self.params, jnp.asarray(padded),
                 jnp.int32(n - prefix_len), jnp.int32(prefix_len),
                 self._cache_k, self._cache_v,
@@ -1318,17 +1379,15 @@ class InferenceEngine:
             )
         else:
             bucket = self._bucket(n)
-            key = ("paged", bucket) if self.paged else bucket
-            if key not in self._prefill_jit:
-                self._prefill_jit[key] = (self._prefill_fn_paged(bucket)
-                                          if self.paged
-                                          else self._prefill_fn(bucket))
             padded = np.zeros((bucket,), np.int32)
             padded[:n] = tokens[:bucket]
             target = (jnp.asarray(
                 self._slot_blocks[slot_id][:bucket // self._block_size],
                 jnp.int32) if self.paged else slot_id)
-            logits, self._cache_k, self._cache_v = self._prefill_jit[key](
+            logits, self._cache_k, self._cache_v = self._run_program(
+                self._prefill_jit, ("paged", bucket) if self.paged else bucket,
+                functools.partial(self._prefill_fn_paged if self.paged
+                                  else self._prefill_fn, bucket),
                 self.params, jnp.asarray(padded), jnp.int32(n),
                 self._cache_k, self._cache_v, target,
             )
@@ -1384,20 +1443,18 @@ class InferenceEngine:
         toks = self._prompt_tokens(tokens, max_new_tokens)
         n = len(toks)
         bucket = self._bucket(n)
-        key = ("export", bucket)
-        if key not in self._prefill_jit:
-            def fn(params, padded, length):
-                logits, ks, vs = _prompt_forward(params, cfg, padded, length,
-                                                 bucket)
-                return logits, ks[:, 0], vs[:, 0]  # [L, bucket, Hkv, D]
 
-            self._prefill_jit[key] = self._jit_cached(
-                jax.jit(fn), f"prefill_export_b{bucket}")
+        def fn(params, padded, length):
+            logits, ks, vs = _prompt_forward(params, cfg, padded, length,
+                                             bucket)
+            return logits, ks[:, 0], vs[:, 0]  # [L, bucket, Hkv, D]
+
         padded = np.zeros((bucket,), np.int32)
         padded[:n] = toks[:bucket]
-        logits, ks, vs = self._prefill_jit[key](
-            self.params, jnp.asarray(padded), jnp.int32(n)
-        )
+        logits, ks, vs = self._run_program(
+            self._prefill_jit, ("export", bucket),
+            lambda: self._jit_cached(fn, f"prefill_export_b{bucket}"),
+            self.params, jnp.asarray(padded), jnp.int32(n))
         logits_np = np.asarray(logits)
         return {
             "ks": np.asarray(ks[:, :n]),
@@ -1464,6 +1521,7 @@ class InferenceEngine:
             first)
         self._emit(slot_id, req, first)
 
+    @jax.named_scope("sample")
     def _sample_on_device(self, logits, temps, top_ps, top_ks, rng):
         """Temperature/top-k/nucleus (top-p) sampling entirely on device.
 
@@ -1621,49 +1679,57 @@ class InferenceEngine:
                     # o + logsumexp per slot), window half in XLA, merged
                     # by logsumexp — numerically the same attention set,
                     # reduction order aside
-                    o_c, lse_c = paged_attn(
-                        qg, layer_k, layer_v, tables, base_len)
-                    s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
-                    s_w = jnp.where(win_mask, s_w,
-                                    -1e30).astype(jnp.float32)
-                    m_w = jnp.max(s_w, axis=-1)
-                    p_w = jnp.exp(s_w - m_w[..., None])
-                    l_w = jnp.sum(p_w, axis=-1)
-                    o_w = jnp.einsum(
-                        "bhgj,jbhd->bhgd", p_w.astype(x.dtype), wv
-                    ).astype(jnp.float32) / l_w[..., None]
-                    lse_w = m_w + jnp.log(l_w)
-                    # empty-cache slots have lse_c = -inf; the window half
-                    # always has column 0 visible, so lse is finite
-                    lse = jnp.logaddexp(lse_c, lse_w)
-                    attn = (o_c * jnp.exp(lse_c - lse)[..., None]
-                            + o_w * jnp.exp(lse_w - lse)[..., None]
-                            ).astype(x.dtype)
+                    with jax.named_scope("paged_attn"):
+                        o_c, lse_c = paged_attn(
+                            qg, layer_k, layer_v, tables, base_len)
+                    with jax.named_scope("attn"):
+                        s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
+                        s_w = jnp.where(win_mask, s_w,
+                                        -1e30).astype(jnp.float32)
+                        m_w = jnp.max(s_w, axis=-1)
+                        p_w = jnp.exp(s_w - m_w[..., None])
+                        l_w = jnp.sum(p_w, axis=-1)
+                        o_w = jnp.einsum(
+                            "bhgj,jbhd->bhgd", p_w.astype(x.dtype), wv
+                        ).astype(jnp.float32) / l_w[..., None]
+                        lse_w = m_w + jnp.log(l_w)
+                        # empty-cache slots have lse_c = -inf; the window
+                        # half always has column 0 visible, so lse is finite
+                        lse = jnp.logaddexp(lse_c, lse_w)
+                        attn = (o_c * jnp.exp(lse_c - lse)[..., None]
+                                + o_w * jnp.exp(lse_w - lse)[..., None]
+                                ).astype(x.dtype)
                 else:
-                    lk = _kv_mat(layer_k, x.dtype)  # quantized dequant fuses in
-                    lv = _kv_mat(layer_v, x.dtype)
-                    s_c = jnp.einsum("bhgd,bkhd->bhgk", qg, lk) * scale
-                    s_c = jnp.where(cache_mask, s_c, -1e30)
-                    s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
-                    s_w = jnp.where(win_mask, s_w, -1e30)
-                    s = jnp.concatenate([s_c, s_w], axis=-1)
-                    probs = jax.nn.softmax(
-                        s.astype(jnp.float32), axis=-1).astype(x.dtype)
-                    p_c, p_w = probs[..., :kv_span], probs[..., kv_span:]
-                    attn = (jnp.einsum("bhgk,bkhd->bhgd", p_c, lv)
-                            + jnp.einsum("bhgj,jbhd->bhgd", p_w, wv))
+                    with jax.named_scope("attn"):
+                        # quantized dequant fuses in
+                        lk = _kv_mat(layer_k, x.dtype)
+                        lv = _kv_mat(layer_v, x.dtype)
+                        s_c = jnp.einsum("bhgd,bkhd->bhgk", qg, lk) * scale
+                        s_c = jnp.where(cache_mask, s_c, -1e30)
+                        s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
+                        s_w = jnp.where(win_mask, s_w, -1e30)
+                        s = jnp.concatenate([s_c, s_w], axis=-1)
+                        probs = jax.nn.softmax(
+                            s.astype(jnp.float32), axis=-1).astype(x.dtype)
+                        p_c, p_w = (probs[..., :kv_span],
+                                    probs[..., kv_span:])
+                        attn = (jnp.einsum("bhgk,bkhd->bhgd", p_c, lv)
+                                + jnp.einsum("bhgj,jbhd->bhgd", p_w, wv))
                 x = _decode_layer_tail(x, attn, lp, cfg, b)
                 return x, (wk, wv)
 
             x, (win_k, win_v) = jax.lax.scan(
                 layer, x, (params["layers"], view_k, view_v, win_k, win_v))
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            logits = qmatmul(x, head, cfg.dtype, preferred=jnp.float32)[:, 0]
+            with jax.named_scope("lm_head"):
+                x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+                logits = qmatmul(x, head, cfg.dtype,
+                                 preferred=jnp.float32)[:, 0]
             if sampling:
                 tokens = self._sample_on_device(logits, temps, top_ps,
                                                 top_ks, step_rng)
             else:
-                tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             new_lengths = jnp.where(active, step_lengths + 1, step_lengths)
             return (tokens, new_lengths, win_k, win_v), tokens
 
@@ -1687,6 +1753,7 @@ class InferenceEngine:
             off = pos % bs
 
             # win: [L, W, B, ...] -> rows indexed by (phys, off) per (b, j)
+            @jax.named_scope("kv_window_write")
             def scatter(cache, win):
                 return _kv_map(cache, win, lambda leaf, rows:
                                leaf.at[:, phys, off].set(
@@ -1819,9 +1886,11 @@ class InferenceEngine:
 
             x, (win_k, win_v) = jax.lax.scan(
                 layer, x, (params["layers"], view_k, view_v, win_k, win_v))
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-            logits = qmatmul(x, head, cfg.dtype, preferred=jnp.float32)
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B,k+1]
+            with jax.named_scope("lm_head"):
+                x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+                logits = qmatmul(x, head, cfg.dtype, preferred=jnp.float32)
+            with jax.named_scope("sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B,k+1]
             match = (drafts == greedy[:, :k])
             n_acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), 1), axis=1)
             n_acc = jnp.where(active, n_acc, 0)
@@ -1941,19 +2010,26 @@ class InferenceEngine:
         window = self._pick_window(remaining)
         sampling = any(
             req is not None and req.temperature > 0.0 for req in self._slots)
-        if self.speculation and not sampling:
-            return self._dispatch_window_spec(remaining, window)
+        with jax.profiler.TraceAnnotation("engine.dispatch_window"):
+            if self.speculation and not sampling:
+                return self._dispatch_window_spec(remaining, window)
+            return self._dispatch_window_plain(remaining, window, sampling)
+
+    def _dispatch_window_plain(self, remaining: int, window: int,
+                               sampling: bool):
+        """Dispatch a plain (non-speculative) window: build its tables and
+        per-slot constants, enqueue the program."""
         nbk = self._ragged_blocks(window) if self.paged else None
-        key = (window, sampling, nbk)
-        if key not in self._decode_jit:
-            self._decode_jit[key] = self._jit_cached(
-                jax.jit(
-                    functools.partial(self._decode_window_fn_buffered,
-                                      window=window, sampling=sampling,
-                                      kv_blocks=nbk),
-                    donate_argnums=(4, 5)),
+
+        def make():
+            return self._jit_cached(
+                functools.partial(self._decode_window_fn_buffered,
+                                  window=window, sampling=sampling,
+                                  kv_blocks=nbk),
                 f"decode_w{window}_s{int(sampling)}"
-                + (f"_kb{nbk}" if nbk is not None else ""))
+                + (f"_kb{nbk}" if nbk is not None else ""),
+                donate_argnums=(4, 5))
+
         # Host->device transfers are RPC round-trips on remote-dispatch
         # backends — per WINDOW they must be near zero, so everything below
         # is cached against the current slot assignment (an admission or
@@ -1986,7 +2062,8 @@ class InferenceEngine:
         else:
             sub = self._rng_key
         tokens_all, self._last_token, self._lengths, \
-            self._cache_k, self._cache_v = self._decode_jit[key](
+            self._cache_k, self._cache_v = self._run_program(
+                self._decode_jit, (window, sampling, nbk), make,
                 self.params, self._last_token, self._lengths, self._active,
                 self._cache_k, self._cache_v, temps, top_ps, top_ks, tables,
                 sub,
@@ -2013,16 +2090,16 @@ class InferenceEngine:
         per step (over-dispatch past that is discarded overshoot, exactly
         like the plain window's)."""
         k = self.speculation_k
-        key = ("spec", window)
-        if key not in self._decode_jit:
-            self._decode_jit[key] = self._jit_cached(
-                jax.jit(
-                    functools.partial(self._decode_window_fn_spec,
-                                      window=window, k=k),
-                    donate_argnums=(4, 5, 6)),
-                f"decode_spec_w{window}")
+
+        def make():
+            return self._jit_cached(
+                functools.partial(self._decode_window_fn_spec,
+                                  window=window, k=k),
+                f"decode_spec_w{window}", donate_argnums=(4, 5, 6))
+
         toks, accs, self._last_token, self._lengths, \
-            self._cache_k, self._cache_v, self._hist = self._decode_jit[key](
+            self._cache_k, self._cache_v, self._hist = self._run_program(
+                self._decode_jit, ("spec", window), make,
                 self.params, self._last_token, self._lengths, self._active,
                 self._cache_k, self._cache_v, self._hist,
             )
@@ -2048,7 +2125,7 @@ class InferenceEngine:
 
     def _record_dispatch(self, n_decoding: int, pending: dict) -> None:
         """Per-window telemetry at dispatch time (batch occupancy, KV
-        utilization, queue depth) + the wall-clock stamp the drain uses
+        utilization, queue depth) + the monotonic stamp the drain uses
         for inter-token latency.  Only called when telemetry is on."""
         t = self.telemetry
         if t is None:  # callers gate too; cheap belt for new call sites
@@ -2057,7 +2134,7 @@ class InferenceEngine:
         t.record_kv_utilization(self._kv_used_fraction())
         t.record_queue_depth(self._queue.qsize())
         t.record_prefill_backlog(self._chunk_backlog())
-        pending["t0"] = time.time()
+        pending["t0"] = time.perf_counter()
 
     def _chunk_backlog(self) -> int:
         """Prompt tokens not yet dispatched across mid-chunking slots —
@@ -2073,9 +2150,11 @@ class InferenceEngine:
         if p is None:
             return
         self._pending = None
-        tokens_np = np.asarray(p["tokens"])
-        if p.get("spec"):
-            accs_np = np.asarray(p["accepted"])  # [W, B]
+        with jax.profiler.TraceAnnotation("engine.pull"):
+            tokens_np = np.asarray(p["tokens"])
+            accs_np = (np.asarray(p["accepted"])  # [W, B]
+                       if p.get("spec") else None)
+        if accs_np is not None:
             # acceptance observability: operators tune speculation_k (or
             # turn speculation off) from this ratio — draft tokens accepted
             # per verification step, over decoding slots only
@@ -2089,10 +2168,21 @@ class InferenceEngine:
                     # same counters, recorder-side: acceptance rate lands
                     # on /metrics next to the latency histograms
                     self.telemetry.record_spec(steps_n, accepted_n)
-            emitted = 0
+        emitted = 0
+        with jax.profiler.TraceAnnotation("engine.emit"):
             for step in range(p["window"]):
                 for slot_id, req in enumerate(self._slots):
                     if req is None or slot_id not in p["decoding"]:
+                        # finished mid-window (discard overshoot) or was
+                        # still prefilling at DISPATCH time (this window
+                        # carried junk for the slot even if its prefill
+                        # has since finished)
+                        continue
+                    if accs_np is None:
+                        self._host_lengths[slot_id] += 1  # mirrors device
+                        emitted += 1
+                        self._emit(slot_id, req,
+                                   int(tokens_np[step, slot_id]))
                         continue
                     for j in range(int(accs_np[step, slot_id]) + 1):
                         if self._slots[slot_id] is None:
@@ -2101,24 +2191,10 @@ class InferenceEngine:
                         emitted += 1
                         self._emit(slot_id, req,
                                    int(tokens_np[step, slot_id, j]))
-            if self.telemetry is not None and "t0" in p:
-                self.telemetry.record_drain(emitted, time.time() - p["t0"],
-                                            len(p["decoding"]))
-            return
-        emitted = 0
-        for step in range(p["window"]):
-            for slot_id, req in enumerate(self._slots):
-                if req is None or slot_id not in p["decoding"]:
-                    # finished mid-window (discard overshoot) or was still
-                    # prefilling at DISPATCH time (this window carried junk
-                    # for the slot even if its prefill has since finished)
-                    continue
-                self._host_lengths[slot_id] += 1  # mirrors device lengths
-                emitted += 1
-                self._emit(slot_id, req, int(tokens_np[step, slot_id]))
         if self.telemetry is not None and "t0" in p:
-            self.telemetry.record_drain(emitted, time.time() - p["t0"],
-                                        len(p["decoding"]))
+            self.telemetry.record_drain(
+                emitted, time.perf_counter() - p["t0"], len(p["decoding"]),
+                steps=p["window"], batch_size=self.batch_size)
 
     def _sample_first(self, logits, req: Request) -> int:
         """Sample a request's FIRST token with the same fused on-device
@@ -2132,20 +2208,18 @@ class InferenceEngine:
         the old and the fused path, so greedy first tokens are
         bit-identical; sampled ones are seed-deterministic through the
         engine's threaded ``jax.random`` key."""
-        key = "first_token"
-        if key not in self._prefill_jit:
-            def fn(lg, temp, top_p, top_k, rng):
-                return self._sample_on_device(
-                    lg[None, :], temp[None], top_p[None], top_k[None],
-                    rng)[0]
+        def fn(lg, temp, top_p, top_k, rng):
+            return self._sample_on_device(
+                lg[None, :], temp[None], top_p[None], top_k[None],
+                rng)[0]
 
-            self._prefill_jit[key] = self._jit_cached(
-                jax.jit(fn), "first_token_sample")
         if req.temperature > 0.0:
             self._rng_key, sub = jax.random.split(self._rng_key)
         else:
             sub = self._rng_key  # greedy ignores it; don't burn entropy
-        return int(self._prefill_jit[key](
+        return int(self._run_program(
+            self._prefill_jit, "first_token",
+            lambda: self._jit_cached(fn, "first_token_sample"),
             jnp.asarray(logits), jnp.float32(req.temperature),
             jnp.float32(req.top_p), jnp.int32(req.top_k or 0), sub))
 
@@ -2159,14 +2233,14 @@ class InferenceEngine:
             # cancelled mid-generation (stop sequence, client disconnect):
             # discard this token and free the slot for the queue
             req.finish_reason = req.finish_reason or "cancelled"
-            req.finished_at = time.time()
+            req.finished_at = req.now()
             self._release(slot_id)
             req.done.set()
             if self.telemetry is not None:
                 self.telemetry.record_finished(req)
             return
         if req.first_token_at is None:
-            req.first_token_at = time.time()
+            req.first_token_at = req.now()
             if self.telemetry is not None:
                 # once per request, never on the per-token path
                 self.telemetry.record_first_token(
@@ -2183,7 +2257,7 @@ class InferenceEngine:
             # reason — don't overwrite it with "length"
             req.finish_reason = req.finish_reason or (
                 "stop" if hit_eos else "length")
-            req.finished_at = time.time()
+            req.finished_at = req.now()
             self._release(slot_id)
             req.done.set()
             if self.telemetry is not None:

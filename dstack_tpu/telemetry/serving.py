@@ -18,7 +18,8 @@ republished with project/run/job/replica labels):
   prefill (real tokens / padded bucket) and per decode window
   (decoding slots / batch_size)
 - ``kv_utilization``        gauge — KV blocks (paged) or cache rows
-  (dense) in use, fraction of capacity
+  (dense) in use, fraction of capacity; ``kv_utilization_peak`` is its
+  highest value since start (what ``total_kv_blocks`` is sized against)
 - ``active_slots`` / ``queue_depth`` gauges
 - ``prefill_backlog_tokens`` gauge — prompt tokens still awaiting a
   chunked-prefill dispatch (the signal a router uses to avoid piling
@@ -26,12 +27,21 @@ republished with project/run/job/replica labels):
 - ``requests_total{outcome}``, ``prefill_tokens_total``,
   ``decode_tokens_total``, ``preemptions_total{reason}``,
   ``spec_steps_total``, ``spec_accepted_total`` counters
+- ``decode_steps_total`` / ``decode_slot_steps_total`` counters — decode
+  steps of the windows handed over so far, and the same times
+  ``batch_size``: what the device computed for them (a step costs the
+  same with one slot live or all of them).
+  ``decode_tokens_total`` over ``decode_slot_steps_total`` is the share of
+  that work that became a token; steps over
+  ``batch_occupancy_count{phase=decode}`` is the mean window size
+- ``programs_built_total{kind}`` counter — engine programs built (compiled
+  or loaded from a cache) by ``decode`` / ``prefill``; growth after
+  warm-up means a live request hit a new shape
 """
 
 from __future__ import annotations
 
 import time
-from collections import deque
 from typing import Dict, List, Optional
 
 from dstack_tpu.telemetry.recorder import (
@@ -96,7 +106,7 @@ def parse_load_headers(headers) -> Optional[Dict]:
 
 
 class EngineTelemetry:
-    """Recorder + ring buffer of recent per-request records.
+    """The engine's metrics recorder.
 
     ``tracer`` (a `dstack_tpu.telemetry.tracing.RequestTracer`) adds
     per-request attribution on top of the aggregates: the engine's
@@ -109,7 +119,7 @@ class EngineTelemetry:
     ``is None`` check.
     """
 
-    def __init__(self, ring_size: int = 512, tracer=None) -> None:
+    def __init__(self, tracer=None) -> None:
         self.tracer = tracer
         self.recorder = MetricsRecorder()
         r = self.recorder
@@ -124,16 +134,17 @@ class EngineTelemetry:
             PREFIX + "batch_occupancy", RATIO_BUCKETS,
             labels={"phase": "decode"})
         self.kv_utilization = r.gauge(PREFIX + "kv_utilization")
+        self.kv_utilization_peak = r.gauge(PREFIX + "kv_utilization_peak")
         self.active_slots = r.gauge(PREFIX + "active_slots")
         self.queue_depth = r.gauge(PREFIX + "queue_depth")
         self.prefill_backlog = r.gauge(PREFIX + "prefill_backlog_tokens")
         self.prefill_tokens = r.counter(PREFIX + "prefill_tokens_total")
         self.decode_tokens = r.counter(PREFIX + "decode_tokens_total")
+        self.decode_steps = r.counter(PREFIX + "decode_steps_total")
+        self.decode_slot_steps = r.counter(
+            PREFIX + "decode_slot_steps_total")
         self.spec_steps = r.counter(PREFIX + "spec_steps_total")
         self.spec_accepted = r.counter(PREFIX + "spec_accepted_total")
-        #: recent finished requests: {submitted_at, queue_wait, ttft, e2e,
-        #: tokens_out, finish_reason}
-        self.ring: deque = deque(maxlen=ring_size)
         self._started_at = time.time()
 
     # -- engine-thread recording hooks ----------------------------------
@@ -154,18 +165,6 @@ class EngineTelemetry:
         self.e2e.observe(e2e, exemplar=trace_id)
         self.recorder.counter(PREFIX + "requests_total",
                               labels={"outcome": outcome}).inc()
-        admitted = getattr(req, "admitted_at", None)
-        self.ring.append({
-            "submitted_at": req.submitted_at,
-            "queue_wait": (max(admitted - req.submitted_at, 0.0)
-                           if admitted else None),
-            "ttft": (max(req.first_token_at - req.submitted_at, 0.0)
-                     if req.first_token_at else None),
-            "e2e": e2e,
-            "tokens_out": len(req.output),
-            "finish_reason": outcome,
-            "trace_id": trace_id,
-        })
         if self.tracer is not None and trace_id is not None:
             self._record_request_spans(req, trace_id, now, outcome)
 
@@ -232,12 +231,20 @@ class EngineTelemetry:
             self.decode_occupancy.observe(min(decoding / batch_size, 1.0))
 
     def record_drain(self, tokens_emitted: int, wall: float,
-                     decoding: int = 1) -> None:
+                     decoding: int = 1, steps: int = 0,
+                     batch_size: int = 0) -> None:
         """``wall`` is the dispatch->drain time of one decode window that
         emitted ``tokens_emitted`` tokens across ``decoding`` slots.  The
         PER-REQUEST token gap is wall / (tokens per request) — dividing by
         the total emitted would shrink the metric with batch occupancy
-        and understate what any single stream experiences."""
+        and understate what any single stream experiences.
+
+        The window's ``steps`` (a speculative window's verification
+        steps) and ``steps * batch_size`` slot-steps are counted HERE, with
+        the tokens they produced, so that tokens over slot-steps between
+        any two readings compares the same windows."""
+        self.decode_steps.inc(steps)
+        self.decode_slot_steps.inc(steps * batch_size)
         if tokens_emitted <= 0:
             return
         self.decode_tokens.inc(tokens_emitted)
@@ -245,7 +252,10 @@ class EngineTelemetry:
             max(wall, 0.0) * max(decoding, 1) / tokens_emitted)
 
     def record_kv_utilization(self, fraction: float) -> None:
-        self.kv_utilization.set(min(max(fraction, 0.0), 1.0))
+        fraction = min(max(fraction, 0.0), 1.0)
+        self.kv_utilization.set(fraction)
+        if fraction > self.kv_utilization_peak.value:
+            self.kv_utilization_peak.set(fraction)
 
     def record_queue_depth(self, depth: int) -> None:
         self.queue_depth.set(depth)
@@ -258,6 +268,12 @@ class EngineTelemetry:
     def record_preemption(self, reason: str) -> None:
         self.recorder.counter(PREFIX + "preemptions_total",
                               labels={"reason": reason}).inc()
+
+    def record_program_built(self, kind: str) -> None:
+        """The engine built a ``decode`` or ``prefill`` program: first use
+        of a shape, a compile or a cache load."""
+        self.recorder.counter(PREFIX + "programs_built_total",
+                              labels={"kind": kind}).inc()
 
     def record_spec(self, steps: int, accepted: int) -> None:
         self.spec_steps.inc(steps)
@@ -281,22 +297,14 @@ class EngineTelemetry:
         return self.recorder.samples()
 
     def stats(self) -> Dict:
-        """JSON for ``/stats``: recorder summary + ring-derived recency.
+        """JSON for ``/stats``: the recorder's summary plus uptime.
 
         The histogram snapshots inside are the gateway's aggregation
         input (mergeable across replicas); ``percentiles`` are this
         replica's own p50/p95/p99.
         """
         out = self.recorder.summary()
-        recent = list(self.ring)
-        out["recent_requests"] = len(recent)
         out["uptime_seconds"] = max(time.time() - self._started_at, 0.0)
-        if recent:
-            window = [r for r in recent
-                      if r["submitted_at"] > time.time() - 300]
-            out["recent_finished_5m"] = len(window)
-            out["recent_tokens_out_5m"] = sum(
-                r["tokens_out"] for r in window)
         return out
 
 

@@ -180,11 +180,12 @@ async def check_serving_metrics() -> int:
     tel.record_first_token(0.04, trace_id=trace_id)
     tel.record_prefill(100, 128)
     tel.record_window(6, 8)
-    tel.record_drain(64, 0.5)
+    tel.record_drain(64, 0.5, steps=64, batch_size=8)
     tel.record_kv_utilization(0.4)
     tel.record_prefill_backlog(512)
     tel.record_preemption("kv_blocks_exhausted")
     tel.record_spec(10, 7)
+    tel.record_program_built("decode")
 
     class _Req:
         submitted_at = 1.0
@@ -227,11 +228,15 @@ async def check_serving_metrics() -> int:
             "dstack_serving_e2e_seconds_count",
             "dstack_serving_batch_occupancy_bucket",
             "dstack_serving_kv_utilization",
+            "dstack_serving_kv_utilization_peak",
             "dstack_serving_active_slots",
             "dstack_serving_queue_depth",
             "dstack_serving_prefill_backlog_tokens",
             "dstack_serving_prefill_tokens_total",
             "dstack_serving_decode_tokens_total",
+            "dstack_serving_decode_steps_total",
+            "dstack_serving_decode_slot_steps_total",
+            "dstack_serving_programs_built_total",
             "dstack_serving_preemptions_total",
             "dstack_serving_spec_steps_total",
             "dstack_serving_spec_accepted_total",

@@ -2078,6 +2078,47 @@ def test_dt802_jit_constructed_in_loop_vs_memoized():
     """, "dstack_tpu/serving/snip.py") == []
 
 
+def test_dt801_leaves_passed_through_the_engines_program_runner():
+    """``_run_program(table, key, make, *leaves)`` calls the cached-jit
+    callable for its caller: the leaves after the maker are traced."""
+    assert codes("""
+        import jax.numpy as jnp
+        class Eng:
+            def tick(self, x):
+                return self._run_program(self._jits, ("k", 4), self._make,
+                                         jnp.asarray(x), 7)
+    """, "dstack_tpu/serving/snip.py") == ["DT801"]
+    # the table key and the maker are not leaves
+    assert codes("""
+        import jax.numpy as jnp
+        class Eng:
+            def tick(self, x):
+                return self._run_program(self._jits, 4, self._make,
+                                         jnp.asarray(x), jnp.int32(7))
+    """, "dstack_tpu/serving/snip.py") == []
+
+
+def test_dt801_naming_jit_helper_reads_its_own_static_spec():
+    out = lint("""
+        f = _named_jit(step, "step", static_argnums=(1,))
+        def run(x):
+            return f(x, 4, 3.0)
+    """, "dstack_tpu/serving/snip.py")
+    assert [f.code for f in out] == ["DT801"]
+    assert "3.0" in out[0].message
+
+
+def test_dt303_function_handed_to_a_naming_jit_helper_is_traced():
+    assert codes("""
+        class Eng:
+            def build(self):
+                def fn(x):
+                    print(x)
+                    return x
+                return self._jit_cached(fn, "prefill_b32")
+    """, "dstack_tpu/serving/snip.py") == ["DT303"]
+
+
 def test_dt8xx_scoped_to_compile_planes():
     # same loop construction outside serving/models/elastic: silent
     assert codes("""
