@@ -699,13 +699,19 @@ def flash_attention_sharded(mesh, q, k, v, *, batch_axes=("dcn", "data", "fsdp")
 # intermediate view.  int8 KV pages ({"q","s"} per serving/quant.py)
 # dequantize in-kernel after the page load — packed bytes are what stream.
 #
-# A block is one WHOLE page, all kv heads: the pool's [BS, Hkv, D] page is
-# viewed as [BS, Hkv*D] (a free reshape of contiguous memory), so the
-# block's last two dims equal the array's — the only shape the TPU
-# lowering accepts here (one head of a page would put 1 of Hkv in the
+# A block is one WHOLE page, all kv heads, and the pool is STORED in the
+# block's form: [L, NUM_BLOCKS, BS, Hkv*D], lane = h*D + d.  The block's
+# last two dims equal the array's — the only shape the TPU lowering
+# accepts here (one head of a page would put 1 of Hkv in the
 # second-to-last dim, which is neither full nor a multiple of 8; the
 # described-v5e compile tests in tests/compute/test_tpu_compile.py hold
 # this).  The kernel walks the heads with static lane slices.
+#
+# The operand IS the stored pool, layer and all: on the chip a custom
+# call's operand is a buffer of its own in the default tiled layout, so a
+# [.., Hkv, D] -> [.., Hkv*D] reshape or a per-layer slice of a stacked
+# pool is a copy of the layer's whole pool, every layer of every step.
+# The layer is a scalar-prefetched index the page's index map adds.
 #
 # Returns a NORMALIZED output plus the softmax logsumexp so the caller can
 # merge other attention pieces (the engine's in-window KV buffer) without
@@ -713,9 +719,9 @@ def flash_attention_sharded(mesh, q, k, v, *, batch_axes=("dcn", "data", "fsdp")
 # zero weight under any logsumexp merge.
 
 
-def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *rest,
+def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, *rest,
                          scale, bs, nbk, quant):
-    del tables_ref  # consumed by the index maps
+    del layer_ref, tables_ref  # consumed by the index maps
     _, hkv, _, d = q_ref.shape
     if quant:
         k_ref, ks_ref, v_ref, vs_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
@@ -737,13 +743,13 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *rest,
         kpos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         for h in range(hkv):
             q = q_ref[0, h]                       # [G, D]
-            k = k_ref[0, :, h * d:(h + 1) * d]    # [BS, D]
-            v = v_ref[0, :, h * d:(h + 1) * d]
+            k = k_ref[0, 0, :, h * d:(h + 1) * d]  # [BS, D]
+            v = v_ref[0, 0, :, h * d:(h + 1) * d]
             if quant:
                 k = (k.astype(jnp.float32)
-                     * ks_ref[0, :, h:h + 1]).astype(q.dtype)
+                     * ks_ref[0, 0, :, h:h + 1]).astype(q.dtype)
                 v = (v.astype(jnp.float32)
-                     * vs_ref[0, :, h:h + 1]).astype(q.dtype)
+                     * vs_ref[0, 0, :, h:h + 1]).astype(q.dtype)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -771,15 +777,18 @@ def _paged_decode_kernel(tables_ref, lengths_ref, q_ref, *rest,
             l > 0, m_scr[...] + jnp.log(safe_l), _NEG_INF)
 
 
-def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
+def paged_decode_attention(q, k_pages, v_pages, layer, tables, lengths, *,
                            scale: float | None = None):
     """Paged single-token GQA decode attention over block tables.
 
     q: [B, Hkv, G, D] (query heads grouped under their kv head);
-    k_pages/v_pages: [NUM_BLOCKS, BS, Hkv, D] paged pools, or int8
-    ``{"q", "s"}`` dicts (scales [NUM_BLOCKS, BS, Hkv]); tables: int32
-    [B, NBK] table columns (0 = NULL block) — pass a sliced table to bound
-    the walk at a ragged bucket; lengths: int32 [B] valid KV rows per slot.
+    k_pages/v_pages: the STACKED paged pools as the engine stores them,
+    [L, NUM_BLOCKS, BS, Hkv*D] (lane = h*D + d), or int8 ``{"q", "s"}``
+    dicts (scales [L, NUM_BLOCKS, BS, Hkv]); layer: int32 scalar (or [1]),
+    the layer whose pages to read — addressed in place, never sliced out;
+    tables: int32 [B, NBK] table columns (0 = NULL block) — pass a sliced
+    table to bound the walk at a ragged bucket; lengths: int32 [B] valid
+    KV rows per slot.
 
     Returns ``(o, lse)``: o float32 [B, Hkv, G, D] NORMALIZED over the
     slot's ``length`` cache rows, lse float32 [B, Hkv, G] (-inf where
@@ -788,7 +797,11 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
     engine keeps those on the XLA gather path.
 
     The kernel runs per device: under a mesh, call it inside ``shard_map``
-    with the kv-head dim sharded (the engine does).
+    with the lane dim sharded (the engine does): a shard holds whole heads,
+    (Hkv/tp)*D lanes.  Any lane width compiles, because the block spans
+    the shard's whole last dim, but only a multiple of 128 is stored in
+    the operand's form: the TPU compiler keeps any other pool with the
+    blocks minor-most and converts all of it around the call.
     """
     quant = isinstance(k_pages, dict)
     if quant and "q4" in k_pages:
@@ -798,28 +811,31 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
     b, hkv, group, d = q.shape
     nbk = tables.shape[1]
     kq, vq = (k_pages["q"], v_pages["q"]) if quant else (k_pages, v_pages)
-    num_blocks, bs = kq.shape[:2]
+    bs = kq.shape[2]
+    if kq.ndim != 4 or kq.shape[3] != hkv * d:
+        raise ValueError(
+            f"pages must be [L, NUM_BLOCKS, BS, Hkv*D = {hkv * d}], got "
+            f"{kq.shape}")
     if scale is None:
         scale = d ** -0.5
 
-    def whole(bb, i, tables, lengths):
+    def whole(bb, i, layer, tables, lengths):
         return (bb, 0, 0, 0)
 
-    def page(bb, i, tables, lengths):
-        return (tables[bb, i], 0, 0)  # the table entry IS the page index
+    def page(bb, i, layer, tables, lengths):
+        # the table entry IS the page index, the layer its plane
+        return (layer[0], tables[bb, i], 0, 0)
 
     def spec(block, index_map):
         return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
 
-    flat = (num_blocks, bs, hkv * d)
-    k_spec = spec((1, bs, hkv * d), page)
+    k_spec = spec((1, 1, bs, hkv * d), page)
     if quant:
-        s_spec = spec((1, bs, hkv), page)
-        inputs = (q, kq.reshape(flat), k_pages["s"],
-                  vq.reshape(flat), v_pages["s"])
+        s_spec = spec((1, 1, bs, hkv), page)
+        inputs = (q, kq, k_pages["s"], vq, v_pages["s"])
         in_specs = [k_spec, s_spec, k_spec, s_spec]
     else:
-        inputs = (q, kq.reshape(flat), vq.reshape(flat))
+        inputs = (q, kq, vq)
         in_specs = [k_spec, k_spec]
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale, bs=bs,
@@ -827,7 +843,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, nbk),
             in_specs=[spec((1, hkv, group, d), whole)] + in_specs,
             out_specs=[spec((1, hkv, group, d), whole),
@@ -844,7 +860,8 @@ def paged_decode_attention(q, k_pages, v_pages, tables, lengths, *,
         ],
         name="paged_decode_attention",
         interpret=_interpret(),
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *inputs)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
+      lengths.astype(jnp.int32), *inputs)
     return o, lse[..., 0]
 
 

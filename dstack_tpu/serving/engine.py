@@ -272,17 +272,49 @@ def _kv_pack(rows, bits: int = 8):
     return {"q": q, "s": s}
 
 
-def _kv_map(cache, rows, fn):
+def _kv_map(cache, rows, fn, lanes: bool = False):
     """Apply ``fn(cache_leaf, rows_leaf)`` over a cache that is either a
     plain array or a quantized {"q"|"q4","s"} dict (rows packed to
     match).  ``fn`` must be shape-generic over trailing dims: the int4
-    "q4" leaf carries D/2 packed bytes and "s" no D dim at all."""
+    "q4" leaf carries D/2 packed bytes and "s" no D dim at all.
+    ``lanes``: the cache is the PAGED pool, whose leaves fold the kv heads
+    into the lane dim (:func:`_fold_heads`; "s" keeps [..., Hkv]) — the
+    new rows are folded to match, never the pool."""
+    fold = _fold_heads if lanes else (lambda a: a)
     if isinstance(cache, dict):
         qk = "q4" if "q4" in cache else "q"
         packed = _kv_pack(rows, bits=4 if qk == "q4" else 8)
-        return {qk: fn(cache[qk], packed[qk]),
+        return {qk: fn(cache[qk], fold(packed[qk])),
                 "s": fn(cache["s"], packed["s"])}
-    return fn(cache, rows)
+    return fn(cache, fold(rows))
+
+
+def _fold_heads(a):
+    """[..., Hkv, D] K/V rows -> [..., Hkv*D], lane = h*D + d: the paged
+    pool's stored form, which is the decode kernel's operand."""
+    return a.reshape(a.shape[:-2] + (-1,))
+
+
+def _split_heads(view, hkv: int):
+    """Inverse of :func:`_fold_heads` for a view GATHERED from the paged
+    pool (array or quantized dict; "s" is [..., Hkv] already)."""
+    split = lambda a: a.reshape(a.shape[:-1] + (hkv, -1))
+    if isinstance(view, dict):
+        return {key: (leaf if key == "s" else split(leaf))
+                for key, leaf in view.items()}
+    return split(view)
+
+
+def _scatter_rows(leaf, idx, rows):
+    """Write ``rows`` [..., X] at flat row indices ``idx`` (layer, block,
+    offset folded: (l*NB + blk)*BS + off) of a paged pool leaf
+    [L, NB, BS, X].  A row scatter over the pool's merged leading dims
+    updates it in place under donation; indexing the layer as a window
+    dim (``.at[:, blk, off]``) makes XLA change the WHOLE pool's layout on
+    the way in and out (tests/compute/test_tpu_compile.py holds this)."""
+    x = leaf.shape[-1]
+    return leaf.reshape(-1, x).at[idx.reshape(-1)].set(
+        rows.reshape(-1, x)).reshape(leaf.shape)
 
 
 @jax.named_scope("kv_window_write")
@@ -304,19 +336,22 @@ def _dense_window_insert(cache, win, widx, in_window):
 
 
 def _suffix_layer(x, lp, cfg: LlamaConfig, positions, inv_freqs, kv_pos,
-                  token_mask, layer_k, layer_v, insert, gather):
+                  token_mask, layer_k, layer_v, insert, gather,
+                  lanes: bool = False):
     """One transformer layer of a suffix/chunk prefill: project the new
     tokens' K/V, ``insert`` them into the slot's cache, then attend the
     new queries over the ``gather``-ed full slot span (earlier rows +
     causal within the new ones, absolute RoPE positions).  The insert and
     gather callbacks are the ONLY difference between the paged suffix
     prefill (block scatter/gather) and the dense chunked prefill (row
-    slice) — both share this body."""
+    slice) — both share this body.  The paged one (``lanes``) hands the
+    WHOLE pool through as ``layer_k``/``layer_v``: its callbacks address
+    the layer in place."""
     sbucket = x.shape[1]
     q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, 1, sbucket)
     with jax.named_scope("kv_insert"):
-        layer_k = _kv_map(layer_k, k, insert)
-        layer_v = _kv_map(layer_v, v, insert)
+        layer_k = _kv_map(layer_k, k, insert, lanes)
+        layer_v = _kv_map(layer_v, v, insert, lanes)
     kv_k = _kv_mat(gather(layer_k), cfg.dtype)
     kv_v = _kv_mat(gather(layer_v), cfg.dtype)
     attn = _masked_attention(q, kv_k, kv_v, positions, kv_pos)
@@ -484,6 +519,7 @@ class InferenceEngine:
         self._paged_kernel = _paged_kernel_default()
         self.mesh = mesh
         self._policy = None
+        t = 1  # tensor-parallel degree
         if mesh is not None:
             from dstack_tpu.models.llama import ShardingPolicy
 
@@ -533,6 +569,20 @@ class InferenceEngine:
                     "the dense equivalent (%d): decode still needs a "
                     "dense-equivalent linear-view allowance in HBM "
                     "(see ROOFLINE.md, serving decode)", n_blocks, dense_equiv)
+            lanes = cfg.num_kv_heads * cfg.head_dim // t
+            if self._paged_kernel and lanes % 128:
+                # the pool is stored as the decode kernel's operand only
+                # in whole 128-lane tiles: the TPU compiler keeps a
+                # narrower or ragged pool with the blocks minor-most and
+                # converts ALL of it around every program that reads it
+                # (tests/compute/test_tpu_compile.py)
+                logger.warning(
+                    "paged KV pool rows are %d lanes a device (kv heads x "
+                    "head_dim / tensor degree), not a multiple of 128: the "
+                    "TPU converts the whole pool's layout around every "
+                    "decode window and holds a second copy of it; use a "
+                    "tensor degree that leaves whole multiples of 128",
+                    lanes)
             self._tables_host = np.zeros(
                 (batch_size, self._blocks_per_slot), np.int32)
             self._slot_blocks: List[List[int]] = [[] for _ in range(batch_size)]
@@ -699,39 +749,45 @@ class InferenceEngine:
                             is_leaf=lambda x: isinstance(x, P))
 
     def _kv_sharding(self):
-        """KV caches shard over KV heads (dim 3 in both layouts; the
-        quantized scale tensors lack the trailing D dim — int4's packed
-        "q4" leaf keeps it, just half as wide)."""
+        """KV caches shard over KV heads.  Dense: dim 3 (the quantized
+        scale tensors lack the trailing D dim — int4's packed "q4" leaf
+        keeps it, just half as wide).  Paged: the last dim of every leaf,
+        Hkv*D lanes (head-major, so a shard holds whole heads) or the Hkv
+        scales."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         t = self._policy.tensor_axis
-        full = NamedSharding(self.mesh, P(None, None, None, t, None))
+        scales = NamedSharding(self.mesh, P(None, None, None, t))
+        full = scales if self.paged else NamedSharding(
+            self.mesh, P(None, None, None, t, None))
         if not self.kv_quant:
             return full
         qk = "q4" if self.kv_quantize == "int4" else "q"
-        return {qk: full,
-                "s": NamedSharding(self.mesh, P(None, None, None, t))}
+        return {qk: full, "s": scales}
 
     def _reset_device_state(self) -> None:
         """(Re-)allocate the KV cache and slot state.  Called at init and
         after a device-side decode failure (the decode jit donates the
         caches, so a raise mid-execution leaves them deleted)."""
         cfg, b = self.cfg, self.batch_size
-        if self.paged:
-            shape = (cfg.num_layers, self._alloc.num_blocks,
-                     self._block_size, cfg.num_kv_heads, cfg.head_dim)
-        else:
-            shape = (cfg.num_layers, b, self.max_len, cfg.num_kv_heads,
-                     cfg.head_dim)
+        lead = ((cfg.num_layers, self._alloc.num_blocks, self._block_size)
+                if self.paged else (cfg.num_layers, b, self.max_len))
+        hkv = cfg.num_kv_heads
+        scales = lead + (hkv,)
+
+        def values(d: int):
+            # the paged pool is stored in the form the decode kernel
+            # reads: kv heads folded into the lane dim (_fold_heads)
+            return lead + ((hkv * d,) if self.paged else (hkv, d))
+
         def mk_zeros():
             if self.kv_quantize == "int4":
-                return {"q4": jnp.zeros(shape[:-1] + (shape[-1] // 2,),
-                                        jnp.int8),
-                        "s": jnp.zeros(shape[:-1], jnp.float32)}
+                return {"q4": jnp.zeros(values(cfg.head_dim // 2), jnp.int8),
+                        "s": jnp.zeros(scales, jnp.float32)}
             if self.kv_quant:
-                return {"q": jnp.zeros(shape, jnp.int8),
-                        "s": jnp.zeros(shape[:-1], jnp.float32)}
-            return jnp.zeros(shape, cfg.dtype)
+                return {"q": jnp.zeros(values(cfg.head_dim), jnp.int8),
+                        "s": jnp.zeros(scales, jnp.float32)}
+            return jnp.zeros(values(cfg.head_dim), cfg.dtype)
 
         if self.mesh is not None:
             # allocate sharded directly — never the full cache on one
@@ -1262,21 +1318,30 @@ class InferenceEngine:
             # MoE: padding must not claim expert capacity
             token_mask = (jnp.arange(sbucket) < suffix_len)[None, :]
 
-            scatter = lambda leaf, rows: leaf.at[blk, off].set(rows[0])
-            gather = lambda layer_kv: jax.tree.map(
-                lambda a: a[tables_row].reshape(
-                    (kv_span,) + a.shape[2:])[None], layer_kv)
+            nb = self._alloc.num_blocks
 
             def layer(carry, inputs):
-                x = carry
-                lp, layer_k, layer_v = inputs
-                x, layer_k, layer_v = _suffix_layer(
+                # the pool travels in the carry and is addressed at
+                # [layer, block, offset] by flat row: scanned as xs/ys it
+                # would be sliced and restacked, a copy of the layer's
+                # whole pool each way
+                x, pool_k, pool_v = carry
+                lp, l = inputs
+                scatter = lambda leaf, rows: _scatter_rows(
+                    leaf, (l * nb + blk) * bs + off, rows[0])
+                gather = lambda pool: _split_heads(jax.tree.map(
+                    lambda a: a.reshape((-1,) + a.shape[2:])[
+                        l * nb + tables_row].reshape(
+                            1, kv_span, a.shape[-1]), pool),
+                    cfg.num_kv_heads)
+                x, pool_k, pool_v = _suffix_layer(
                     x, lp, cfg, positions, inv_freqs, kv_pos, token_mask,
-                    layer_k, layer_v, scatter, gather)
-                return x, (layer_k, layer_v)
+                    pool_k, pool_v, scatter, gather, lanes=True)
+                return (x, pool_k, pool_v), None
 
-            x, (cache_k, cache_v) = jax.lax.scan(
-                layer, x, (params["layers"], cache_k, cache_v))
+            (x, cache_k, cache_v), _ = jax.lax.scan(
+                layer, (x, cache_k, cache_v),
+                (params["layers"], jnp.arange(cfg.num_layers)))
             logits = _last_logits(params, cfg, x, suffix_len)
             return logits, cache_k, cache_v
 
@@ -1345,13 +1410,15 @@ class InferenceEngine:
                                              bucket)
 
             def insert(leaf, rows):
+                # the new rows take the pool's blocked form, never the
+                # pool theirs: whole blocks, every layer, in place
                 blocked = rows.reshape(
                     (cfg.num_layers, nblk, bs) + rows.shape[2:])
                 return leaf.at[:, bids].set(blocked)
 
             with jax.named_scope("kv_insert"):
-                cache_k = _kv_map(cache_k, ks[:, 0], insert)
-                cache_v = _kv_map(cache_v, vs[:, 0], insert)
+                cache_k = _kv_map(cache_k, ks[:, 0], insert, lanes=True)
+                cache_v = _kv_map(cache_v, vs[:, 0], insert, lanes=True)
             return logits, cache_k, cache_v
 
         return self._jit_cached(fn, f"prefill_paged_b{bucket}",
@@ -1502,8 +1569,8 @@ class InferenceEngine:
 
         ks = jnp.asarray(ks_np, dtype=self.cfg.dtype)  # [L, rows, Hkv, D]
         vs = jnp.asarray(vs_np, dtype=self.cfg.dtype)
-        self._cache_k = _kv_map(self._cache_k, ks, insert)
-        self._cache_v = _kv_map(self._cache_v, vs, insert)
+        self._cache_k = _kv_map(self._cache_k, ks, insert, self.paged)
+        self._cache_v = _kv_map(self._cache_v, vs, insert, self.paged)
         if p.get("logits") is not None:
             # request-aware first token (temperature/top_p/top_k honored;
             # PD-wire logits arrive as numpy — asarray is host->device)
@@ -1614,22 +1681,28 @@ class InferenceEngine:
         # attended from the buffer instead)
         cache_mask = (kv_index < base_len[:, None])[:, None, None, :]
         if use_kernel:
-            # the kernel reads blocks in place through the table — scan
-            # the paged cache itself; no linear view, no gather
-            view_k, view_v = cache_k, cache_v
+            # the kernel reads blocks in place through the table, out of
+            # the stored pool: the layer scan carries the layer's INDEX and
+            # the kernel closes over the whole pool — no linear view, no
+            # gather, and no per-layer slice of the pool (a scanned pool is
+            # sliced into a buffer of its own for the custom call: a copy
+            # of the layer's whole K and V pool every layer-step)
+            layer_kv = jnp.arange(cfg.num_layers)
         elif self.paged:
             # one gather for the whole window: [L, B, span, ...] linear
             # views of each slot's blocks (read-only until the final
             # insert; quantized caches gather the packed bytes — half
-            # (int8) or a quarter (int4) of the bf16 traffic)
+            # (int8) or a quarter (int4) of the bf16 traffic); the heads
+            # unfold on the gathered view, not on the pool
             def gather_view(cache):
-                return jax.tree.map(
+                return _split_heads(jax.tree.map(
                     lambda a: a[:, tables].reshape(
-                        (cfg.num_layers, b, kv_span) + a.shape[3:]), cache)
+                        cfg.num_layers, b, kv_span, a.shape[-1]), cache),
+                    hkv)
 
-            view_k, view_v = gather_view(cache_k), gather_view(cache_v)
+            layer_kv = (gather_view(cache_k), gather_view(cache_v))
         else:
-            view_k, view_v = cache_k, cache_v
+            layer_kv = (cache_k, cache_v)
 
         if use_kernel:
             from dstack_tpu.ops.flash_attention import (
@@ -1643,12 +1716,12 @@ class InferenceEngine:
 
                 t = self._policy.tensor_axis
                 heads = P(None, t, None, None)    # q, o: [B, Hkv, G, D]
-                pages = P(None, None, t, None)    # [NUM_BLOCKS, BS, Hkv, D]
+                pages = P(None, None, None, t)    # every leaf of the pool
                 if self.kv_quant:
-                    pages = {"q": pages, "s": P(None, None, t)}
+                    pages = {"q": pages, "s": pages}
                 paged_attn = jax.shard_map(
                     paged_attn, mesh=self.mesh,
-                    in_specs=(heads, pages, pages, P(), P()),
+                    in_specs=(heads, pages, pages, P(), P(), P()),
                     out_specs=(heads, P(None, t, None)), check_vma=False)
 
         win_shape = (cfg.num_layers, w, b, hkv, cfg.head_dim)
@@ -1667,7 +1740,7 @@ class InferenceEngine:
 
             def layer(carry, inputs):
                 x = carry
-                lp, layer_k, layer_v, wk, wv = inputs
+                lp, kv, wk, wv = inputs
                 q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, b)
                 # stash this step's K/V in the window buffer (small, in-place)
                 wk = jax.lax.dynamic_update_index_in_dim(wk, k[:, 0], i, 0)
@@ -1681,7 +1754,7 @@ class InferenceEngine:
                     # reduction order aside
                     with jax.named_scope("paged_attn"):
                         o_c, lse_c = paged_attn(
-                            qg, layer_k, layer_v, tables, base_len)
+                            qg, cache_k, cache_v, kv, tables, base_len)
                     with jax.named_scope("attn"):
                         s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
                         s_w = jnp.where(win_mask, s_w,
@@ -1702,8 +1775,8 @@ class InferenceEngine:
                 else:
                     with jax.named_scope("attn"):
                         # quantized dequant fuses in
-                        lk = _kv_mat(layer_k, x.dtype)
-                        lv = _kv_mat(layer_v, x.dtype)
+                        lk = _kv_mat(kv[0], x.dtype)
+                        lv = _kv_mat(kv[1], x.dtype)
                         s_c = jnp.einsum("bhgd,bkhd->bhgk", qg, lk) * scale
                         s_c = jnp.where(cache_mask, s_c, -1e30)
                         s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
@@ -1719,7 +1792,7 @@ class InferenceEngine:
                 return x, (wk, wv)
 
             x, (win_k, win_v) = jax.lax.scan(
-                layer, x, (params["layers"], view_k, view_v, win_k, win_v))
+                layer, x, (params["layers"], layer_kv, win_k, win_v))
             with jax.named_scope("lm_head"):
                 x = rms_norm(x, params["final_norm"], cfg.rms_eps)
                 logits = qmatmul(x, head, cfg.dtype,
@@ -1752,12 +1825,16 @@ class InferenceEngine:
                 safe, jnp.take_along_axis(tables, blk_col, axis=1), 0)
             off = pos % bs
 
-            # win: [L, W, B, ...] -> rows indexed by (phys, off) per (b, j)
+            # win: [L, W, B, ...] -> rows of the pool by flat index, per
+            # (l, b, j); masked rows collide in the NULL blocks, so the
+            # indices are not unique
+            idx = ((jnp.arange(cfg.num_layers)[:, None, None]
+                    * self._alloc.num_blocks + phys[None]) * bs + off[None])
+
             @jax.named_scope("kv_window_write")
             def scatter(cache, win):
-                return _kv_map(cache, win, lambda leaf, rows:
-                               leaf.at[:, phys, off].set(
-                                   jnp.moveaxis(rows, 1, 2)))
+                return _kv_map(cache, win, lambda leaf, rows: _scatter_rows(
+                    leaf, idx, jnp.moveaxis(rows, 1, 2)), lanes=True)
 
             cache_k = scatter(cache_k, win_k)
             cache_v = scatter(cache_v, win_v)
@@ -2015,20 +2092,23 @@ class InferenceEngine:
                 return self._dispatch_window_spec(remaining, window)
             return self._dispatch_window_plain(remaining, window, sampling)
 
+    def _decode_window_program(self, window: int, sampling: bool,
+                               nbk: Optional[int]):
+        """The jitted plain decode window for one (window, sampling,
+        table-bucket) key."""
+        return self._jit_cached(
+            functools.partial(self._decode_window_fn_buffered,
+                              window=window, sampling=sampling,
+                              kv_blocks=nbk),
+            f"decode_w{window}_s{int(sampling)}"
+            + (f"_kb{nbk}" if nbk is not None else ""),
+            donate_argnums=(4, 5))
+
     def _dispatch_window_plain(self, remaining: int, window: int,
                                sampling: bool):
         """Dispatch a plain (non-speculative) window: build its tables and
         per-slot constants, enqueue the program."""
         nbk = self._ragged_blocks(window) if self.paged else None
-
-        def make():
-            return self._jit_cached(
-                functools.partial(self._decode_window_fn_buffered,
-                                  window=window, sampling=sampling,
-                                  kv_blocks=nbk),
-                f"decode_w{window}_s{int(sampling)}"
-                + (f"_kb{nbk}" if nbk is not None else ""),
-                donate_argnums=(4, 5))
 
         # Host->device transfers are RPC round-trips on remote-dispatch
         # backends — per WINDOW they must be near zero, so everything below
@@ -2063,7 +2143,9 @@ class InferenceEngine:
             sub = self._rng_key
         tokens_all, self._last_token, self._lengths, \
             self._cache_k, self._cache_v = self._run_program(
-                self._decode_jit, (window, sampling, nbk), make,
+                self._decode_jit, (window, sampling, nbk),
+                functools.partial(self._decode_window_program, window,
+                                  sampling, nbk),
                 self.params, self._last_token, self._lengths, self._active,
                 self._cache_k, self._cache_v, temps, top_ps, top_ks, tables,
                 sub,

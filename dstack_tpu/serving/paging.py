@@ -1,8 +1,14 @@
 """Block allocator for the paged KV cache.
 
 vLLM-style paging, TPU-shaped: the cache is [L, num_blocks, block_size,
-Hkv, D]; a slot's logical sequence maps to physical blocks through a
-per-slot block table.  Block 0 is a reserved NULL block — padding table
+Hkv*D] (kv heads folded into the lane dim, lane = h*D + d: the form the
+decode kernel's operand has, so no program reshapes, slices or re-lays a
+layer of it; the quantized scales are [L, num_blocks, block_size, Hkv]);
+a slot's logical sequence maps to physical blocks through a per-slot
+block table.  On the chip the per-head form [.., Hkv, D] is not "the same
+memory": the kernel's operand is a buffer of its own in the tiled layout,
+and XLA copied every layer's whole pool to make it, every decode step.
+Block 0 is a reserved NULL block — padding table
 entries of inactive/short slots point at it, stray masked writes land in
 it, and it is never handed out — so scatter/gather with padded tables
 needs no bounds branching on device.

@@ -202,6 +202,35 @@ def test_paged_kernel_and_its_scope_are_named(model, lowered, monkeypatch):
     assert "paged_decode_attention" in decode
 
 
+@pytest.mark.parametrize("kernel", ["1", "0"], ids=["kernel", "gather"])
+def test_no_program_slices_a_layer_out_of_the_pool(model, lowered,
+                                                   monkeypatch, kernel):
+    """The pool is stored ``[L, blocks, block, Hkv*D]`` and every paged
+    program addresses a layer in place: no lowered program holds a tensor
+    of ONE layer of it (what a scan over the pool as ``xs`` slices out
+    and, as ``ys``, stacks back), and the decode window calls the kernel
+    once, in its layer loop."""
+    monkeypatch.setenv("DSTACK_TPU_PAGED_ATTN_KERNEL", kernel)
+    engine = _engine(model)
+    engine.generate(list(range(1, 41)), max_new_tokens=3)
+    engine.generate(list(range(1, 101)), max_new_tokens=3)  # chunked
+    layers, blocks, block, lanes = engine._cache_k.shape
+    cfg = engine.cfg
+    assert (layers, lanes) == (cfg.num_layers,
+                               cfg.num_kv_heads * cfg.head_dim)
+    assert {"prefill_paged_b64", "prefill_prefix_b64"} <= set(lowered)
+    for name, text in lowered.items():
+        if not name.startswith(("decode_", "prefill_")):
+            continue
+        assert f"tensor<{layers}x{blocks}x{block}x{lanes}x" in text, name
+        assert f"tensor<1x{blocks}x{block}x{lanes}x" not in text, name
+        assert f"tensor<{blocks}x{block}x{lanes}x" not in text, name
+    decode = next(t for n, t in lowered.items() if n.startswith("decode_"))
+    assert decode.count("paged_decode_attention") >= int(kernel)
+    if kernel == "0":
+        assert "paged_decode_attention" not in decode
+
+
 def test_decode_counters_add_up(traced):
     """Slot-steps are steps x all slots (what the device computes), tokens
     handed over never exceed them, and a window's steps are counted where

@@ -147,14 +147,23 @@ def test_chunked_cross_entropy_matches_dense():
 # -- paged decode kernel -----------------------------------------------------
 
 
-def _paged_case(seed=5, b=3, hkv=2, g=2, d=32, nb=9, bs=16, nbk=4):
+#: layers of the stacked pool in the paged cases: every test reads EACH of
+#: them and compares with that layer's own reference, so a wrong layer
+#: index cannot pass
+_PAGED_LAYERS = 3
+
+
+def _paged_case(seed=5, b=3, hkv=2, g=2, d=32, nb=9, bs=16, nbk=4,
+                layers=_PAGED_LAYERS):
+    """q, the STACKED pools per head ([L, nb, bs, hkv, d], what the
+    reference reads a layer of), tables, lengths."""
     key = jax.random.PRNGKey(seed)
     q = jax.random.normal(jax.random.fold_in(key, 0), (b, hkv, g, d),
                           jnp.float32)
-    k_pages = jax.random.normal(jax.random.fold_in(key, 1), (nb, bs, hkv, d),
-                                jnp.float32)
-    v_pages = jax.random.normal(jax.random.fold_in(key, 2), (nb, bs, hkv, d),
-                                jnp.float32)
+    k_pages = jax.random.normal(jax.random.fold_in(key, 1),
+                                (layers, nb, bs, hkv, d), jnp.float32)
+    v_pages = jax.random.normal(jax.random.fold_in(key, 2),
+                                (layers, nb, bs, hkv, d), jnp.float32)
     # slot 0 empty, slot 1 ends EXACTLY on a block boundary, slot 2 ragged
     # across a boundary mid-block; NULL (0) entries pad unused columns
     tables = jnp.asarray([[1, 0, 0, 0],
@@ -162,6 +171,12 @@ def _paged_case(seed=5, b=3, hkv=2, g=2, d=32, nb=9, bs=16, nbk=4):
                           [3, 4, 5, 6]], jnp.int32)
     lengths = jnp.asarray([0, bs, 50], jnp.int32)
     return q, k_pages, v_pages, tables, lengths
+
+
+def _lanes(pages):
+    """The pool as the engine stores it and the kernel reads it: kv heads
+    folded into the lane dim, [L, nb, bs, hkv*d]."""
+    return pages.reshape(pages.shape[:-2] + (-1,))
 
 
 def _paged_reference(q, k_pages, v_pages, tables, lengths, scale):
@@ -189,21 +204,42 @@ def _paged_reference(q, k_pages, v_pages, tables, lengths, scale):
 def test_paged_decode_matches_reference():
     """Block-table walk vs a dense gather+softmax reference: ragged lengths
     (empty slot -> o=0/lse=-inf, exact-boundary slot, mid-block slot), no
-    dense [B, max_len] intermediate on the kernel side."""
+    dense [B, max_len] intermediate on the kernel side; every layer of the
+    stacked pool against its own reference."""
     q, kp, vp, tables, lengths = _paged_case()
     scale = q.shape[-1] ** -0.5
-    o, lse = paged_decode_attention(q, kp, vp, tables, lengths)
-    want_o, want_lse = _paged_reference(q, kp, vp, tables, lengths, scale)
-    np.testing.assert_allclose(np.asarray(o), want_o, atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(lse)[1:], want_lse[1:], atol=1e-5,
-                               rtol=1e-5)
-    # the empty slot's halves are the logsumexp-merge identity: o = 0 and
-    # an lse so low that exp(lse - anything) underflows to exactly 0 (the
-    # kernel uses a finite -1e30 sentinel, not IEEE -inf, so the merge
-    # arithmetic stays NaN-free)
-    assert np.all(np.asarray(o)[0] == 0.0)
-    assert np.all(np.asarray(lse)[0] <= -1e29)
-    assert np.all(np.exp(np.asarray(lse)[0]) == 0.0)
+    for layer in range(_PAGED_LAYERS):
+        o, lse = paged_decode_attention(q, _lanes(kp), _lanes(vp), layer,
+                                        tables, lengths)
+        want_o, want_lse = _paged_reference(q, kp[layer], vp[layer], tables,
+                                            lengths, scale)
+        np.testing.assert_allclose(np.asarray(o), want_o, atol=1e-5,
+                                   rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(lse)[1:], want_lse[1:],
+                                   atol=1e-5, rtol=1e-5)
+        # the empty slot's halves are the logsumexp-merge identity: o = 0
+        # and an lse so low that exp(lse - anything) underflows to exactly
+        # 0 (the kernel uses a finite -1e30 sentinel, not IEEE -inf, so the
+        # merge arithmetic stays NaN-free)
+        assert np.all(np.asarray(o)[0] == 0.0)
+        assert np.all(np.asarray(lse)[0] <= -1e29)
+        assert np.all(np.exp(np.asarray(lse)[0]) == 0.0)
+
+
+def test_paged_decode_layer_index_is_traced():
+    """The layer is a run-time scalar (the engine's layer scan carries
+    it): one compiled program reads whichever layer it is handed, and the
+    layers differ."""
+    q, kp, vp, tables, lengths = _paged_case()
+    fn = jax.jit(paged_decode_attention)
+    outs = [np.asarray(fn(q, _lanes(kp), _lanes(vp), jnp.int32(layer),
+                          tables, lengths)[0])
+            for layer in range(_PAGED_LAYERS)]
+    for layer, o in enumerate(outs):
+        want_o, _ = _paged_reference(q, kp[layer], vp[layer], tables,
+                                     lengths, q.shape[-1] ** -0.5)
+        np.testing.assert_allclose(o, want_o, atol=1e-5, rtol=1e-5)
+    assert not np.allclose(outs[0], outs[1], atol=1e-3)
 
 
 def test_paged_decode_ragged_table_slice_is_exact():
@@ -211,11 +247,16 @@ def test_paged_decode_ragged_table_slice_is_exact():
     fewer pages but must produce the SAME numbers when every length fits
     the slice."""
     q, kp, vp, tables, lengths = _paged_case()
+    kp, vp = _lanes(kp), _lanes(vp)
     lengths = jnp.minimum(lengths, 30)  # everything fits 2 blocks
-    o_full, lse_full = paged_decode_attention(q, kp, vp, tables, lengths)
-    o_cut, lse_cut = paged_decode_attention(q, kp, vp, tables[:, :2], lengths)
-    np.testing.assert_array_equal(np.asarray(o_full), np.asarray(o_cut))
-    np.testing.assert_array_equal(np.asarray(lse_full), np.asarray(lse_cut))
+    for layer in range(_PAGED_LAYERS):
+        o_full, lse_full = paged_decode_attention(q, kp, vp, layer, tables,
+                                                  lengths)
+        o_cut, lse_cut = paged_decode_attention(q, kp, vp, layer,
+                                                tables[:, :2], lengths)
+        np.testing.assert_array_equal(np.asarray(o_full), np.asarray(o_cut))
+        np.testing.assert_array_equal(np.asarray(lse_full),
+                                      np.asarray(lse_cut))
 
 
 def test_paged_decode_int8_pages_match_dequantized_reference():
@@ -227,21 +268,33 @@ def test_paged_decode_int8_pages_match_dequantized_reference():
     q, kp, vp, tables, lengths = _paged_case()
     kq, ks = quantize_kv(kp)
     vq, vs = quantize_kv(vp)
-    o, lse = paged_decode_attention(q, {"q": kq, "s": ks},
-                                    {"q": vq, "s": vs}, tables, lengths)
-    want_o, want_lse = _paged_reference(
-        q, dequantize_kv(kq, ks, jnp.float32),
-        dequantize_kv(vq, vs, jnp.float32), tables, lengths,
-        q.shape[-1] ** -0.5)
-    np.testing.assert_allclose(np.asarray(o), want_o, atol=1e-4, rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(lse)[1:], want_lse[1:], atol=1e-4,
-                               rtol=1e-4)
-    assert np.all(np.asarray(lse)[0] <= -1e29)  # empty slot sentinel
+    k_deq = dequantize_kv(kq, ks, jnp.float32)
+    v_deq = dequantize_kv(vq, vs, jnp.float32)
+    for layer in range(_PAGED_LAYERS):
+        o, lse = paged_decode_attention(
+            q, {"q": _lanes(kq), "s": ks}, {"q": _lanes(vq), "s": vs},
+            layer, tables, lengths)
+        want_o, want_lse = _paged_reference(
+            q, k_deq[layer], v_deq[layer], tables, lengths,
+            q.shape[-1] ** -0.5)
+        np.testing.assert_allclose(np.asarray(o), want_o, atol=1e-4,
+                                   rtol=1e-4)
+        np.testing.assert_allclose(np.asarray(lse)[1:], want_lse[1:],
+                                   atol=1e-4, rtol=1e-4)
+        assert np.all(np.asarray(lse)[0] <= -1e29)  # empty slot sentinel
 
 
 def test_paged_decode_rejects_int4_pages():
     q, kp, vp, tables, lengths = _paged_case()
-    fake_int4 = {"q4": jnp.zeros((9, 16, 2, 16), jnp.int8),
-                 "s": jnp.ones((9, 16, 2), jnp.float32)}
+    fake_int4 = {"q4": jnp.zeros((_PAGED_LAYERS, 9, 16, 2 * 16), jnp.int8),
+                 "s": jnp.ones((_PAGED_LAYERS, 9, 16, 2), jnp.float32)}
     with pytest.raises(NotImplementedError):
-        paged_decode_attention(q, fake_int4, fake_int4, tables, lengths)
+        paged_decode_attention(q, fake_int4, fake_int4, 0, tables, lengths)
+
+
+def test_paged_decode_rejects_unfolded_pages():
+    """The per-head [.., Hkv, D] pool of before is refused by shape, not
+    read as garbage."""
+    q, kp, vp, tables, lengths = _paged_case()
+    with pytest.raises(ValueError, match="Hkv\\*D"):
+        paged_decode_attention(q, kp[0], vp[0], 0, tables, lengths)

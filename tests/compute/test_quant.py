@@ -122,6 +122,33 @@ def test_int8_kv_engine_output_close_to_exact(setup):
     assert got == want
 
 
+@pytest.mark.parametrize("kv_quantize", ["int8", "int4"])
+def test_quantized_pages_fold_heads_and_match_dense(setup, kv_quantize):
+    """The paged pool folds the kv heads into the lane dim for the packed
+    values ("q": Hkv*D, "q4": Hkv*D/2) and keeps the scales per (row,
+    head); the rows a prefill, a chunk and a decode window write there
+    dequantize to what the dense quantized cache holds: same tokens."""
+    from dstack_tpu.serving.engine import InferenceEngine
+
+    cfg, params = setup  # float32
+    prompts = [[1, 5, 9, 42, 7], [(i * 13) % 50 + 1 for i in range(45)]]
+    dense = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
+                            kv_quantize=kv_quantize)
+    want = [dense.generate(list(p), max_new_tokens=6).output
+            for p in prompts]
+    engine = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
+                             paged=True, kv_block_size=16, prefill_chunk=32,
+                             kv_quantize=kv_quantize)
+    qk = "q" if kv_quantize == "int8" else "q4"
+    lead = (cfg.num_layers, engine._alloc.num_blocks, 16)
+    lanes = cfg.num_kv_heads * cfg.head_dim // (1 if qk == "q" else 2)
+    assert engine._cache_k[qk].shape == lead + (lanes,)
+    assert engine._cache_v["s"].shape == lead + (cfg.num_kv_heads,)
+    got = [engine.generate(list(p), max_new_tokens=6).output
+           for p in prompts]
+    assert got == want
+
+
 @pytest.mark.slow
 def test_int8_kv_composes_with_paging_weights_and_prefix(setup):
     """The realistic fully-quantized serving config: int8 weights + int8

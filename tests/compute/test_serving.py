@@ -151,6 +151,112 @@ def test_paged_engine_matches_dense():
             assert engine._alloc.free_blocks == engine._alloc.num_blocks - 1
 
 
+#: what the paged pool's stored form (kv heads folded into the lane dim,
+#: every program addressing a layer in place) must not change: the tokens.
+#: One prompt per paged prefill bucket of a 128-token engine (32, 64, 128:
+#: two, four and eight blocks of 16), so every ``prefill_paged_b*`` program
+#: writes a pool the decode window then reads.
+_BUCKET_PROMPTS = [[(7 * i + k) % 500 + 1 for i in range(n)]
+                   for k, n in enumerate((5, 40, 100))]
+_SHARED = [(i * 7) % 50 + 1 for i in range(37)]  # two whole blocks + 5
+_POOL_FORM_CASES = {
+    # name: (engine kwargs beyond paged=True, dense kwargs, prompts)
+    "buckets": ({}, {}, _BUCKET_PROMPTS),
+    "chunked": ({"prefill_chunk": 16}, {},
+                [[(i * 13) % 50 + 1 for i in range(45)], [3, 1, 4]]),
+    "prefix-cache": ({"prefix_cache": True}, {},
+                     [_SHARED + [1, 2, 3], _SHARED + [4, 5]]),
+    "int8": ({"kv_quantize": "int8"}, {"kv_quantize": "int8"},
+             _BUCKET_PROMPTS),
+    "tensor-mesh": ({"mesh": 2}, {}, _BUCKET_PROMPTS),
+}
+
+
+@pytest.mark.parametrize("attention", ["gather", "kernel"])
+@pytest.mark.parametrize("case", sorted(_POOL_FORM_CASES))
+def test_paged_pool_form_matches_dense_cache(monkeypatch, case, attention):
+    """A paged engine's greedy tokens equal the dense-cache engine's
+    (float32: no bf16 tie-breaks) through every writer and reader of the
+    pool: each paged prefill bucket, the chunk program, the prefix cache's
+    suffix prefill, int8 pages and a tensor mesh, on the XLA gather path
+    and through the block-table kernel (interpreted here)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from dstack_tpu.models.llama import LlamaConfig, init_params
+    from dstack_tpu.serving.engine import InferenceEngine, Request
+
+    monkeypatch.setenv("DSTACK_TPU_PAGED_ATTN_KERNEL",
+                       "1" if attention == "kernel" else "0")
+    cfg = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    paged_kw, dense_kw, prompts = _POOL_FORM_CASES[case]
+    paged_kw = dict(paged_kw)
+    if "mesh" in paged_kw:
+        paged_kw["mesh"] = _tp_mesh(paged_kw["mesh"])
+
+    def run(engine, sequential):
+        reqs = [Request(tokens=list(p), max_new_tokens=6) for p in prompts]
+        for r in reqs:
+            engine.submit(r)
+            if sequential:  # the second prompt must find the first's blocks
+                for _ in range(100):
+                    if r.done.is_set():
+                        break
+                    engine.step()
+        for _ in range(200):
+            if all(r.done.is_set() for r in reqs):
+                break
+            engine.step()
+        return [r.output for r in reqs]
+
+    want = run(InferenceEngine(cfg, params=params, batch_size=4, max_len=128,
+                               **dense_kw), False)
+    engine = InferenceEngine(cfg, params=params, batch_size=4, max_len=128,
+                             paged=True, kv_block_size=16, **paged_kw)
+    # the stored form: [L, blocks, block, Hkv*D], scales [.., Hkv]
+    pool = engine._cache_k["q"] if case == "int8" else engine._cache_k
+    assert pool.shape == (cfg.num_layers, engine._alloc.num_blocks, 16,
+                          cfg.num_kv_heads * cfg.head_dim)
+    assert run(engine, case == "prefix-cache") == want
+    assert all(len(out) == 6 for out in want)
+    programs = {k[0] if isinstance(k, tuple) else k
+                for k in engine._prefill_jit}
+    if case in ("prefix-cache", "chunked"):
+        assert "prefix" in programs  # the suffix/chunk program ran
+    if case == "buckets":
+        assert {k[1] for k in engine._prefill_jit
+                if isinstance(k, tuple)} == {32, 64, 128}
+    assert engine._alloc.available_blocks == engine._alloc.num_blocks - 1
+
+
+@pytest.mark.parametrize("kv_heads,kernel,warns", [
+    (4, "1", True),    # 4 x 16 = 64 lanes a pool row
+    (8, "1", False),   # 128: a whole tile
+    (4, "0", False),   # the gather path reads any form
+], ids=["64-lanes", "128-lanes", "gather"])
+def test_paged_pool_warns_when_rows_are_not_whole_lane_tiles(
+        monkeypatch, caplog, kv_heads, kernel, warns):
+    """The pool is the kernel's operand as stored only in whole 128-lane
+    tiles (tests/compute/test_tpu_compile.py shows what the chip's compiler
+    does otherwise); the engine says so at start, it has no switch."""
+    import dataclasses
+    import logging
+
+    from dstack_tpu.models.llama import LlamaConfig
+    from dstack_tpu.serving.engine import InferenceEngine
+
+    monkeypatch.setenv("DSTACK_TPU_PAGED_ATTN_KERNEL", kernel)
+    cfg = dataclasses.replace(LlamaConfig.tiny(), num_kv_heads=kv_heads)
+    with caplog.at_level(logging.WARNING, logger="dstack_tpu.serving.engine"):
+        InferenceEngine(cfg, params={"layers": {}}, batch_size=2,
+                        max_len=64, paged=True, kv_block_size=16)
+    said = [r for r in caplog.records if "multiple of 128" in r.getMessage()]
+    assert bool(said) == warns
+
+
 @pytest.mark.slow
 def test_paged_engine_slot_reuse(setup):
     from dstack_tpu.serving.engine import InferenceEngine
