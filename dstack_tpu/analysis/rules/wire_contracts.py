@@ -1172,18 +1172,20 @@ def _recorded_families(idx: ContractIndex,
     return out
 
 
-def _gated_families(root: Path) -> Optional[Set[str]]:
+def _gated_families(root: Path, recorded) -> Optional[Set[str]]:
+    """Families the gate script asserts on /metrics.  A gated sample name
+    stands for the family of that very name where one is ``recorded`` (a
+    counter may end in ``_sum``), else for its histogram
+    (``_bucket``/``_count``/``_sum`` taken off)."""
     gate = root / GATE_RELPATH
     try:
         tree = ast.parse(gate.read_text())
     except (OSError, SyntaxError):
         return None
-    out: Set[str] = set()
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Constant) and isinstance(node.value, str) \
-                and node.value.startswith(_METRIC_PREFIX):
-            out.add(_base_family(node.value))
-    return out
+    names = {node.value for node in ast.walk(tree)
+             if isinstance(node, ast.Constant) and isinstance(node.value, str)
+             and node.value.startswith(_METRIC_PREFIX)}
+    return {n if n in recorded else _base_family(n) for n in names}
 
 
 def _check_metric_families(idx: ContractIndex) -> Iterable[Finding]:
@@ -1191,10 +1193,10 @@ def _check_metric_families(idx: ContractIndex) -> Iterable[Finding]:
     root = idx.tree_root()
     if tm is None or root is None:
         return
-    gated = _gated_families(root)
+    recorded = _recorded_families(idx, tm)
+    gated = _gated_families(root, recorded)
     if gated is None:
         return  # no gate script next to the tree: file-scoped run
-    recorded = _recorded_families(idx, tm)
     for name, node in sorted(recorded.items()):
         if name not in gated:
             yield tm.finding(
@@ -1275,7 +1277,7 @@ def contract_inventory(project: Project) -> Dict:
     tm = idx.module_ending(SERVING_TELEMETRY_SUFFIX)
     root = idx.tree_root()
     recorded = sorted(_recorded_families(idx, tm)) if tm else []
-    gated = sorted(_gated_families(root) or ()) if root else []
+    gated = sorted(_gated_families(root, recorded) or ()) if root else []
     return {
         "routes": [{"path": p, "file": f, "line": ln}
                    for p, f, ln in routes],

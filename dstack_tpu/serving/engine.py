@@ -28,14 +28,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from dstack_tpu.elastic.compile_cache import CompileCache, maybe_cached
+from dstack_tpu.models.ling_hybrid import LingHybridConfig
+from dstack_tpu.models.ling_hybrid import init_params as hybrid_init
 from dstack_tpu.models.llama import (
     LlamaConfig,
     Params,
     init_params,
     output_head,
 )
+from dstack_tpu.ops.pool import scatter_rows as _scatter_rows
 from dstack_tpu.ops.rmsnorm import rms_norm
 from dstack_tpu.ops.rotary import apply_rope, rope_frequencies
+from dstack_tpu.serving.hybrid import HybridPrograms
 from dstack_tpu.serving.paging import BlockAllocator, PrefixBlockAllocator
 from dstack_tpu.serving.quant import (
     dequantize_kv,
@@ -305,18 +309,6 @@ def _split_heads(view, hkv: int):
     return split(view)
 
 
-def _scatter_rows(leaf, idx, rows):
-    """Write ``rows`` [..., X] at flat row indices ``idx`` (layer, block,
-    offset folded: (l*NB + blk)*BS + off) of a paged pool leaf
-    [L, NB, BS, X].  A row scatter over the pool's merged leading dims
-    updates it in place under donation; indexing the layer as a window
-    dim (``.at[:, blk, off]``) makes XLA change the WHOLE pool's layout on
-    the way in and out (tests/compute/test_tpu_compile.py holds this)."""
-    x = leaf.shape[-1]
-    return leaf.reshape(-1, x).at[idx.reshape(-1)].set(
-        rows.reshape(-1, x)).reshape(leaf.shape)
-
-
 @jax.named_scope("kv_window_write")
 def _dense_window_insert(cache, win, widx, in_window):
     """End-of-window bulk insert for the DENSE cache: cache position (b, s)
@@ -497,6 +489,41 @@ class InferenceEngine:
         """
         self.cfg = cfg
         self.telemetry = telemetry
+        #: the state and the programs of a model whose memory is not K and
+        #: V rows (serving/hybrid.py: a recurrent state beside a paged
+        #: latent pool); None for the Llama family, whose programs are this
+        #: class's own.  The scheduler below is one for both: beside the
+        #: constructor only ``_reset_device_state`` and the three builders
+        #: of paged programs (prefill, chunk, decode window) ask which.
+        self._hybrid = None
+        #: where a paged prefill or chunk program writes, from (slot, its
+        #: pages): the pages, and for per-slot recurrent state the slot too
+        self._slot_target = lambda slot_id, pages: pages
+        #: why prefill/decode disaggregation is refused, if it is
+        self._pd_refusal: Optional[str] = None
+        hybrid = isinstance(cfg, LingHybridConfig)
+        if hybrid:
+            self._pd_refusal = (
+                "prefill/decode disaggregation is not served for this "
+                "model: the wire carries K and V rows, not a recurrent "
+                "state and latent rows")
+            for refused, needs in (
+                (not paged, "paged=False: the MLA layers' latent rows live "
+                 "in the paged pool, a dense latent cache is not written"),
+                (prefix_cache, "prefix_cache: a cached block would need a "
+                 "snapshot of the recurrent state at its boundary"),
+                (speculation, "speculation: rejected drafts would need the "
+                 "recurrent state rolled back"),
+                (kv_quantize, "kv_quantize: latent pages would need scales "
+                 "and an absorbed product over quantized rows"),
+                (quantize, "quantize: the grouped expert product would need "
+                 "int8 forms of the expert stacks"),
+                (mesh is not None, "a mesh: it would need the expert "
+                 "exchange and sharding rules for the recurrent state"),
+            ):
+                if refused:
+                    raise ValueError(
+                        f"{type(cfg).__name__} is not served with {needs}")
         self.compile_cache = (compile_cache if compile_cache is not None
                               else CompileCache.from_env())
         self.batch_size = batch_size
@@ -569,7 +596,14 @@ class InferenceEngine:
                     "the dense equivalent (%d): decode still needs a "
                     "dense-equivalent linear-view allowance in HBM "
                     "(see ROOFLINE.md, serving decode)", n_blocks, dense_equiv)
-            lanes = cfg.num_kv_heads * cfg.head_dim // t
+            if hybrid:
+                self._hybrid = HybridPrograms(
+                    cfg, batch_size=batch_size, max_len=self.max_len,
+                    block_size=kv_block_size, num_blocks=n_blocks,
+                    sample=self._sample_on_device)
+                self._slot_target = self._hybrid.slot_target
+            lanes = (cfg.latent_lanes if hybrid
+                     else cfg.num_kv_heads * cfg.head_dim // t)
             if self._paged_kernel and lanes % 128:
                 # the pool is stored as the decode kernel's operand only
                 # in whole 128-lane tiles: the TPU compiler keeps a
@@ -637,7 +671,8 @@ class InferenceEngine:
                     out_shardings=self._param_shardings(shapes),
                 )()
             else:
-                params = (moe_init if isinstance(cfg, MoEConfig)
+                params = (hybrid_init if hybrid
+                          else moe_init if isinstance(cfg, MoEConfig)
                           else init_params)(jax.random.PRNGKey(rng_seed), cfg)
         elif mesh is not None:
             # host (numpy / checkpoint) arrays transfer shard-wise here;
@@ -769,6 +804,35 @@ class InferenceEngine:
         """(Re-)allocate the KV cache and slot state.  Called at init and
         after a device-side decode failure (the decode jit donates the
         caches, so a raise mid-execution leaves them deleted)."""
+        b = self.batch_size
+        if self._hybrid is not None:
+            # the two donated state trees are the latent pool and the
+            # recurrent state, not a K and a V cache
+            self._cache_k, self._cache_v = self._hybrid.init_state()
+            if self.telemetry is not None:
+                self.telemetry.record_recurrent_state_bytes(
+                    self._hybrid.recurrent_state_bytes())
+        else:
+            self._alloc_kv_caches()
+        if self.paged and isinstance(self._alloc, PrefixBlockAllocator):
+            # the KV backing every cached key was just reallocated
+            self._alloc.clear_cache()
+        self._decode_consts = None  # cached device constants died with it
+        self._pending = None        # in-flight window handles died with it
+        self._chunking = {}         # mid-chunk prefill state died with it
+        self._lengths = jnp.zeros((b,), jnp.int32)     # tokens in cache
+        # host mirror of _lengths: _emit's bookkeeping must not pay a
+        # device->host fetch per generated token (it dominated serving
+        # throughput on remote-RPC backends)
+        self._host_lengths = np.zeros((b,), np.int64)
+        self._last_token = jnp.zeros((b,), jnp.int32)
+        self._active = jnp.zeros((b,), jnp.bool_)
+        #: on-device token history per slot (speculation's n-gram corpus)
+        self._hist = jnp.zeros((b, self.max_len), jnp.int32)
+
+    def _alloc_kv_caches(self) -> None:
+        """The Llama family's K and V caches, zeroed: dense rows per slot,
+        or the paged pool."""
         cfg, b = self.cfg, self.batch_size
         lead = ((cfg.num_layers, self._alloc.num_blocks, self._block_size)
                 if self.paged else (cfg.num_layers, b, self.max_len))
@@ -802,21 +866,6 @@ class InferenceEngine:
         else:
             self._cache_k = mk_zeros()
             self._cache_v = mk_zeros()
-        if self.paged and isinstance(self._alloc, PrefixBlockAllocator):
-            # the KV backing every cached key was just reallocated
-            self._alloc.clear_cache()
-        self._decode_consts = None  # cached device constants died with it
-        self._pending = None        # in-flight window handles died with it
-        self._chunking = {}         # mid-chunk prefill state died with it
-        self._lengths = jnp.zeros((b,), jnp.int32)     # tokens in cache
-        # host mirror of _lengths: _emit's bookkeeping must not pay a
-        # device->host fetch per generated token (it dominated serving
-        # throughput on remote-RPC backends)
-        self._host_lengths = np.zeros((b,), np.int64)
-        self._last_token = jnp.zeros((b,), jnp.int32)
-        self._active = jnp.zeros((b,), jnp.bool_)
-        #: on-device token history per slot (speculation's n-gram corpus)
-        self._hist = jnp.zeros((b, self.max_len), jnp.int32)
 
     # -- public API --------------------------------------------------------
 
@@ -824,6 +873,8 @@ class InferenceEngine:
         if self.draining:
             # belt for non-HTTP callers; the server's handlers 503 first
             raise EngineDraining("engine is draining; not admitting")
+        if request.prefill is not None and self._pd_refusal:
+            raise ValueError(self._pd_refusal)
         # clamp so prompt + generation always fit the cache
         request.max_new_tokens = max(min(request.max_new_tokens,
                                          self.max_len - 2), 1)
@@ -1026,7 +1077,8 @@ class InferenceEngine:
                 self.params, jnp.asarray(padded),
                 jnp.int32(len(chunk)), jnp.int32(done),
                 self._cache_k, self._cache_v,
-                jnp.asarray(self._tables_host[slot_id]))
+                self._slot_target(
+                    slot_id, jnp.asarray(self._tables_host[slot_id])))
         else:
             logits, self._cache_k, self._cache_v = self._run_program(
                 self._prefill_jit, ("chunk", cbucket),
@@ -1297,6 +1349,10 @@ class InferenceEngine:
         attends the suffix queries over the gathered full span with
         absolute positions (RoPE phases match the cached prefix's).
         """
+        if self._hybrid is not None:
+            return self._jit_cached(self._hybrid.chunk_fn(sbucket),
+                                    f"prefill_prefix_b{sbucket}",
+                                    donate_argnums=(4, 5))
         cfg = self.cfg
         bs = self._block_size
         bps = self._blocks_per_slot
@@ -1400,6 +1456,10 @@ class InferenceEngine:
                                 donate_argnums=(4, 5))
 
     def _prefill_fn_paged(self, bucket: int):
+        if self._hybrid is not None:
+            return self._jit_cached(self._hybrid.prefill_fn(bucket),
+                                    f"prefill_paged_b{bucket}",
+                                    donate_argnums=(3, 4))
         cfg = self.cfg
         bs = self._block_size
         nblk = bucket // bs
@@ -1448,9 +1508,9 @@ class InferenceEngine:
             bucket = self._bucket(n)
             padded = np.zeros((bucket,), np.int32)
             padded[:n] = tokens[:bucket]
-            target = (jnp.asarray(
+            target = (self._slot_target(slot_id, jnp.asarray(
                 self._slot_blocks[slot_id][:bucket // self._block_size],
-                jnp.int32) if self.paged else slot_id)
+                jnp.int32)) if self.paged else slot_id)
             logits, self._cache_k, self._cache_v = self._run_program(
                 self._prefill_jit, ("paged", bucket) if self.paged else bucket,
                 functools.partial(self._prefill_fn_paged if self.paged
@@ -1505,6 +1565,8 @@ class InferenceEngine:
         integration — on TPU the KV rides the router instead of a
         bootstrap-port side channel.
         """
+        if self._pd_refusal:
+            raise ValueError(self._pd_refusal)
         cfg = self.cfg
         max_new_tokens = max(min(max_new_tokens, self.max_len - 2), 1)
         toks = self._prompt_tokens(tokens, max_new_tokens)
@@ -2096,11 +2158,12 @@ class InferenceEngine:
                                nbk: Optional[int]):
         """The jitted plain decode window for one (window, sampling,
         table-bucket) key."""
+        fn = (self._hybrid.decode_window_fn(window, sampling, nbk)
+              if self._hybrid is not None else functools.partial(
+                  self._decode_window_fn_buffered, window=window,
+                  sampling=sampling, kv_blocks=nbk))
         return self._jit_cached(
-            functools.partial(self._decode_window_fn_buffered,
-                              window=window, sampling=sampling,
-                              kv_blocks=nbk),
-            f"decode_w{window}_s{int(sampling)}"
+            fn, f"decode_w{window}_s{int(sampling)}"
             + (f"_kb{nbk}" if nbk is not None else ""),
             donate_argnums=(4, 5))
 
@@ -2141,8 +2204,9 @@ class InferenceEngine:
             self._rng_key, sub = jax.random.split(self._rng_key)
         else:
             sub = self._rng_key
+        # a model with experts returns, last, the window's expert load
         tokens_all, self._last_token, self._lengths, \
-            self._cache_k, self._cache_v = self._run_program(
+            self._cache_k, self._cache_v, *expert_load = self._run_program(
                 self._decode_jit, (window, sampling, nbk),
                 functools.partial(self._decode_window_program, window,
                                   sampling, nbk),
@@ -2158,7 +2222,7 @@ class InferenceEngine:
             if req is not None and slot_id not in self._chunking)
         pending = {"tokens": tokens_all, "window": window,
                    "remaining_after": remaining - window,
-                   "decoding": decoding}
+                   "decoding": decoding, "expert_load": expert_load}
         if self.telemetry is not None:
             self._record_dispatch(len(decoding), pending)
         return pending
@@ -2277,6 +2341,9 @@ class InferenceEngine:
             self.telemetry.record_drain(
                 emitted, time.perf_counter() - p["t0"], len(p["decoding"]),
                 steps=p["window"], batch_size=self.batch_size)
+            if p.get("expert_load"):
+                self.telemetry.record_expert_load(
+                    *np.asarray(p["expert_load"][0]).tolist())
 
     def _sample_first(self, logits, req: Request) -> int:
         """Sample a request's FIRST token with the same fused on-device

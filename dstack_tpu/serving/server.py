@@ -21,6 +21,7 @@ from typing import Optional
 
 from aiohttp import web
 
+from dstack_tpu.models.ling_hybrid import LingHybridConfig
 from dstack_tpu.models.llama import LlamaConfig
 from dstack_tpu.serving import deadlines
 from dstack_tpu.serving.engine import EngineDraining, InferenceEngine, Request
@@ -64,6 +65,8 @@ CONFIGS = {
     "llama3-1b": LlamaConfig.llama3_1b,
     "llama3-8b": LlamaConfig.llama3_8b,
     "llama3-70b": LlamaConfig.llama3_70b,
+    # the hybrid family (KDA + MLA + routed experts); needs --paged
+    "ling-hybrid-tiny": LingHybridConfig.tiny,
 }
 
 

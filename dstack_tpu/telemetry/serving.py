@@ -279,6 +279,29 @@ class EngineTelemetry:
         self.spec_steps.inc(steps)
         self.spec_accepted.inc(accepted)
 
+    def record_expert_load(self, held: float, absent: float,
+                           load_max: float, load_mean: float,
+                           touched: float) -> None:
+        """One drained decode window of a model with routed experts, from
+        the sums its program returned: token-expert pairs that fell on
+        experts ``held`` on this chip and on ``absent`` ones, and, summed
+        over the window's expert layer-steps, the largest and the mean
+        number of pairs one held expert got and the number of held experts
+        that got any (``touched``: their weights are what the step read)."""
+        r = self.recorder
+        r.counter(PREFIX + "moe_pairs_total",
+                  labels={"where": "held"}).inc(held)
+        r.counter(PREFIX + "moe_pairs_total",
+                  labels={"where": "absent"}).inc(absent)
+        r.counter(PREFIX + "moe_expert_load_max_sum").inc(load_max)
+        r.counter(PREFIX + "moe_expert_load_mean_sum").inc(load_mean)
+        r.counter(PREFIX + "moe_experts_touched_sum").inc(touched)
+
+    def record_recurrent_state_bytes(self, nbytes: int) -> None:
+        """Bytes of per-slot recurrent state (linear-attention layers) the
+        engine holds beside the paged pool."""
+        self.recorder.gauge(PREFIX + "recurrent_state_bytes").set(nbytes)
+
     # -- read side -------------------------------------------------------
 
     def load_snapshot(self) -> Dict:

@@ -186,6 +186,8 @@ async def check_serving_metrics() -> int:
     tel.record_preemption("kv_blocks_exhausted")
     tel.record_spec(10, 7)
     tel.record_program_built("decode")
+    tel.record_expert_load(200.0, 600.0, 9.0, 2.0, 90.0)
+    tel.record_recurrent_state_bytes(1 << 20)
 
     class _Req:
         submitted_at = 1.0
@@ -240,6 +242,11 @@ async def check_serving_metrics() -> int:
             "dstack_serving_preemptions_total",
             "dstack_serving_spec_steps_total",
             "dstack_serving_spec_accepted_total",
+            "dstack_serving_moe_pairs_total",
+            "dstack_serving_moe_expert_load_max_sum",
+            "dstack_serving_moe_expert_load_mean_sum",
+            "dstack_serving_moe_experts_touched_sum",
+            "dstack_serving_recurrent_state_bytes",
             "dstack_serving_requests_total",
         ):
             assert required in names, f"serving /metrics missing {required}"

@@ -239,16 +239,18 @@ PROGRAMS = ["decode_w64", "prefill_paged_b32", "prefill_paged_b512",
             "prefill_prefix_b512"]
 
 _HLO_LINE = re.compile(
-    r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\](?:\{[^}]*\})? "
+    r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\](?:\{[^}]*\})? "
     r"([\w\-]+)\(")
 #: instructions that hold no buffer of their own
 _NO_BUFFER = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
               "constant"}
 
 
-def _pool_sized_ops(text: str, layer_elems: int, layers: int):
+def _pool_sized_ops(text: str, layer_elems: int, layers: int,
+                    dtype: str = None):
     """Instructions of compiled HLO ``text`` whose result is 1..``layers``
     whole layers of a pool leaf (``layer_elems`` elements a layer) and
+    (of element type ``dtype``, an HLO name such as ``f32``, where given)
     that materialise it: everything outside fused computations except the
     plumbing of _NO_BUFFER and the scatters (and the fusions around them;
     a one-block scatter compiles to a dynamic-update-slice) that update
@@ -267,15 +269,17 @@ def _pool_sized_ops(text: str, layer_elems: int, layers: int):
             continue
         for line in body.splitlines():
             m = _HLO_LINE.match(line)
-            if not m or m.group(3) in _NO_BUFFER:
+            if not m or m.group(4) in _NO_BUFFER:
                 continue
-            dims = [int(x) for x in m.group(2).split(",") if x]
+            if dtype is not None and m.group(2) != dtype:
+                continue
+            dims = [int(x) for x in m.group(3).split(",") if x]
             if math.prod(dims) not in sizes:
                 continue
-            if m.group(3) == "scatter":
+            if m.group(4) == "scatter":
                 continue
             called = re.search(r"calls=%?([\w.\-]+)", line)
-            if m.group(3) == "fusion" and called and re.search(
+            if m.group(4) == "fusion" and called and re.search(
                     r" (scatter|dynamic-update-slice)\(",
                     bodies.get(called.group(1), "")):
                 continue
@@ -375,3 +379,93 @@ def test_engine_program_temporaries_ignore_pool(engine_programs, geometry,
              - small.memory_analysis().temp_size_in_bytes)
     assert grown < 0.05 * (large_pool - small_pool), (
         grown, large_pool - small_pool)
+
+
+#: the hybrid cell of BENCHMARK.json as its files size it: every width, all
+#: seven layers, 128 slots, 8,192 blocks.  ``decode_w64`` at the widest table
+#: bucket (128 columns): its gathered view is twice a layer of the pool, so
+#: size tells them apart (at 64 columns, 128 slots x 64 blocks, they match).
+HYBRID_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_prefix_b512"]
+V5E_USABLE_BYTES = 15.75e9      # "Used 15.94G of 15.75G hbm" (PERF.md, PR 26)
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(one_chip, real_lowering):
+    """``compile_program(program)`` -> (compiled, state tree shapes): the
+    engine's own builders for the hybrid decoder at the cell's real sizes,
+    weights never made."""
+    import json
+    from pathlib import Path
+
+    from benchmarks.harness.sizes import load_config, program_config
+    from dstack_tpu.models.ling_hybrid import init_params
+    from dstack_tpu.serving.engine import InferenceEngine
+
+    root = Path(__file__).resolve().parents[2] / "benchmarks"
+    cfg = program_config(load_config(
+        root / "configs" / "ling-3.0-flash-vl-7l-ep4.json"))
+    load = json.loads((root / "workloads"
+                       / "ling-3.0-flash-vl-7l-ep4.reason.json").read_text())
+    args = dict(load["engine"], prefill_chunk=512)
+    engine = InferenceEngine(cfg, params={"layers": {}}, **args)
+    b, bs, kb = args["batch_size"], args["kv_block_size"], 128
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = sds((engine._cache_k, engine._cache_v))
+    i32, f32 = jnp.int32, jnp.float32
+
+    @functools.lru_cache(maxsize=None)
+    def compile_program(program: str):
+        if program == "decode_w64":
+            fn = engine._decode_window_program(64, False, kb)
+            args = (params, arg(i32, b), arg(i32, b), arg(jnp.bool_, b),
+                    *state, arg(f32, b), arg(f32, b), arg(i32, b),
+                    arg(i32, b, kb), arg(jnp.uint32, 2))
+        elif program == "prefill_paged_b512":
+            fn = engine._prefill_fn_paged(512)
+            args = (params, arg(i32, 512), arg(i32), *state,
+                    (arg(i32, 512 // bs), arg(i32)))
+        else:
+            assert program == "prefill_prefix_b512", program
+            fn = engine._prefill_fn_prefix(512)
+            args = (params, arg(i32, 512), arg(i32), arg(i32), *state,
+                    (arg(i32, engine.max_len // bs), arg(i32)))
+        return fn.lower(*args).compile(), state
+
+    return compile_program
+
+
+@pytest.mark.parametrize("program", HYBRID_PROGRAMS)
+def test_hybrid_program_fits_and_copies_no_state(hybrid_programs, program):
+    """Each program of the hybrid cell fits the chip beside its weights, and
+    nothing but the in-place updates yields a layer (or k layers) of the
+    recurrent state or the latent pool; its temporaries are far under the
+    state's size (the decode window's are its gathered view of the pages)."""
+    compiled, (pool, rec) = hybrid_programs(program)
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < V5E_USABLE_BYTES, held
+    # the state and the pool are donated: outputs alias them
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, rec)))
+    assert mem.alias_size_in_bytes >= donated
+    state = rec["state"]
+    assert mem.temp_size_in_bytes < 0.6 * state.size * 4
+    text = compiled.as_text()
+    layers = state.shape[0]
+    assert _pool_sized_ops(text, state.size // layers, layers, "f32") == []
+    assert _pool_sized_ops(text, pool.size // pool.shape[0], pool.shape[0],
+                           "bf16") == []
+    # the grouped expert product is XLA's own kernel, three an expert layer
+    # (the decode window's sit in its step loop, once)
+    assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ",
+                          text)) == 3 * 6

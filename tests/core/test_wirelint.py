@@ -487,6 +487,21 @@ def test_dt906_gated_but_never_recorded(tmp_path, capsys):
     assert "DT906" in out and "dstack_serving_departed_total" in out
 
 
+def test_dt906_counter_named_like_a_histogram_sample(tmp_path, capsys):
+    """A counter whose own name ends in ``_sum`` is gated under that name,
+    and its gate entry is not taken for the ``_sum`` of a histogram."""
+    from dstack_tpu.analysis.__main__ import main
+
+    root = _write_metric_tree(tmp_path, [
+        "dstack_serving_ttft_seconds_sum", "dstack_serving_active_slots",
+        "dstack_serving_load_max_sum"])
+    telemetry = root / "dstack_tpu" / "telemetry" / "serving.py"
+    telemetry.write_text(SERVING_TELEMETRY + (
+        '        self._load = r.counter(PREFIX + "load_max_sum")\n'))
+    assert main([str(root), "--no-baseline"]) == 0
+    capsys.readouterr()
+
+
 # -- CLI drift probes (the acceptance shapes, as regression fixtures) --------
 
 
