@@ -441,11 +441,15 @@ class InferenceEngine:
         trade-off is documented in docs/concepts/services.md).
 
         ``prefill_chunk``: prompts longer than this prefill in chunks of at
-        most this many tokens, ONE chunk per scheduling step, interleaved
-        with decode windows — a long prompt no longer stalls every active
-        decode slot for its whole prefill (it stalls them one chunk at a
-        time instead).  The admitted slot stays inactive until its last
-        chunk completes and produces the first token.  Works on dense and
+        most this many tokens, interleaved with decode windows: each
+        scheduling step spends a budget of at most ``batch_size`` chunks
+        ahead of its window, on the oldest-admitted prompt first and each
+        prompt to its end before the next — a long prompt no longer stalls
+        every active decode slot for its whole prefill, and a burst of long
+        prompts stalls them for ``batch_size * prefill_chunk`` prompt
+        tokens a window at most.  The admitted slot stays inactive until
+        its last chunk completes and produces the first token; it decodes
+        in the very next window.  Works on dense and
         paged caches (paged chunks ride the suffix-prefill block
         scatter/gather and COMPOSE with prefix caching: a reused prefix
         skips its chunks entirely).  None disables (whole-prompt prefill
@@ -1014,22 +1018,33 @@ class InferenceEngine:
         bulk insert could clobber.  The overlap chain therefore breaks
         whenever a queued request could take a free slot, costing one
         non-overlapped window at request boundaries.
+
+        Chunked prompts are advanced ahead of the step's window, on a
+        budget (see :meth:`_advance_chunks`).  The chain also breaks on a
+        step whose chunks complete a prompt: the in-flight window is
+        drained first (the chunks run on the device meanwhile), the prompt
+        is activated, and only then is the next window dispatched, so the
+        new slot decodes in it instead of riding it out as junk.
         """
-        advanced = False
+        # chunks this step may still put ahead of its window
+        budget = self.batch_size
         if self._pending is not None:
             nxt = None
             if not self._can_admit():
-                self._advance_chunks()  # chains before nxt on device
-                advanced = True
-                nxt = self._dispatch_window(self._pending["remaining_after"])
+                # chunks chain on the donated cache behind the in-flight
+                # window, and ahead of nxt
+                spent, completed = self._advance_chunks(budget)
+                budget -= spent
+                if not completed:
+                    nxt = self._dispatch_window(
+                        self._pending["remaining_after"])
             self._drain_window()
             self._finish_chunked()
             self._pending = nxt
             if nxt is not None:
                 return
         self._admit()
-        if not advanced:  # at most ONE chunk per step (decode-stall bound)
-            self._advance_chunks()
+        self._advance_chunks(budget)
         self._finish_chunked()
         decoding = [
             req for slot_id, req in enumerate(self._slots)
@@ -1039,26 +1054,43 @@ class InferenceEngine:
                 req.max_new_tokens - len(req.output) for req in decoding)
             self._pending = self._dispatch_window(remaining)
 
-    def _advance_chunks(self) -> None:
-        """Dispatch at most ONE prefill chunk across all mid-chunking slots
-        (bounds the decode stall any single step can add)."""
+    def _advance_chunks(self, budget: int) -> tuple:
+        """Dispatch prefill chunks, the oldest-admitted prompt's first and
+        each prompt to its end before the next one starts (a finished
+        prompt is a slot that decodes), until no mid-chunking slot has a
+        chunk left or ``budget`` chunks went out.  The budget bounds the
+        stall one scheduling step can put ahead of its decode window: a
+        step starts with ``batch_size`` chunks and its second call gets
+        what the first left.  Returns (chunks dispatched, whether some
+        prompt's last chunk was among them)."""
+        if budget <= 0:  # spent by the step's first call, which counted that
+            return 0, False
+        spent, completed = 0, False
+        # dict order is admission order: a slot is keyed when it is claimed
         for slot_id, st in list(self._chunking.items()):
-            if "logits" in st:
-                continue  # complete; awaiting _finish_chunked
-            req = self._slots[slot_id]
-            if req is None or req.cancelled:
-                del self._chunking[slot_id]
-                if req is not None:
-                    self._release(slot_id)
-                    req.finish_reason = req.finish_reason or "cancelled"
-                    req.finished_at = req.now()
-                    req.done.set()
-                    if self.telemetry is not None:
-                        self.telemetry.record_finished(req)
-                continue
-            with jax.profiler.TraceAnnotation("engine.chunk"):
-                self._dispatch_chunk(slot_id, st)
-            return
+            # a state with logits is complete: _finish_chunked's
+            while "logits" not in st and spent < budget:
+                req = self._slots[slot_id]
+                if req is None or req.cancelled:
+                    del self._chunking[slot_id]
+                    if req is not None:
+                        self._release(slot_id)
+                        req.finish_reason = req.finish_reason or "cancelled"
+                        req.finished_at = req.now()
+                        req.done.set()
+                        if self.telemetry is not None:
+                            self.telemetry.record_finished(req)
+                    break
+                with jax.profiler.TraceAnnotation("engine.chunk"):
+                    self._dispatch_chunk(slot_id, st)
+                spent += 1
+                completed = completed or "logits" in st
+        if self.telemetry is not None and spent:
+            self.telemetry.record_prefill_chunks(
+                spent, first_of_step=budget == self.batch_size,
+                budget_exhausted=(spent == budget
+                                  and self._chunk_backlog() > 0))
+        return spent, completed
 
     def _dispatch_chunk(self, slot_id: int, st: dict) -> None:
         """Dispatch the next prefill chunk of one mid-chunking slot."""
@@ -1194,12 +1226,12 @@ class InferenceEngine:
                             self._insert_prefilled(slot_id, req)
                     elif (self.prefill_chunk is not None
                           and self._prompt_len(req) > self.prefill_chunk):
-                        # long prompt: claim the slot now, prefill one chunk
-                        # per step (interleaved with decode windows); the
-                        # slot stays inactive until the last chunk yields
-                        # the first token.  A prefix-cache hit starts past
-                        # the reused rows — its chunks are skipped, not
-                        # recomputed.
+                        # long prompt: claim the slot now, prefill in chunks
+                        # on the steps' budget (interleaved with decode
+                        # windows); the slot stays inactive until the last
+                        # chunk yields the first token.  A prefix-cache hit
+                        # starts past the reused rows — its chunks are
+                        # skipped, not recomputed.
                         tokens = self._prompt_tokens(req.tokens,
                                                      req.max_new_tokens)
                         done = (self._slot_prefix[slot_id][0]

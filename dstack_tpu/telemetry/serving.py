@@ -34,6 +34,11 @@ republished with project/run/job/replica labels):
   ``decode_tokens_total`` over ``decode_slot_steps_total`` is the share of
   that work that became a token; steps over
   ``batch_occupancy_count{phase=decode}`` is the mean window size
+- ``prefill_chunks_total`` / ``prefill_chunk_steps_total`` counters —
+  chunked-prefill programs dispatched, and scheduling steps that
+  dispatched any: their ratio is chunks per step (a step may spend up to
+  ``batch_size``); ``prefill_budget_exhausted_total`` counts the steps
+  whose budget ran out with a chunk still waiting
 - ``programs_built_total{kind}`` counter — engine programs built (compiled
   or loaded from a cache) by ``decode`` / ``prefill``; growth after
   warm-up means a live request hit a new shape
@@ -143,6 +148,11 @@ class EngineTelemetry:
         self.decode_steps = r.counter(PREFIX + "decode_steps_total")
         self.decode_slot_steps = r.counter(
             PREFIX + "decode_slot_steps_total")
+        self.prefill_chunks = r.counter(PREFIX + "prefill_chunks_total")
+        self.prefill_chunk_steps = r.counter(
+            PREFIX + "prefill_chunk_steps_total")
+        self.prefill_budget_exhausted = r.counter(
+            PREFIX + "prefill_budget_exhausted_total")
         self.spec_steps = r.counter(PREFIX + "spec_steps_total")
         self.spec_accepted = r.counter(PREFIX + "spec_accepted_total")
         self._started_at = time.time()
@@ -224,6 +234,18 @@ class EngineTelemetry:
         self.prefill_tokens.inc(n_tokens)
         if bucket > 0:
             self.prefill_occupancy.observe(min(n_tokens / bucket, 1.0))
+
+    def record_prefill_chunks(self, chunks: int, first_of_step: bool,
+                              budget_exhausted: bool) -> None:
+        """``chunks`` chunked-prefill programs dispatched by one call of the
+        scheduler's chunk queue; a scheduling step makes up to two calls
+        and is counted with its first chunks.  ``budget_exhausted``: the
+        step's budget ran out with a chunk still waiting."""
+        self.prefill_chunks.inc(chunks)
+        if first_of_step:
+            self.prefill_chunk_steps.inc()
+        if budget_exhausted:
+            self.prefill_budget_exhausted.inc()
 
     def record_window(self, decoding: int, batch_size: int) -> None:
         self.active_slots.set(decoding)

@@ -235,6 +235,9 @@ async def check_serving_metrics() -> int:
             "dstack_serving_queue_depth",
             "dstack_serving_prefill_backlog_tokens",
             "dstack_serving_prefill_tokens_total",
+            "dstack_serving_prefill_chunks_total",
+            "dstack_serving_prefill_chunk_steps_total",
+            "dstack_serving_prefill_budget_exhausted_total",
             "dstack_serving_decode_tokens_total",
             "dstack_serving_decode_steps_total",
             "dstack_serving_decode_slot_steps_total",

@@ -117,8 +117,12 @@ def test_programs_are_named_by_their_compile_cache_tags(traced):
     # the CPU trace has no XLA Modules line; its host line names each call
     called = {n for line in traced["lines"] for n in line
               if n.startswith("PjitFunction(")}
-    for name in decode | prefill:
+    for name in prefill:
         assert f"PjitFunction({name})" in called
+    # which table buckets the traced windows need is the schedule's; each
+    # decode call carries one of the engine's tags
+    ran = {n for n in called if n.startswith("PjitFunction(decode_")}
+    assert ran and ran <= {f"PjitFunction({name})" for name in decode}
     assert not {n for n in called if "unknown" in n or n in (
         "PjitFunction(fn)", "PjitFunction(<lambda>)")}
 
@@ -253,6 +257,23 @@ def test_decode_counters_add_up(traced):
     assert delta("decode_tokens_total") == tokens - len(traced["requests"])
     windows = engine.telemetry.decode_occupancy.count
     assert after["dstack_serving_decode_steps_total"] == 8 * windows
+
+
+def test_every_chunk_has_its_span(traced):
+    """The 100-token prompt goes out as two chunks of one scheduling step
+    (a budget of ``batch_size`` = 4): one ``engine.chunk`` span a chunk,
+    and one more where the completed prompt is activated."""
+    after, before = traced["after"], traced["before"]
+
+    def delta(name):
+        return after["dstack_serving_" + name] - before.get(
+            "dstack_serving_" + name, 0.0)
+
+    assert delta("prefill_chunks_total") == 2
+    assert delta("prefill_chunk_steps_total") == 1
+    assert delta("prefill_budget_exhausted_total") == 0
+    names = [n for line in traced["lines"] for n in line]
+    assert names.count("engine.chunk") == 2 + 1
 
 
 def test_kv_peak_is_recorded_where_the_pool_grows(model):

@@ -259,6 +259,31 @@ def test_slots_decode_together_as_they_do_alone(uncut):
     assert [r.output for r in reqs] == alone
 
 
+def test_two_chunked_prompts_keep_their_own_state(uncut):
+    """Two prompts chunk at once on a budget of three chunks a step, a short
+    request decoding beside them: the older prompt's last chunk and the
+    younger's first go out back to back, and each slot's recurrent state
+    stays its own (the tokens are those each request gets alone)."""
+    _, weights, cfg = uncut
+    prompts = [_prompt(n, seed=n).tolist() for n in (18, 100, 120)]
+    alone = [_engine(cfg, weights).generate(p, max_new_tokens=24).output
+             for p in prompts]
+    engine = _engine(cfg, weights, batch_size=3)
+    order = []
+    dispatch = engine._dispatch_chunk
+    engine._dispatch_chunk = lambda slot, st: (order.append(slot),
+                                               dispatch(slot, st))[1]
+    reqs = [engine.submit(Request(tokens=p, max_new_tokens=24))
+            for p in prompts]
+    steps = 0
+    while not all(r.done.is_set() for r in reqs):
+        engine.step()
+        steps += 1
+        assert steps < 100
+    assert order == [1] * 4 + [2] * 4   # oldest first, each to its end
+    assert [r.output for r in reqs] == alone
+
+
 @pytest.mark.parametrize("option,value", [
     ("paged", False), ("prefix_cache", True), ("speculation", "ngram"),
     ("kv_quantize", "int8"), ("quantize", "int8"), ("mesh", "a mesh")])

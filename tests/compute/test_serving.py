@@ -815,39 +815,171 @@ def test_chunked_prefill_matches_whole_prompt(setup):
     assert req.finish_reason == "length"
 
 
+def _count_chunks(engine):
+    """Spy on the engine's chunk dispatch: the returned list gets the slot
+    of every chunk, in dispatch order."""
+    slots = []
+    dispatch = engine._dispatch_chunk
+
+    def counting(slot_id, st):
+        slots.append(slot_id)
+        return dispatch(slot_id, st)
+
+    engine._dispatch_chunk = counting
+    return slots
+
+
 @pytest.mark.slow
 def test_chunked_prefill_interleaves_with_decode(setup):
     """A long prompt prefilling in chunks must not stop an active slot from
-    emitting tokens between chunks."""
+    emitting tokens between chunk steps, and a prompt of more chunks than
+    the step's budget (``batch_size``) takes more than one step."""
     from dstack_tpu.serving.engine import InferenceEngine, Request
 
     cfg, params = setup
     engine = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
                              prefill_chunk=16)
-    short = Request(tokens=[1, 2, 3], max_new_tokens=8)
+    engine.DECODE_WINDOWS = (8,)  # several windows while the prompt chunks
+    chunks = _count_chunks(engine)
+    short = Request(tokens=[1, 2, 3], max_new_tokens=30)
     engine.submit(short)
     engine.step()  # admit + first window dispatched
-    long_req = Request(tokens=[(i * 7) % 50 + 1 for i in range(64)],
+    long_req = Request(tokens=[(i * 7) % 50 + 1 for i in range(96)],
                        max_new_tokens=4)
     engine.submit(long_req)
-    chunk_steps = 0
+    per_step, emitted = [], []
     for _ in range(200):
         if long_req.done.is_set() and short.done.is_set():
             break
+        before = len(chunks)
         engine.step()
-        if engine._chunking:
-            chunk_steps += 1
+        if len(chunks) > before:
+            per_step.append(len(chunks) - before)
+            emitted.append(len(short.output))
     assert short.done.is_set() and long_req.done.is_set()
-    assert chunk_steps >= 2  # the 64-token prompt took several chunk steps
-    assert short.finish_reason == "length"
+    # six chunks on a budget of two a step: the budget binds
+    assert per_step == [2, 2, 2]
+    # the short request got a window's tokens at every one of those steps
+    assert emitted[0] > 1 and emitted == sorted(set(emitted))
+    assert short.finish_reason == "length" and len(short.output) == 30
     assert long_req.finish_reason == "length"
     # both produced correct greedy continuations (short horizons: longer
     # ones can flip argmax ties between the incremental and full-forward
     # paths — pre-existing float reduction-order noise, see the 8-token
     # cap in the tests above)
-    assert short.output == reference_greedy(cfg, params, short.tokens, 8)
+    assert short.output[:8] == reference_greedy(cfg, params, short.tokens, 8)
     assert long_req.output == reference_greedy(
         cfg, params, long_req.tokens, 4)
+
+
+PAGED = {"dense": {}, "paged": dict(paged=True, kv_block_size=16)}
+
+
+@pytest.mark.parametrize("cache", PAGED)
+def test_two_chunked_prompts_finish_in_admission_order(setup, cache):
+    """Two long prompts admitted together: the chunk queue finishes the
+    older one before it starts the younger (a finished prompt is a slot
+    that decodes), across steps whose budget cuts it short."""
+    from dstack_tpu.serving.engine import InferenceEngine, Request
+
+    cfg, params = setup
+    whole = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
+                            **PAGED[cache])
+    engine = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
+                             prefill_chunk=16, **PAGED[cache])
+    chunks = _count_chunks(engine)
+    reqs = [Request(tokens=[(i * k) % 50 + 1 for i in range(40)],
+                    max_new_tokens=5) for k in (7, 11)]
+    for r in reqs:
+        engine.submit(r)
+    firsts = []
+    for _ in range(100):
+        if all(r.done.is_set() for r in reqs):
+            break
+        engine.step()
+        firsts.append([bool(r.output) for r in reqs])
+    assert chunks == [0, 0, 0, 1, 1, 1]
+    assert [True, False] in firsts  # the older prompt's first token first
+    assert reqs[0].first_token_at < reqs[1].first_token_at
+    for r in reqs:
+        assert r.finish_reason == "length"
+        assert r.output == whole.generate(list(r.tokens),
+                                          max_new_tokens=5).output
+
+
+@pytest.mark.parametrize("cache", PAGED)
+def test_completed_prompt_decodes_in_the_next_window(setup, cache):
+    """A prompt whose last chunk is dispatched behind an in-flight window
+    is activated before the NEXT window is dispatched: that window decodes
+    for it, and its drain hands the prompt's decode tokens over."""
+    from dstack_tpu.serving.engine import InferenceEngine, Request
+
+    cfg, params = setup
+    engine = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
+                             prefill_chunk=16, **PAGED[cache])
+    engine.DECODE_WINDOWS = (8,)
+    incumbent = Request(tokens=[1, 2, 3], max_new_tokens=60)
+    engine.submit(incumbent)
+    engine.step()
+    long_req = Request(tokens=[(i * 7) % 50 + 1 for i in range(64)],
+                       max_new_tokens=8)
+    engine.submit(long_req)
+    for _ in range(100):
+        in_flight = engine._pending is not None
+        engine.step()
+        if long_req.output:
+            break
+    # the last chunk went out in the pipelined branch, behind a window
+    assert in_flight and len(long_req.output) == 1
+    slot = engine._slots.index(long_req)
+    assert slot in engine._pending["decoding"]
+    engine.step()  # drains that window: one token from prefill, seven here
+    assert long_req.done.is_set() and len(long_req.output) == 8
+    whole = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
+                            **PAGED[cache])
+    assert long_req.output == whole.generate(list(long_req.tokens),
+                                             max_new_tokens=8).output
+    while not incumbent.done.is_set():
+        engine.step()
+    assert len(incumbent.output) == 60
+
+
+@pytest.mark.parametrize("cache", PAGED)
+def test_cancel_of_second_chunking_prompt_mid_budget(setup, cache):
+    """The younger of two chunking prompts is cancelled with chunks of it
+    already written: the queue releases its slot (and its blocks) and the
+    older prompt's output is untouched."""
+    from dstack_tpu.serving.engine import InferenceEngine, Request
+
+    cfg, params = setup
+    engine = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
+                             prefill_chunk=8, **PAGED[cache])
+    chunks = _count_chunks(engine)
+    older = Request(tokens=[(i * 7) % 50 + 1 for i in range(20)],
+                    max_new_tokens=5)
+    younger = Request(tokens=[(i * 11) % 50 + 1 for i in range(30)],
+                      max_new_tokens=5)
+    engine.submit(older)
+    engine.submit(younger)
+    engine.step()  # both admitted; the budget's two chunks go to the older
+    engine.step()  # its last chunk, and the younger's first
+    assert chunks == [0, 0, 0, 1] and 1 in engine._chunking
+    younger.cancel()
+    while not older.done.is_set():
+        engine.step()
+    assert younger.done.is_set() and younger.finish_reason == "cancelled"
+    assert not younger.output and chunks == [0, 0, 0, 1]
+    assert engine._slots == [None, None] and not engine._chunking
+    if engine.paged:
+        assert engine._alloc.free_blocks == engine._alloc.num_blocks - 1
+    whole = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
+                            **PAGED[cache])
+    assert older.output == whole.generate(list(older.tokens),
+                                          max_new_tokens=5).output
+    # the released slot serves the next request
+    follow = engine.generate(list(younger.tokens), max_new_tokens=5)
+    assert follow.output == whole.generate(list(younger.tokens),
+                                           max_new_tokens=5).output
 
 
 @pytest.mark.slow

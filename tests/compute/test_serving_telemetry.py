@@ -375,11 +375,43 @@ def test_chunked_prefill_backlog_gauge(setup):
         engine.step()
         peak = max(peak, int(tel.prefill_backlog.value))
     assert req.done.is_set()
-    # 32-token prompt, 8-token chunks: after the first chunk dispatch the
-    # remaining backlog is visible (24 then 16 then 8 then 0)
+    # 32-token prompt, 8-token chunks, a budget of two chunks a step: the
+    # remaining backlog is visible after the first step (16, then 0)
     assert peak >= 8, peak
     assert tel.prefill_backlog.value == 0
     snap = tel.load_snapshot()
     assert snap["prefill_backlog_tokens"] == 0
     assert set(snap) == {"active_slots", "queue_depth", "kv_utilization",
                          "prefill_backlog_tokens"}
+
+
+@pytest.mark.parametrize("prompts,chunk,chunks,steps,exhausted", [
+    ([30], 16, 2, 1, 0),        # inside the budget of batch_size = 2
+    ([20, 20], 16, 4, 2, 1),    # the younger prompt waits a step
+    ([40], 8, 5, 3, 2),         # one prompt over the budget, twice cut
+], ids=["inside-budget", "two-prompts", "over-budget"])
+def test_prefill_chunk_counters(setup, prompts, chunk, chunks, steps,
+                                exhausted):
+    """``prefill_chunks_total`` over ``prefill_chunk_steps_total`` is the
+    chunks a scheduling step dispatched, and
+    ``prefill_budget_exhausted_total`` counts the steps whose budget
+    (``batch_size`` chunks) ran out with a chunk still waiting."""
+    from dstack_tpu.serving.engine import Request
+
+    cfg, params = setup
+    engine = _make_engine(cfg, params, prefill_chunk=chunk)
+    reqs = [engine.submit(Request(tokens=list(range(1, n + 1)),
+                                  max_new_tokens=3)) for n in prompts]
+    for _ in range(100):
+        if all(r.done.is_set() for r in reqs):
+            break
+        engine.step()
+    assert all(r.finish_reason == "length" for r in reqs)
+    tel = engine.telemetry
+    assert (tel.prefill_chunks.value, tel.prefill_chunk_steps.value,
+            tel.prefill_budget_exhausted.value) == (chunks, steps, exhausted)
+    counters = tel.recorder.summary()["counters"]
+    assert counters["dstack_serving_prefill_chunks_total"] == chunks
+    assert counters["dstack_serving_prefill_chunk_steps_total"] == steps
+    assert counters["dstack_serving_prefill_budget_exhausted_total"] == \
+        exhausted
