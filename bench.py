@@ -345,86 +345,6 @@ def run_decode_bench(steps_budget: float = 30.0, small: bool = False):
     return out
 
 
-def run_decode_overlap_sweep(ks=(2, 4, 6, 8), chunks=(128, 256, 512),
-                             small: bool = False):
-    """Speculation-k x prefill-chunk overlap sweep (PR 18 tentpole knob 4).
-
-    The two features fight over the same windows: a bigger speculative
-    draft amortizes more weight reads per accepted run but widens the
-    forward every step (pure overhead at low acceptance), while a smaller
-    prefill chunk protects TTFT for late arrivals at the cost of more
-    prefill dispatches stealing decode windows.  Each config runs the
-    mixed workload run_ttft_bench models — repetitive greedy background
-    streams (so n-gram drafts actually accept) with a long-prompt arrival
-    mid-decode — and scores background tok/s; the winner is the fastest
-    config whose probe TTFT stays within 25% of the best TTFT seen.
-
-    The winning config is recorded as the engine's TUNED_SPECULATION_K /
-    TUNED_PREFILL_CHUNK defaults, pinned by
-    tests/compute/test_serving_decode.py.
-    """
-    import dataclasses
-
-    from dstack_tpu.serving.engine import InferenceEngine, Request
-
-    if small:
-        # probe longer than the largest chunk so EVERY config actually
-        # chunks the arrival (a probe under the chunk size would make the
-        # big-chunk arms degenerate to whole-prompt prefill)
-        cfg = dataclasses.replace(llama.LlamaConfig.tiny(), max_seq_len=2048)
-        bg_n, max_len, probe_len = 3, 2048, 1024
-    else:
-        cfg = llama.LlamaConfig.llama3_1b()
-        bg_n, max_len, probe_len = 7, 2048, 1024
-    params = None
-    # 8-token cycle: generation repeats context n-grams, so drafts accept
-    bg_prompts = [[(i * 8 + j % 8) % 500 + 1 for j in range(64)]
-                  for i in range(bg_n)]
-    results = {}
-    for k in ks:
-        for chunk in chunks:
-            engine = InferenceEngine(
-                cfg, params=params, batch_size=bg_n + 1, max_len=max_len,
-                speculation="ngram", speculation_k=k, prefill_chunk=chunk)
-            params = engine.params
-            # bg streams outlive the measurement (generation caps at the
-            # cache, not max_new) — the metric is their rate WHILE the
-            # probe prefills and decodes, the contention chunking tunes
-            bg = [Request(tokens=list(p), max_new_tokens=4 * max_len)
-                  for p in bg_prompts]
-            for r in bg:
-                engine.submit(r)
-            warm = Request(tokens=[(5 * j) % 500 + 1 for j in range(probe_len)],
-                           max_new_tokens=1)
-            engine.submit(warm)
-            t0 = time.perf_counter()
-            while not warm.done.is_set() and time.perf_counter() - t0 < 120:
-                engine.step()
-            probe = Request(tokens=[(3 * j) % 500 + 1 for j in range(probe_len)],
-                            max_new_tokens=16)
-            n0 = sum(len(r.output) for r in bg)
-            t0 = time.time()
-            engine.submit(probe)
-            while not probe.done.is_set() and time.time() - t0 < 120:
-                engine.step()
-            dt = time.time() - t0
-            ttft = (probe.first_token_at or time.time()) - t0
-            tok_s = (sum(len(r.output) for r in bg) - n0) / dt
-            results[(k, chunk)] = {"tok_s": tok_s, "ttft_ms": ttft * 1e3}
-            log(f"overlap k={k} chunk={chunk}: bg {tok_s:,.0f} tok/s, "
-                f"probe TTFT {ttft*1e3:,.0f} ms")
-    best_ttft = min(m["ttft_ms"] for m in results.values())
-    ok = {kc: m for kc, m in results.items()
-          if m["ttft_ms"] <= 1.25 * best_ttft}
-    (win_k, win_chunk) = max(ok, key=lambda kc: ok[kc]["tok_s"])
-    log(f"overlap winner: k={win_k} chunk={win_chunk} "
-        f"({ok[(win_k, win_chunk)]['tok_s']:,.0f} tok/s, "
-        f"TTFT {ok[(win_k, win_chunk)]['ttft_ms']:,.0f} ms)")
-    return {"k": win_k, "chunk": win_chunk,
-            "tok_s": round(ok[(win_k, win_chunk)]["tok_s"], 1),
-            "results": results}
-
-
 def run_gateway_routing_bench():
     """Routing-policy comparison on the seeded multi-replica simulator
     (gateway/routing_sim.py — drives the REAL ReplicaLoadTracker): p95
@@ -860,10 +780,6 @@ def main():
         # decode hot-loop arms: dense-paged baseline vs ragged buckets
         # vs quantized KV, plus the TTFT pair (PR 18)
         extra.update(run_decode_bench())
-        sweep = run_decode_overlap_sweep()
-        extra["serving_decode_overlap_best_k"] = sweep["k"]
-        extra["serving_decode_overlap_best_chunk"] = sweep["chunk"]
-        extra["serving_decode_overlap_tok_s"] = sweep["tok_s"]
         # routing comparison keys: gateway_routing_<policy>_<metric>
         # (short policy names keep the payload readable)
         short = {"round_robin": "rr", "least_loaded": "p2c",

@@ -39,11 +39,11 @@ DEFINING = ("dstack_tpu/elastic/compile_cache.py",)
 #: call shapes that produce a compile-cache-routed (or plain jitted)
 #: callable
 _JIT_CONSTRUCTORS = ("jit", "pjit", "CachedJit", "maybe_cached",
-                     "_jit_cached", "_named_jit")
+                     "_jit_cached", "named_jit")
 #: constructors that take the plain function and the jit keywords
-#: themselves (``_named_jit(fn, name, static_argnums=...)``), so the static
+#: themselves (``named_jit(fn, name, static_argnums=...)``), so the static
 #: spec is read off their own call
-_JIT_KEYWORD_CONSTRUCTORS = ("_jit_cached", "_named_jit")
+_JIT_KEYWORD_CONSTRUCTORS = ("_jit_cached", "named_jit")
 #: helpers that call a cached-jit callable for their caller: name ->
 #: index of the first traced leaf (``_run_program(table, key, make,
 #: *leaves)`` builds ``table[key]`` on first use and calls it on the leaves)

@@ -43,9 +43,10 @@ TRACER_ENTRY_POINTS = {
     "jax.jit", "jit", "pjit", "jax.pmap", "pmap",
     "shard_map", "jax.shard_map", "jax.experimental.shard_map.shard_map",
     "jax.experimental.shard_map",
-    # serving/engine.py's naming wrappers around jax.jit: their first
-    # argument is the function that gets traced
-    "_named_jit", "self._jit_cached",
+    # the serving engine's naming wrappers around jax.jit
+    # (utils/jax_runtime.py named_jit, InferenceEngine._jit_cached): their
+    # first argument is the function that gets traced
+    "named_jit", "self._jit_cached",
 }
 
 #: attribute reads on a traced array that are static at trace time

@@ -1,11 +1,18 @@
-"""Continuous-batching inference engine (JetStream-style) on the Llama stack.
+"""Continuous-batching inference engine (JetStream-style): the scheduler.
 
-The serving counterpart of models/llama.py: a fixed pool of decode *slots*
-shares one batched KV cache; prefill computes a prompt's K/V with the full
-forward pass and inserts them into a free slot; decode advances ALL active
-slots one token per step with per-slot positions. Static shapes throughout
-(prompt lengths padded to buckets) so both phases jit-compile once and stay
-on the MXU.
+A fixed pool of decode *slots* shares one model replica's device state;
+prefill computes a prompt's state with the full forward pass and inserts it
+into a free slot; decode advances ALL active slots one token per step with
+per-slot positions. Static shapes throughout (prompt lengths padded to
+buckets) so both phases jit-compile once and stay on the MXU.
+
+This module owns slots, KV blocks, queues, decode windows, sampling,
+telemetry, the compile cache and the order of dispatch.  What the model's
+memory IS and what a layer computes belong to the family's provider
+(``serving/families.py`` picks it: ``serving/dense.py`` for the Llama
+family, ``serving/hybrid.py`` for the KDA + MLA hybrid): it allocates the
+two donated state trees this module hands to every program as they are,
+and builds the functions this module names, jits and dispatches.
 
 No reference equivalent — the reference proxies to SGLang/TGI
 (gateway/services/model_routers/sglang.py); this engine is the TPU-native
@@ -28,56 +35,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from dstack_tpu.elastic.compile_cache import CompileCache, maybe_cached
-from dstack_tpu.models.ling_hybrid import LingHybridConfig
-from dstack_tpu.models.ling_hybrid import init_params as hybrid_init
-from dstack_tpu.models.llama import (
-    LlamaConfig,
-    Params,
-    init_params,
-    output_head,
-)
-from dstack_tpu.ops.pool import scatter_rows as _scatter_rows
-from dstack_tpu.ops.rmsnorm import rms_norm
-from dstack_tpu.ops.rotary import apply_rope, rope_frequencies
-from dstack_tpu.serving.hybrid import HybridPrograms
+from dstack_tpu.serving.families import programs_for
 from dstack_tpu.serving.paging import BlockAllocator, PrefixBlockAllocator
-from dstack_tpu.serving.quant import (
-    dequantize_kv,
-    dequantize_kv4,
-    qmatmul,
-    quantize_kv,
-    quantize_kv4,
-    quantize_params,
-)
+from dstack_tpu.utils.jax_runtime import named_jit
 
 PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
 
 logger = logging.getLogger(__name__)
-
-
-def _paged_kernel_default() -> bool:
-    """Whether paged decode attention should run the Pallas block-table
-    kernel (ops/flash_attention.py paged_decode_attention) instead of the
-    XLA gather path.  ``DSTACK_TPU_PAGED_ATTN_KERNEL``: "auto" (default —
-    on for a real TPU backend, off for CPU/interpret where the XLA path
-    wins), "1"/"0" to force.  Whichever is chosen is the only path: a
-    kernel the compiler refuses fails the decode, nothing falls back."""
-    v = os.environ.get("DSTACK_TPU_PAGED_ATTN_KERNEL", "auto")
-    if v == "auto":
-        return jax.default_backend() == "tpu"
-    return v not in ("0", "false", "off")
-
-
-def _named_jit(fn, name: str, **jit_kwargs):
-    """``jax.jit`` of ``fn`` under ``name``: the compiled program shows as
-    ``jit_<name>`` on a profiler trace's ``XLA Modules`` line and in HLO
-    dumps (a ``functools.partial`` or a local ``fn`` would read
-    ``jit__unknown`` / ``jit_fn``).  The only ``jax.jit`` in this module."""
-    def named(*args, **kwargs):
-        return fn(*args, **kwargs)
-
-    named.__name__ = named.__qualname__ = name
-    return jax.jit(named, **jit_kwargs)
 
 
 class EngineDraining(RuntimeError):
@@ -147,254 +111,30 @@ class Request:
         self.cancelled = True
 
 
-# Device-side regions carry a jax.named_scope so that a profiler trace and an
-# HLO dump say which part of a program an operation belongs to: qkv, attn,
-# paged_attn, mlp, lm_head, sample, kv_insert (prefill's write of a prompt's
-# K/V), kv_window_write (the decode window's one write at its end).
-
-
-@jax.named_scope("mlp")
-def _mlp_block(h, lp, cfg: LlamaConfig, token_mask=None):
-    """Dense SwiGLU or routed-expert MLP on [B, S, D] normed hiddens.
-
-    The rest of the serving math (attention, KV cache, sampling) is
-    model-agnostic, so this one dispatch point is what makes the engine
-    serve both Llama-family and Mixtral-style MoE checkpoints.  MoE decode
-    routes each generated token independently through the same GShard
-    static-capacity path training uses (models/moe.py).
-    """
-    if "router" not in lp:
-        gated = jax.nn.silu(qmatmul(h, lp["w_gate"], cfg.dtype))
-        up = qmatmul(h, lp["w_up"], cfg.dtype)
-        return qmatmul(gated * up, lp["w_down"], cfg.dtype)
-    from dstack_tpu.models.moe import _moe_mlp
-
-    b, s, _ = h.shape
-    # Decode (one token per slot): force DROPLESS capacity — an expert can
-    # hold every token, so no generated token ever loses an expert to
-    # capacity pressure from its batch neighbours (GShard capacity is a
-    # training-time economy; at t=B the dispatch tensor is tiny anyway).
-    # Prefill: `token_mask` keeps bucket-padding out of routing (pads must
-    # not steal real tokens' expert slots), and capacity derives from the
-    # bucket length, which is >= the unpadded training forward's — so a
-    # served prompt can only ever KEEP tokens training-time capacity would
-    # drop, never lose ones it would keep.
-    capacity = b * s if s == 1 else None
-    out, _aux = _moe_mlp(h, lp, cfg, None, None, capacity=capacity,
-                         token_mask=token_mask)
-    return out
-
-
-def _layer_kv(params, cfg: LlamaConfig, x, positions, inv_freqs,
-              token_mask=None):
-    """Per-layer K/V for a full sequence — shared by prefill.
-    ``token_mask`` [B, S] marks real (non-padding) tokens for MoE routing."""
-    b, s, _ = x.shape
-
-    def layer(carry, lp):
-        x = carry
-        q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, b, s)
-        attn = _masked_attention(q, k, v, positions, positions)
-        x = x + qmatmul(attn.reshape(b, s, cfg.q_dim),
-                       lp["wo"], cfg.dtype)
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp_block(h, lp, cfg, token_mask)
-        return x, (k, v)
-
-    x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
-    return x, ks, vs  # ks/vs: [L, B, S, Hkv, D]
-
-
-def _last_logits(params, cfg: LlamaConfig, x, length):
-    """Logits at the last of ``length`` real positions of a [1, S, D]
-    prefill activation."""
-    with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-        head = output_head(params, cfg)
-        return qmatmul(x[0, length - 1, :], head, cfg.dtype,
-                       preferred=jnp.float32)
-
-
-def _prompt_forward(params, cfg: LlamaConfig, padded, length, bucket: int):
-    """Forward over a padded prompt: (last-position logits, ks, vs).
-    The single source of truth for prefill math — used by both the
-    slot-inserting prefill jit and the PD export jit."""
-    positions = jnp.arange(bucket)[None, :]
-    inv_freqs = jnp.asarray(rope_frequencies(
-        cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
-    x = params["embed"].astype(cfg.dtype)[padded][None, :, :]
-    token_mask = (jnp.arange(bucket)[None, :] < length)
-    x, ks, vs = _layer_kv(params, cfg, x, positions, inv_freqs, token_mask)
-    return _last_logits(params, cfg, x, length), ks, vs
-
-
-@jax.named_scope("qkv")
-def _decode_qkv(x, lp, cfg: LlamaConfig, positions, inv_freqs, b: int,
-                m: int = 1):
-    """Per-token projections + RoPE — factored out so the dense and paged
-    branches of the buffered decode (and the prefill programs) can never
-    diverge numerically.  ``m`` is the tokens per row: 1 for plain decode,
-    draft_k+1 for speculative verification, the bucket for a prefill."""
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = qmatmul(h, lp["wq"], cfg.dtype).reshape(
-        b, m, cfg.num_heads, cfg.head_dim)
-    k = qmatmul(h, lp["wk"], cfg.dtype).reshape(
-        b, m, cfg.num_kv_heads, cfg.head_dim)
-    v = qmatmul(h, lp["wv"], cfg.dtype).reshape(
-        b, m, cfg.num_kv_heads, cfg.head_dim)
-    return (apply_rope(q, positions, inv_freqs),
-            apply_rope(k, positions, inv_freqs), v)
-
-
-def _decode_layer_tail(x, attn, lp, cfg: LlamaConfig, b: int, m: int = 1):
-    """Shared post-attention half of a decode layer (wo + MLP)."""
-    x = x + qmatmul(attn.reshape(b, m, cfg.q_dim), lp["wo"], cfg.dtype)
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    return x + _mlp_block(h, lp, cfg)
-
-
-def _kv_mat(cache_leaf, dtype):
-    """A KV tensor ready for attention: plain arrays pass through;
-    quantized dicts dequantize — int8 {"q","s"} or nibble-packed int4
-    {"q4","s"} (the dict key IS the format marker).  XLA fuses the
-    convert+scale into the consuming dot, so the quantized bytes are what
-    cross HBM."""
-    if isinstance(cache_leaf, dict):
-        if "q4" in cache_leaf:
-            return dequantize_kv4(cache_leaf["q4"], cache_leaf["s"], dtype)
-        return dequantize_kv(cache_leaf["q"], cache_leaf["s"], dtype)
-    return cache_leaf
-
-
-def _kv_pack(rows, bits: int = 8):
-    """Quantize bf16 K/V rows [..., D] into the cache's dict form:
-    {"q","s"} at 8 bits, {"q4","s"} nibble-packed at 4."""
-    if bits == 4:
-        q4, s = quantize_kv4(rows)
-        return {"q4": q4, "s": s}
-    q, s = quantize_kv(rows)
-    return {"q": q, "s": s}
-
-
-def _kv_map(cache, rows, fn, lanes: bool = False):
-    """Apply ``fn(cache_leaf, rows_leaf)`` over a cache that is either a
-    plain array or a quantized {"q"|"q4","s"} dict (rows packed to
-    match).  ``fn`` must be shape-generic over trailing dims: the int4
-    "q4" leaf carries D/2 packed bytes and "s" no D dim at all.
-    ``lanes``: the cache is the PAGED pool, whose leaves fold the kv heads
-    into the lane dim (:func:`_fold_heads`; "s" keeps [..., Hkv]) — the
-    new rows are folded to match, never the pool."""
-    fold = _fold_heads if lanes else (lambda a: a)
-    if isinstance(cache, dict):
-        qk = "q4" if "q4" in cache else "q"
-        packed = _kv_pack(rows, bits=4 if qk == "q4" else 8)
-        return {qk: fn(cache[qk], fold(packed[qk])),
-                "s": fn(cache["s"], packed["s"])}
-    return fn(cache, fold(rows))
-
-
-def _fold_heads(a):
-    """[..., Hkv, D] K/V rows -> [..., Hkv*D], lane = h*D + d: the paged
-    pool's stored form, which is the decode kernel's operand."""
-    return a.reshape(a.shape[:-2] + (-1,))
-
-
-def _split_heads(view, hkv: int):
-    """Inverse of :func:`_fold_heads` for a view GATHERED from the paged
-    pool (array or quantized dict; "s" is [..., Hkv] already)."""
-    split = lambda a: a.reshape(a.shape[:-1] + (hkv, -1))
-    if isinstance(view, dict):
-        return {key: (leaf if key == "s" else split(leaf))
-                for key, leaf in view.items()}
-    return split(view)
-
-
-@jax.named_scope("kv_window_write")
-def _dense_window_insert(cache, win, widx, in_window):
-    """End-of-window bulk insert for the DENSE cache: cache position (b, s)
-    takes window column ``widx[b, s]`` wherever ``in_window[b, s]`` — the
-    one write the buffered formulations (plain and speculative) amortize
-    the whole window's cache updates into."""
-    def one(leaf, rows):
-        rows_t = jnp.moveaxis(rows, 1, 2)            # [L, B, cols, ...]
-        idx = widx[None, :, :]
-        idx = idx.reshape(idx.shape + (1,) * (rows_t.ndim - 3))
-        picked = jnp.take_along_axis(rows_t, idx, axis=2)
-        sel = in_window[None, :, :]
-        sel = sel.reshape(sel.shape + (1,) * (rows_t.ndim - 3))
-        return jnp.where(sel, picked, leaf)
-
-    return _kv_map(cache, win, one)
-
-
-def _suffix_layer(x, lp, cfg: LlamaConfig, positions, inv_freqs, kv_pos,
-                  token_mask, layer_k, layer_v, insert, gather,
-                  lanes: bool = False):
-    """One transformer layer of a suffix/chunk prefill: project the new
-    tokens' K/V, ``insert`` them into the slot's cache, then attend the
-    new queries over the ``gather``-ed full slot span (earlier rows +
-    causal within the new ones, absolute RoPE positions).  The insert and
-    gather callbacks are the ONLY difference between the paged suffix
-    prefill (block scatter/gather) and the dense chunked prefill (row
-    slice) — both share this body.  The paged one (``lanes``) hands the
-    WHOLE pool through as ``layer_k``/``layer_v``: its callbacks address
-    the layer in place."""
-    sbucket = x.shape[1]
-    q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, 1, sbucket)
-    with jax.named_scope("kv_insert"):
-        layer_k = _kv_map(layer_k, k, insert, lanes)
-        layer_v = _kv_map(layer_v, v, insert, lanes)
-    kv_k = _kv_mat(gather(layer_k), cfg.dtype)
-    kv_v = _kv_mat(gather(layer_v), cfg.dtype)
-    attn = _masked_attention(q, kv_k, kv_v, positions, kv_pos)
-    x = x + qmatmul(attn.reshape(1, sbucket, cfg.q_dim), lp["wo"], cfg.dtype)
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    x = x + _mlp_block(h, lp, cfg, token_mask)
-    return x, layer_k, layer_v
-
-
-@jax.named_scope("attn")
-def _masked_attention(q, k, v, q_pos, kv_pos):
-    """Causal GQA attention with explicit position masks (prefill)."""
-    b, s, hq, d = q.shape
-    hkv = k.shape[2]
-    group = hq // hkv
-    q = q.reshape(b, s, hkv, group, d)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) / (d ** 0.5)
-    mask = (kv_pos[:, None, :] <= q_pos[:, :, None])[:, None, None, :, :]
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, s, hq, d)
-
-
 class InferenceEngine:
     """Slot-based continuous batching over one model replica.
 
-    batch_size slots share a [L, B, max_len, Hkv, D] cache; `step()` is one
+    batch_size slots share the family's device state (for the Llama family
+    a [L, B, max_len, Hkv, D] cache or a paged pool); `step()` is one
     scheduling iteration: admit waiting prompts into free slots (prefill),
     then advance every active slot a WINDOW of tokens in one dispatch
-    (`_decode_window_fn_buffered`) with on-device nucleus sampling.  Streaming
-    callbacks therefore arrive in bursts of up to `DECODE_WINDOWS[-1]`
-    tokens, and a queued prompt waits at most one window for a free slot —
-    the price of amortizing the host round-trip across the window.
+    (the provider's ``decode_window_fn``) with on-device nucleus sampling.
+    Streaming callbacks therefore arrive in bursts of up to
+    `DECODE_WINDOWS[-1]` tokens, and a queued prompt waits at most one
+    window for a free slot — the price of amortizing the host round-trip
+    across the window.
     """
 
-    #: Speculation x chunked-prefill overlap sweep winner (bench.py
-    #: run_decode_overlap_sweep, PR 18): k=2 beat every larger draft at
-    #: every chunk size — past 2, the widened verify forward costs more
-    #: than the extra accepted tokens return on the mixed workload — and
-    #: chunk=512 held background decode within range of smaller chunks at
-    #: the best arrival TTFT.  speculation_k=None resolves to the tuned
-    #: value; tests/compute/test_serving_decode.py pins both so a default
-    #: change is a deliberate re-sweep, not drift.
-    TUNED_SPECULATION_K = 2
+    #: Chunked-prefill sweep winner (PR 18): chunk=512 held background
+    #: decode within range of smaller chunks at the best arrival TTFT.
+    #: tests/compute/test_serving_decode.py pins it so a default change is
+    #: a deliberate re-sweep, not drift.
     TUNED_PREFILL_CHUNK = 512
 
     def __init__(
         self,
-        cfg: LlamaConfig,
-        params: Optional[Params] = None,
+        cfg: Any,
+        params: Optional[Any] = None,
         batch_size: int = 8,
         max_len: int = 1024,
         rng_seed: int = 0,
@@ -407,8 +147,6 @@ class InferenceEngine:
         sharding_policy: Optional[Any] = None,
         prefix_cache: bool = False,
         prefill_chunk: Optional[int] = None,
-        speculation: Optional[str] = None,
-        speculation_k: Optional[int] = None,
         telemetry: Optional[Any] = None,
         compile_cache: Optional[CompileCache] = None,
     ) -> None:
@@ -455,17 +193,9 @@ class InferenceEngine:
         skips its chunks entirely).  None disables (whole-prompt prefill
         at admission).
 
-        ``speculation="ngram"``: n-gram (prompt-lookup) speculative
-        decoding — GREEDY windows verify ``speculation_k`` draft tokens
-        per step in one widened forward, emitting several tokens per
-        weight pass when generation repeats n-grams from the context
-        (code, extraction, chat-with-history).  Output tokens are
-        identical to non-speculative greedy; sampled requests and paged
-        engines use the plain window.  See _decode_window_fn_spec.
-
         ``telemetry``: a `dstack_tpu.telemetry.serving.EngineTelemetry`
         recording queue-wait/TTFT/inter-token histograms, batch occupancy,
-        KV utilization, preemptions and spec-decode acceptance from the
+        KV utilization and preemptions from the
         scheduler thread (serving/server.py exposes it on /metrics and
         /stats).  None (the default) disables recording entirely: the hot
         paths pay a single ``is None`` check and ``_emit`` allocates
@@ -485,90 +215,26 @@ class InferenceEngine:
         ``compile_cache``: a `dstack_tpu.elastic.compile_cache.CompileCache`
         consulted before every jit lowering — a scaling-up replica whose
         programs a peer already compiled deserializes them in
-        milliseconds instead of paying the 11.8-17.4 s compile leg
-        (an earlier v5e run, 2026-08-01).  Defaults to the env-configured cache
+        milliseconds instead of compiling them.  Defaults to the
+        env-configured cache
         (``DSTACK_COMPILE_CACHE`` / ``DSTACK_COMPILE_CACHE_PEERS``);
         both unset → no caching, the plain jit path.  Hit/miss counters
         surface on ``/load`` and ``/stats``.
         """
         self.cfg = cfg
         self.telemetry = telemetry
-        #: the state and the programs of a model whose memory is not K and
-        #: V rows (serving/hybrid.py: a recurrent state beside a paged
-        #: latent pool); None for the Llama family, whose programs are this
-        #: class's own.  The scheduler below is one for both: beside the
-        #: constructor only ``_reset_device_state`` and the three builders
-        #: of paged programs (prefill, chunk, decode window) ask which.
-        self._hybrid = None
-        #: where a paged prefill or chunk program writes, from (slot, its
-        #: pages): the pages, and for per-slot recurrent state the slot too
-        self._slot_target = lambda slot_id, pages: pages
-        #: why prefill/decode disaggregation is refused, if it is
-        self._pd_refusal: Optional[str] = None
-        hybrid = isinstance(cfg, LingHybridConfig)
-        if hybrid:
-            self._pd_refusal = (
-                "prefill/decode disaggregation is not served for this "
-                "model: the wire carries K and V rows, not a recurrent "
-                "state and latent rows")
-            for refused, needs in (
-                (not paged, "paged=False: the MLA layers' latent rows live "
-                 "in the paged pool, a dense latent cache is not written"),
-                (prefix_cache, "prefix_cache: a cached block would need a "
-                 "snapshot of the recurrent state at its boundary"),
-                (speculation, "speculation: rejected drafts would need the "
-                 "recurrent state rolled back"),
-                (kv_quantize, "kv_quantize: latent pages would need scales "
-                 "and an absorbed product over quantized rows"),
-                (quantize, "quantize: the grouped expert product would need "
-                 "int8 forms of the expert stacks"),
-                (mesh is not None, "a mesh: it would need the expert "
-                 "exchange and sharding rules for the recurrent state"),
-            ):
-                if refused:
-                    raise ValueError(
-                        f"{type(cfg).__name__} is not served with {needs}")
         self.compile_cache = (compile_cache if compile_cache is not None
                               else CompileCache.from_env())
         self.batch_size = batch_size
         self.max_len = min(max_len, cfg.max_seq_len)
         self.paged = paged
-        if kv_quantize not in (None, "int8", "int4"):
-            raise ValueError(f"unsupported kv_quantize={kv_quantize!r} "
-                             "(only 'int8' or 'int4')")
-        if kv_quantize == "int4" and cfg.head_dim % 2:
-            raise ValueError("int4 KV packing needs an even head_dim")
-        self.kv_quantize = kv_quantize
-        self.kv_quant = kv_quantize is not None
         #: paged decode reads only a power-of-two BUCKET of each slot's
         #: block table sized to the longest active slot (ragged lengths),
         #: instead of the full blocks_per_slot span; DSTACK_TPU_RAGGED_DECODE=0
         #: restores the full-span gather (the dense-paged bench baseline)
         self._ragged = os.environ.get(
             "DSTACK_TPU_RAGGED_DECODE", "1") != "0"
-        #: Pallas block-table decode kernel (resolved once at init)
-        self._paged_kernel = _paged_kernel_default()
-        self.mesh = mesh
-        self._policy = None
-        t = 1  # tensor-parallel degree
-        if mesh is not None:
-            from dstack_tpu.models.llama import ShardingPolicy
-
-            self._policy = sharding_policy or ShardingPolicy(
-                batch_axes=(), fsdp_axis=None, tensor_axis="tensor")
-            if (self._policy.tensor_axis
-                    and self._policy.tensor_axis not in mesh.axis_names):
-                raise ValueError(
-                    f"mesh axes {mesh.axis_names} lack the policy's tensor "
-                    f"axis {self._policy.tensor_axis!r}; name the mesh axis "
-                    f"to match (or pass a sharding_policy)")
-            t = (mesh.shape.get(self._policy.tensor_axis, 1)
-                 if self._policy.tensor_axis else 1)
-            if cfg.num_kv_heads % t or cfg.num_heads % t:
-                raise ValueError(
-                    f"tensor-parallel serving needs head counts divisible "
-                    f"by the tensor degree: heads {cfg.num_heads}/"
-                    f"{cfg.num_kv_heads}, tensor={t}")
+        n_blocks = 0
         if paged:
             if kv_block_size <= 0 or kv_block_size & (kv_block_size - 1):
                 # buckets are powers of two: any power-of-two block size
@@ -587,6 +253,16 @@ class InferenceEngine:
                 raise ValueError(
                     f"total_kv_blocks must exceed {self._blocks_per_slot} "
                     f"(= max_len / kv_block_size)")
+        #: the family's provider (serving/families.py): what the device
+        #: state is, and the functions of the programs dispatched below.
+        #: It refuses the options its model is not served with.
+        self._programs = programs_for(
+            cfg, batch_size=batch_size, max_len=self.max_len, paged=paged,
+            block_size=kv_block_size, num_blocks=n_blocks,
+            prefix_cache=prefix_cache, quantize=quantize,
+            kv_quantize=kv_quantize, mesh=mesh,
+            sharding_policy=sharding_policy, sample=self._sample_on_device)
+        if paged:
             self._alloc = (PrefixBlockAllocator(n_blocks) if prefix_cache
                            else BlockAllocator(n_blocks))
             # The buffered-window decode materializes a dense-equivalent
@@ -600,45 +276,13 @@ class InferenceEngine:
                     "the dense equivalent (%d): decode still needs a "
                     "dense-equivalent linear-view allowance in HBM "
                     "(see ROOFLINE.md, serving decode)", n_blocks, dense_equiv)
-            if hybrid:
-                self._hybrid = HybridPrograms(
-                    cfg, batch_size=batch_size, max_len=self.max_len,
-                    block_size=kv_block_size, num_blocks=n_blocks,
-                    sample=self._sample_on_device)
-                self._slot_target = self._hybrid.slot_target
-            lanes = (cfg.latent_lanes if hybrid
-                     else cfg.num_kv_heads * cfg.head_dim // t)
-            if self._paged_kernel and lanes % 128:
-                # the pool is stored as the decode kernel's operand only
-                # in whole 128-lane tiles: the TPU compiler keeps a
-                # narrower or ragged pool with the blocks minor-most and
-                # converts ALL of it around every program that reads it
-                # (tests/compute/test_tpu_compile.py)
-                logger.warning(
-                    "paged KV pool rows are %d lanes a device (kv heads x "
-                    "head_dim / tensor degree), not a multiple of 128: the "
-                    "TPU converts the whole pool's layout around every "
-                    "decode window and holds a second copy of it; use a "
-                    "tensor degree that leaves whole multiples of 128",
-                    lanes)
             self._tables_host = np.zeros(
                 (batch_size, self._blocks_per_slot), np.int32)
             self._slot_blocks: List[List[int]] = [[] for _ in range(batch_size)]
-        elif prefix_cache:
-            raise ValueError("prefix_cache requires paged=True (the cache "
-                             "is block-addressed)")
         if prefill_chunk is not None and prefill_chunk < 1:
             # 0 would make every request chunk forever on empty slices
             raise ValueError("prefill_chunk must be >= 1")
         self.prefill_chunk = prefill_chunk
-        if speculation not in (None, "ngram"):
-            raise ValueError(f"unsupported speculation={speculation!r} "
-                             "(only 'ngram')")
-        if speculation and paged:
-            raise ValueError("speculation requires the dense cache")
-        self.speculation = speculation
-        self.speculation_k = (speculation_k if speculation_k is not None
-                              else self.TUNED_SPECULATION_K)
         #: slot_id -> {"tokens", "done", ("logits", "n")} for prompts
         #: mid-chunked-prefill (see prefill_chunk)
         self._chunking: dict = {}
@@ -646,75 +290,19 @@ class InferenceEngine:
         #: per-slot (prefix_len, block_keys) staged between reserve and
         #: prefill (prefix-cache mode)
         self._slot_prefix: List[tuple] = [(0, []) for _ in range(batch_size)]
-        from dstack_tpu.models.moe import MoEConfig, init_params as moe_init
-
-        self._is_moe = (
-            isinstance(cfg, MoEConfig)
-            or (params is not None and "router" in (
-                params["layers"][0]
-                if isinstance(params["layers"], (list, tuple))
-                else params["layers"])))
-        if mesh is not None and self._is_moe:
-            e = mesh.shape.get("expert", 1)
-            if e > 1 and cfg.num_experts % e:
-                raise ValueError(
-                    f"expert-parallel serving needs num_experts "
-                    f"({cfg.num_experts}) divisible by the expert mesh "
-                    f"degree ({e})")
-        if params is None:
-            if mesh is not None:
-                # init directly sharded — the full model must never
-                # materialize on one device (the whole point of mesh serving
-                # is models that don't fit one chip's HBM)
-                init = moe_init if isinstance(cfg, MoEConfig) else init_params
-                shapes = jax.eval_shape(
-                    lambda: init(jax.random.PRNGKey(0), cfg))
-                params = _named_jit(
-                    lambda: init(jax.random.PRNGKey(rng_seed), cfg),
-                    "init_params",
-                    out_shardings=self._param_shardings(shapes),
-                )()
-            else:
-                params = (hybrid_init if hybrid
-                          else moe_init if isinstance(cfg, MoEConfig)
-                          else init_params)(jax.random.PRNGKey(rng_seed), cfg)
-        elif mesh is not None:
-            # host (numpy / checkpoint) arrays transfer shard-wise here;
-            # already-committed device arrays get resharded
-            params = jax.device_put(params, self._param_shardings(params))
-        self.params = params
-        if quantize is not None:
-            if quantize != "int8":
-                raise ValueError(f"unsupported quantize={quantize!r} "
-                                 "(only 'int8')")
-            # weight-only int8 (serving/quant.py): decode is weight-read
-            # bound, so int8 weights ~halve the per-step HBM floor; tied
-            # models get an int8 COPY of the head so the logits matmul
-            # (the single largest read) streams int8 too
-            # under a mesh this runs on already-sharded arrays (executes
-            # distributed); the device_put below only re-aligns the int8
-            # scales and the tied-head copy
-            self.params = quantize_params(
-                self.params, tied_head_copy=cfg.tie_embeddings)
-            if mesh is not None:
-                self.params = jax.device_put(
-                    self.params, self._param_shardings(self.params))
-        if mesh is None:
-            # commit the params: an UNcommitted tree lowers without
-            # mhlo.sharding annotations while a checkpoint-restored
-            # (committed) one carries "{replicated}", so the same program
-            # would hash to two different compile-cache keys depending on
-            # where the weights came from (elastic/compile_cache.py keys
-            # on the HLO text) — a peer's cache entry would never hit
-            self.params = jax.device_put(self.params, jax.devices()[0])
+        self.params = self._programs.prepare_params(params, rng_seed)
         self._queue: "queue.Queue[Request]" = queue.Queue()
         #: head-of-line request waiting for KV blocks (paged mode)
         self._stalled: Optional[Request] = None
         self._slots: List[Optional[Request]] = [None] * batch_size
-        self._rng = np.random.default_rng(rng_seed)
 
         self._reset_device_state()
 
+        #: compile-cache tags (and so a trace's module names) of the two
+        #: prompt programs: they say which cache the program writes
+        self._prefill_tag, self._chunk_tag = (
+            ("prefill_paged_b", "prefill_prefix_b") if paged
+            else ("prefill_b", "prefill_chunk_b"))
         self._prefill_jit = {}
         self._decode_jit = {}  # (window, sampling) -> jitted K-step decode
         self._rng_key = jax.random.PRNGKey(rng_seed)
@@ -741,83 +329,20 @@ class InferenceEngine:
         self._watchdog_s = float(os.environ.get(
             "DSTACK_TPU_ENGINE_WATCHDOG_S", "300"))
         self._step_started_at: Optional[float] = None
-        #: speculative-decode counters: DEVICE-side verification steps and
-        #: draft tokens accepted (includes discarded end-of-request
-        #: overshoot, so this measures verification efficiency, not exact
-        #: emitted-token counts)
-        self.spec_stats = {"steps": 0, "accepted": 0}
-
-    def _param_shardings(self, params):
-        """NamedSharding pytree mirroring ``params`` (a value or eval_shape
-        tree; incl. int8 {"q","s"} leaves — "s" drops the contraction dim,
-        keeping per-out-channel scales aligned with their sharded
-        channels)."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from dstack_tpu.models import llama as llama_mod
-
-        if self._is_moe:
-            from dstack_tpu.models import moe as moe_mod
-
-            expert_axis = ("expert"
-                           if self.mesh.shape.get("expert", 1) > 1 else None)
-            specs = moe_mod.param_specs(self.cfg, self._policy, expert_axis)
-        else:
-            specs = llama_mod.param_specs(self.cfg, self._policy)
-        # Serving overrides vs the training specs:
-        # - embed replicated: decode reads ONE row per token — a
-        #   vocab-sharded table would make SPMD all-gather the whole table
-        #   every dispatch (llama._embed_lookup docstring).  Big TP models
-        #   are untied (or int8-tied with a separate head copy), so the
-        #   logits matmul still shards via lm_head.
-        specs["embed"] = P(None, None)
-        if "lm_head" in params and "lm_head" not in specs:
-            # untied head, or a tied model's int8 head copy (quantize_params)
-            specs["lm_head"] = P(self._policy.fsdp_axis,
-                                 self._policy.tensor_axis)
-
-        def leaf(spec, value):
-            if isinstance(value, dict) and "q" in value:
-                dims = tuple(spec)
-                s_spec = P(*(dims[:-2] + dims[-1:])) if len(dims) >= 2 else P()
-                return {"q": NamedSharding(self.mesh, spec),
-                        "s": NamedSharding(self.mesh, s_spec)}
-            return NamedSharding(self.mesh, spec)
-
-        return jax.tree.map(leaf, specs, params,
-                            is_leaf=lambda x: isinstance(x, P))
-
-    def _kv_sharding(self):
-        """KV caches shard over KV heads.  Dense: dim 3 (the quantized
-        scale tensors lack the trailing D dim — int4's packed "q4" leaf
-        keeps it, just half as wide).  Paged: the last dim of every leaf,
-        Hkv*D lanes (head-major, so a shard holds whole heads) or the Hkv
-        scales."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        t = self._policy.tensor_axis
-        scales = NamedSharding(self.mesh, P(None, None, None, t))
-        full = scales if self.paged else NamedSharding(
-            self.mesh, P(None, None, None, t, None))
-        if not self.kv_quant:
-            return full
-        qk = "q4" if self.kv_quantize == "int4" else "q"
-        return {qk: full, "s": scales}
 
     def _reset_device_state(self) -> None:
-        """(Re-)allocate the KV cache and slot state.  Called at init and
-        after a device-side decode failure (the decode jit donates the
-        caches, so a raise mid-execution leaves them deleted)."""
+        """(Re-)allocate the family's device state and the slot state.
+        Called at init and after a device-side decode failure (the decode
+        jit donates the state, so a raise mid-execution leaves it
+        deleted)."""
         b = self.batch_size
-        if self._hybrid is not None:
-            # the two donated state trees are the latent pool and the
-            # recurrent state, not a K and a V cache
-            self._cache_k, self._cache_v = self._hybrid.init_state()
-            if self.telemetry is not None:
-                self.telemetry.record_recurrent_state_bytes(
-                    self._hybrid.recurrent_state_bytes())
-        else:
-            self._alloc_kv_caches()
+        #: the two donated trees every program takes and returns: what they
+        #: hold is the provider's (a K and a V cache; a latent pool and a
+        #: recurrent state)
+        self._state = self._programs.init_state()
+        recurrent = self._programs.recurrent_state_bytes()
+        if self.telemetry is not None and recurrent:
+            self.telemetry.record_recurrent_state_bytes(recurrent)
         if self.paged and isinstance(self._alloc, PrefixBlockAllocator):
             # the KV backing every cached key was just reallocated
             self._alloc.clear_cache()
@@ -826,50 +351,10 @@ class InferenceEngine:
         self._chunking = {}         # mid-chunk prefill state died with it
         self._lengths = jnp.zeros((b,), jnp.int32)     # tokens in cache
         # host mirror of _lengths: _emit's bookkeeping must not pay a
-        # device->host fetch per generated token (it dominated serving
-        # throughput on remote-RPC backends)
+        # device->host fetch per generated token
         self._host_lengths = np.zeros((b,), np.int64)
         self._last_token = jnp.zeros((b,), jnp.int32)
         self._active = jnp.zeros((b,), jnp.bool_)
-        #: on-device token history per slot (speculation's n-gram corpus)
-        self._hist = jnp.zeros((b, self.max_len), jnp.int32)
-
-    def _alloc_kv_caches(self) -> None:
-        """The Llama family's K and V caches, zeroed: dense rows per slot,
-        or the paged pool."""
-        cfg, b = self.cfg, self.batch_size
-        lead = ((cfg.num_layers, self._alloc.num_blocks, self._block_size)
-                if self.paged else (cfg.num_layers, b, self.max_len))
-        hkv = cfg.num_kv_heads
-        scales = lead + (hkv,)
-
-        def values(d: int):
-            # the paged pool is stored in the form the decode kernel
-            # reads: kv heads folded into the lane dim (_fold_heads)
-            return lead + ((hkv * d,) if self.paged else (hkv, d))
-
-        def mk_zeros():
-            if self.kv_quantize == "int4":
-                return {"q4": jnp.zeros(values(cfg.head_dim // 2), jnp.int8),
-                        "s": jnp.zeros(scales, jnp.float32)}
-            if self.kv_quant:
-                return {"q": jnp.zeros(values(cfg.head_dim), jnp.int8),
-                        "s": jnp.zeros(scales, jnp.float32)}
-            return jnp.zeros(values(cfg.head_dim), cfg.dtype)
-
-        if self.mesh is not None:
-            # allocate sharded directly — never the full cache on one
-            # device.  The jitted allocator is cached: a rebuild per
-            # decode-failure recovery would re-trace for nothing.
-            if getattr(self, "_cache_alloc", None) is None:
-                self._cache_alloc = _named_jit(
-                    mk_zeros, "kv_cache_alloc",
-                    out_shardings=self._kv_sharding())
-            self._cache_k = self._cache_alloc()
-            self._cache_v = self._cache_alloc()
-        else:
-            self._cache_k = mk_zeros()
-            self._cache_v = mk_zeros()
 
     # -- public API --------------------------------------------------------
 
@@ -877,8 +362,8 @@ class InferenceEngine:
         if self.draining:
             # belt for non-HTTP callers; the server's handlers 503 first
             raise EngineDraining("engine is draining; not admitting")
-        if request.prefill is not None and self._pd_refusal:
-            raise ValueError(self._pd_refusal)
+        if request.prefill is not None and self._programs.pd_refusal:
+            raise ValueError(self._programs.pd_refusal)
         # clamp so prompt + generation always fit the cache
         request.max_new_tokens = max(min(request.max_new_tokens,
                                          self.max_len - 2), 1)
@@ -938,8 +423,8 @@ class InferenceEngine:
                         if self.telemetry is not None:
                             self.telemetry.record_preemption("engine_error")
                             self.telemetry.record_finished(req)
-                # the decode jit donates the caches: if it raised after
-                # donation, self._cache_k/_v point at deleted buffers and
+                # the decode jit donates the state: if it raised after
+                # donation, self._state points at deleted buffers and
                 # every later request would die — reallocate device state
                 try:
                     self._reset_device_state()
@@ -1010,8 +495,7 @@ class InferenceEngine:
         only those handles, not the tokens.  So when a window is in flight,
         the next one is dispatched BEFORE the current one's tokens are
         pulled to the host — the np.asarray round-trip and the Python emit
-        loop (≈1.5 ms/step-equivalent on the remote-dispatch bench backend,
-        more than half the end-to-end step cost) overlap device compute.
+        loop overlap device compute.
 
         Admission (prefill) only ever happens when NO window is in flight:
         a prefill writes cache rows that an in-flight window's end-of-window
@@ -1099,25 +583,7 @@ class InferenceEngine:
         cbucket = self._bucket(len(chunk))
         padded = np.zeros((cbucket,), np.int32)
         padded[:len(chunk)] = chunk
-        if self.paged:
-            # paged chunks ride the suffix-prefill program (block
-            # scatter + gathered-span attention) with prefix_len = rows
-            # already in the slot's blocks
-            logits, self._cache_k, self._cache_v = self._run_program(
-                self._prefill_jit, ("prefix", cbucket),
-                functools.partial(self._prefill_fn_prefix, cbucket),
-                self.params, jnp.asarray(padded),
-                jnp.int32(len(chunk)), jnp.int32(done),
-                self._cache_k, self._cache_v,
-                self._slot_target(
-                    slot_id, jnp.asarray(self._tables_host[slot_id])))
-        else:
-            logits, self._cache_k, self._cache_v = self._run_program(
-                self._prefill_jit, ("chunk", cbucket),
-                functools.partial(self._prefill_fn_chunk, cbucket),
-                self.params, jnp.asarray(padded),
-                jnp.int32(len(chunk)), jnp.int32(done),
-                self._cache_k, self._cache_v, jnp.int32(slot_id))
+        logits = self._run_chunk(slot_id, padded, len(chunk), done)
         st["done"] = done + len(chunk)
         if self.telemetry is not None:
             self.telemetry.record_prefill(len(chunk), cbucket)
@@ -1148,14 +614,8 @@ class InferenceEngine:
                     if (i + 1) * self._block_size <= n and i < len(blocks):
                         self._alloc.register(bkey, blocks[i])
             with jax.profiler.TraceAnnotation("engine.chunk"):
-                first = self._sample_first(st["logits"], req)
-                self._slots_gen += 1
-                self._lengths = self._lengths.at[slot_id].set(n)
-                self._host_lengths[slot_id] = n
-                self._last_token = self._last_token.at[slot_id].set(first)
-                self._active = self._active.at[slot_id].set(True)
-                self._record_history(slot_id, st["tokens"], first)
-                self._emit(slot_id, req, first)
+                self._activate(slot_id, req, n,
+                               self._sample_first(st["logits"], req))
 
     def _can_admit(self) -> bool:
         """A waiting request could take a free slot."""
@@ -1265,10 +725,6 @@ class InferenceEngine:
                 self.telemetry.record_admitted(
                     req.admitted_at - req.submitted_at,
                     trace_id=req.trace_id)
-                if self.speculation:
-                    # baseline for the decode span's spec-accept attrs
-                    req._spec0 = (self.telemetry.spec_steps.value,
-                                  self.telemetry.spec_accepted.value)
 
     def _prompt_tokens(self, tokens: List[int],
                        max_new_tokens: int) -> List[int]:
@@ -1331,7 +787,7 @@ class InferenceEngine:
         """Jit ``fn`` under its compile-cache tag (so a device trace names
         the program ``jit_<tag>``) and route it through the persistent
         compile cache (no-op passthrough when the cache is disabled)."""
-        return maybe_cached(_named_jit(fn, tag, **jit_kwargs),
+        return maybe_cached(named_jit(fn, tag, **jit_kwargs),
                             self.compile_cache, tag=tag)
 
     def _run_program(self, table: dict, key, make, *args):
@@ -1350,171 +806,36 @@ class InferenceEngine:
                     "decode" if table is self._decode_jit else "prefill")
             return fn(*args)
 
-    def _prefill_fn(self, bucket: int):
-        cfg = self.cfg
-
-        def fn(params, tokens, length, cache_k, cache_v, slot):
-            # tokens: [bucket] padded; length: scalar actual prompt length
-            logits, ks, vs = _prompt_forward(params, cfg, tokens, length,
-                                             bucket)
-
-            # insert prompt K/V into the slot: [L, bucket, Hkv, D] -> cache
-            def insert(leaf, rows):
-                start = (0, slot) + (0,) * (leaf.ndim - 2)
-                return jax.lax.dynamic_update_slice(
-                    leaf, rows[:, None], start)
-
-            with jax.named_scope("kv_insert"):
-                cache_k = _kv_map(cache_k, ks[:, 0], insert)
-                cache_v = _kv_map(cache_v, vs[:, 0], insert)
-            return logits, cache_k, cache_v
-
-        return self._jit_cached(fn, f"prefill_b{bucket}",
+    def _prefill_program(self, bucket: int):
+        """The jitted whole-prompt prefill of one bucket."""
+        return self._jit_cached(self._programs.prefill_fn(bucket),
+                                f"{self._prefill_tag}{bucket}",
                                 donate_argnums=(3, 4))
 
-    def _prefill_fn_prefix(self, sbucket: int):
-        """Suffix prefill against a cached prefix (prefix-cache mode).
-
-        The slot's leading ``prefix_len`` positions already hold valid KV
-        (reused blocks); this computes KV only for the suffix tokens —
-        each layer scatters the suffix K/V into the slot's blocks, then
-        attends the suffix queries over the gathered full span with
-        absolute positions (RoPE phases match the cached prefix's).
-        """
-        if self._hybrid is not None:
-            return self._jit_cached(self._hybrid.chunk_fn(sbucket),
-                                    f"prefill_prefix_b{sbucket}",
-                                    donate_argnums=(4, 5))
-        cfg = self.cfg
-        bs = self._block_size
-        bps = self._blocks_per_slot
-        kv_span = bps * bs
-
-        def fn(params, suffix_tokens, suffix_len, prefix_len,
-               cache_k, cache_v, tables_row):
-            positions = prefix_len + jnp.arange(sbucket)[None, :]
-            inv_freqs = jnp.asarray(rope_frequencies(
-                cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
-            x = params["embed"].astype(cfg.dtype)[suffix_tokens][None, :, :]
-            kv_pos = jnp.arange(kv_span)[None, :]
-            idx = prefix_len + jnp.arange(sbucket)
-            # padding rows past the span write to the NULL block
-            safe = idx < kv_span
-            blk = jnp.where(
-                safe, tables_row[jnp.clip(idx // bs, 0, bps - 1)], 0)
-            off = idx % bs
-            # MoE: padding must not claim expert capacity
-            token_mask = (jnp.arange(sbucket) < suffix_len)[None, :]
-
-            nb = self._alloc.num_blocks
-
-            def layer(carry, inputs):
-                # the pool travels in the carry and is addressed at
-                # [layer, block, offset] by flat row: scanned as xs/ys it
-                # would be sliced and restacked, a copy of the layer's
-                # whole pool each way
-                x, pool_k, pool_v = carry
-                lp, l = inputs
-                scatter = lambda leaf, rows: _scatter_rows(
-                    leaf, (l * nb + blk) * bs + off, rows[0])
-                gather = lambda pool: _split_heads(jax.tree.map(
-                    lambda a: a.reshape((-1,) + a.shape[2:])[
-                        l * nb + tables_row].reshape(
-                            1, kv_span, a.shape[-1]), pool),
-                    cfg.num_kv_heads)
-                x, pool_k, pool_v = _suffix_layer(
-                    x, lp, cfg, positions, inv_freqs, kv_pos, token_mask,
-                    pool_k, pool_v, scatter, gather, lanes=True)
-                return (x, pool_k, pool_v), None
-
-            (x, cache_k, cache_v), _ = jax.lax.scan(
-                layer, (x, cache_k, cache_v),
-                (params["layers"], jnp.arange(cfg.num_layers)))
-            logits = _last_logits(params, cfg, x, suffix_len)
-            return logits, cache_k, cache_v
-
-        return self._jit_cached(fn, f"prefill_prefix_b{sbucket}",
+    def _chunk_program(self, cbucket: int):
+        """The jitted program of one bucket that prefills a chunk of a long
+        prompt, or a prompt's suffix behind a cached prefix."""
+        return self._jit_cached(self._programs.chunk_fn(cbucket),
+                                f"{self._chunk_tag}{cbucket}",
                                 donate_argnums=(4, 5))
 
-    def _prefill_fn_chunk(self, cbucket: int):
-        """One chunk of a long prompt against the DENSE cache: computes the
-        chunk's K/V, writes it at the slot's rows [prefix_len, prefix_len +
-        chunk), and attends the chunk's queries over everything the slot
-        holds so far (earlier chunks + causal within this one).  RoPE uses
-        absolute positions, so the result is bit-identical in structure to
-        a whole-prompt prefill.  Returns last-position logits (meaningful
-        on the final chunk only)."""
-        cfg = self.cfg
-        span = self.max_len
-
-        def fn(params, chunk_tokens, chunk_len, prefix_len,
-               cache_k, cache_v, slot):
-            positions = prefix_len + jnp.arange(cbucket)[None, :]
-            inv_freqs = jnp.asarray(rope_frequencies(
-                cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
-            x = params["embed"].astype(cfg.dtype)[chunk_tokens][None, :, :]
-            kv_pos = jnp.arange(span)[None, :]
-            token_mask = (jnp.arange(cbucket) < chunk_len)[None, :]
-            # write targets: real chunk rows land at their positions;
-            # bucket-padding rows (and any row past max_len — a final
-            # chunk's bucket can overshoot it) are pushed out of range and
-            # DROPPED, never clamped onto earlier valid rows
-            row_idx = jnp.where(jnp.arange(cbucket) < chunk_len,
-                                prefix_len + jnp.arange(cbucket), span)
-
-            def insert(leaf, rows):
-                # rows: [1, cbucket, ...] -> slot's rows, row_idx-mapped
-                return leaf.at[slot, row_idx].set(rows[0], mode="drop")
-
-            def gather(layer_kv):
-                return jax.tree.map(
-                    lambda leaf: jax.lax.dynamic_index_in_dim(
-                        leaf, slot, 0, keepdims=True), layer_kv)
-
-            def layer(carry, inputs):
-                x = carry
-                lp, layer_k, layer_v = inputs
-                x, layer_k, layer_v = _suffix_layer(
-                    x, lp, cfg, positions, inv_freqs, kv_pos, token_mask,
-                    layer_k, layer_v, insert, gather)
-                return x, (layer_k, layer_v)
-
-            x, (cache_k, cache_v) = jax.lax.scan(
-                layer, x, (params["layers"], cache_k, cache_v))
-            logits = _last_logits(params, cfg, x, chunk_len)
-            return logits, cache_k, cache_v
-
-        return self._jit_cached(fn, f"prefill_chunk_b{cbucket}",
-                                donate_argnums=(4, 5))
-
-    def _prefill_fn_paged(self, bucket: int):
-        if self._hybrid is not None:
-            return self._jit_cached(self._hybrid.prefill_fn(bucket),
-                                    f"prefill_paged_b{bucket}",
-                                    donate_argnums=(3, 4))
-        cfg = self.cfg
-        bs = self._block_size
-        nblk = bucket // bs
-
-        def fn(params, tokens, length, cache_k, cache_v, bids):
-            # bids: [nblk] physical block ids owned by the slot
-            logits, ks, vs = _prompt_forward(params, cfg, tokens, length,
-                                             bucket)
-
-            def insert(leaf, rows):
-                # the new rows take the pool's blocked form, never the
-                # pool theirs: whole blocks, every layer, in place
-                blocked = rows.reshape(
-                    (cfg.num_layers, nblk, bs) + rows.shape[2:])
-                return leaf.at[:, bids].set(blocked)
-
-            with jax.named_scope("kv_insert"):
-                cache_k = _kv_map(cache_k, ks[:, 0], insert, lanes=True)
-                cache_v = _kv_map(cache_v, vs[:, 0], insert, lanes=True)
-            return logits, cache_k, cache_v
-
-        return self._jit_cached(fn, f"prefill_paged_b{bucket}",
-                                donate_argnums=(3, 4))
+    def _run_chunk(self, slot_id: int, padded, n: int, prefix_len: int):
+        """Prefill ``n`` prompt tokens (``padded`` to a bucket) behind the
+        ``prefix_len`` rows the slot already holds; returns the logits at
+        the last of them."""
+        pages = (jnp.asarray(self._tables_host[slot_id]) if self.paged
+                 else None)
+        logits, *self._state = self._run_program(
+            self._prefill_jit, ("chunk", len(padded)),
+            functools.partial(self._chunk_program, len(padded)),
+            self.params, jnp.asarray(padded), jnp.int32(n),
+            jnp.int32(prefix_len), *self._state,
+            # the slot as a device scalar here and as a Python int in
+            # _prefill: how these programs have always been lowered (a
+            # weak-typed index lowers with a convert of its own), so a
+            # peer's compile-cache entries still hit
+            self._programs.slot_target(jnp.int32(slot_id), pages))
+        return logits
 
     def _prefill(self, slot_id: int, req: Request) -> None:
         # keep the newest prompt tokens so generation fits the cache
@@ -1528,28 +849,20 @@ class InferenceEngine:
             sbucket = self._bucket(n - prefix_len)
             padded = np.zeros((sbucket,), np.int32)
             padded[:n - prefix_len] = tokens[prefix_len:prefix_len + sbucket]
-            logits, self._cache_k, self._cache_v = self._run_program(
-                self._prefill_jit, ("prefix", sbucket),
-                functools.partial(self._prefill_fn_prefix, sbucket),
-                self.params, jnp.asarray(padded),
-                jnp.int32(n - prefix_len), jnp.int32(prefix_len),
-                self._cache_k, self._cache_v,
-                jnp.asarray(self._tables_host[slot_id]),
-            )
+            logits = self._run_chunk(slot_id, padded, n - prefix_len,
+                                     prefix_len)
         else:
             bucket = self._bucket(n)
             padded = np.zeros((bucket,), np.int32)
             padded[:n] = tokens[:bucket]
-            target = (self._slot_target(slot_id, jnp.asarray(
+            pages = (jnp.asarray(
                 self._slot_blocks[slot_id][:bucket // self._block_size],
-                jnp.int32)) if self.paged else slot_id)
-            logits, self._cache_k, self._cache_v = self._run_program(
-                self._prefill_jit, ("paged", bucket) if self.paged else bucket,
-                functools.partial(self._prefill_fn_paged if self.paged
-                                  else self._prefill_fn, bucket),
+                jnp.int32) if self.paged else None)
+            logits, *self._state = self._run_program(
+                self._prefill_jit, ("prefill", bucket),
+                functools.partial(self._prefill_program, bucket),
                 self.params, jnp.asarray(padded), jnp.int32(n),
-                self._cache_k, self._cache_v, target,
-            )
+                *self._state, self._programs.slot_target(slot_id, pages))
         if self.prefix_cache:
             # publish this prompt's full blocks for future prefix reuse
             # (no-ops for the ones that were themselves reused)
@@ -1562,28 +875,19 @@ class InferenceEngine:
             # (prefix reuse prefills only the suffix)
             self.telemetry.record_prefill(n - prefix_len,
                                           self._bucket(n - prefix_len))
-        first = self._sample_first(logits, req)
+        self._activate(slot_id, req, n, self._sample_first(logits, req))
+
+    def _activate(self, slot_id: int, req: Request, n: int,
+                  first: int) -> None:
+        """Open a slot whose prompt (``n`` tokens) is in the device state
+        for decode windows, and hand over its first token."""
         self._slots[slot_id] = req
         self._slots_gen += 1
         self._lengths = self._lengths.at[slot_id].set(n)
         self._host_lengths[slot_id] = n
         self._last_token = self._last_token.at[slot_id].set(first)
         self._active = self._active.at[slot_id].set(True)
-        self._record_history(slot_id, tokens, first)
         self._emit(slot_id, req, first)
-
-    def _record_history(self, slot_id: int, tokens, first: int) -> None:
-        """Seed the slot's on-device token history (speculation's n-gram
-        corpus): the prompt at positions [0, n), the first generated token
-        at n.  Whole-row write so a reused slot can't leak its previous
-        occupant's tokens into drafts."""
-        if not self.speculation:
-            return
-        n = min(len(tokens), self.max_len - 2)
-        padded = np.zeros((self.max_len,), np.int32)
-        padded[:n] = tokens[:n]
-        padded[n] = first
-        self._hist = self._hist.at[slot_id].set(jnp.asarray(padded))
 
     def prefill_export(self, tokens: List[int],
                        max_new_tokens: int = 128) -> dict:
@@ -1591,30 +895,23 @@ class InferenceEngine:
         last-position logits WITHOUT occupying a slot; the result ships to
         a decode replica (serving/server.py serializes it).  The prompt
         budget mirrors _prefill's (max_len - max_new_tokens - 1) so the
-        disaggregated path truncates exactly like a colocated one.
+        disaggregated path truncates exactly like a colocated one.  A family
+        whose state the wire does not carry refuses in ``export_fn``.
 
         Parity role: the prefill worker half of the reference's SGLang PD
         integration — on TPU the KV rides the router instead of a
         bootstrap-port side channel.
         """
-        if self._pd_refusal:
-            raise ValueError(self._pd_refusal)
-        cfg = self.cfg
         max_new_tokens = max(min(max_new_tokens, self.max_len - 2), 1)
         toks = self._prompt_tokens(tokens, max_new_tokens)
         n = len(toks)
         bucket = self._bucket(n)
-
-        def fn(params, padded, length):
-            logits, ks, vs = _prompt_forward(params, cfg, padded, length,
-                                             bucket)
-            return logits, ks[:, 0], vs[:, 0]  # [L, bucket, Hkv, D]
-
         padded = np.zeros((bucket,), np.int32)
         padded[:n] = toks[:bucket]
         logits, ks, vs = self._run_program(
             self._prefill_jit, ("export", bucket),
-            lambda: self._jit_cached(fn, f"prefill_export_b{bucket}"),
+            lambda: self._jit_cached(self._programs.export_fn(bucket),
+                                     f"prefill_export_b{bucket}"),
             self.params, jnp.asarray(padded), jnp.int32(n))
         logits_np = np.asarray(logits)
         return {
@@ -1633,54 +930,21 @@ class InferenceEngine:
         into a slot and start decoding from its first token."""
         self._mark_admitted(req)
         p = req.prefill
-        n = int(p["length"])
         # a prefill replica configured with a larger max_len must not be
-        # able to crash this engine: keep the newest rows that fit
-        limit = self.max_len - 2
-        ks_np, vs_np = p["ks"], p["vs"]
-        if n > limit:
-            ks_np = ks_np[:, n - limit:]
-            vs_np = vs_np[:, n - limit:]
-            n = limit
-        if self.paged:
-            # pad to whole blocks, scatter into the slot's physical blocks
-            cfg, bs = self.cfg, self._block_size
-            nblk = -(-n // bs)
-            pad = nblk * bs - n
-            ks_np = np.pad(ks_np, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            vs_np = np.pad(vs_np, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            bids = jnp.asarray(self._slot_blocks[slot_id][:nblk], jnp.int32)
-
-            def insert(leaf, rows):
-                blocked = rows.reshape(
-                    (cfg.num_layers, nblk, bs) + rows.shape[2:])
-                return leaf.at[:, bids].set(blocked)
-
-        else:
-            def insert(leaf, rows):
-                start = (0, slot_id) + (0,) * (leaf.ndim - 2)
-                return jax.lax.dynamic_update_slice(leaf, rows[:, None], start)
-
-        ks = jnp.asarray(ks_np, dtype=self.cfg.dtype)  # [L, rows, Hkv, D]
-        vs = jnp.asarray(vs_np, dtype=self.cfg.dtype)
-        self._cache_k = _kv_map(self._cache_k, ks, insert, self.paged)
-        self._cache_v = _kv_map(self._cache_v, vs, insert, self.paged)
+        # able to crash this engine: the newest rows that fit are kept
+        n = min(int(p["length"]), self.max_len - 2)
+        pages = (jnp.asarray(
+            self._slot_blocks[slot_id][:-(-n // self._block_size)],
+            jnp.int32) if self.paged else None)
+        self._state = self._programs.insert_rows(
+            *self._state, p, n, self._programs.slot_target(slot_id, pages))
         if p.get("logits") is not None:
             # request-aware first token (temperature/top_p/top_k honored;
             # PD-wire logits arrive as numpy — asarray is host->device)
             first = self._sample_first(jnp.asarray(p["logits"]), req)
         else:
             first = int(p["first_token"])
-        self._slots[slot_id] = req
-        self._slots_gen += 1
-        self._lengths = self._lengths.at[slot_id].set(n)
-        self._host_lengths[slot_id] = n
-        self._last_token = self._last_token.at[slot_id].set(first)
-        self._active = self._active.at[slot_id].set(True)
-        self._record_history(
-            slot_id, self._prompt_tokens(req.tokens, req.max_new_tokens)[:n],
-            first)
-        self._emit(slot_id, req, first)
+        self._activate(slot_id, req, n, first)
 
     @jax.named_scope("sample")
     def _sample_on_device(self, logits, temps, top_ps, top_ks, rng):
@@ -1718,396 +982,16 @@ class InferenceEngine:
         greedy = idx[:, 0]
         return jnp.where(temps > 0.0, sampled, greedy).astype(jnp.int32)
 
-    def _decode_window_fn_buffered(self, params, last_token, lengths, active,
-                                   cache_k, cache_v, temps, top_ps, top_ks,
-                                   tables, rng, *, window: int,
-                                   sampling: bool = True,
-                                   kv_blocks: Optional[int] = None):
-        """Decode window with a write-once cache (dense AND paged).
-
-        The classic formulation (removed r4; see ROOFLINE.md for the A/B
-        numbers) rewrote the whole [L, B, S] KV cache every step with a
-        masked multiply-add — ~45% of the decode step's non-weight HBM
-        traffic at the bench shape.  Here the big cache is READ-ONLY for
-        the whole window: each step's K/V goes into a small [L, W] window
-        buffer, attention runs over (cache ⧺ window prefix), and the cache
-        absorbs all W rows in ONE pass at the end — full-cache write cost
-        amortized 1/W.  Same logical attention set per step.
-
-        Paged mode gets a second, larger win from the same invariance: the
-        block-table gather (each slot's blocks → a linear KV view) happens
-        ONCE per window instead of once per step — at long max_len that
-        gather dominated the per-step formulation (22.4 → 8.2 ms/step at a
-        4k span).
-
-        RAGGED lengths (``kv_blocks``): the dispatcher passes a
-        power-of-two bucket of table columns covering the longest active
-        slot through the END of this window, so short sequences stop
-        paying max_len-sized gathers and attention — the linear view (and
-        its peak-memory allowance) shrinks from [L, B, blocks_per_slot*bs]
-        to [L, B, kv_blocks*bs].  Columns a shorter slot doesn't own are
-        cache_mask'ed exactly like the full span's, so the bucketed
-        program emits the same tokens.
-
-        On a TPU backend the gather disappears entirely: the Pallas
-        block-table kernel (ops/flash_attention.py paged_decode_attention)
-        reads K/V blocks straight from the paged pool via scalar-prefetched
-        tables and returns a normalized (o, lse) pair per slot; the window
-        buffer's attention merges with it by logsumexp, so no
-        dense-equivalent linear view is ever materialized
-        (DSTACK_TPU_PAGED_ATTN_KERNEL, auto = TPU only; int4 caches use
-        the XLA path — the kernel dequantizes int8 in-kernel).
-        """
-        cfg = self.cfg
-        b = self.batch_size
-        w = window
-        nbk = (kv_blocks or self._blocks_per_slot) if self.paged else 0
-        kv_span = nbk * self._block_size if self.paged else self.max_len
-        use_kernel = (self.paged and self._paged_kernel
-                      and self.kv_quantize != "int4")
-        inv_freqs = jnp.asarray(
-            rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
-        kv_index = jnp.arange(kv_span)[None, :]  # [1, S]
-        head = output_head(params, cfg)
-        base_len = jnp.minimum(lengths, self.max_len - 1)  # frozen for the window
-        hkv, group = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
-        # cache rows valid for every step of this window (window rows are
-        # attended from the buffer instead)
-        cache_mask = (kv_index < base_len[:, None])[:, None, None, :]
-        if use_kernel:
-            # the kernel reads blocks in place through the table, out of
-            # the stored pool: the layer scan carries the layer's INDEX and
-            # the kernel closes over the whole pool — no linear view, no
-            # gather, and no per-layer slice of the pool (a scanned pool is
-            # sliced into a buffer of its own for the custom call: a copy
-            # of the layer's whole K and V pool every layer-step)
-            layer_kv = jnp.arange(cfg.num_layers)
-        elif self.paged:
-            # one gather for the whole window: [L, B, span, ...] linear
-            # views of each slot's blocks (read-only until the final
-            # insert; quantized caches gather the packed bytes — half
-            # (int8) or a quarter (int4) of the bf16 traffic); the heads
-            # unfold on the gathered view, not on the pool
-            def gather_view(cache):
-                return _split_heads(jax.tree.map(
-                    lambda a: a[:, tables].reshape(
-                        cfg.num_layers, b, kv_span, a.shape[-1]), cache),
-                    hkv)
-
-            layer_kv = (gather_view(cache_k), gather_view(cache_v))
-        else:
-            layer_kv = (cache_k, cache_v)
-
-        if use_kernel:
-            from dstack_tpu.ops.flash_attention import (
-                paged_decode_attention as paged_attn,
-            )
-
-            if self.mesh is not None:
-                # a Pallas call is opaque to GSPMD: run it per device over
-                # the kv-head shards the cache already has (_kv_sharding)
-                from jax.sharding import PartitionSpec as P
-
-                t = self._policy.tensor_axis
-                heads = P(None, t, None, None)    # q, o: [B, Hkv, G, D]
-                pages = P(None, None, None, t)    # every leaf of the pool
-                if self.kv_quant:
-                    pages = {"q": pages, "s": pages}
-                paged_attn = jax.shard_map(
-                    paged_attn, mesh=self.mesh,
-                    in_specs=(heads, pages, pages, P(), P(), P()),
-                    out_specs=(heads, P(None, t, None)), check_vma=False)
-
-        win_shape = (cfg.num_layers, w, b, hkv, cfg.head_dim)
-        win_k0 = jnp.zeros(win_shape, cfg.dtype)
-        win_v0 = jnp.zeros(win_shape, cfg.dtype)
-        win_j = jnp.arange(w)
-
-        def one_step(carry, inputs):
-            last_token, step_lengths, win_k, win_v = carry
-            i, step_rng = inputs
-            positions = jnp.minimum(step_lengths, self.max_len - 1)[:, None]
-            x = params["embed"].astype(cfg.dtype)[last_token][:, None, :]
-            # window cols visible at step i: j <= i (their positions are
-            # base_len + j per slot)
-            win_mask = (win_j[None, :] <= i)[:, None, None, :]  # [1,1,1,W]
-
-            def layer(carry, inputs):
-                x = carry
-                lp, kv, wk, wv = inputs
-                q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, b)
-                # stash this step's K/V in the window buffer (small, in-place)
-                wk = jax.lax.dynamic_update_index_in_dim(wk, k[:, 0], i, 0)
-                wv = jax.lax.dynamic_update_index_in_dim(wv, v[:, 0], i, 0)
-                qg = q.reshape(b, hkv, group, cfg.head_dim)
-                scale = cfg.head_dim ** -0.5
-                if use_kernel:
-                    # cache half straight off the block table (normalized
-                    # o + logsumexp per slot), window half in XLA, merged
-                    # by logsumexp — numerically the same attention set,
-                    # reduction order aside
-                    with jax.named_scope("paged_attn"):
-                        o_c, lse_c = paged_attn(
-                            qg, cache_k, cache_v, kv, tables, base_len)
-                    with jax.named_scope("attn"):
-                        s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
-                        s_w = jnp.where(win_mask, s_w,
-                                        -1e30).astype(jnp.float32)
-                        m_w = jnp.max(s_w, axis=-1)
-                        p_w = jnp.exp(s_w - m_w[..., None])
-                        l_w = jnp.sum(p_w, axis=-1)
-                        o_w = jnp.einsum(
-                            "bhgj,jbhd->bhgd", p_w.astype(x.dtype), wv
-                        ).astype(jnp.float32) / l_w[..., None]
-                        lse_w = m_w + jnp.log(l_w)
-                        # empty-cache slots have lse_c = -inf; the window
-                        # half always has column 0 visible, so lse is finite
-                        lse = jnp.logaddexp(lse_c, lse_w)
-                        attn = (o_c * jnp.exp(lse_c - lse)[..., None]
-                                + o_w * jnp.exp(lse_w - lse)[..., None]
-                                ).astype(x.dtype)
-                else:
-                    with jax.named_scope("attn"):
-                        # quantized dequant fuses in
-                        lk = _kv_mat(kv[0], x.dtype)
-                        lv = _kv_mat(kv[1], x.dtype)
-                        s_c = jnp.einsum("bhgd,bkhd->bhgk", qg, lk) * scale
-                        s_c = jnp.where(cache_mask, s_c, -1e30)
-                        s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
-                        s_w = jnp.where(win_mask, s_w, -1e30)
-                        s = jnp.concatenate([s_c, s_w], axis=-1)
-                        probs = jax.nn.softmax(
-                            s.astype(jnp.float32), axis=-1).astype(x.dtype)
-                        p_c, p_w = (probs[..., :kv_span],
-                                    probs[..., kv_span:])
-                        attn = (jnp.einsum("bhgk,bkhd->bhgd", p_c, lv)
-                                + jnp.einsum("bhgj,jbhd->bhgd", p_w, wv))
-                x = _decode_layer_tail(x, attn, lp, cfg, b)
-                return x, (wk, wv)
-
-            x, (win_k, win_v) = jax.lax.scan(
-                layer, x, (params["layers"], layer_kv, win_k, win_v))
-            with jax.named_scope("lm_head"):
-                x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-                logits = qmatmul(x, head, cfg.dtype,
-                                 preferred=jnp.float32)[:, 0]
-            if sampling:
-                tokens = self._sample_on_device(logits, temps, top_ps,
-                                                top_ks, step_rng)
-            else:
-                with jax.named_scope("sample"):
-                    tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            new_lengths = jnp.where(active, step_lengths + 1, step_lengths)
-            return (tokens, new_lengths, win_k, win_v), tokens
-
-        (last, new_lengths, win_k, win_v), tokens_all = jax.lax.scan(
-            one_step, (last_token, lengths, win_k0, win_v0),
-            (jnp.arange(w), jax.random.split(rng, w)))
-
-        if self.paged:
-            # row-wise scatter of the W new rows into each slot's blocks
-            # (positions base_len + j; overshoot past the span lands in the
-            # NULL block like the classic path's clamped writes)
-            bs = self._block_size
-            pos = base_len[:, None] + win_j[None, :]            # [B, W]
-            # inactive slots (released, or mid-chunked-prefill) must not
-            # write: their window rows are junk and a chunked prefill may
-            # be filling those cache rows concurrently
-            safe = (pos < kv_span) & active[:, None]
-            blk_col = jnp.clip(pos // bs, 0, nbk - 1)
-            phys = jnp.where(
-                safe, jnp.take_along_axis(tables, blk_col, axis=1), 0)
-            off = pos % bs
-
-            # win: [L, W, B, ...] -> rows of the pool by flat index, per
-            # (l, b, j); masked rows collide in the NULL blocks, so the
-            # indices are not unique
-            idx = ((jnp.arange(cfg.num_layers)[:, None, None]
-                    * self._alloc.num_blocks + phys[None]) * bs + off[None])
-
-            @jax.named_scope("kv_window_write")
-            def scatter(cache, win):
-                return _kv_map(cache, win, lambda leaf, rows: _scatter_rows(
-                    leaf, idx, jnp.moveaxis(rows, 1, 2)), lanes=True)
-
-            cache_k = scatter(cache_k, win_k)
-            cache_v = scatter(cache_v, win_v)
-            return tokens_all, last, new_lengths, cache_k, cache_v
-
-        # Dense: ONE bulk insert — cache position p takes window row
-        # p - base_len wherever base_len <= p < base_len + W.
-        widx = jnp.clip(kv_index - base_len[:, None], 0, w - 1)  # [B, S]
-        in_window = ((kv_index >= base_len[:, None])
-                     & (kv_index < base_len[:, None] + w)
-                     & active[:, None])  # see the paged-scatter note
-        cache_k = _dense_window_insert(cache_k, win_k, widx, in_window)
-        cache_v = _dense_window_insert(cache_v, win_v, widx, in_window)
-        return tokens_all, last, new_lengths, cache_k, cache_v
-
-    def _decode_window_fn_spec(self, params, last_token, lengths, active,
-                               cache_k, cache_v, hist, *, window: int,
-                               k: int):
-        """Greedy decode window with n-gram (prompt-lookup) speculation.
-
-        Each scan step verifies ``k`` draft tokens plus the real one in a
-        single (k+1)-wide forward: drafts come from the latest bigram match
-        in the slot's on-device token history (``hist``), the forward
-        produces greedy continuations at all k+1 positions, and the
-        longest draft prefix that matches is accepted — emitting 1..k+1
-        tokens per step for the cost of one weight pass (decode is
-        weight-read-bound, so the extra width is nearly free; with zero
-        acceptance throughput matches the plain window).
-
-        Static shapes despite variable acceptance: the window KV buffer
-        has ``window*(k+1)`` columns whose validity lives in ``win_pos``
-        ([B, cols], -1 = invalid).  Rows are written OPTIMISTICALLY before
-        acceptance is known and retroactively invalidated — sound because
-        a query at draft depth j is only USED when drafts 1..j were
-        accepted, in which case every row it attended was real.  Accepted
-        positions across steps are disjoint (step i+1 starts where step i
-        accepted up to), so the end-of-window insert maps positions to
-        columns uniquely.  Greedy only (acceptance is exact-match) and
-        dense cache only; tokens match the plain window exactly in f32
-        (tested over long acceptance-heavy generations) — in bf16 the
-        widened forward's different reduction order can flip argmax
-        near-ties, the same noise class as the paged-vs-dense programs.
-        """
-        cfg = self.cfg
-        b = self.batch_size
-        kv_span = self.max_len
-        wc = window * (k + 1)
-        inv_freqs = jnp.asarray(
-            rope_frequencies(cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
-        kv_index = jnp.arange(kv_span)[None, :]
-        head = output_head(params, cfg)
-        base_len = jnp.minimum(lengths, self.max_len - 1)
-        hkv, group = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
-        cache_mask = (kv_index < base_len[:, None])[:, None, None, None, :]
-        view_k, view_v = cache_k, cache_v
-
-        win_shape = (cfg.num_layers, wc, b, hkv, cfg.head_dim)
-        win_k0 = jnp.zeros(win_shape, cfg.dtype)
-        win_v0 = jnp.zeros(win_shape, cfg.dtype)
-        win_pos0 = jnp.full((b, wc), -1, jnp.int32)
-        jj = jnp.arange(k + 1)[None, :]
-
-        def one_step(carry, i):
-            last_token, cur_len, win_k, win_v, win_pos, hist = carry
-            p0 = jnp.minimum(cur_len, kv_span - 1)
-            # drafts: the k tokens that followed the LATEST earlier
-            # occurrence of the current bigram (prev, last) in the history.
-            # Invariant: hist[cur_len] == last_token (prefill seeds the
-            # first token at n with lengths=n; window writes land at
-            # positions+1), so the bigram's first element is
-            # hist[cur_len-1]; earlier pairs start at p <= cur_len-2.
-            prev_idx = jnp.clip(cur_len - 1, 0, kv_span - 1)
-            prev = jnp.take_along_axis(hist, prev_idx[:, None], 1)[:, 0]
-            pos_r = jnp.arange(kv_span - 1)[None, :]
-            m = ((hist[:, :-1] == prev[:, None])
-                 & (hist[:, 1:] == last_token[:, None])
-                 & (pos_r < (cur_len - 1)[:, None]))
-            found = m.any(axis=1) & (cur_len >= 2)
-            p = (kv_span - 2) - jnp.argmax(m[:, ::-1], axis=1)
-            didx = p[:, None] + 2 + jnp.arange(k)[None, :]
-            draft_ok = found[:, None] & (didx < cur_len[:, None])
-            drafts = jnp.take_along_axis(
-                hist, jnp.clip(didx, 0, kv_span - 1), 1)
-            drafts = jnp.where(draft_ok, drafts, -1)  # -1 never accepted
-            tokens_in = jnp.concatenate(
-                [last_token[:, None], jnp.maximum(drafts, 0)], axis=1)
-            positions = p0[:, None] + jj                    # [B, k+1]
-            positions_c = jnp.minimum(positions, kv_span - 1)
-            x = params["embed"].astype(cfg.dtype)[tokens_in]  # [B, k+1, D]
-            col0 = i * (k + 1)
-            # optimistic validity: every row of this step, unless past the
-            # cache span
-            step_pos = jnp.where(positions < kv_span, positions, -1)
-            win_pos = jax.lax.dynamic_update_slice(win_pos, step_pos,
-                                                   (0, col0))
-            qpos = positions
-
-            def layer(carry, inputs):
-                x = carry
-                lp, layer_k, layer_v, wk, wv = inputs
-                q, kk, vv = _decode_qkv(x, lp, cfg, positions_c, inv_freqs,
-                                        b, m=k + 1)
-                wk = jax.lax.dynamic_update_slice(
-                    wk, kk.transpose(1, 0, 2, 3), (col0, 0, 0, 0))
-                wv = jax.lax.dynamic_update_slice(
-                    wv, vv.transpose(1, 0, 2, 3), (col0, 0, 0, 0))
-                qg = q.reshape(b, k + 1, hkv, group, cfg.head_dim)
-                scale = cfg.head_dim ** -0.5
-                lk = _kv_mat(layer_k, x.dtype)
-                lv = _kv_mat(layer_v, x.dtype)
-                s_c = jnp.einsum("bqhgd,bkhd->bhgqk", qg, lk) * scale
-                s_c = jnp.where(cache_mask, s_c, -1e30)
-                s_w = jnp.einsum("bqhgd,wbhd->bhgqw", qg, wk) * scale
-                w_mask = ((win_pos[:, None, None, None, :] >= 0)
-                          & (win_pos[:, None, None, None, :]
-                             <= qpos[:, None, None, :, None]))
-                s_w = jnp.where(w_mask, s_w, -1e30)
-                s = jnp.concatenate([s_c, s_w], axis=-1)
-                probs = jax.nn.softmax(
-                    s.astype(jnp.float32), axis=-1).astype(x.dtype)
-                p_c, p_w = probs[..., :kv_span], probs[..., kv_span:]
-                attn = (jnp.einsum("bhgqk,bkhd->bqhgd", p_c, lv)
-                        + jnp.einsum("bhgqw,wbhd->bqhgd", p_w, wv))
-                x = _decode_layer_tail(x, attn, lp, cfg, b, m=k + 1)
-                return x, (wk, wv)
-
-            x, (win_k, win_v) = jax.lax.scan(
-                layer, x, (params["layers"], view_k, view_v, win_k, win_v))
-            with jax.named_scope("lm_head"):
-                x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-                logits = qmatmul(x, head, cfg.dtype, preferred=jnp.float32)
-            with jax.named_scope("sample"):
-                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B,k+1]
-            match = (drafts == greedy[:, :k])
-            n_acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), 1), axis=1)
-            n_acc = jnp.where(active, n_acc, 0)
-            # retro-invalidate: draft rows past the accepted prefix, and
-            # every row of inactive slots
-            step_valid = ((jj <= n_acc[:, None]) & (step_pos >= 0)
-                          & active[:, None])
-            win_pos = jax.lax.dynamic_update_slice(
-                win_pos, jnp.where(step_valid, step_pos, -1), (0, col0))
-            # emitted tokens enter the history at positions+1 (each greedy
-            # token CONTINUES the position it was predicted at)
-            wpos = jnp.where(step_valid & (positions + 1 < kv_span),
-                             positions + 1, kv_span)  # kv_span = dropped
-            hist = hist.at[jnp.arange(b)[:, None], wpos].set(
-                greedy, mode="drop")
-            new_last = jnp.take_along_axis(greedy, n_acc[:, None], 1)[:, 0]
-            new_last = jnp.where(active, new_last, last_token)
-            cur_len = cur_len + jnp.where(active, n_acc + 1, 0)
-            return ((new_last, cur_len, win_k, win_v, win_pos, hist),
-                    (greedy, n_acc))
-
-        (last, new_lengths, win_k, win_v, win_pos, hist), (toks, accs) = \
-            jax.lax.scan(
-                one_step,
-                (last_token, lengths, win_k0, win_v0, win_pos0, hist),
-                jnp.arange(window))
-
-        # end-of-window bulk insert, keyed by each column's position
-        eq = kv_index[:, :, None] == win_pos[:, None, :]      # [B, S, Wc]
-        in_window = eq.any(-1)
-        widx = jnp.argmax(eq, axis=-1)                        # [B, S]
-        cache_k = _dense_window_insert(cache_k, win_k, widx, in_window)
-        cache_v = _dense_window_insert(cache_v, win_v, widx, in_window)
-        return toks, accs, last, new_lengths, cache_k, cache_v, hist
-
     #: decode-window sizes; each compiles once.  The biggest window is the
-    #: steady-state path (measured +37% aggregate tok/s over capping at 32
-    #: on the remote-dispatch bench backend); the small ones avoid large
+    #: steady-state path; the small ones avoid large
     #: overshoot on short tails.  Trade-off: streaming callbacks burst up
     #: to 64 tokens and a queued prompt waits up to one window for a slot —
     #: latency-sensitive deployments can override this class attribute.
     DECODE_WINDOWS = (8, 32, 64)
 
     #: fixed per-window dispatch overhead expressed in decode steps (host
-    #: round-trip + emit loop ≈ 8 steps' device time on the bench backend);
-    #: _pick_window weighs overshoot against this when splitting tails
+    #: round-trip + emit loop); _pick_window weighs overshoot against this
+    #: when splitting tails
     WINDOW_DISPATCH_COST_STEPS = 8
 
     def _pick_window(self, remaining: int) -> int:
@@ -2182,35 +1066,29 @@ class InferenceEngine:
         sampling = any(
             req is not None and req.temperature > 0.0 for req in self._slots)
         with jax.profiler.TraceAnnotation("engine.dispatch_window"):
-            if self.speculation and not sampling:
-                return self._dispatch_window_spec(remaining, window)
-            return self._dispatch_window_plain(remaining, window, sampling)
+            return self._dispatch_window_program(remaining, window, sampling)
 
     def _decode_window_program(self, window: int, sampling: bool,
                                nbk: Optional[int]):
-        """The jitted plain decode window for one (window, sampling,
+        """The jitted decode window for one (window, sampling,
         table-bucket) key."""
-        fn = (self._hybrid.decode_window_fn(window, sampling, nbk)
-              if self._hybrid is not None else functools.partial(
-                  self._decode_window_fn_buffered, window=window,
-                  sampling=sampling, kv_blocks=nbk))
         return self._jit_cached(
-            fn, f"decode_w{window}_s{int(sampling)}"
+            self._programs.decode_window_fn(window, sampling, nbk),
+            f"decode_w{window}_s{int(sampling)}"
             + (f"_kb{nbk}" if nbk is not None else ""),
             donate_argnums=(4, 5))
 
-    def _dispatch_window_plain(self, remaining: int, window: int,
-                               sampling: bool):
-        """Dispatch a plain (non-speculative) window: build its tables and
-        per-slot constants, enqueue the program."""
+    def _dispatch_window_program(self, remaining: int, window: int,
+                                 sampling: bool):
+        """Build the window's tables and per-slot constants, enqueue the
+        program."""
         nbk = self._ragged_blocks(window) if self.paged else None
 
-        # Host->device transfers are RPC round-trips on remote-dispatch
-        # backends — per WINDOW they must be near zero, so everything below
-        # is cached against the current slot assignment (an admission or
-        # release bumps _slots_gen; table buckets cache per ragged width)
-        # and rng only advances when sampling (greedy windows ignore it —
-        # reuse one constant key).
+        # Host->device transfers per WINDOW must be near zero, so
+        # everything below is cached against the current slot assignment
+        # (an admission or release bumps _slots_gen; table buckets cache
+        # per ragged width) and rng only advances when sampling (greedy
+        # windows ignore it — reuse one constant key).
         gen = self._slots_gen
         if self._decode_consts is None or self._decode_consts[0] != gen:
             temps = jnp.asarray([
@@ -2237,15 +1115,15 @@ class InferenceEngine:
         else:
             sub = self._rng_key
         # a model with experts returns, last, the window's expert load
-        tokens_all, self._last_token, self._lengths, \
-            self._cache_k, self._cache_v, *expert_load = self._run_program(
+        tokens_all, self._last_token, self._lengths, *rest = \
+            self._run_program(
                 self._decode_jit, (window, sampling, nbk),
                 functools.partial(self._decode_window_program, window,
                                   sampling, nbk),
                 self.params, self._last_token, self._lengths, self._active,
-                self._cache_k, self._cache_v, temps, top_ps, top_ks, tables,
-                sub,
+                *self._state, temps, top_ps, top_ks, tables, sub,
             )
+        self._state, expert_load = rest[:2], rest[2:]
         # snapshot which slots this window actually decodes for: by drain
         # time a mid-chunking slot may have finished its prefill (left
         # _chunking), but ITS rows in this window are still junk
@@ -2255,38 +1133,6 @@ class InferenceEngine:
         pending = {"tokens": tokens_all, "window": window,
                    "remaining_after": remaining - window,
                    "decoding": decoding, "expert_load": expert_load}
-        if self.telemetry is not None:
-            self._record_dispatch(len(decoding), pending)
-        return pending
-
-    def _dispatch_window_spec(self, remaining: int, window: int):
-        """Dispatch a speculative greedy window (see _decode_window_fn_spec).
-
-        Bookkeeping difference vs the plain window: each step emits a
-        VARIABLE 1..k+1 tokens per slot, so the drain walks the accepted
-        counts, and remaining_after uses the guaranteed-minimum one token
-        per step (over-dispatch past that is discarded overshoot, exactly
-        like the plain window's)."""
-        k = self.speculation_k
-
-        def make():
-            return self._jit_cached(
-                functools.partial(self._decode_window_fn_spec,
-                                  window=window, k=k),
-                f"decode_spec_w{window}", donate_argnums=(4, 5, 6))
-
-        toks, accs, self._last_token, self._lengths, \
-            self._cache_k, self._cache_v, self._hist = self._run_program(
-                self._decode_jit, ("spec", window), make,
-                self.params, self._last_token, self._lengths, self._active,
-                self._cache_k, self._cache_v, self._hist,
-            )
-        decoding = frozenset(
-            slot_id for slot_id, req in enumerate(self._slots)
-            if req is not None and slot_id not in self._chunking)
-        pending = {"tokens": toks, "accepted": accs, "window": window,
-                   "remaining_after": remaining - window,
-                   "decoding": decoding, "spec": True}
         if self.telemetry is not None:
             self._record_dispatch(len(decoding), pending)
         return pending
@@ -2330,22 +1176,6 @@ class InferenceEngine:
         self._pending = None
         with jax.profiler.TraceAnnotation("engine.pull"):
             tokens_np = np.asarray(p["tokens"])
-            accs_np = (np.asarray(p["accepted"])  # [W, B]
-                       if p.get("spec") else None)
-        if accs_np is not None:
-            # acceptance observability: operators tune speculation_k (or
-            # turn speculation off) from this ratio — draft tokens accepted
-            # per verification step, over decoding slots only
-            cols = sorted(p["decoding"])
-            if cols:
-                steps_n = p["window"] * len(cols)
-                accepted_n = int(accs_np[:, cols].sum())
-                self.spec_stats["steps"] += steps_n
-                self.spec_stats["accepted"] += accepted_n
-                if self.telemetry is not None:
-                    # same counters, recorder-side: acceptance rate lands
-                    # on /metrics next to the latency histograms
-                    self.telemetry.record_spec(steps_n, accepted_n)
         emitted = 0
         with jax.profiler.TraceAnnotation("engine.emit"):
             for step in range(p["window"]):
@@ -2356,19 +1186,9 @@ class InferenceEngine:
                         # carried junk for the slot even if its prefill
                         # has since finished)
                         continue
-                    if accs_np is None:
-                        self._host_lengths[slot_id] += 1  # mirrors device
-                        emitted += 1
-                        self._emit(slot_id, req,
-                                   int(tokens_np[step, slot_id]))
-                        continue
-                    for j in range(int(accs_np[step, slot_id]) + 1):
-                        if self._slots[slot_id] is None:
-                            break  # finished mid-burst: drop the rest
-                        self._host_lengths[slot_id] += 1
-                        emitted += 1
-                        self._emit(slot_id, req,
-                                   int(tokens_np[step, slot_id, j]))
+                    self._host_lengths[slot_id] += 1  # mirrors device
+                    emitted += 1
+                    self._emit(slot_id, req, int(tokens_np[step, slot_id]))
         if self.telemetry is not None and "t0" in p:
             self.telemetry.record_drain(
                 emitted, time.perf_counter() - p["t0"], len(p["decoding"]),

@@ -1,10 +1,12 @@
-"""What the engine needs from a model whose state is not K and V rows: the
-device state, and the three programs the scheduler runs (whole-prompt
-prefill, one chunk, a decode window), for ``models/ling_hybrid.py``.
+"""The hybrid decoder (``models/ling_hybrid.py``) behind the engine's seam:
+the device state of a model whose memory is not K and V rows, and the
+programs the scheduler runs over it (whole-prompt prefill, one chunk, a
+decode window).  ``serving/dense.py`` answers the same calls for the Llama
+family; ``serving/families.py`` picks between them.
 
 The engine keeps two donated trees of device state and hands both to every
-program.  For a Llama-family model they are the K and the V pool; here they
-are
+program as they are.  For a Llama-family model they are the K and the V
+pool; here they are
 
 * ``pool`` [L_mla, blocks, block_size, lanes]: the paged pool with ONE leaf,
   a latent row a token (``ops/mla.py``), addressed through the same block
@@ -21,14 +23,14 @@ carries the state through the engine's chunk queue as it is.  A decode
 window leaves slots that are not ``active`` (free, or mid-chunk) untouched.
 
 MLA decode reads the pool through a gather, once a window (each slot's pages
-laid end to end, as the engine's XLA path does for K and V), not through
+laid end to end, as the Llama family's XLA path does for K and V), not through
 ``paged_decode_attention``: that kernel takes K and V pools of one head
 width and scales by it, and MLA's key is 576 lanes where its value is 512.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,9 +42,34 @@ from dstack_tpu.ops.rmsnorm import rms_norm
 
 
 class HybridPrograms:
+    #: why prefill/decode disaggregation is refused
+    pd_refusal = (
+        "prefill/decode disaggregation is not served for this "
+        "model: the wire carries K and V rows, not a recurrent "
+        "state and latent rows")
+
     def __init__(self, cfg: model.LingHybridConfig, *, batch_size: int,
-                 max_len: int, block_size: int, num_blocks: int,
-                 sample: Callable):
+                 max_len: int, paged: bool, block_size: int, num_blocks: int,
+                 prefix_cache: bool, quantize: Optional[str],
+                 kv_quantize: Optional[str], mesh: Optional[Any],
+                 sharding_policy: Optional[Any], sample: Callable):
+        """What ``serving/dense.py`` ``DensePrograms`` takes, of which this
+        model is served with the paged pool alone."""
+        for refused, needs in (
+            (not paged, "paged=False: the MLA layers' latent rows live "
+             "in the paged pool, a dense latent cache is not written"),
+            (prefix_cache, "prefix_cache: a cached block would need a "
+             "snapshot of the recurrent state at its boundary"),
+            (kv_quantize, "kv_quantize: latent pages would need scales "
+             "and an absorbed product over quantized rows"),
+            (quantize, "quantize: the grouped expert product would need "
+             "int8 forms of the expert stacks"),
+            (mesh is not None, "a mesh: it would need the expert "
+             "exchange and sharding rules for the recurrent state"),
+        ):
+            if refused:
+                raise ValueError(
+                    f"{type(cfg).__name__} is not served with {needs}")
         self.cfg = cfg
         self.batch_size = batch_size
         self.max_len = max_len
@@ -51,6 +78,14 @@ class HybridPrograms:
         self.blocks_per_slot = max_len // block_size
         #: the engine's on-device sampler (logits, temps, top_ps, top_ks, rng)
         self._sample = sample
+
+    def prepare_params(self, params: Optional[model.Params], rng_seed: int):
+        """The weights, initialised from ``rng_seed`` when ``params`` is
+        None, committed to the device (what an uncommitted tree does to a
+        compile-cache key: ``DensePrograms.prepare_params``)."""
+        if params is None:
+            params = model.init_params(jax.random.PRNGKey(rng_seed), self.cfg)
+        return jax.device_put(params, jax.devices()[0])
 
     # -- state ---------------------------------------------------------------
     def init_state(self):
@@ -67,6 +102,8 @@ class HybridPrograms:
         return pool, rec
 
     def recurrent_state_bytes(self) -> int:
+        """Bytes of ``rec``: the state the slots hold whatever their
+        lengths (the ``recurrent_state_bytes`` gauge)."""
         return self.cfg.recurrent_state_bytes(self.batch_size)
 
     @staticmethod
@@ -145,6 +182,13 @@ class HybridPrograms:
             return logits, pool, self._put_slot(rec, slot, state, tail)
 
         return fn
+
+    # -- the PD wire ---------------------------------------------------------
+    def export_fn(self, bucket: int):
+        raise ValueError(self.pd_refusal)
+
+    def insert_rows(self, pool, rec, prefill: dict, n: int, target):
+        raise ValueError(self.pd_refusal)
 
     # -- decode --------------------------------------------------------------
     def decode_window_fn(self, window: int, sampling: bool,

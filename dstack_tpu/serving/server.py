@@ -538,15 +538,6 @@ class ServingApp:
         if self.warmup_error is not None:
             out["error"] = self.warmup_error
             return web.json_response(out, status=503)
-        if self.engine.speculation:
-            # snapshot once: the engine thread mutates these, and the rate
-            # must equal accepted/steps OF THIS RESPONSE
-            steps = self.engine.spec_stats["steps"]
-            accepted = self.engine.spec_stats["accepted"]
-            out["speculation"] = {
-                "steps": steps, "accepted": accepted,
-                "accept_rate": accepted / steps if steps else 0.0,
-            }
         return web.json_response(out)
 
     async def metrics(self, request: web.Request) -> web.Response:
@@ -620,13 +611,6 @@ class ServingApp:
         if getattr(self.engine, "prefix_cache", False):
             # the allocator's own counters: lookups, hit_blocks, evictions
             out["prefix_cache"] = dict(self.engine._alloc.stats)
-        if self.engine.speculation:
-            steps = self.engine.spec_stats["steps"]
-            accepted = self.engine.spec_stats["accepted"]
-            out["speculation"] = {
-                "steps": steps, "accepted": accepted,
-                "accept_rate": accepted / steps if steps else 0.0,
-            }
         return web.json_response(out)
 
     async def models(self, request: web.Request) -> web.Response:
@@ -995,15 +979,6 @@ def main() -> None:
              f"default: the tuned {InferenceEngine.TUNED_PREFILL_CHUNK} "
              "(overlap sweep winner); 0 disables chunking")
     parser.add_argument(
-        "--speculation", choices=["ngram"], default=None,
-        help="n-gram speculative decoding for greedy requests (several "
-             "tokens per weight pass on repetitive continuations)")
-    parser.add_argument(
-        "--speculation-k", type=int, default=None, metavar="K",
-        help="draft tokens verified per speculative step (default: the "
-             f"tuned {InferenceEngine.TUNED_SPECULATION_K}, overlap sweep "
-             "winner)")
-    parser.add_argument(
         "--no-telemetry", action="store_true",
         help="disable the in-process serving telemetry (/metrics + /stats "
              "then serve empty; also DSTACK_TPU_SERVING_TELEMETRY=0)")
@@ -1155,8 +1130,6 @@ def main() -> None:
         prefill_chunk=(InferenceEngine.TUNED_PREFILL_CHUNK
                        if args.prefill_chunk is None
                        else (args.prefill_chunk or None)),
-        speculation=args.speculation,
-        speculation_k=args.speculation_k,
         telemetry=None if args.no_telemetry else make_engine_telemetry(),
         compile_cache=compile_cache,
     )

@@ -25,8 +25,7 @@ republished with project/run/job/replica labels):
   chunked-prefill dispatch (the signal a router uses to avoid piling
   long prompts onto one replica)
 - ``requests_total{outcome}``, ``prefill_tokens_total``,
-  ``decode_tokens_total``, ``preemptions_total{reason}``,
-  ``spec_steps_total``, ``spec_accepted_total`` counters
+  ``decode_tokens_total``, ``preemptions_total{reason}`` counters
 - ``decode_steps_total`` / ``decode_slot_steps_total`` counters — decode
   steps of the windows handed over so far, and the same times
   ``batch_size``: what the device computed for them (a step costs the
@@ -153,8 +152,6 @@ class EngineTelemetry:
             PREFIX + "prefill_chunk_steps_total")
         self.prefill_budget_exhausted = r.counter(
             PREFIX + "prefill_budget_exhausted_total")
-        self.spec_steps = r.counter(PREFIX + "spec_steps_total")
-        self.spec_accepted = r.counter(PREFIX + "spec_accepted_total")
         self._started_at = time.time()
 
     # -- engine-thread recording hooks ----------------------------------
@@ -188,8 +185,7 @@ class EngineTelemetry:
         - ``engine.kv_wait``     KV-block stall -> admission (paged pool
                                  exhaustion — the starvation signal)
         - ``engine.prefill``     admission -> first token
-        - ``engine.decode``      first token -> finished (spec-decode
-                                 accept counters as attrs when enabled)
+        - ``engine.decode``      first token -> finished
         """
         t = self.tracer
         status = "error" if outcome == "error" else "ok"
@@ -218,17 +214,10 @@ class EngineTelemetry:
                           attrs={"prompt_tokens":
                                  len(getattr(req, "tokens", None) or ())})
         if first is not None:
-            attrs = {"tokens_out": len(req.output),
-                     "finish_reason": outcome}
-            spec0 = getattr(req, "_spec0", None)
-            if spec0 is not None:
-                # engine-wide window deltas over this request's lifetime
-                # (speculation verifies whole windows, not single slots)
-                attrs["spec_steps"] = int(self.spec_steps.value - spec0[0])
-                attrs["spec_accepted"] = int(
-                    self.spec_accepted.value - spec0[1])
             t.record_span("engine.decode", trace_id, start=first, end=now,
-                          parent_id=rid, attrs=attrs)
+                          parent_id=rid,
+                          attrs={"tokens_out": len(req.output),
+                                 "finish_reason": outcome})
 
     def record_prefill(self, n_tokens: int, bucket: int) -> None:
         self.prefill_tokens.inc(n_tokens)
@@ -261,8 +250,8 @@ class EngineTelemetry:
         the total emitted would shrink the metric with batch occupancy
         and understate what any single stream experiences.
 
-        The window's ``steps`` (a speculative window's verification
-        steps) and ``steps * batch_size`` slot-steps are counted HERE, with
+        The window's ``steps`` and ``steps * batch_size`` slot-steps are
+        counted HERE, with
         the tokens they produced, so that tokens over slot-steps between
         any two readings compares the same windows."""
         self.decode_steps.inc(steps)
@@ -296,10 +285,6 @@ class EngineTelemetry:
         of a shape, a compile or a cache load."""
         self.recorder.counter(PREFIX + "programs_built_total",
                               labels={"kind": kind}).inc()
-
-    def record_spec(self, steps: int, accepted: int) -> None:
-        self.spec_steps.inc(steps)
-        self.spec_accepted.inc(accepted)
 
     def record_expert_load(self, held: float, absent: float,
                            load_max: float, load_mean: float,

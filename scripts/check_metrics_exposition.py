@@ -184,7 +184,6 @@ async def check_serving_metrics() -> int:
     tel.record_kv_utilization(0.4)
     tel.record_prefill_backlog(512)
     tel.record_preemption("kv_blocks_exhausted")
-    tel.record_spec(10, 7)
     tel.record_program_built("decode")
     tel.record_expert_load(200.0, 600.0, 9.0, 2.0, 90.0)
     tel.record_recurrent_state_bytes(1 << 20)
@@ -201,7 +200,6 @@ async def check_serving_metrics() -> int:
 
     class _StubEngine:
         telemetry = tel
-        speculation = None
         batch_size = 8  # capacity_slots in the /load snapshot
 
         def run_forever(self):  # the app's engine-thread target
@@ -243,8 +241,6 @@ async def check_serving_metrics() -> int:
             "dstack_serving_decode_slot_steps_total",
             "dstack_serving_programs_built_total",
             "dstack_serving_preemptions_total",
-            "dstack_serving_spec_steps_total",
-            "dstack_serving_spec_accepted_total",
             "dstack_serving_moe_pairs_total",
             "dstack_serving_moe_expert_load_max_sum",
             "dstack_serving_moe_expert_load_mean_sum",

@@ -131,11 +131,7 @@ def test_programs_are_named_by_their_compile_cache_tags(traced):
     (dict(paged=False, total_kv_blocks=None, prefill_chunk=32),
      {"prefill_b32", "prefill_chunk_b32", "first_token_sample",
       "decode_w8_s0"}),
-    (dict(paged=False, total_kv_blocks=None, prefill_chunk=None,
-          speculation="ngram"),
-     {"prefill_b32", "prefill_b64", "first_token_sample",
-      "decode_spec_w8"}),
-], ids=["dense-chunked", "speculative"])
+], ids=["dense-chunked"])
 def test_every_program_kind_lowers_under_its_tag(model, kwargs, tags):
     engine = _engine(model, **kwargs)
     engine.generate(list(range(1, 41)), max_new_tokens=5)
@@ -155,7 +151,7 @@ def lowered(monkeypatch):
     from dstack_tpu.serving import engine as engine_mod
 
     texts = {}
-    named_jit = engine_mod._named_jit
+    named_jit = engine_mod.named_jit
 
     def recording(fn, name, **kw):
         jitted = named_jit(fn, name, **kw)
@@ -168,7 +164,7 @@ def lowered(monkeypatch):
         call.__name__ = name
         return call
 
-    monkeypatch.setattr(engine_mod, "_named_jit", recording)
+    monkeypatch.setattr(engine_mod, "named_jit", recording)
     return texts
 
 
@@ -199,7 +195,7 @@ def test_paged_kernel_and_its_scope_are_named(model, lowered, monkeypatch):
     under the ``paged_attn`` scope and carries its own name."""
     monkeypatch.setenv("DSTACK_TPU_PAGED_ATTN_KERNEL", "1")
     engine = _engine(model)
-    assert engine._paged_kernel
+    assert engine._programs._paged_kernel
     engine.generate(list(range(1, 41)), max_new_tokens=3)
     decode = next(t for n, t in lowered.items() if n.startswith("decode_"))
     assert _in_scope(decode, "paged_attn")
@@ -218,7 +214,7 @@ def test_no_program_slices_a_layer_out_of_the_pool(model, lowered,
     engine = _engine(model)
     engine.generate(list(range(1, 41)), max_new_tokens=3)
     engine.generate(list(range(1, 101)), max_new_tokens=3)  # chunked
-    layers, blocks, block, lanes = engine._cache_k.shape
+    layers, blocks, block, lanes = engine._state[0].shape
     cfg = engine.cfg
     assert (layers, lanes) == (cfg.num_layers,
                                cfg.num_kv_heads * cfg.head_dim)

@@ -200,9 +200,9 @@ def test_prefill_logits_are_the_reference_s(uncut):
     prompt = _prompt(50)
     padded = np.zeros((64,), np.int32)
     padded[:50] = prompt
-    logits, _, rec = engine._prefill_fn_paged(64)(
-        engine.params, jnp.asarray(padded), jnp.int32(50), engine._cache_k,
-        engine._cache_v, (jnp.arange(1, 5, dtype=jnp.int32), jnp.int32(2)))
+    logits, _, rec = engine._prefill_program(64)(
+        engine.params, jnp.asarray(padded), jnp.int32(50), *engine._state,
+        (jnp.arange(1, 5, dtype=jnp.int32), jnp.int32(2)))
     want = ref.logits(weights, sizes, prompt, 49, 1, config=UNCUT)[0]
     np.testing.assert_allclose(logits, want, atol=2e-4)
     assert float(jnp.abs(rec["state"][:, 2]).max()) > 0
@@ -220,9 +220,9 @@ def test_chunked_prefill_carries_the_state(uncut):
     b = chunked.generate(prompt, max_new_tokens=1)
     assert a.output == b.output
     for key in ("state", "tail"):
-        np.testing.assert_allclose(chunked._cache_v[key][:, 0],
-                                   whole._cache_v[key][:, 0], atol=2e-5)
-    rows = lambda e: e._cache_k[0, 1:8].reshape(-1, e._cache_k.shape[-1])
+        np.testing.assert_allclose(chunked._state[1][key][:, 0],
+                                   whole._state[1][key][:, 0], atol=2e-5)
+    rows = lambda e: e._state[0][0, 1:8].reshape(-1, e._state[0].shape[-1])
     np.testing.assert_allclose(rows(chunked)[:100], rows(whole)[:100],
                                atol=2e-5)
     assert whole.generate(prompt, max_new_tokens=24).output == \
@@ -234,13 +234,13 @@ def test_a_reused_slot_holds_nothing_of_its_last_request(uncut, second):
     _, weights, cfg = uncut
     used = _engine(cfg, weights, batch_size=1, total_kv_blocks=20)
     used.generate(_prompt(80, seed=1).tolist(), max_new_tokens=30)
-    assert float(jnp.abs(used._cache_v["state"]).max()) > 0
+    assert float(jnp.abs(used._state[1]["state"]).max()) > 0
     fresh = _engine(cfg, weights, batch_size=1, total_kv_blocks=20)
     prompt = _prompt(second, seed=2).tolist()
     assert used.generate(prompt, max_new_tokens=20).output == \
         fresh.generate(prompt, max_new_tokens=20).output
-    np.testing.assert_array_equal(used._cache_v["state"],
-                                  fresh._cache_v["state"])
+    np.testing.assert_array_equal(used._state[1]["state"],
+                                  fresh._state[1]["state"])
 
 
 def test_slots_decode_together_as_they_do_alone(uncut):
@@ -285,7 +285,7 @@ def test_two_chunked_prompts_keep_their_own_state(uncut):
 
 
 @pytest.mark.parametrize("option,value", [
-    ("paged", False), ("prefix_cache", True), ("speculation", "ngram"),
+    ("paged", False), ("prefix_cache", True),
     ("kv_quantize", "int8"), ("quantize", "int8"), ("mesh", "a mesh")])
 def test_options_the_model_cannot_be_served_with_raise(option, value):
     cfg = model.LingHybridConfig.tiny()
@@ -324,6 +324,6 @@ def test_expert_load_reaches_the_telemetry():
     assert got[("dstack_serving_moe_experts_touched_sum", ())] <= \
         pairs("held")
     assert got[("dstack_serving_recurrent_state_bytes", ())] == \
-        engine._hybrid.recurrent_state_bytes() == sum(
+        engine._programs.recurrent_state_bytes() == sum(
             a.size * a.dtype.itemsize
-            for a in jax.tree.leaves(engine._cache_v))
+            for a in jax.tree.leaves(engine._state[1]))

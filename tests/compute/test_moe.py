@@ -88,7 +88,7 @@ def test_moe_expert_parallel_matches_unsharded(cpu_devices):
 def test_route_token_mask_excludes_pads():
     """Masked (padding) tokens claim no expert-capacity slots: real tokens
     route exactly as they would with no pads present (the serving engine's
-    prefill relies on this — engine._mlp_block)."""
+    prefill relies on this — serving/dense.py _mlp_block)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

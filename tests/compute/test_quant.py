@@ -117,7 +117,7 @@ def test_int8_kv_engine_output_close_to_exact(setup):
     want = exact.generate(list(prompt), max_new_tokens=6).output
     engine = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
                              kv_quantize="int8")
-    assert engine._cache_k["q"].dtype == np.int8
+    assert engine._state[0]["q"].dtype == np.int8
     got = engine.generate(list(prompt), max_new_tokens=6).output
     assert got == want
 
@@ -142,8 +142,8 @@ def test_quantized_pages_fold_heads_and_match_dense(setup, kv_quantize):
     qk = "q" if kv_quantize == "int8" else "q4"
     lead = (cfg.num_layers, engine._alloc.num_blocks, 16)
     lanes = cfg.num_kv_heads * cfg.head_dim // (1 if qk == "q" else 2)
-    assert engine._cache_k[qk].shape == lead + (lanes,)
-    assert engine._cache_v["s"].shape == lead + (cfg.num_kv_heads,)
+    assert engine._state[0][qk].shape == lead + (lanes,)
+    assert engine._state[1]["s"].shape == lead + (cfg.num_kv_heads,)
     got = [engine.generate(list(p), max_new_tokens=6).output
            for p in prompts]
     assert got == want
@@ -240,7 +240,7 @@ def test_int8_kv_composes_with_mesh_tensor_parallel(setup):
     mesh = build_mesh(MeshSpec(tensor=2), jax.devices("cpu")[:2])
     engine = InferenceEngine(cfg, params=params, batch_size=2, max_len=64,
                              kv_quantize="int8", mesh=mesh)
-    assert engine._cache_k["q"].sharding.spec[3] == "tensor"
-    assert engine._cache_k["s"].sharding.spec[3] == "tensor"
+    assert engine._state[0]["q"].sharding.spec[3] == "tensor"
+    assert engine._state[0]["s"].sharding.spec[3] == "tensor"
     got = engine.generate([2, 7, 1, 8], max_new_tokens=5).output
     assert got == want

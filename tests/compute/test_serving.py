@@ -217,7 +217,7 @@ def test_paged_pool_form_matches_dense_cache(monkeypatch, case, attention):
     engine = InferenceEngine(cfg, params=params, batch_size=4, max_len=128,
                              paged=True, kv_block_size=16, **paged_kw)
     # the stored form: [L, blocks, block, Hkv*D], scales [.., Hkv]
-    pool = engine._cache_k["q"] if case == "int8" else engine._cache_k
+    pool = engine._state[0]["q"] if case == "int8" else engine._state[0]
     assert pool.shape == (cfg.num_layers, engine._alloc.num_blocks, 16,
                           cfg.num_kv_heads * cfg.head_dim)
     assert run(engine, case == "prefix-cache") == want
@@ -225,7 +225,7 @@ def test_paged_pool_form_matches_dense_cache(monkeypatch, case, attention):
     programs = {k[0] if isinstance(k, tuple) else k
                 for k in engine._prefill_jit}
     if case in ("prefix-cache", "chunked"):
-        assert "prefix" in programs  # the suffix/chunk program ran
+        assert "chunk" in programs  # the suffix/chunk program ran
     if case == "buckets":
         assert {k[1] for k in engine._prefill_jit
                 if isinstance(k, tuple)} == {32, 64, 128}
@@ -250,7 +250,7 @@ def test_paged_pool_warns_when_rows_are_not_whole_lane_tiles(
 
     monkeypatch.setenv("DSTACK_TPU_PAGED_ATTN_KERNEL", kernel)
     cfg = dataclasses.replace(LlamaConfig.tiny(), num_kv_heads=kv_heads)
-    with caplog.at_level(logging.WARNING, logger="dstack_tpu.serving.engine"):
+    with caplog.at_level(logging.WARNING, logger="dstack_tpu.serving.dense"):
         InferenceEngine(cfg, params={"layers": {}}, batch_size=2,
                         max_len=64, paged=True, kv_block_size=16)
     said = [r for r in caplog.records if "multiple of 128" in r.getMessage()]
@@ -345,8 +345,8 @@ def test_engine_recovers_after_device_error(setup):
             state["failed"] = True
             engine._admit()  # put the request in flight
             # simulate an XLA error AFTER the caches were donated
-            engine._cache_k.delete()
-            engine._cache_v.delete()
+            for tree in engine._state:
+                tree.delete()
             raise RuntimeError("simulated device failure")
         orig_step()
 
@@ -648,7 +648,7 @@ def test_engine_mesh_inits_params_sharded(setup):
     engine = InferenceEngine(cfg, batch_size=2, max_len=64, mesh=_tp_mesh(4))
     wq = engine.params["layers"]["wq"]
     assert "tensor" in (wq.sharding.spec[-1] or ())
-    assert engine._cache_k.sharding.spec[3] == "tensor"
+    assert engine._state[0].sharding.spec[3] == "tensor"
     req = engine.generate([1, 2, 3], max_new_tokens=4)
     assert len(req.output) == 4
 
@@ -1066,103 +1066,6 @@ def test_chunk_bucket_overshoot_does_not_corrupt_cache(setup):
 
 
 @pytest.mark.slow
-def test_speculative_decode_matches_plain_greedy(setup):
-    """Speculation's defining property: tokens are IDENTICAL to plain
-    greedy decoding — acceptance only changes speed.  Repetitive and
-    non-repetitive prompts, plus slot reuse (history must not leak)."""
-    from dstack_tpu.serving.engine import InferenceEngine
-
-    cfg, params = setup
-    plain = InferenceEngine(cfg, params=params, batch_size=2, max_len=128)
-    spec = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
-                           speculation="ngram")
-    prompts = [
-        [5, 9, 5, 9, 5, 9, 5, 9, 5, 9],      # bigram-repetitive
-        [3, 1, 4, 1, 5, 9, 2, 6],             # mixed
-        [7, 7, 7],                            # slot reuse after the above
-    ]
-    for p in prompts:
-        want = plain.generate(list(p), max_new_tokens=12).output
-        got = spec.generate(list(p), max_new_tokens=12).output
-        assert got == want, (p, got, want)
-        assert len(got) == 12
-
-
-@pytest.mark.slow
-def test_speculative_decode_int8_kv(setup):
-    from dstack_tpu.serving.engine import InferenceEngine
-
-    cfg, params = setup
-    plain = InferenceEngine(cfg, params=params, batch_size=1, max_len=128,
-                            kv_quantize="int8")
-    spec = InferenceEngine(cfg, params=params, batch_size=1, max_len=128,
-                           kv_quantize="int8", speculation="ngram")
-    p = [2, 4, 2, 4, 2, 4, 8]
-    want = plain.generate(list(p), max_new_tokens=8).output
-    got = spec.generate(list(p), max_new_tokens=8).output
-    assert got == want
-
-
-@pytest.mark.slow
-def test_speculative_decode_multi_slot_and_sampled_fallback(setup):
-    """Two concurrent greedy requests decode speculatively and match the
-    plain engine; a sampled request forces the plain window (speculative
-    acceptance is exact-match, meaningless under sampling)."""
-    from dstack_tpu.serving.engine import InferenceEngine, Request
-
-    cfg, params = setup
-    plain = InferenceEngine(cfg, params=params, batch_size=2, max_len=128)
-    wants = [plain.generate([1, 2, 1, 2, 1, 2], max_new_tokens=6).output,
-             plain.generate([9, 8, 9, 8], max_new_tokens=6).output]
-    spec = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
-                           speculation="ngram")
-    reqs = [Request(tokens=[1, 2, 1, 2, 1, 2], max_new_tokens=6),
-            Request(tokens=[9, 8, 9, 8], max_new_tokens=6)]
-    for r in reqs:
-        spec.submit(r)
-    for _ in range(100):
-        if all(r.done.is_set() for r in reqs):
-            break
-        spec.step()
-    assert [r.output for r in reqs] == wants
-    # sampled request: engine serves it through the plain window
-    r = spec.generate([1, 2, 3], max_new_tokens=5, temperature=0.8)
-    assert len(r.output) == 5
-
-
-def test_speculation_rejects_paged(setup):
-    from dstack_tpu.serving.engine import InferenceEngine
-
-    cfg, params = setup
-    with pytest.raises(ValueError, match="dense"):
-        InferenceEngine(cfg, params=params, batch_size=1, max_len=128,
-                        paged=True, speculation="ngram")
-
-
-@pytest.mark.slow
-def test_speculative_decode_exact_in_f32_long_horizon(setup):
-    """In float32 (no bf16 argmax-tie noise — same discipline as
-    test_paged_engine_matches_dense) speculative greedy matches plain
-    greedy EXACTLY over a long, acceptance-heavy generation."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from dstack_tpu.models.llama import LlamaConfig, init_params
-    from dstack_tpu.serving.engine import InferenceEngine
-
-    cfg = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    plain = InferenceEngine(cfg, params=params, batch_size=1, max_len=256)
-    want = plain.generate([5, 9, 2], max_new_tokens=100).output
-    spec = InferenceEngine(cfg, params=params, batch_size=1, max_len=256,
-                           speculation="ngram")
-    got = spec.generate([5, 9, 2], max_new_tokens=100).output
-    assert got == want
-
-
-@pytest.mark.slow
 def test_chunked_prefill_paged_matches_whole_prompt(setup):
     """Paged chunked prefill (suffix-prefill blocks per chunk) must match
     the whole-prompt paged engine, including across block boundaries."""
@@ -1225,74 +1128,3 @@ def test_prefill_chunk_must_be_positive(setup):
         InferenceEngine(cfg, params=params, batch_size=1, max_len=64,
                         prefill_chunk=0)
 
-
-def test_speculation_stats_exposed(setup):
-    from dstack_tpu.serving.engine import InferenceEngine
-
-    cfg, params = setup
-    eng = InferenceEngine(cfg, params=params, batch_size=1, max_len=128,
-                          speculation="ngram")
-    eng.generate([5, 9, 2], max_new_tokens=10)
-    assert eng.spec_stats["steps"] > 0
-    assert eng.spec_stats["accepted"] >= 0
-
-
-@pytest.mark.slow
-def test_speculation_composes_with_chunked_prefill():
-    """Both features on: a long prompt chunk-prefills while another slot
-    decodes SPECULATIVELY; the spec window's optimistic KV writes must
-    never clobber the chunking slot's rows (validity-masked), and both
-    outputs match the plain engine."""
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from dstack_tpu.models.llama import LlamaConfig, init_params
-    from dstack_tpu.serving.engine import InferenceEngine, Request
-
-    # f32: spec-vs-plain are different programs, so bf16 argmax near-ties
-    # could flip at this horizon (same discipline as the exactness test)
-    cfg = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    plain = InferenceEngine(cfg, params=params, batch_size=2, max_len=256)
-    # 40 tokens: enough decode windows that several are IN FLIGHT while
-    # the long prompt chunk-prefills (review-verified overlap)
-    short_want = plain.generate([5, 9, 5, 9], max_new_tokens=40).output
-    long_prompt = [(i * 7) % 50 + 1 for i in range(64)]
-    long_want = plain.generate(list(long_prompt), max_new_tokens=4).output
-
-    eng = InferenceEngine(cfg, params=params, batch_size=2, max_len=256,
-                          speculation="ngram", prefill_chunk=16)
-    short = Request(tokens=[5, 9, 5, 9], max_new_tokens=40)
-    eng.submit(short)
-    eng.step()  # short admitted, first spec window in flight
-    long_req = Request(tokens=list(long_prompt), max_new_tokens=4)
-    eng.submit(long_req)
-    overlapped = 0
-    for _ in range(300):
-        if short.done.is_set() and long_req.done.is_set():
-            break
-        eng.step()
-        if eng._chunking and eng._pending is not None \
-                and eng._pending.get("spec"):
-            overlapped += 1
-    assert overlapped > 0  # the composition actually happened
-    assert short.output == short_want
-    assert long_req.output == long_want
-
-
-@pytest.mark.slow
-def test_speculative_decode_tensor_parallel(setup):
-    """Speculation composes with mesh TP: GSPMD partitions the widened
-    verification forward like every other engine program, and greedy
-    tokens match the single-device plain engine."""
-    from dstack_tpu.serving.engine import InferenceEngine
-
-    cfg, params = setup
-    plain = InferenceEngine(cfg, params=params, batch_size=2, max_len=128)
-    want = plain.generate([5, 9, 5, 9, 2], max_new_tokens=10).output
-    spec = InferenceEngine(cfg, params=params, batch_size=2, max_len=128,
-                           mesh=_tp_mesh(4), speculation="ngram")
-    got = spec.generate([5, 9, 5, 9, 2], max_new_tokens=10).output
-    assert got == want

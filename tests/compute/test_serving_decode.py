@@ -290,19 +290,9 @@ def test_sampled_decoding_seed_deterministic(setup):
 # -- tuned overlap defaults --------------------------------------------------
 
 
-def test_tuned_overlap_defaults_pinned(setup):
-    """The speculation x chunked-prefill sweep winner (bench.py
-    run_decode_overlap_sweep) is recorded as engine defaults; changing
-    them means re-running the sweep, not drift."""
+def test_tuned_overlap_defaults_pinned():
+    """The chunked-prefill sweep winner (PR 18) is recorded as the engine's
+    default; changing it means re-running a sweep, not drift."""
     from dstack_tpu.serving.engine import InferenceEngine
 
-    cfg, params = setup
-    assert InferenceEngine.TUNED_SPECULATION_K == 2
     assert InferenceEngine.TUNED_PREFILL_CHUNK == 512
-    eng = InferenceEngine(cfg, params=params, batch_size=1, max_len=64,
-                          speculation="ngram")
-    assert eng.speculation_k == InferenceEngine.TUNED_SPECULATION_K
-    # explicit override still wins
-    eng = InferenceEngine(cfg, params=params, batch_size=1, max_len=64,
-                          speculation="ngram", speculation_k=5)
-    assert eng.speculation_k == 5

@@ -223,18 +223,6 @@ def test_paged_engine_kv_utilization_and_stall_preemption(setup):
     assert 1 <= stalls <= 2
 
 
-def test_spec_stats_surface_through_recorder(setup):
-    """Speculative-decode acceptance counters land on the recorder (and
-    /metrics) as well as the legacy spec_stats dict."""
-    cfg, params = setup
-    engine = _make_engine(cfg, params, speculation="ngram", speculation_k=2)
-    engine.generate([1, 2, 3, 1, 2, 3, 1, 2], max_new_tokens=12)
-    assert engine.spec_stats["steps"] > 0
-    tel = engine.telemetry
-    assert tel.spec_steps.value == engine.spec_stats["steps"]
-    assert tel.spec_accepted.value == engine.spec_stats["accepted"]
-
-
 def test_telemetry_disabled_is_free(setup):
     """telemetry=None: no recorder objects anywhere on the engine, no
     admission stamps recorded via telemetry, identical outputs."""

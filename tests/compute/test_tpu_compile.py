@@ -318,7 +318,7 @@ def engine_programs(one_chip, real_lowering):
             prefill_chunk=512)
         params = sds(jax.eval_shape(
             lambda: init_params(jax.random.PRNGKey(0), cfg)))
-        pool = sds((engine._cache_k, engine._cache_v))
+        pool = sds(tuple(engine._state))
         leaf = jax.tree.leaves(pool)[0]
         layer_elems = leaf.size // ENGINE_LAYERS
         for weight in jax.tree.leaves(params):
@@ -334,12 +334,12 @@ def engine_programs(one_chip, real_lowering):
                     arg(i32, b, g["kb"]), arg(jnp.uint32, 2))
         elif program.startswith("prefill_paged_b"):
             bucket = int(program.rsplit("b", 1)[1])
-            fn = engine._prefill_fn_paged(bucket)
+            fn = engine._prefill_program(bucket)
             args = (params, arg(i32, bucket), arg(i32), *pool,
                     arg(i32, bucket // bs))
         else:
             assert program == "prefill_prefix_b512", program
-            fn = engine._prefill_fn_prefix(512)
+            fn = engine._chunk_program(512)
             args = (params, arg(i32, 512), arg(i32), arg(i32), *pool,
                     arg(i32, g["max_len"] // bs))
         pool_bytes = sum(a.size * a.dtype.itemsize
@@ -419,7 +419,7 @@ def hybrid_programs(one_chip, real_lowering):
 
     params = sds(jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
-    state = sds((engine._cache_k, engine._cache_v))
+    state = sds(tuple(engine._state))
     i32, f32 = jnp.int32, jnp.float32
 
     @functools.lru_cache(maxsize=None)
@@ -430,12 +430,12 @@ def hybrid_programs(one_chip, real_lowering):
                     *state, arg(f32, b), arg(f32, b), arg(i32, b),
                     arg(i32, b, kb), arg(jnp.uint32, 2))
         elif program == "prefill_paged_b512":
-            fn = engine._prefill_fn_paged(512)
+            fn = engine._prefill_program(512)
             args = (params, arg(i32, 512), arg(i32), *state,
                     (arg(i32, 512 // bs), arg(i32)))
         else:
             assert program == "prefill_prefix_b512", program
-            fn = engine._prefill_fn_prefix(512)
+            fn = engine._chunk_program(512)
             args = (params, arg(i32, 512), arg(i32), arg(i32), *state,
                     (arg(i32, engine.max_len // bs), arg(i32)))
         return fn.lower(*args).compile(), state
