@@ -693,88 +693,246 @@ def flash_attention_sharded(mesh, q, k, v, *, batch_axes=("dcn", "data", "fsdp")
 # scalar-prefetched block tables.  The XLA paged path first gathers each
 # slot's blocks into a dense [B, span, Hkv, D] view — at a 4k span that
 # gather IS the decode step's non-weight HBM bill, and it reads padding for
-# every slot shorter than the span.  Here the grid walks (slot, table
-# column) and the BlockSpec index_map turns the table entry into the page
-# address, so only owned pages cross HBM, exactly once, with no
-# intermediate view.  int8 KV pages ({"q","s"} per serving/quant.py)
-# dequantize in-kernel after the page load — packed bytes are what stream.
+# every slot shorter than the span.  Here only owned pages cross HBM,
+# exactly once, with no intermediate view.  int8 KV pages ({"q","s"} per
+# serving/quant.py) stream as packed bytes; their scales are applied
+# in-kernel.
 #
-# A block is one WHOLE page, all kv heads, and the pool is STORED in the
-# block's form: [L, NUM_BLOCKS, BS, Hkv*D], lane = h*D + d.  The block's
-# last two dims equal the array's — the only shape the TPU lowering
-# accepts here (one head of a page would put 1 of Hkv in the
-# second-to-last dim, which is neither full nor a multiple of 8; the
-# described-v5e compile tests in tests/compute/test_tpu_compile.py hold
-# this).  The kernel walks the heads with static lane slices.
+# What a grid step is.  The grid is (slot, ceil(table columns / P)): a
+# step covers a COMPUTE BLOCK of P pages, P * BS rows of every kv head.  A
+# grid step costs a fraction of a microsecond whatever it reads, and a
+# one-page step reads for less than that, so a walk of one page a step is
+# bound by the number of steps, not by bytes: P pages a step divide that
+# overhead by P and give the matrix unit P * BS rows at a time.
 #
-# The operand IS the stored pool, layer and all: on the chip a custom
-# call's operand is a buffer of its own in the default tiled layout, so a
-# [.., Hkv, D] -> [.., Hkv*D] reshape or a per-layer slice of a stacked
-# pool is a copy of the layer's whole pool, every layer of every step.
-# The layer is a scalar-prefetched index the page's index map adds.
+# Who fetches.  The pools stay in HBM as they are stored, [L, NUM_BLOCKS,
+# BS, Hkv*D] with lane = h*D + d (operands in ``pl.ANY``: on the chip a
+# custom call's operand is a buffer of its own, so a [.., Hkv, D] ->
+# [.., Hkv*D] reshape or a per-layer slice of a stacked pool would copy the
+# layer's whole pool every layer of every step; the layer is a
+# scalar-prefetched index).  The kernel copies a block's pages itself, one
+# ``make_async_copy`` a page (``pool.at[layer, tables[slot, column]]``: a
+# contiguous BS x Hkv*D tile), into a double-buffered VMEM scratch.  The
+# copies of the NEXT live step (the slot's next block, or the first block
+# of the next slot that has any rows) start before this step computes, so
+# only the call's first fetch is exposed.  A step past a slot's length
+# starts no copy and computes nothing; a block the length cuts copies only
+# its live pages.
+#
+# What P is derived from.  The bytes of a page against a fixed budget of
+# VMEM for the scratch (K and V, two buffers each): the largest power of
+# two that fits, at most the table's width (:func:`_pages_per_step`).  int8
+# pages have half the bytes and take twice the pages.  The table's width
+# need not divide: the last block is cut like any other.
+#
+# The dead-row hazard.  Rows of the scratch that no copy filled (dead pages
+# of a cut block) hold whatever was there, and rows of a live page past the
+# slot's length hold whatever the pool holds.  Masking the scores alone is
+# not enough: a probability of 0 times a NaN in V is NaN.  V's rows past the
+# length are zeroed in the scratch before the product (K's need nothing: a
+# select on the score drops a NaN).  An int8 page holds no NaN; there it is
+# the scales of those rows that are zeroed.
+#
+# int8 pages are never dequantized.  A row of the block-diagonal query is
+# nonzero on one head's lanes only, so that head's scale of a cache row
+# can multiply the score (K) and the probability (V) instead of the page:
+# [rows, Hkv] scales become [Hq, rows] by a 0/1 product, and the pages go
+# to the matrix unit as they are (int8 -> bf16 is exact).
+#
+# All heads at once.  The scores of a block are ONE product, a
+# block-diagonal query [Hq, Hkv*D] (row h*G+g holds q[h, g] on head h's
+# lanes, zero elsewhere; built in-kernel once a slot) against the block's
+# K [P*BS, Hkv*D], and P.V one product into an [Hq, Hkv*D] accumulator
+# whose diagonal blocks are the output.  No head is sliced out of the lanes
+# in the walk, whatever D and G are: each K/V element is loaded into the
+# matrix unit once either way, and the off-diagonal products ride along on
+# rows that would otherwise be idle (G rows of 128).
 #
 # Returns a NORMALIZED output plus the softmax logsumexp so the caller can
 # merge other attention pieces (the engine's in-window KV buffer) without
 # re-reading pages.  Slots with length 0 return o = 0, lse = -inf — exact
 # zero weight under any logsumexp merge.
 
+#: VMEM the K and V block buffers may take together (two buffers each): a
+#: quarter of v5e's 16 MiB scoped default, which leaves the rest to the
+#: accumulators, the scores and the compiler's own temporaries
+_PAGED_SCRATCH_BYTES = 4 * 1024 * 1024
+
+
+def _pages_per_step(page_bytes: int, table_width: int) -> int:
+    """Pages a grid step fetches and attends: the largest power of two whose
+    K and V double buffers (4 x P x ``page_bytes``) fit the scratch budget,
+    at most the largest power of two <= the table's width."""
+    p = 1
+    while (8 * p * page_bytes <= _PAGED_SCRATCH_BYTES
+           and 2 * p <= table_width):
+        p *= 2
+    return p
+
 
 def _paged_decode_kernel(layer_ref, tables_ref, lengths_ref, q_ref, *rest,
-                         scale, bs, nbk, quant):
-    del layer_ref, tables_ref  # consumed by the index maps
-    _, hkv, _, d = q_ref.shape
+                         scale, bs, pages, nbk, hkv, quant):
+    """One grid step (slot ``b``, compute block ``i``) of the paged walk;
+    see the comment block above for the form."""
     if quant:
-        k_ref, ks_ref, v_ref, vs_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
+        (k_hbm, ks_hbm, v_hbm, vs_hbm, o_ref, lse_ref, k_buf, v_buf, ks_buf,
+         vs_buf, sems, state, qbd, acc, m_scr, l_scr) = rest
+        sources = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                   (vs_hbm, vs_buf))
     else:
-        k_ref, v_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
+        (k_hbm, v_hbm, o_ref, lse_ref, k_buf, v_buf, sems, state, qbd, acc,
+         m_scr, l_scr) = rest
+        sources = ((k_hbm, k_buf), (v_hbm, v_buf))
+    _, hq, d = q_ref.shape
+    group = hq // hkv
+    width = qbd.shape[1]        # Hkv*D, padded to whole lane tiles
+    rows = pages * bs
+    nslots = pl.num_programs(0)
     b = pl.program_id(0)
     i = pl.program_id(1)
+    layer = layer_ref[0]
+
+    def rows_of(slot):
+        # no walk passes the table: a length beyond it attends what the
+        # table has, as a grid that ends with the table always did
+        return jnp.minimum(lengths_ref[slot], nbk * bs)
+
+    def block_copies(slot, blk, buf, act):
+        """Start (or wait for) the copies of the LIVE pages of block ``blk``
+        of ``slot`` into buffer ``buf``: one loop a call site, its trip
+        count the pages the slot's length reaches in this block."""
+        live = jnp.clip(pl.cdiv(rows_of(slot) - blk * rows, bs), 0, pages)
+
+        def page(j, carry):
+            at = tables_ref[slot, blk * pages + j]
+            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            for n, (hbm, vmem) in enumerate(sources):
+                act(pltpu.make_async_copy(
+                    hbm.at[layer, at], vmem.at[buf, dst], sems.at[buf, n]))
+            return carry
+
+        jax.lax.fori_loop(0, live, page, 0)
+
+    def start(slot, blk, buf):
+        block_copies(slot, blk, buf, lambda copy: copy.start())
+
+    def wait(slot, blk, buf):
+        block_copies(slot, blk, buf, lambda copy: copy.wait())
+
+    length = rows_of(b)
+
+    @pl.when((b == 0) & (i == 0))
+    def _first_step():
+        state[0] = 0   # the buffer the next live step reads
+        state[1] = 0   # 1 once a live step has run: each fetches for the next
 
     @pl.when(i == 0)
-    def _init():
+    def _init_slot():
         acc[...] = jnp.zeros_like(acc)
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
+        # the block-diagonal query: every head's q tiled along the lanes by
+        # a 0/1 product (exact), then everything off head h's lanes dropped
+        tile = (jax.lax.broadcasted_iota(jnp.int32, (d, width), 1) % d
+                == jax.lax.broadcasted_iota(jnp.int32, (d, width), 0))
+        tiled = jax.lax.dot_general(
+            q_ref[0], tile.astype(q_ref.dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [Hq, width]
+        own = (jax.lax.broadcasted_iota(jnp.int32, (hq, width), 0) // group
+               == jax.lax.broadcasted_iota(jnp.int32, (hq, width), 1) // d)
+        qbd[...] = jnp.where(own, tiled, 0.0).astype(qbd.dtype)
 
-    length = lengths_ref[b]
+    @pl.when(i * rows < length)
+    def _live_step():
+        buf = state[0]
 
-    @pl.when(i * bs < length)
-    def _compute():
-        kpos = i * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        for h in range(hkv):
-            q = q_ref[0, h]                       # [G, D]
-            k = k_ref[0, 0, :, h * d:(h + 1) * d]  # [BS, D]
-            v = v_ref[0, 0, :, h * d:(h + 1) * d]
-            if quant:
-                k = (k.astype(jnp.float32)
-                     * ks_ref[0, 0, :, h:h + 1]).astype(q.dtype)
-                v = (v.astype(jnp.float32)
-                     * vs_ref[0, 0, :, h:h + 1]).astype(q.dtype)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale              # [G, BS]
-            s = jnp.where(kpos < length, s, _NEG_INF)
-            # at least one column is valid here (i*bs < length), so m_new
-            # is finite and the m_prev = -inf first block gives alpha = 0
-            m_prev = m_scr[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc[h] = acc[h] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_scr[h] = m_new
+        @pl.when(state[1] == 0)
+        def _():    # the call's first live step: nobody fetched for it
+            start(b, i, buf)
 
-    @pl.when(i == nbk - 1)
+        # the next live step in grid order: this slot's next block, else
+        # block 0 of the next slot that has rows
+        def next_slot():
+            return jax.lax.while_loop(
+                lambda s: (s < nslots)
+                & (lengths_ref[jnp.minimum(s, nslots - 1)] <= 0),
+                lambda s: s + 1, b + 1)
+
+        more = (i + 1) * rows < length
+        nb = jax.lax.cond(more, lambda: b, next_slot)
+
+        @pl.when(nb < nslots)
+        def _():
+            start(nb, jnp.where(more, i + 1, 0), 1 - buf)
+
+        state[0] = 1 - buf
+        state[1] = 1
+        wait(b, i, buf)
+
+        base = i * rows
+        kpos = base + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        q = qbd[...]                                     # [Hq, width]
+        if quant:
+            # the scales never meet the pages: a row of q is nonzero on
+            # one head's lanes only, so that head's scale of a cache row
+            # multiplies the score (K) or the probability (V) instead;
+            # int8 -> q's dtype is exact.  [rows, Hkv] scales -> [Hq, rows]
+            # by a 0/1 product (exact at HIGHEST)
+            heads = ks_buf.shape[2]
+            pick = (jax.lax.broadcasted_iota(jnp.int32, (hq, heads), 0)
+                    // group
+                    == jax.lax.broadcasted_iota(jnp.int32, (hq, heads), 1)
+                    ).astype(jnp.float32)
+
+            def per_head(scales):
+                return jax.lax.dot_general(
+                    pick, scales[buf], (((1,), (1,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)  # [Hq, rows]
+
+            # V's rows past the length hold finite int8 whatever is there;
+            # it is their scales a NaN could come in by
+            k_scale = per_head(ks_buf)
+            v_scale = jnp.where(kpos < length, per_head(vs_buf), 0.0)
+            k = k_buf[buf].astype(q.dtype)
+            v = v_buf[buf].astype(q.dtype)
+        else:
+            @pl.when(base + rows > length)
+            def _zero_dead_rows():
+                row = base + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+                v_buf[buf] = jnp.where(row < length, v_buf[buf],
+                                       jnp.zeros_like(v_buf[buf]))
+
+            k, v = k_buf[buf], v_buf[buf]                # [rows, width]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [Hq, rows]
+        if quant:
+            s = s * k_scale
+        s = jnp.where(kpos < length, s, _NEG_INF)
+        # at least one column is valid here (base < length), so m_new is
+        # finite and the m_prev = -inf first block gives alpha = 0
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if quant:
+            p = p * v_scale
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # [Hq, width]
+        m_scr[...] = m_new
+
+    @pl.when(i == pl.num_programs(1) - 1)
     def _flush():
         l = l_scr[...]
         safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc[...] / safe_l).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(
-            l > 0, m_scr[...] + jnp.log(safe_l), _NEG_INF)
+        lse_ref[0] = jnp.where(l > 0, m_scr[...] + jnp.log(safe_l), _NEG_INF)
+        for h in range(hkv):     # the accumulator's diagonal blocks
+            r = slice(h * group, (h + 1) * group)
+            o_ref[0, r, :] = (acc[r, h * d:(h + 1) * d]
+                              / safe_l[r]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, layer, tables, lengths, *,
@@ -788,7 +946,14 @@ def paged_decode_attention(q, k_pages, v_pages, layer, tables, lengths, *,
     the layer whose pages to read — addressed in place, never sliced out;
     tables: int32 [B, NBK] table columns (0 = NULL block) — pass a sliced
     table to bound the walk at a ragged bucket; lengths: int32 [B] valid
-    KV rows per slot.
+    KV rows per slot, at most NBK * BS.
+
+    A grid step attends a block of P pages that the kernel fetches itself
+    out of the pools in HBM, double-buffered, the next live block's copies
+    in flight while this one computes; P follows from the bytes of a page
+    (:func:`_pages_per_step`), not from an option.  Pages past a slot's
+    length are neither fetched nor read: what the NULL block, unowned pages
+    or the rows of a page past the length hold cannot reach the output.
 
     Returns ``(o, lse)``: o float32 [B, Hkv, G, D] NORMALIZED over the
     slot's ``length`` cache rows, lse float32 [B, Hkv, G] (-inf where
@@ -798,7 +963,7 @@ def paged_decode_attention(q, k_pages, v_pages, layer, tables, lengths, *,
 
     The kernel runs per device: under a mesh, call it inside ``shard_map``
     with the lane dim sharded (the engine does): a shard holds whole heads,
-    (Hkv/tp)*D lanes.  Any lane width compiles, because the block spans
+    (Hkv/tp)*D lanes.  Any lane width compiles, because a page's copy spans
     the shard's whole last dim, but only a multiple of 128 is stored in
     the operand's form: the TPU compiler keeps any other pool with the
     blocks minor-most and converts all of it around the call.
@@ -809,60 +974,73 @@ def paged_decode_attention(q, k_pages, v_pages, layer, tables, lengths, *,
             "paged_decode_attention reads int8/bf16 pages; int4 caches "
             "use the XLA gather path")
     b, hkv, group, d = q.shape
+    hq, width = hkv * group, hkv * d
     nbk = tables.shape[1]
     kq, vq = (k_pages["q"], v_pages["q"]) if quant else (k_pages, v_pages)
     bs = kq.shape[2]
-    if kq.ndim != 4 or kq.shape[3] != hkv * d:
+    if kq.ndim != 4 or kq.shape[3] != width:
         raise ValueError(
-            f"pages must be [L, NUM_BLOCKS, BS, Hkv*D = {hkv * d}], got "
+            f"pages must be [L, NUM_BLOCKS, BS, Hkv*D = {width}], got "
             f"{kq.shape}")
     if scale is None:
         scale = d ** -0.5
 
-    def whole(bb, i, layer, tables, lengths):
-        return (bb, 0, 0, 0)
+    def lane_tiles(a):
+        # a page's copy has to span whole 128-lane tiles of its source.  A
+        # pool of another width is not stored in the operand's form anyway
+        # (the compiler converts all of it around the call, see above):
+        # the conversion is this pad
+        short = -a.shape[-1] % 128
+        return jnp.pad(a, ((0, 0),) * 3 + ((0, short),)) if short else a
 
-    def page(bb, i, layer, tables, lengths):
-        # the table entry IS the page index, the layer its plane
-        return (layer[0], tables[bb, i], 0, 0)
+    def slot_block(bb, i, layer, tables, lengths):
+        return (bb, 0, 0)
 
-    def spec(block, index_map):
-        return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+    def spec(block):
+        return pl.BlockSpec(block, slot_block, memory_space=pltpu.VMEM)
 
-    k_spec = spec((1, 1, bs, hkv * d), page)
+    kq, vq = lane_tiles(kq), lane_tiles(vq)
+    width = kq.shape[3]
+    pages = _pages_per_step(bs * width * kq.dtype.itemsize, nbk)
+    rows = pages * bs
+    buffers = [pltpu.VMEM((2, rows, width), kq.dtype)] * 2
     if quant:
-        s_spec = spec((1, 1, bs, hkv), page)
-        inputs = (q, kq, k_pages["s"], vq, v_pages["s"])
-        in_specs = [k_spec, s_spec, k_spec, s_spec]
+        ks, vs = lane_tiles(k_pages["s"]), lane_tiles(v_pages["s"])
+        inputs = (kq, ks, vq, vs)
+        buffers += [pltpu.VMEM((2, rows, ks.shape[3]), jnp.float32)] * 2
     else:
-        inputs = (q, kq, vq)
-        in_specs = [k_spec, k_spec]
+        inputs = (kq, vq)
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale, bs=bs,
-                               nbk=nbk, quant=quant)
+                               pages=pages, nbk=nbk, hkv=hkv, quant=quant)
     o, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(b, nbk),
-            in_specs=[spec((1, hkv, group, d), whole)] + in_specs,
-            out_specs=[spec((1, hkv, group, d), whole),
-                       spec((1, hkv, group, 1), whole)],
-            scratch_shapes=[
-                pltpu.VMEM((hkv, group, d), jnp.float32),
-                pltpu.VMEM((hkv, group, 1), jnp.float32),
-                pltpu.VMEM((hkv, group, 1), jnp.float32),
+            grid=(b, pl.cdiv(nbk, pages)),
+            in_specs=[spec((1, hq, d))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(inputs),
+            out_specs=[spec((1, hq, d)), spec((1, hq, 1))],
+            scratch_shapes=buffers + [
+                pltpu.SemaphoreType.DMA((2, len(inputs))),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((hq, width), q.dtype),       # block-diagonal q
+                pltpu.VMEM((hq, width), jnp.float32),   # accumulator
+                pltpu.VMEM((hq, 1), jnp.float32),       # running max
+                pltpu.VMEM((hq, 1), jnp.float32),       # running sum
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, group, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         name="paged_decode_attention",
         interpret=_interpret(),
     )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
-      lengths.astype(jnp.int32), *inputs)
-    return o, lse[..., 0]
+      lengths.astype(jnp.int32), q.reshape(b, hq, d), *inputs)
+    return o.reshape(b, hkv, group, d), lse.reshape(b, hkv, group)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -783,12 +783,14 @@ class DensePrograms:
 
         On a TPU backend the gather disappears entirely: the Pallas
         block-table kernel (ops/flash_attention.py paged_decode_attention)
-        reads K/V blocks straight from the paged pool via scalar-prefetched
-        tables and returns a normalized (o, lse) pair per slot; the window
-        buffer's attention merges with it by logsumexp, so no
-        dense-equivalent linear view is ever materialized
-        (DSTACK_TPU_PAGED_ATTN_KERNEL, auto = TPU only; int4 caches use
-        the XLA path — the kernel dequantizes int8 in-kernel).
+        copies a slot's live pages out of the stored pool itself, a block
+        of several pages a grid step by the scalar-prefetched tables, and
+        returns a normalized (o, lse) pair per slot; the bucket bounds the
+        grid, the lengths what is fetched.  The window buffer's attention
+        merges with it by logsumexp, so no dense-equivalent linear view is
+        ever materialized (DSTACK_TPU_PAGED_ATTN_KERNEL, auto = TPU only;
+        int4 caches use the XLA path — the kernel reads int8 pages and
+        applies their scales in-kernel).
         """
         cfg = self.cfg
         b = self.batch_size

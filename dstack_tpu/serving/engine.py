@@ -1024,6 +1024,11 @@ class InferenceEngine:
                 best_w, best_c = w, c
         return best_w
 
+    def _inflight_steps(self) -> int:
+        """Steps of the window still in flight: what the host's lengths
+        lag the device's by."""
+        return self._pending["window"] if self._pending is not None else 0
+
     def _ragged_blocks(self, window: int) -> int:
         """Block-table columns the NEXT decode window can touch, rounded
         up to a power of two (bounds the jit-key cardinality at
@@ -1036,8 +1041,7 @@ class InferenceEngine:
         only over-sizes the bucket — never under."""
         if not self._ragged:
             return self._blocks_per_slot
-        inflight = (self._pending["window"]
-                    if self._pending is not None else 0)
+        inflight = self._inflight_steps()
         need = 0
         for slot_id, req in enumerate(self._slots):
             if req is None or slot_id in self._chunking:
@@ -1134,7 +1138,7 @@ class InferenceEngine:
                    "remaining_after": remaining - window,
                    "decoding": decoding, "expert_load": expert_load}
         if self.telemetry is not None:
-            self._record_dispatch(len(decoding), pending)
+            self._record_dispatch(decoding, pending, nbk)
         return pending
 
     def _kv_used_fraction(self) -> float:
@@ -1147,14 +1151,25 @@ class InferenceEngine:
         return (float(self._host_lengths.sum())
                 / max(self.batch_size * self.max_len, 1))
 
-    def _record_dispatch(self, n_decoding: int, pending: dict) -> None:
-        """Per-window telemetry at dispatch time (batch occupancy, KV
+    def _record_dispatch(self, decoding, pending: dict,
+                         nbk: Optional[int]) -> None:
+        """Per-window telemetry at dispatch time (batch occupancy, the
+        pages the window's table walk covers and those that are live, KV
         utilization, queue depth) + the monotonic stamp the drain uses
         for inter-token latency.  Only called when telemetry is on."""
         t = self.telemetry
         if t is None:  # callers gate too; cheap belt for new call sites
             return
-        t.record_window(n_decoding, self.batch_size)
+        live = walked = 0
+        if nbk is not None:
+            # rows the cache holds for a slot as this window starts: the
+            # host's count lags by a window still in flight (as in
+            # _ragged_blocks, which sized nbk to cover them)
+            held = (self._host_lengths[sorted(decoding)]
+                    + self._inflight_steps())
+            live = int(np.minimum(-(-held // self._block_size), nbk).sum())
+            walked = self.batch_size * nbk
+        t.record_window(len(decoding), self.batch_size, live, walked)
         t.record_kv_utilization(self._kv_used_fraction())
         t.record_queue_depth(self._queue.qsize())
         t.record_prefill_backlog(self._chunk_backlog())

@@ -26,6 +26,9 @@ MLA decode reads the pool through a gather, once a window (each slot's pages
 laid end to end, as the Llama family's XLA path does for K and V), not through
 ``paged_decode_attention``: that kernel takes K and V pools of one head
 width and scales by it, and MLA's key is 576 lanes where its value is 512.
+(A variant for the latent pool could keep that kernel's walk, a block of
+pages a grid step copied out of the pool by the tables and cut at the
+length, but not its products: here every head reads the SAME row.)
 """
 
 from __future__ import annotations

@@ -33,6 +33,12 @@ republished with project/run/job/replica labels):
   ``decode_tokens_total`` over ``decode_slot_steps_total`` is the share of
   that work that became a token; steps over
   ``batch_occupancy_count{phase=decode}`` is the mean window size
+- ``paged_walk_pages_total{kind}`` counter — per decode window of a paged
+  engine, at dispatch: ``walked`` = ``batch_size`` x the window's table
+  bucket, the pages a step's walk of the block tables covers;
+  ``live`` = the pages that hold the decoding slots' rows.  Live over
+  walked is the share of the walk that reads anything: what bounding the
+  walk further (a list of live blocks only) could still save
 - ``prefill_chunks_total`` / ``prefill_chunk_steps_total`` counters —
   chunked-prefill programs dispatched, and scheduling steps that
   dispatched any: their ratio is chunks per step (a step may spend up to
@@ -147,6 +153,10 @@ class EngineTelemetry:
         self.decode_steps = r.counter(PREFIX + "decode_steps_total")
         self.decode_slot_steps = r.counter(
             PREFIX + "decode_slot_steps_total")
+        self.walk_pages_live = r.counter(
+            PREFIX + "paged_walk_pages_total", labels={"kind": "live"})
+        self.walk_pages_walked = r.counter(
+            PREFIX + "paged_walk_pages_total", labels={"kind": "walked"})
         self.prefill_chunks = r.counter(PREFIX + "prefill_chunks_total")
         self.prefill_chunk_steps = r.counter(
             PREFIX + "prefill_chunk_steps_total")
@@ -236,10 +246,17 @@ class EngineTelemetry:
         if budget_exhausted:
             self.prefill_budget_exhausted.inc()
 
-    def record_window(self, decoding: int, batch_size: int) -> None:
+    def record_window(self, decoding: int, batch_size: int,
+                      live_pages: int = 0, walked_pages: int = 0) -> None:
+        """One decode window at dispatch.  A paged engine passes the pages
+        its decoding slots' rows lie in (``live_pages``) and the pages a
+        step's walk covers, every slot's table at the window's bucket
+        (``walked_pages``)."""
         self.active_slots.set(decoding)
         if batch_size > 0:
             self.decode_occupancy.observe(min(decoding / batch_size, 1.0))
+        self.walk_pages_live.inc(live_pages)
+        self.walk_pages_walked.inc(walked_pages)
 
     def record_drain(self, tokens_emitted: int, wall: float,
                      decoding: int = 1, steps: int = 0,

@@ -239,6 +239,7 @@ async def check_serving_metrics() -> int:
             "dstack_serving_decode_tokens_total",
             "dstack_serving_decode_steps_total",
             "dstack_serving_decode_slot_steps_total",
+            "dstack_serving_paged_walk_pages_total",
             "dstack_serving_programs_built_total",
             "dstack_serving_preemptions_total",
             "dstack_serving_moe_pairs_total",

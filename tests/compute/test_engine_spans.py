@@ -255,6 +255,46 @@ def test_decode_counters_add_up(traced):
     assert after["dstack_serving_decode_steps_total"] == 8 * windows
 
 
+def test_paged_walk_pages_by_hand(model):
+    """``paged_walk_pages_total``: per decode window, the pages a step's
+    table walk covers (all 4 slots x the window's bucket of columns) and
+    the pages the decoding slots' rows lie in, worked out by hand for one
+    request at a time over pages of 16 rows and windows of 8 steps."""
+    engine = _engine(model)
+
+    def walk():
+        c = _counters(engine)
+        return (c["dstack_serving_paged_walk_pages_total{kind=live}"],
+                c["dstack_serving_paged_walk_pages_total{kind=walked}"])
+
+    assert walk() == (0, 0)
+    # 40 rows lie in 3 pages; the window ends at 48 rows: 3 columns, in a
+    # bucket of 4, for each of the 4 slots.  One window: 8 tokens follow
+    # the prefill's first
+    engine.generate(list(range(1, 41)), max_new_tokens=9)
+    assert walk() == (3, 4 * 4)
+    # 100 rows (7 pages), two windows: 108 rows end in column 7 and 116
+    # in column 8, a bucket of 8 both; the second starts from 108 rows,
+    # still 7 pages
+    engine.generate(list(range(1, 101)), max_new_tokens=17)
+    live, walked = walk()
+    assert (live, walked) == (3 + 7 + 7, 16 + 2 * 4 * 8)
+    assert live <= walked
+    assert engine.telemetry.decode_occupancy.count == 3  # one inc a window
+
+
+def test_paged_walk_pages_are_zero_for_a_rows_cache(model):
+    """An engine that is not paged walks no table: both series stay 0
+    (and are there, so a dashboard's ratio has its terms)."""
+    engine = _engine(model, paged=False, total_kv_blocks=None,
+                     prefill_chunk=32)
+    engine.generate(list(range(1, 41)), max_new_tokens=9)
+    counters = _counters(engine)
+    assert engine.telemetry.decode_occupancy.count >= 1
+    assert counters["dstack_serving_paged_walk_pages_total{kind=live}"] == 0
+    assert counters["dstack_serving_paged_walk_pages_total{kind=walked}"] == 0
+
+
 def test_every_chunk_has_its_span(traced):
     """The 100-token prompt goes out as two chunks of one scheduling step
     (a budget of ``batch_size`` = 4): one ``engine.chunk`` span a chunk,

@@ -154,23 +154,46 @@ _PAGED_LAYERS = 3
 
 
 def _paged_case(seed=5, b=3, hkv=2, g=2, d=32, nb=9, bs=16, nbk=4,
-                layers=_PAGED_LAYERS):
+                layers=_PAGED_LAYERS, lengths=None, poison=None):
     """q, the STACKED pools per head ([L, nb, bs, hkv, d], what the
-    reference reads a layer of), tables, lengths."""
+    reference reads a layer of), tables, lengths.
+
+    Without ``lengths``: the three slots every kernel test started from
+    (empty, ends EXACTLY on a page edge, ragged across one).  With
+    ``lengths``: one slot each, its pages dealt out of the pool in a
+    scrambled order, NULL (0) in the columns it does not own.  ``poison``
+    (a float) fills the NULL block, every page no slot owns AND the rows of
+    an owned page past its slot's length: nothing of them may reach the
+    output."""
     key = jax.random.PRNGKey(seed)
+    if lengths is None:
+        assert (b, nbk) == (3, 4)
+        tables = np.asarray([[1, 0, 0, 0], [2, 0, 0, 0], [3, 4, 5, 6]])
+        lengths = [0, bs, 50]
+    else:
+        b = len(lengths)
+        need = [-(-n // bs) for n in lengths]
+        assert max(need) <= nbk and sum(need) < nb, (need, nbk, nb)
+        order = np.random.default_rng(seed).permutation(np.arange(1, nb))
+        tables, at = np.zeros((b, nbk), np.int64), 0
+        for slot, n in enumerate(need):
+            tables[slot, :n] = order[at:at + n]
+            at += n
     q = jax.random.normal(jax.random.fold_in(key, 0), (b, hkv, g, d),
                           jnp.float32)
-    k_pages = jax.random.normal(jax.random.fold_in(key, 1),
-                                (layers, nb, bs, hkv, d), jnp.float32)
-    v_pages = jax.random.normal(jax.random.fold_in(key, 2),
-                                (layers, nb, bs, hkv, d), jnp.float32)
-    # slot 0 empty, slot 1 ends EXACTLY on a block boundary, slot 2 ragged
-    # across a boundary mid-block; NULL (0) entries pad unused columns
-    tables = jnp.asarray([[1, 0, 0, 0],
-                          [2, 0, 0, 0],
-                          [3, 4, 5, 6]], jnp.int32)
-    lengths = jnp.asarray([0, bs, 50], jnp.int32)
-    return q, k_pages, v_pages, tables, lengths
+    k_pages = np.array(jax.random.normal(
+        jax.random.fold_in(key, 1), (layers, nb, bs, hkv, d), jnp.float32))
+    v_pages = np.array(jax.random.normal(
+        jax.random.fold_in(key, 2), (layers, nb, bs, hkv, d), jnp.float32))
+    if poison is not None:
+        dead = np.ones((nb, bs), bool)
+        for slot, n in enumerate(lengths):
+            for col in range(-(-n // bs)):
+                dead[tables[slot, col], :min(bs, n - col * bs)] = False
+        k_pages[:, dead] = poison
+        v_pages[:, dead] = poison
+    return (q, jnp.asarray(k_pages), jnp.asarray(v_pages),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(lengths, jnp.int32))
 
 
 def _lanes(pages):
@@ -201,29 +224,76 @@ def _paged_reference(q, k_pages, v_pages, tables, lengths, scale):
     return o, lse
 
 
-def test_paged_decode_matches_reference():
+#: what the block walk can get wrong, one case each.  A compute block is P
+#: pages (here the largest power of two <= the table's width: the pages
+#: are small, the VMEM budget never binds), so at nbk 4..7 a block is 4
+#: pages of 16 rows = 64 rows.
+_PAGED_CASES = {
+    # the three slots the tests began with: empty, page edge, ragged
+    "empty-edge-ragged": dict(),
+    # a length that ends inside a block's FIRST page (64 + 5), ON a page
+    # edge inside a block (64 + 16), ON a block edge (128), one row past it
+    "first-page-of-block": dict(nbk=8, nb=40, lengths=[69, 80, 128, 65]),
+    # an empty slot beside a full one, first and last in the batch: the
+    # prefetch has to skip it and the flush still writes o = 0
+    "empty-beside-full": dict(nbk=4, nb=20, lengths=[0, 64, 0, 64, 0]),
+    # a width P does not divide (7 = 4 + a cut block of 3, which holds
+    # live pages) and a width of ONE page
+    "width-not-divided": dict(nbk=7, nb=30, lengths=[112, 100, 3, 64]),
+    "width-one-page": dict(nbk=1, nb=6, lengths=[16, 0, 7]),
+    # NaN / inf in everything dead: the NULL block, unowned pages, the
+    # rows of an owned page past the length.  Slots of 3 blocks beside
+    # slots that end early in a block, so that a cut block's dead pages
+    # lie over rows an earlier full block left in BOTH buffers
+    "poison-nan": dict(nbk=12, nb=60, poison=np.nan,
+                       lengths=[192, 70, 0, 130, 1, 191]),
+    "poison-inf": dict(nbk=12, nb=60, poison=np.inf,
+                       lengths=[192, 70, 0, 130, 1, 191]),
+    # the two serving cells' head geometry, 2 and 3 blocks a slot (P = 8
+    # pages of 32 rows): MHA 32/32 at d 64 and GQA 32/8 at d 128
+    "mha-32x32-d64": dict(hkv=32, g=1, d=64, bs=32, nbk=24, nb=50, layers=1,
+                          lengths=[600, 0, 257, 512], poison=np.nan),
+    "gqa-32x8-d128": dict(hkv=8, g=4, d=128, bs=32, nbk=24, nb=50, layers=1,
+                          lengths=[600, 0, 257, 512], poison=np.nan),
+}
+
+
+#: one trace a shape: the layer is a run-time scalar
+_paged_jit = jax.jit(paged_decode_attention)
+
+
+def _assert_paged_matches(o, lse, want_o, want_lse, lengths, tol):
+    o, lse, lengths = np.asarray(o), np.asarray(lse), np.asarray(lengths)
+    assert np.all(np.isfinite(o))
+    np.testing.assert_allclose(o, want_o, atol=tol, rtol=tol)
+    live = lengths > 0
+    np.testing.assert_allclose(lse[live], want_lse[live], atol=tol, rtol=tol)
+    # an empty slot's halves are the logsumexp-merge identity: o = 0 and
+    # an lse so low that exp(lse - anything) underflows to exactly 0 (the
+    # kernel uses a finite -1e30 sentinel, not IEEE -inf, so the merge
+    # arithmetic stays NaN-free)
+    assert np.all(o[~live] == 0.0)
+    assert np.all(lse[~live] <= -1e29)
+    assert np.all(np.exp(lse[~live]) == 0.0)
+
+
+@pytest.mark.parametrize("case", sorted(_PAGED_CASES))
+def test_paged_decode_matches_reference(case):
     """Block-table walk vs a dense gather+softmax reference: ragged lengths
     (empty slot -> o=0/lse=-inf, exact-boundary slot, mid-block slot), no
     dense [B, max_len] intermediate on the kernel side; every layer of the
     stacked pool against its own reference."""
-    q, kp, vp, tables, lengths = _paged_case()
+    kwargs = _PAGED_CASES[case]
+    q, kp, vp, tables, lengths = _paged_case(**kwargs)
     scale = q.shape[-1] ** -0.5
-    for layer in range(_PAGED_LAYERS):
-        o, lse = paged_decode_attention(q, _lanes(kp), _lanes(vp), layer,
-                                        tables, lengths)
-        want_o, want_lse = _paged_reference(q, kp[layer], vp[layer], tables,
-                                            lengths, scale)
-        np.testing.assert_allclose(np.asarray(o), want_o, atol=1e-5,
-                                   rtol=1e-5)
-        np.testing.assert_allclose(np.asarray(lse)[1:], want_lse[1:],
-                                   atol=1e-5, rtol=1e-5)
-        # the empty slot's halves are the logsumexp-merge identity: o = 0
-        # and an lse so low that exp(lse - anything) underflows to exactly
-        # 0 (the kernel uses a finite -1e30 sentinel, not IEEE -inf, so the
-        # merge arithmetic stays NaN-free)
-        assert np.all(np.asarray(o)[0] == 0.0)
-        assert np.all(np.asarray(lse)[0] <= -1e29)
-        assert np.all(np.exp(np.asarray(lse)[0]) == 0.0)
+    # the reference may read poison only where it is masked: clean it
+    clean = [jnp.nan_to_num(x, nan=0.0, posinf=0.0) for x in (kp, vp)]
+    for layer in range(kp.shape[0]):
+        o, lse = _paged_jit(q, _lanes(kp), _lanes(vp), jnp.int32(layer),
+                            tables, lengths)
+        want_o, want_lse = _paged_reference(
+            q, clean[0][layer], clean[1][layer], tables, lengths, scale)
+        _assert_paged_matches(o, lse, want_o, want_lse, lengths, 1e-5)
 
 
 def test_paged_decode_layer_index_is_traced():
@@ -242,46 +312,70 @@ def test_paged_decode_layer_index_is_traced():
     assert not np.allclose(outs[0], outs[1], atol=1e-3)
 
 
-def test_paged_decode_ragged_table_slice_is_exact():
+@pytest.mark.parametrize("full,cut,fits", [
+    # the same pages a step (P = 4 at both widths): the same numbers, bit
+    # for bit, from a walk one block shorter
+    (7, 4, 50),
+    # an odd width, and one narrower than the full table's block (P = 2
+    # against 4): the same attention set summed in other blocks
+    (4, 3, 40),
+    (4, 2, 30),
+    (4, 1, 16),
+])
+def test_paged_decode_ragged_table_slice_is_exact(full, cut, fits):
     """A table sliced to the ragged bucket (the engine's fast path) walks
     fewer pages but must produce the SAME numbers when every length fits
     the slice."""
-    q, kp, vp, tables, lengths = _paged_case()
+    from dstack_tpu.ops.flash_attention import _pages_per_step
+
+    q, kp, vp, tables, lengths = _paged_case(
+        nbk=full, nb=20, lengths=[0, 16, min(fits, 50), fits])
     kp, vp = _lanes(kp), _lanes(vp)
-    lengths = jnp.minimum(lengths, 30)  # everything fits 2 blocks
+    same_block = (_pages_per_step(kp[0, 0].nbytes, full)
+                  == _pages_per_step(kp[0, 0].nbytes, cut))
+    assert same_block == (cut == 4)
     for layer in range(_PAGED_LAYERS):
-        o_full, lse_full = paged_decode_attention(q, kp, vp, layer, tables,
-                                                  lengths)
-        o_cut, lse_cut = paged_decode_attention(q, kp, vp, layer,
-                                                tables[:, :2], lengths)
-        np.testing.assert_array_equal(np.asarray(o_full), np.asarray(o_cut))
-        np.testing.assert_array_equal(np.asarray(lse_full),
-                                      np.asarray(lse_cut))
+        o_full, lse_full = _paged_jit(q, kp, vp, jnp.int32(layer), tables,
+                                      lengths)
+        o_cut, lse_cut = _paged_jit(q, kp, vp, jnp.int32(layer),
+                                    tables[:, :cut], lengths)
+        # another block size sums the same rows in another order
+        tol = dict(rtol=0, atol=0) if same_block else dict(rtol=2e-6,
+                                                            atol=2e-6)
+        np.testing.assert_allclose(np.asarray(o_full), np.asarray(o_cut),
+                                   **tol)
+        np.testing.assert_allclose(np.asarray(lse_full), np.asarray(lse_cut),
+                                   **tol)
 
 
-def test_paged_decode_int8_pages_match_dequantized_reference():
+@pytest.mark.parametrize("case", ["empty-edge-ragged", "first-page-of-block",
+                                  "width-not-divided", "poison-nan"])
+def test_paged_decode_int8_pages_match_dequantized_reference(case):
     """int8 {"q","s"} pages dequantize IN-KERNEL (per-row f32 scales) —
     against the float reference computed on the dequantized pool the only
-    difference is float association, not quantization handling."""
+    difference is float association, not quantization handling.  Poison
+    goes into the SCALES of everything dead (an int8 page holds no NaN)."""
     from dstack_tpu.serving.quant import dequantize_kv, quantize_kv
 
-    q, kp, vp, tables, lengths = _paged_case()
+    kwargs = dict(_PAGED_CASES[case])
+    poison = kwargs.pop("poison", None)
+    q, kp, vp, tables, lengths = _paged_case(**kwargs)
     kq, ks = quantize_kv(kp)
     vq, vs = quantize_kv(vp)
     k_deq = dequantize_kv(kq, ks, jnp.float32)
     v_deq = dequantize_kv(vq, vs, jnp.float32)
+    if poison is not None:
+        marked = _paged_case(**kwargs, poison=poison)[1]
+        dead = jnp.isnan(marked[..., 0, 0])[..., None]      # [L, nb, bs, 1]
+        ks, vs = jnp.where(dead, poison, ks), jnp.where(dead, poison, vs)
     for layer in range(_PAGED_LAYERS):
-        o, lse = paged_decode_attention(
+        o, lse = _paged_jit(
             q, {"q": _lanes(kq), "s": ks}, {"q": _lanes(vq), "s": vs},
-            layer, tables, lengths)
+            jnp.int32(layer), tables, lengths)
         want_o, want_lse = _paged_reference(
             q, k_deq[layer], v_deq[layer], tables, lengths,
             q.shape[-1] ** -0.5)
-        np.testing.assert_allclose(np.asarray(o), want_o, atol=1e-4,
-                                   rtol=1e-4)
-        np.testing.assert_allclose(np.asarray(lse)[1:], want_lse[1:],
-                                   atol=1e-4, rtol=1e-4)
-        assert np.all(np.asarray(lse)[0] <= -1e29)  # empty slot sentinel
+        _assert_paged_matches(o, lse, want_o, want_lse, lengths, 1e-4)
 
 
 def test_paged_decode_rejects_int4_pages():

@@ -114,6 +114,13 @@ PAGED_HEADS = {
 PAGED_BLOCKS = 16385
 
 
+#: the pages' element type as compiled HLO names it: the f32 scales of
+#: int8 pages, padded to whole lane tiles around the call, can be as many
+#: ELEMENTS as a layer of the pages (two layers x 128 lanes against one x
+#: 256) and are no copy of it
+_PAGES_HLO_TYPE = {"bf16": "bf16", "int8": "s8"}
+
+
 def _pool(sds, pages, lead, hkv, d):
     """The stacked paged pool as the engine stores it: kv heads folded
     into the lane dim."""
@@ -121,6 +128,19 @@ def _pool(sds, pages, lead, hkv, d):
         return sds(lead + (hkv * d,), jnp.bfloat16)
     return {"q": sds(lead + (hkv * d,), jnp.int8),
             "s": sds(lead + (hkv,), jnp.float32)}
+
+
+def _paged_call(fn, *args):
+    """(grid, VMEM scratch bytes) of the one Pallas call ``fn`` makes on
+    ``args``, read from the call as traced."""
+    calls = [e for e in jax.make_jaxpr(fn)(*args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1, "one kernel call a layer-step, not a pair"
+    mapping = calls[0].params["grid_mapping"]
+    scratch = sum(
+        math.prod(a.shape) * a.dtype.itemsize for a in mapping.scratch_avals
+        if str(getattr(a, "memory_space", "")) == "vmem")
+    return mapping.grid, scratch
 
 
 @pytest.mark.parametrize("pages", ["bf16", "int8"])
@@ -146,20 +166,89 @@ def test_paged_decode_compiles(one_chip, real_lowering, geometry, pages):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     if (hkv * d) % 128:
-        # any lane width compiles (the block spans the whole last dim), but
-        # only whole tiles are stored row-major: the compiler keeps a
-        # narrower or ragged pool with the BLOCKS minor-most and converts
-        # all of it into the operand's form around the call.  The engine
-        # warns at start (test_serving.py); nothing to hold here but that
-        # the lowering takes it.
+        # any lane width compiles, but only whole tiles are stored
+        # row-major: the compiler keeps a narrower or ragged pool with the
+        # BLOCKS minor-most and converts all of it into the operand's form
+        # around the call, and the kernel's own copies take whole 128-lane
+        # tiles, so the call pads it besides.  The engine warns at start
+        # (test_serving.py); nothing to hold here but that the lowering
+        # takes it.
         return
-    assert not _pool_sized_ops(text, num_blocks * bs * hkv * d, layers)
+    assert not _pool_sized_ops(text, num_blocks * bs * hkv * d, layers,
+                               _PAGES_HLO_TYPE[pages])
     if pages == "bf16":
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
     # int8: the f32 scales [.., BS, Hkv] are kept with the blocks minor-most
     # (Hkv lanes of 128 would pad them 16x) and converted to the operand's
-    # row-major form, whole, around the call: small beside the pages, and
-    # once a decode window in the engine's program (PERF.md, open questions)
+    # row-major, lane-padded form, whole, around the call: small beside the
+    # pages, and once a decode window in the engine's program (PERF.md,
+    # open questions)
+
+
+#: the decode-window calls of the benchmark's two dense cells: (heads, kv
+#: heads, head_dim, layers, blocks, slots, widest table bucket)
+CELL_CALLS = {
+    "smollm2-1.7b.chat": (32, 32, 64, 24, 768, 32, 64),
+    "mistral-7b-v0.3-16l.batch": (32, 8, 128, 16, 2048, 16, 128),
+}
+
+
+@pytest.mark.parametrize("pages", ["bf16", "int8"])
+@pytest.mark.parametrize("cell", sorted(CELL_CALLS))
+def test_paged_decode_grid_follows_the_page_bytes(one_chip, real_lowering,
+                                                  cell, pages):
+    """The two cells' own calls compile, and their grid is the one the
+    page-bytes rule gives: P pages a step from the bytes of a page against
+    the scratch budget (K and V, two buffers each), ``slots x ceil(columns
+    / P)`` steps, the block buffers inside the budget and the whole
+    scratch far inside v5e's 16 MiB of scoped VMEM."""
+    hq, hkv, d, layers, blocks, slots, columns = CELL_CALLS[cell]
+    bs = 32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    kv = _pool(sds, pages, (layers, blocks, bs), hkv, d)
+    args = (sds((slots, hkv, hq // hkv, d), jnp.bfloat16), kv, kv,
+            sds((), jnp.int32), sds((slots, columns), jnp.int32),
+            sds((slots,), jnp.int32))
+    itemsize = 2 if pages == "bf16" else 1
+    page_bytes = bs * hkv * d * itemsize
+    per_step = fa._pages_per_step(page_bytes, columns)
+    # the rule, worked out: 4 MiB over 4 block buffers of P pages each
+    assert per_step == {("smollm2-1.7b.chat", "bf16"): 8,
+                        ("smollm2-1.7b.chat", "int8"): 16,
+                        ("mistral-7b-v0.3-16l.batch", "bf16"): 16,
+                        ("mistral-7b-v0.3-16l.batch", "int8"): 32}[cell, pages]
+    assert 4 * per_step * page_bytes <= fa._PAGED_SCRATCH_BYTES
+    grid, scratch = _paged_call(fa.paged_decode_attention, *args)
+    assert grid == (slots, -(-columns // per_step))
+    assert scratch < 8 << 20
+    compiled = jax.jit(fa.paged_decode_attention).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "paged_decode_attention" in text
+    assert not _pool_sized_ops(text, blocks * bs * hkv * d, layers,
+                               _PAGES_HLO_TYPE[pages])
+    if pages == "bf16":
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_pages_per_step_rule():
+    """P from what the call can see: a power of two, bounded by the scratch
+    budget and by the table's width, 1 at the least."""
+    budget = fa._PAGED_SCRATCH_BYTES
+    assert fa._pages_per_step(128 << 10, 64) == 8       # chat, bf16
+    assert fa._pages_per_step(64 << 10, 128) == 16      # batch, bf16
+    assert fa._pages_per_step(64 << 10, 8) == 8         # a narrow bucket
+    assert fa._pages_per_step(64 << 10, 7) == 4         # ... an odd one
+    assert fa._pages_per_step(64 << 10, 1) == 1
+    assert fa._pages_per_step(budget, 64) == 1          # a page too large
+    for page_bytes in (1 << 10, 48 << 10, 128 << 10, 1 << 20):
+        for width in (1, 2, 3, 13, 64, 128):
+            p = fa._pages_per_step(page_bytes, width)
+            assert p >= 1 and p & (p - 1) == 0 and p <= max(width, 1)
+            assert p == 1 or 4 * p * page_bytes <= budget
 
 
 @pytest.mark.parametrize("pages", ["bf16", "int8"])
@@ -195,7 +284,8 @@ def test_paged_decode_compiles_under_tensor_mesh(topo, real_lowering, pages):
         sds(P())((b,), jnp.int32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert not _pool_sized_ops(text, num_blocks * bs * hkv * d // 4, layers)
+    assert not _pool_sized_ops(text, num_blocks * bs * hkv * d // 4, layers,
+                               _PAGES_HLO_TYPE[pages])
 
 
 # ---------------------------------------------------------------------------
