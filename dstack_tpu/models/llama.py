@@ -95,6 +95,17 @@ class LlamaConfig:
         )
 
     @property
+    def ut_steps(self) -> int:
+        """Passes over the layer stack: 1, but for a looped decoder
+        (``models/ouro.py``, whose config makes this a field)."""
+        return 1
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of K/V a token holds: a pass attends its own."""
+        return self.ut_steps * self.num_layers
+
+    @property
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
 

@@ -1,9 +1,11 @@
 """The Llama family behind the engine's seam: what its memory is (K and V
 rows, a dense row per slot or a paged pool; plain, int8 or int4), what a
 layer computes (pre-norm attention with RoPE, then a dense SwiGLU or a
-Mixtral-style routed MLP), and the programs the scheduler dispatches over
-them (whole-prompt prefill, one chunk, a decode window, the PD wire's
-export and insert).
+Mixtral-style routed MLP; a looped decoder's sandwich norms), how often the
+layer stack runs (once, or ``cfg.ut_steps`` passes over the same weights,
+each pass with cache layers of its own: ``models/ouro.py``), and the
+programs the scheduler dispatches over them (whole-prompt prefill, one
+chunk, a decode window, the PD wire's export and insert).
 
 The engine (``serving/engine.py``) owns slots, blocks, queues, windows,
 sampling and the order of dispatch; it hands this class's two state trees,
@@ -23,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dstack_tpu.models import ouro
 from dstack_tpu.models.llama import (
     LlamaConfig,
     Params,
@@ -61,7 +64,8 @@ def _paged_kernel_default() -> bool:
 # Device-side regions carry a jax.named_scope so that a profiler trace and an
 # HLO dump say which part of a program an operation belongs to: qkv, attn,
 # paged_attn, mlp, lm_head, sample, kv_insert (prefill's write of a prompt's
-# K/V), kv_window_write (the decode window's one write at its end).
+# K/V), kv_window_write (the decode window's one write at its end), and for a
+# looped decoder loop_pass (one pass over the layer stack) and exit_gate.
 
 
 @jax.named_scope("mlp")
@@ -96,34 +100,78 @@ def _mlp_block(h, lp, cfg: LlamaConfig, token_mask=None):
     return out
 
 
-def _layer_kv(params, cfg: LlamaConfig, x, positions, inv_freqs,
+def _layer_kv(params, cfg: LlamaConfig, x, positions, inv_freqs, length,
               token_mask=None):
     """Per-layer K/V for a full sequence — shared by prefill.
-    ``token_mask`` [B, S] marks real (non-padding) tokens for MoE routing."""
+    ``token_mask`` [B, S] marks real (non-padding) tokens for MoE routing;
+    ``length`` picks the row a looped decoder's passes hand on
+    (:func:`_layer_passes`)."""
     b, s, _ = x.shape
 
-    def layer(carry, lp):
-        x = carry
+    def layer(carry, inputs):
+        (x,), (lp,) = carry, inputs
         q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, b, s)
         attn = _masked_attention(q, k, v, positions, positions)
-        x = x + qmatmul(attn.reshape(b, s, cfg.q_dim),
-                       lp["wo"], cfg.dtype)
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp_block(h, lp, cfg, token_mask)
-        return x, (k, v)
+        return (_layer_tail(x, attn, lp, cfg, token_mask),), (k, v)
 
-    x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
-    return x, ks, vs  # ks/vs: [L, B, S, Hkv, D]
+    (x,), (ks, vs), states = _layer_passes(params, cfg, layer, (x,), (),
+                                           length)
+    return x, ks, vs, states  # ks/vs: [cache layers, B, S, Hkv, D]
 
 
-def _last_logits(params, cfg: LlamaConfig, x, length):
-    """Logits at the last of ``length`` real positions of a [1, S, D]
-    prefill activation."""
-    with jax.named_scope("lm_head"):
+def _layer_passes(params, cfg: LlamaConfig, layer, carry, xs, length=None):
+    """The layer stack over ``carry``, as often as the model runs it.
+
+    ``layer(carry, (lp, *xs_l))`` is a program's layer body, ``carry`` its
+    scan carry (a tuple that starts with the activations x), ``xs`` what it
+    scans beside the layers' weights, a leading dim of ``cfg.cache_layers``
+    each.  Returns (carry, the layers' ys stacked per CACHE layer, states).
+
+    A plain decoder scans its layers once and ``states`` is None.  A looped
+    one (``cfg.ut_steps`` > 1) scans the SAME stacked weights once a pass,
+    pass t over cache layers [t*L, (t+1)*L); the shared final norm ends
+    every pass, and ``states`` [T, ..., D] holds each pass's normed output
+    for :func:`_output_rows`: whole, or of a prefill's [1, S, D] the row at
+    ``length`` - 1."""
+    if cfg.ut_steps == 1:
+        carry, ys = jax.lax.scan(layer, carry, (params["layers"],) + xs)
+        return carry, ys, None
+    passes = (cfg.ut_steps, cfg.num_layers)
+
+    @jax.named_scope("loop_pass")
+    def one_pass(carry, xs_t):
+        (x, *rest), ys = jax.lax.scan(layer, carry,
+                                      (params["layers"],) + xs_t)
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        return (x, *rest), (ys, x if length is None else x[0, length - 1])
+
+    carry, (ys, states) = jax.lax.scan(one_pass, carry, jax.tree.map(
+        lambda a: a.reshape(passes + a.shape[1:]), xs), length=passes[0])
+    return carry, jax.tree.map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), ys), states
+
+
+def _output_rows(params, cfg: LlamaConfig, x, states):
+    """The rows the head reads and, for a looped decoder, the pass each was
+    taken from: the final norm of a plain stack's output ``x``, or (every
+    pass's output is normed already) the pass the exit gate selects among
+    ``states`` (:func:`_layer_passes`)."""
+    if states is None:
+        with jax.named_scope("lm_head"):
+            return rms_norm(x, params["final_norm"], cfg.rms_eps), None
+    return ouro.exit_select(params, cfg, states)
+
+
+def _last_logits(params, cfg: LlamaConfig, x, length, states):
+    """Logits at the last of ``length`` real positions of a [1, S, D]
+    prefill activation (a looped decoder: of ``states``, that row of every
+    pass)."""
+    rows, _ = _output_rows(params, cfg, x, states)
+    with jax.named_scope("lm_head"):
         head = output_head(params, cfg)
-        return qmatmul(x[0, length - 1, :], head, cfg.dtype,
-                       preferred=jnp.float32)
+        if states is None:
+            rows = rows[0, length - 1, :]
+        return qmatmul(rows, head, cfg.dtype, preferred=jnp.float32)
 
 
 def _prompt_forward(params, cfg: LlamaConfig, padded, length, bucket: int):
@@ -135,8 +183,9 @@ def _prompt_forward(params, cfg: LlamaConfig, padded, length, bucket: int):
         cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
     x = params["embed"].astype(cfg.dtype)[padded][None, :, :]
     token_mask = (jnp.arange(bucket)[None, :] < length)
-    x, ks, vs = _layer_kv(params, cfg, x, positions, inv_freqs, token_mask)
-    return _last_logits(params, cfg, x, length), ks, vs
+    x, ks, vs, states = _layer_kv(params, cfg, x, positions, inv_freqs,
+                                  length, token_mask)
+    return _last_logits(params, cfg, x, length, states), ks, vs
 
 
 @jax.named_scope("qkv")
@@ -157,11 +206,20 @@ def _decode_qkv(x, lp, cfg: LlamaConfig, positions, inv_freqs, b: int,
             apply_rope(k, positions, inv_freqs), v)
 
 
-def _decode_layer_tail(x, attn, lp, cfg: LlamaConfig, b: int):
-    """Post-attention half of a decode layer (wo + MLP)."""
-    x = x + qmatmul(attn.reshape(b, 1, cfg.q_dim), lp["wo"], cfg.dtype)
+def _layer_tail(x, attn, lp, cfg: LlamaConfig, token_mask=None):
+    """Post-attention half of a layer over [B, S, D] activations: wo and
+    the MLP, each added to the residual, behind a norm of its own where the
+    layer has one (a looped decoder's sandwich, ``models/ouro.py``)."""
+    b, s, _ = x.shape
+    branch = qmatmul(attn.reshape(b, s, cfg.q_dim), lp["wo"], cfg.dtype)
+    if "attn_out_norm" in lp:
+        branch = rms_norm(branch, lp["attn_out_norm"], cfg.rms_eps)
+    x = x + branch
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    return x + _mlp_block(h, lp, cfg)
+    branch = _mlp_block(h, lp, cfg, token_mask)
+    if "mlp_out_norm" in lp:
+        branch = rms_norm(branch, lp["mlp_out_norm"], cfg.rms_eps)
+    return x + branch
 
 
 def _kv_mat(cache_leaf, dtype):
@@ -258,10 +316,7 @@ def _suffix_layer(x, lp, cfg: LlamaConfig, positions, inv_freqs, kv_pos,
     kv_k = _kv_mat(gather(layer_k), cfg.dtype)
     kv_v = _kv_mat(gather(layer_v), cfg.dtype)
     attn = _masked_attention(q, kv_k, kv_v, positions, kv_pos)
-    x = x + qmatmul(attn.reshape(1, sbucket, cfg.q_dim), lp["wo"], cfg.dtype)
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    x = x + _mlp_block(h, lp, cfg, token_mask)
-    return x, layer_k, layer_v
+    return _layer_tail(x, attn, lp, cfg, token_mask), layer_k, layer_v
 
 
 @jax.named_scope("attn")
@@ -362,6 +417,9 @@ class DensePrograms:
         from dstack_tpu.models.moe import MoEConfig, init_params as moe_init
 
         cfg, mesh = self.cfg, self.mesh
+        init = (moe_init if isinstance(cfg, MoEConfig)
+                else ouro.init_params if isinstance(cfg, ouro.OuroConfig)
+                else init_params)
         self._is_moe = (
             isinstance(cfg, MoEConfig)
             or (params is not None and "router" in (
@@ -380,7 +438,6 @@ class DensePrograms:
                 # init directly sharded — the full model must never
                 # materialize on one device (the whole point of mesh serving
                 # is models that don't fit one chip's HBM)
-                init = moe_init if isinstance(cfg, MoEConfig) else init_params
                 shapes = jax.eval_shape(
                     lambda: init(jax.random.PRNGKey(0), cfg))
                 params = named_jit(
@@ -389,8 +446,7 @@ class DensePrograms:
                     out_shardings=self._param_shardings(shapes),
                 )()
             else:
-                params = (moe_init if isinstance(cfg, MoEConfig)
-                          else init_params)(jax.random.PRNGKey(rng_seed), cfg)
+                params = init(jax.random.PRNGKey(rng_seed), cfg)
         elif mesh is not None:
             # host (numpy / checkpoint) arrays transfer shard-wise here;
             # already-committed device arrays get resharded
@@ -437,7 +493,9 @@ class DensePrograms:
                            if self.mesh.shape.get("expert", 1) > 1 else None)
             specs = moe_mod.param_specs(self.cfg, self._policy, expert_axis)
         else:
-            specs = llama_mod.param_specs(self.cfg, self._policy)
+            model = (ouro if isinstance(self.cfg, ouro.OuroConfig)
+                     else llama_mod)
+            specs = model.param_specs(self.cfg, self._policy)
         # Serving overrides vs the training specs:
         # - embed replicated: decode reads ONE row per token — a
         #   vocab-sharded table would make SPMD all-gather the whole table
@@ -483,8 +541,8 @@ class DensePrograms:
         """``(cache_k, cache_v)``, zeroed: dense rows per slot, or the
         paged pool."""
         cfg, b = self.cfg, self.batch_size
-        lead = ((cfg.num_layers, self.num_blocks, self.block_size)
-                if self.paged else (cfg.num_layers, b, self.max_len))
+        lead = ((cfg.cache_layers, self.num_blocks, self.block_size)
+                if self.paged else (cfg.cache_layers, b, self.max_len))
         hkv = cfg.num_kv_heads
         scales = lead + (hkv,)
 
@@ -518,6 +576,24 @@ class DensePrograms:
         row of K and V belongs to a token."""
         return 0
 
+    def kv_geometry(self) -> tuple:
+        """(cache layers, bytes of K and V one token holds over them: int8
+        and int4 rows carry a float32 scale a kv head)."""
+        cfg = self.cfg
+        head_bytes = {None: cfg.head_dim * jnp.dtype(cfg.dtype).itemsize,
+                      "int8": cfg.head_dim + 4,
+                      "int4": cfg.head_dim // 2 + 4}[self.kv_quantize]
+        return cfg.cache_layers, (2 * cfg.cache_layers * cfg.num_kv_heads
+                                  * head_bytes)
+
+    def record_window_counts(self, telemetry, counts) -> None:
+        """A drained window's last output, which only a looped decoder
+        returns: the passes its steps ran, then its tokens by exit pass."""
+        if telemetry is None:
+            return
+        passes, *exit_tokens = counts.tolist()
+        telemetry.record_loop_passes(passes, exit_tokens)
+
     def slot_target(self, slot_id, pages):
         """Where a prefill or chunk program writes: the slot's ``pages``
         (block ids or its table row) when paged, its row of the cache
@@ -546,7 +622,8 @@ class DensePrograms:
             logits, ks, vs = _prompt_forward(params, cfg, tokens, length,
                                              bucket)
 
-            # insert prompt K/V into the slot: [L, bucket, Hkv, D] -> cache
+            # insert prompt K/V into the slot: [cache layers, bucket, Hkv,
+            # D] -> cache
             def insert(leaf, rows):
                 start = (0, slot) + (0,) * (leaf.ndim - 2)
                 return jax.lax.dynamic_update_slice(
@@ -593,9 +670,9 @@ class DensePrograms:
 
             def layer(carry, inputs):
                 # the pool travels in the carry and is addressed at
-                # [layer, block, offset] by flat row: scanned as xs/ys it
-                # would be sliced and restacked, a copy of the layer's
-                # whole pool each way
+                # [cache layer, block, offset] by flat row: scanned as
+                # xs/ys it would be sliced and restacked, a copy of the
+                # layer's whole pool each way
                 x, pool_k, pool_v = carry
                 lp, l = inputs
                 scatter = lambda leaf, rows: _scatter_rows(
@@ -610,10 +687,10 @@ class DensePrograms:
                     pool_k, pool_v, scatter, gather, lanes=True)
                 return (x, pool_k, pool_v), None
 
-            (x, cache_k, cache_v), _ = jax.lax.scan(
-                layer, (x, cache_k, cache_v),
-                (params["layers"], jnp.arange(cfg.num_layers)))
-            logits = _last_logits(params, cfg, x, suffix_len)
+            (x, cache_k, cache_v), _, states = _layer_passes(
+                params, cfg, layer, (x, cache_k, cache_v),
+                (jnp.arange(cfg.cache_layers),), suffix_len)
+            logits = _last_logits(params, cfg, x, suffix_len, states)
             return logits, cache_k, cache_v
 
         return fn
@@ -654,16 +731,15 @@ class DensePrograms:
                         leaf, slot, 0, keepdims=True), layer_kv)
 
             def layer(carry, inputs):
-                x = carry
-                lp, layer_k, layer_v = inputs
+                (x,), (lp, layer_k, layer_v) = carry, inputs
                 x, layer_k, layer_v = _suffix_layer(
                     x, lp, cfg, positions, inv_freqs, kv_pos, token_mask,
                     layer_k, layer_v, insert, gather)
-                return x, (layer_k, layer_v)
+                return (x,), (layer_k, layer_v)
 
-            x, (cache_k, cache_v) = jax.lax.scan(
-                layer, x, (params["layers"], cache_k, cache_v))
-            logits = _last_logits(params, cfg, x, chunk_len)
+            (x,), (cache_k, cache_v), states = _layer_passes(
+                params, cfg, layer, (x,), (cache_k, cache_v), chunk_len)
+            logits = _last_logits(params, cfg, x, chunk_len, states)
             return logits, cache_k, cache_v
 
         return fn
@@ -682,7 +758,7 @@ class DensePrograms:
                 # the new rows take the pool's blocked form, never the
                 # pool theirs: whole blocks, every layer, in place
                 blocked = rows.reshape(
-                    (cfg.num_layers, nblk, bs) + rows.shape[2:])
+                    (cfg.cache_layers, nblk, bs) + rows.shape[2:])
                 return leaf.at[:, bids].set(blocked)
 
             with jax.named_scope("kv_insert"):
@@ -726,7 +802,7 @@ class DensePrograms:
 
             def insert(leaf, rows):
                 blocked = rows.reshape(
-                    (cfg.num_layers, nblk, bs) + rows.shape[2:])
+                    (cfg.cache_layers, nblk, bs) + rows.shape[2:])
                 return leaf.at[:, target].set(blocked)
 
         else:
@@ -815,7 +891,7 @@ class DensePrograms:
             # gather, and no per-layer slice of the pool (a scanned pool is
             # sliced into a buffer of its own for the custom call: a copy
             # of the layer's whole K and V pool every layer-step)
-            layer_kv = jnp.arange(cfg.num_layers)
+            layer_kv = jnp.arange(cfg.cache_layers)
         elif self.paged:
             # one gather for the whole window: [L, B, span, ...] linear
             # views of each slot's blocks (read-only until the final
@@ -825,7 +901,7 @@ class DensePrograms:
             def gather_view(cache):
                 return _split_heads(jax.tree.map(
                     lambda a: a[:, tables].reshape(
-                        cfg.num_layers, b, kv_span, a.shape[-1]), cache),
+                        cfg.cache_layers, b, kv_span, a.shape[-1]), cache),
                     hkv)
 
             layer_kv = (gather_view(cache_k), gather_view(cache_v))
@@ -852,7 +928,7 @@ class DensePrograms:
                     in_specs=(heads, pages, pages, P(), P(), P()),
                     out_specs=(heads, P(None, t, None)), check_vma=False)
 
-        win_shape = (cfg.num_layers, w, b, hkv, cfg.head_dim)
+        win_shape = (cfg.cache_layers, w, b, hkv, cfg.head_dim)
         win_k0 = jnp.zeros(win_shape, cfg.dtype)
         win_v0 = jnp.zeros(win_shape, cfg.dtype)
         win_j = jnp.arange(w)
@@ -867,8 +943,7 @@ class DensePrograms:
             win_mask = (win_j[None, :] <= i)[:, None, None, :]  # [1,1,1,W]
 
             def layer(carry, inputs):
-                x = carry
-                lp, kv, wk, wv = inputs
+                (x,), (lp, kv, wk, wv) = carry, inputs
                 q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, b)
                 # stash this step's K/V in the window buffer (small, in-place)
                 wk = jax.lax.dynamic_update_index_in_dim(wk, k[:, 0], i, 0)
@@ -916,13 +991,12 @@ class DensePrograms:
                                     probs[..., kv_span:])
                         attn = (jnp.einsum("bhgk,bkhd->bhgd", p_c, lv)
                                 + jnp.einsum("bhgj,jbhd->bhgd", p_w, wv))
-                x = _decode_layer_tail(x, attn, lp, cfg, b)
-                return x, (wk, wv)
+                return (_layer_tail(x, attn, lp, cfg),), (wk, wv)
 
-            x, (win_k, win_v) = jax.lax.scan(
-                layer, x, (params["layers"], layer_kv, win_k, win_v))
+            (x,), (win_k, win_v), states = _layer_passes(
+                params, cfg, layer, (x,), (layer_kv, win_k, win_v))
+            x, exit_step = _output_rows(params, cfg, x, states)
             with jax.named_scope("lm_head"):
-                x = rms_norm(x, params["final_norm"], cfg.rms_eps)
                 logits = qmatmul(x, head, cfg.dtype,
                                  preferred=jnp.float32)[:, 0]
             if sampling:
@@ -932,11 +1006,21 @@ class DensePrograms:
                 with jax.named_scope("sample"):
                     tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             new_lengths = jnp.where(active, step_lengths + 1, step_lengths)
-            return (tokens, new_lengths, win_k, win_v), tokens
+            # a looped decoder counts its step: the passes it ran and, by
+            # exit pass, the active slots' tokens ([1 + T] float32)
+            counts = None if states is None else jnp.concatenate([
+                jnp.full((1,), states.shape[0], jnp.float32),
+                jnp.sum((exit_step[:, 0, None] == jnp.arange(cfg.ut_steps))
+                        & active[:, None], axis=0, dtype=jnp.float32)])
+            return (tokens, new_lengths, win_k, win_v), (tokens, counts)
 
-        (last, new_lengths, win_k, win_v), tokens_all = jax.lax.scan(
-            one_step, (last_token, lengths, win_k0, win_v0),
-            (jnp.arange(w), jax.random.split(rng, w)))
+        (last, new_lengths, win_k, win_v), (tokens_all, counts) = \
+            jax.lax.scan(
+                one_step, (last_token, lengths, win_k0, win_v0),
+                (jnp.arange(w), jax.random.split(rng, w)))
+        # what the window returns behind its caches: nothing, or a looped
+        # decoder's counts summed over its steps (record_window_counts)
+        counts = () if counts is None else (counts.sum(0),)
 
         if self.paged:
             # row-wise scatter of the W new rows into each slot's blocks
@@ -956,7 +1040,7 @@ class DensePrograms:
             # win: [L, W, B, ...] -> rows of the pool by flat index, per
             # (l, b, j); masked rows collide in the NULL blocks, so the
             # indices are not unique
-            idx = ((jnp.arange(cfg.num_layers)[:, None, None]
+            idx = ((jnp.arange(cfg.cache_layers)[:, None, None]
                     * self.num_blocks + phys[None]) * bs + off[None])
 
             @jax.named_scope("kv_window_write")
@@ -966,7 +1050,7 @@ class DensePrograms:
 
             cache_k = scatter(cache_k, win_k)
             cache_v = scatter(cache_v, win_v)
-            return tokens_all, last, new_lengths, cache_k, cache_v
+            return (tokens_all, last, new_lengths, cache_k, cache_v, *counts)
 
         # Dense: ONE bulk insert — cache position p takes window row
         # p - base_len wherever base_len <= p < base_len + W.
@@ -976,4 +1060,4 @@ class DensePrograms:
                      & active[:, None])  # see the paged-scatter note
         cache_k = _dense_window_insert(cache_k, win_k, widx, in_window)
         cache_v = _dense_window_insert(cache_v, win_v, widx, in_window)
-        return tokens_all, last, new_lengths, cache_k, cache_v
+        return (tokens_all, last, new_lengths, cache_k, cache_v, *counts)

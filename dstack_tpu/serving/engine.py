@@ -12,7 +12,8 @@ memory IS and what a layer computes belong to the family's provider
 (``serving/families.py`` picks it: ``serving/dense.py`` for the Llama
 family, ``serving/hybrid.py`` for the KDA + MLA hybrid): it allocates the
 two donated state trees this module hands to every program as they are,
-and builds the functions this module names, jits and dispatches.
+builds the functions this module names, jits and dispatches, and records
+what its decode windows count about themselves.
 
 No reference equivalent — the reference proxies to SGLang/TGI
 (gateway/services/model_routers/sglang.py); this engine is the TPU-native
@@ -262,20 +263,34 @@ class InferenceEngine:
             prefix_cache=prefix_cache, quantize=quantize,
             kv_quantize=kv_quantize, mesh=mesh,
             sharding_policy=sharding_policy, sample=self._sample_on_device)
+        cache_layers, token_bytes = self._programs.kv_geometry()
+        if telemetry is not None:
+            telemetry.record_kv_geometry(cache_layers, token_bytes)
         if paged:
             self._alloc = (PrefixBlockAllocator(n_blocks) if prefix_cache
                            else BlockAllocator(n_blocks))
-            # The buffered-window decode materializes a dense-equivalent
-            # [L, B, span] linear KV view per window — HBM sizing must
-            # budget pool + one dense cache, so heavy pool overcommit does
-            # not deliver a proportional memory saving during decode.
-            dense_equiv = batch_size * self._blocks_per_slot
-            if n_blocks < dense_equiv // 2:
+            # The pool in the units it is sized in.  Far fewer tokens than
+            # the slots could ask for is a choice (requests shorter than
+            # max_len) whose price is said with it: admission waits on the
+            # pool, and a decode path without the block-table kernel still
+            # gathers a [cache layers, B, span] linear view of its own.
+            tokens = (n_blocks - 1) * kv_block_size  # block 0 is NULL
+            asked = batch_size * self.max_len
+            sized = ("paged KV pool: %d blocks of %d hold %d tokens in "
+                     "%.2f GB (%d B a token over %d cache layers); %d slots "
+                     "at max_len %d could ask for %d")
+            facts = (n_blocks, kv_block_size, tokens,
+                     n_blocks * kv_block_size * token_bytes / 1e9,
+                     token_bytes, cache_layers, batch_size, self.max_len,
+                     asked)
+            if tokens < asked // 2:
                 logger.warning(
-                    "paged KV pool (%d blocks) is overcommitted well below "
-                    "the dense equivalent (%d): decode still needs a "
-                    "dense-equivalent linear-view allowance in HBM "
-                    "(see ROOFLINE.md, serving decode)", n_blocks, dense_equiv)
+                    sized + ": the pool, not the slot count, bounds the "
+                    "batch, and a decode path that gathers a linear view "
+                    "(no TPU kernel, int4 pages) holds up to %.2f GB beside "
+                    "it", *facts, asked * token_bytes / 1e9)
+            else:
+                logger.info(sized, *facts)
             self._tables_host = np.zeros(
                 (batch_size, self._blocks_per_slot), np.int32)
             self._slot_blocks: List[List[int]] = [[] for _ in range(batch_size)]
@@ -1118,7 +1133,9 @@ class InferenceEngine:
             self._rng_key, sub = jax.random.split(self._rng_key)
         else:
             sub = self._rng_key
-        # a model with experts returns, last, the window's expert load
+        # behind its two state trees a window may return what it counted
+        # about itself (a model with experts its load, a looped one its
+        # passes): the provider's to record, where the window is drained
         tokens_all, self._last_token, self._lengths, *rest = \
             self._run_program(
                 self._decode_jit, (window, sampling, nbk),
@@ -1127,7 +1144,7 @@ class InferenceEngine:
                 self.params, self._last_token, self._lengths, self._active,
                 *self._state, temps, top_ps, top_ks, tables, sub,
             )
-        self._state, expert_load = rest[:2], rest[2:]
+        self._state, window_counts = rest[:2], rest[2:]
         # snapshot which slots this window actually decodes for: by drain
         # time a mid-chunking slot may have finished its prefill (left
         # _chunking), but ITS rows in this window are still junk
@@ -1136,7 +1153,7 @@ class InferenceEngine:
             if req is not None and slot_id not in self._chunking)
         pending = {"tokens": tokens_all, "window": window,
                    "remaining_after": remaining - window,
-                   "decoding": decoding, "expert_load": expert_load}
+                   "decoding": decoding, "window_counts": window_counts}
         if self.telemetry is not None:
             self._record_dispatch(decoding, pending, nbk)
         return pending
@@ -1208,9 +1225,9 @@ class InferenceEngine:
             self.telemetry.record_drain(
                 emitted, time.perf_counter() - p["t0"], len(p["decoding"]),
                 steps=p["window"], batch_size=self.batch_size)
-            if p.get("expert_load"):
-                self.telemetry.record_expert_load(
-                    *np.asarray(p["expert_load"][0]).tolist())
+            if p.get("window_counts"):
+                self._programs.record_window_counts(
+                    self.telemetry, np.asarray(p["window_counts"][0]))
 
     def _sample_first(self, logits, req: Request) -> int:
         """Sample a request's FIRST token with the same fused on-device

@@ -109,6 +109,20 @@ class HybridPrograms:
         lengths (the ``recurrent_state_bytes`` gauge)."""
         return self.cfg.recurrent_state_bytes(self.batch_size)
 
+    def kv_geometry(self) -> tuple:
+        """(cache layers, bytes one token holds over them): a latent row
+        in each MLA layer."""
+        cfg = self.cfg
+        return cfg.mla_layers, (cfg.mla_layers * cfg.latent_lanes
+                                * jnp.dtype(cfg.dtype).itemsize)
+
+    @staticmethod
+    def record_window_counts(telemetry, counts) -> None:
+        """A drained window's last output: its expert load."""
+        if telemetry is None:
+            return
+        telemetry.record_expert_load(*counts.tolist())
+
     @staticmethod
     def slot_target(slot_id: int, pages):
         """Where a prefill or chunk program writes: the slot's pages and

@@ -22,6 +22,7 @@ from typing import Optional
 from aiohttp import web
 
 from dstack_tpu.models.ling_hybrid import LingHybridConfig
+from dstack_tpu.models.ouro import OuroConfig
 from dstack_tpu.models.llama import LlamaConfig
 from dstack_tpu.serving import deadlines
 from dstack_tpu.serving.engine import EngineDraining, InferenceEngine, Request
@@ -67,6 +68,10 @@ CONFIGS = {
     "llama3-70b": LlamaConfig.llama3_70b,
     # the hybrid family (KDA + MLA + routed experts); needs --paged
     "ling-hybrid-tiny": LingHybridConfig.tiny,
+    # a looped decoder (the layer stack run ut_steps times, a K/V cache for
+    # every pass), served by the Llama family's programs
+    "ouro-tiny": OuroConfig.tiny,
+    "ouro-2.6b": OuroConfig.ouro_2_6b,
 }
 
 

@@ -44,6 +44,13 @@ republished with project/run/job/replica labels):
   dispatched any: their ratio is chunks per step (a step may spend up to
   ``batch_size``); ``prefill_budget_exhausted_total`` counts the steps
   whose budget ran out with a chunk still waiting
+- ``loop_passes_total{phase}`` / ``loop_exit_tokens_total{step}``
+  counters — a looped decoder's decode windows, counted inside the program
+  and added where a window is drained: passes over the layer stack its
+  steps ran (over ``decode_steps_total``: passes a step), and decoded
+  tokens by the pass the exit gate took their logits from
+- ``kv_cache_layers`` / ``kv_bytes_per_token`` gauges — layers of K/V a
+  token holds and its bytes over all of them: what a pool is sized from
 - ``programs_built_total{kind}`` counter — engine programs built (compiled
   or loaded from a cache) by ``decode`` / ``prefill``; growth after
   warm-up means a live request hit a new shape
@@ -320,6 +327,26 @@ class EngineTelemetry:
         r.counter(PREFIX + "moe_expert_load_max_sum").inc(load_max)
         r.counter(PREFIX + "moe_expert_load_mean_sum").inc(load_mean)
         r.counter(PREFIX + "moe_experts_touched_sum").inc(touched)
+
+    def record_loop_passes(self, passes: float, exit_tokens) -> None:
+        """One drained decode window of a looped decoder, from the sums its
+        program returned: the layer-stack ``passes`` its steps ran, and
+        its decoded tokens by the pass their logits were taken from."""
+        r = self.recorder
+        r.counter(PREFIX + "loop_passes_total",
+                  labels={"phase": "decode"}).inc(passes)
+        for step, tokens in enumerate(exit_tokens):
+            r.counter(PREFIX + "loop_exit_tokens_total",
+                      labels={"step": str(step)}).inc(tokens)
+
+    def record_kv_geometry(self, cache_layers: int,
+                           bytes_per_token: int) -> None:
+        """What a KV pool is sized from: the layers of cache a token holds
+        (a looped decoder: passes x layers) and its bytes over all of
+        them."""
+        self.recorder.gauge(PREFIX + "kv_cache_layers").set(cache_layers)
+        self.recorder.gauge(PREFIX + "kv_bytes_per_token").set(
+            bytes_per_token)
 
     def record_recurrent_state_bytes(self, nbytes: int) -> None:
         """Bytes of per-slot recurrent state (linear-attention layers) the

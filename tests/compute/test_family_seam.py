@@ -26,8 +26,9 @@ ROOT = Path(__file__).resolve().parents[2]
 SERVING = ROOT / "dstack_tpu" / "serving"
 #: the calls of the seam and how many arguments each takes
 SEAM = {"prepare_params": 2, "init_state": 0, "recurrent_state_bytes": 0,
-        "slot_target": 2, "prefill_fn": 1, "chunk_fn": 1,
-        "decode_window_fn": 3, "export_fn": 1, "insert_rows": 5}
+        "kv_geometry": 0, "slot_target": 2, "prefill_fn": 1, "chunk_fn": 1,
+        "decode_window_fn": 3, "record_window_counts": 2, "export_fn": 1,
+        "insert_rows": 5}
 PAGED = dict(paged=True, kv_block_size=16, total_kv_blocks=20)
 BUILT_WITH = dict(batch_size=2, max_len=64, paged=True, block_size=16,
                   num_blocks=9, prefix_cache=False, quantize=None,
@@ -69,8 +70,22 @@ def _hybrid():
         weights, sizes, np.asarray(seq), 0, len(seq), config=uncut)
 
 
+def _looped():
+    from benchmarks.harness.sizes import program_config, sizes_of
+    from benchmarks.references import ouro_looped as ref
+
+    toy = json.loads((ROOT / "tests/benchmark/fixture_looped/cells/configs"
+                      / "tiny-looped.json").read_text())
+    sizes = sizes_of(toy)
+    weights = ref.init_weights(sizes, 5)
+    return program_config(toy), weights, lambda seq: ref.logits(
+        weights, sizes, np.asarray(seq), 0, len(seq), config=toy)
+
+
 FAMILIES = {
     "llama-rows": (_llama, {}, DensePrograms),
+    "looped-rows": (_looped, {}, DensePrograms),
+    "looped-paged": (_looped, PAGED, DensePrograms),
     "llama-paged": (_llama, PAGED, DensePrograms),
     "routed-mlp-paged": (_routed_mlp, PAGED, DensePrograms),
     "hybrid-paged": (_hybrid, PAGED, HybridPrograms),
@@ -144,6 +159,9 @@ def test_state_trees_are_the_size_the_provider_says(family):
                               for a in jax.tree.leaves(tree))
     pool, second = engine._state
     assert nbytes(pool) == 20 * 16 * rows
+    layers, token_bytes = engine._programs.kv_geometry()
+    assert layers == pool.shape[0] if family == "hybrid-paged" else \
+        (layers, token_bytes) == (cfg.num_layers, 2 * rows)
     recurrent = engine._programs.recurrent_state_bytes()
     if family == "hybrid-paged":
         assert nbytes(second) == recurrent == cfg.recurrent_state_bytes(3) > 0

@@ -353,6 +353,14 @@ def _pool_sized_ops(text: str, layer_elems: int, layers: int,
     fused = set(re.findall(r" fusion\(.*calls=%?([\w.\-]+)", text))
     applied = set(re.findall(r"to_apply=%?([\w.\-]+)", text))
     sizes = {layer_elems * k for k in range(1, layers + 1)}
+
+    def updates_in_place(computation: str) -> bool:
+        """The computation, or a fusion nested in it, is the scatter."""
+        body = bodies.get(computation, "")
+        return bool(re.search(r" (scatter|dynamic-update-slice)\(", body)
+                    ) or any(updates_in_place(inner) for inner in
+                             re.findall(r"calls=%?([\w.\-]+)", body))
+
     found = []
     for name, body in bodies.items():
         if name in fused or name in applied:
@@ -369,9 +377,8 @@ def _pool_sized_ops(text: str, layer_elems: int, layers: int,
             if m.group(4) == "scatter":
                 continue
             called = re.search(r"calls=%?([\w.\-]+)", line)
-            if m.group(4) == "fusion" and called and re.search(
-                    r" (scatter|dynamic-update-slice)\(",
-                    bodies.get(called.group(1), "")):
+            if m.group(4) == "fusion" and called and \
+                    updates_in_place(called.group(1)):
                 continue
             found.append(line.strip()[:160])
     return found
@@ -559,3 +566,91 @@ def test_hybrid_program_fits_and_copies_no_state(hybrid_programs, program):
     # (the decode window's sit in its step loop, once)
     assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ",
                           text)) == 3 * 6
+
+
+#: the looped decoder's cell of BENCHMARK.json as its files size it: every
+#: width, 48 layers run 4 times, 8 slots, the cell's own pool; ``decode_w64``
+#: at the widest table bucket (64 columns)
+LOOPED_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_prefix_b512"]
+
+
+@pytest.fixture(scope="module")
+def looped_programs(one_chip, real_lowering):
+    """``compile_program(program)`` -> (compiled, pool shapes): the engine's
+    own builders for the looped decoder at the cell's real sizes, weights
+    never made."""
+    import json
+    from pathlib import Path
+
+    from benchmarks.harness.sizes import load_config, program_config
+    from dstack_tpu.models.ouro import init_params
+    from dstack_tpu.serving.engine import InferenceEngine
+
+    env = pytest.MonkeyPatch()
+    env.setenv("DSTACK_TPU_PAGED_ATTN_KERNEL", "1")  # read at engine init
+    root = Path(__file__).resolve().parents[2] / "benchmarks"
+    cfg = program_config(load_config(root / "configs" / "ouro-2.6b.json"))
+    load = json.loads((root / "workloads" / "ouro-2.6b.chat.json").read_text())
+    args = dict(load["engine"], prefill_chunk=512)
+    engine = InferenceEngine(cfg, params={"layers": {}}, **args)
+    env.undo()
+    b, bs = args["batch_size"], args["kv_block_size"]
+    kb = args["max_len"] // bs
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = sds(tuple(engine._state))
+    i32, f32 = jnp.int32, jnp.float32
+
+    @functools.lru_cache(maxsize=None)
+    def compile_program(program: str):
+        if program == "decode_w64":
+            fn = engine._decode_window_program(64, False, kb)
+            args = (params, arg(i32, b), arg(i32, b), arg(jnp.bool_, b),
+                    *pool, arg(f32, b), arg(f32, b), arg(i32, b),
+                    arg(i32, b, kb), arg(jnp.uint32, 2))
+        elif program == "prefill_paged_b512":
+            fn = engine._prefill_program(512)
+            args = (params, arg(i32, 512), arg(i32), *pool,
+                    arg(i32, 512 // bs))
+        else:
+            assert program == "prefill_prefix_b512", program
+            fn = engine._chunk_program(512)
+            args = (params, arg(i32, 512), arg(i32), arg(i32), *pool,
+                    arg(i32, kb))
+        return fn.lower(*args).compile(), pool
+
+    return compile_program
+
+
+@pytest.mark.parametrize("program", LOOPED_PROGRAMS)
+def test_looped_program_fits_and_stays_a_loop(looped_programs, program):
+    """Each program of the looped decoder's cell fits the chip beside its
+    weights at the cell's own pool size; the pool is [192, blocks, 32, 2048],
+    donated and addressed in place (nothing yields k of its 192 cache
+    layers); the pass loop and the layer scan stay loops in the compiled
+    program: ONE kernel call site for 192 calls a step, and a program text
+    that is no multiple of the plain decoder's."""
+    compiled, pool = looped_programs(program)
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < V5E_USABLE_BYTES, held
+    k_pool = pool[0]
+    assert k_pool.shape[0] == 192 and k_pool.shape[2:] == (32, 2048)
+    assert mem.alias_size_in_bytes >= 2 * k_pool.size * 2
+    text = compiled.as_text()
+    assert _pool_sized_ops(text, k_pool.size // 192, 192, "bf16") == []
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    assert kernels == (1 if program.startswith("decode") else 0)
+    # two nested loops around the layer body (and the window's step loop
+    # around them in the decode program), not 192 copies of it
+    assert len(re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = .* while\(", text,
+                          re.M)) <= 6
