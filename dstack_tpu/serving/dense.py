@@ -126,6 +126,10 @@ def _layer_passes(params, cfg: LlamaConfig, layer, carry, xs, length=None):
     scan carry (a tuple that starts with the activations x), ``xs`` what it
     scans beside the layers' weights, a leading dim of ``cfg.cache_layers``
     each.  Returns (carry, the layers' ys stacked per CACHE layer, states).
+    What a layer updates in place (the paged pool, the decode window's
+    buffer) belongs in ``carry`` with the cache-layer index in ``xs``: the
+    carry passes through both scans as it stands, an ``xs``/``ys`` is
+    sliced and stacked a layer (and a pass) at a time.
 
     A plain decoder scans its layers once and ``states`` is None.  A looped
     one (``cfg.ut_steps`` > 1) scans the SAME stacked weights once a pass,
@@ -842,6 +846,16 @@ class DensePrograms:
         absorbs all W rows in ONE pass at the end — full-cache write cost
         amortized 1/W.  Same logical attention set per step.
 
+        The window buffer ([cache layers, W, B, Hkv, D], K's and V's) lives
+        in the scans' CARRIES, never in their xs/ys: the step scan hands it
+        to the layer scan (through a looped decoder's pass scan, too), a
+        layer-step writes its one row at (cache layer, step) in place and
+        reads its layer's slab inside the products.  A scan slices an xs
+        operand into a buffer of its own and stacks its ys into a fresh
+        one every iteration; for this buffer that was a third of a decode
+        step on the chip (PERF.md section 6, PR 34), as it was for the
+        paged pool before it.
+
         Paged mode gets a second, larger win from the same invariance: the
         block-table gather (each slot's blocks → a linear KV view) happens
         ONCE per window instead of once per step — at long max_len that
@@ -886,12 +900,12 @@ class DensePrograms:
         cache_mask = (kv_index < base_len[:, None])[:, None, None, :]
         if use_kernel:
             # the kernel reads blocks in place through the table, out of
-            # the stored pool: the layer scan carries the layer's INDEX and
-            # the kernel closes over the whole pool — no linear view, no
+            # the stored pool: it closes over the whole pool and takes the
+            # cache-layer INDEX every layer-step gets — no linear view, no
             # gather, and no per-layer slice of the pool (a scanned pool is
             # sliced into a buffer of its own for the custom call: a copy
             # of the layer's whole K and V pool every layer-step)
-            layer_kv = jnp.arange(cfg.cache_layers)
+            layer_kv = None
         elif self.paged:
             # one gather for the whole window: [L, B, span, ...] linear
             # views of each slot's blocks (read-only until the final
@@ -928,6 +942,12 @@ class DensePrograms:
                     in_specs=(heads, pages, pages, P(), P(), P()),
                     out_specs=(heads, P(None, t, None)), check_vma=False)
 
+        # the window buffer: row (l, i) is step i's K (or V) at cache layer
+        # l, carried through every scan (see the docstring).  The heads
+        # stay a dim of their own: folded into lanes as the pool's are, a
+        # layer's slab has to be un-folded for the products, and the TPU
+        # compiler makes that a buffer (two at head_dim 64) every
+        # layer-step (PERF.md section 6, PR 34)
         win_shape = (cfg.cache_layers, w, b, hkv, cfg.head_dim)
         win_k0 = jnp.zeros(win_shape, cfg.dtype)
         win_v0 = jnp.zeros(win_shape, cfg.dtype)
@@ -943,11 +963,16 @@ class DensePrograms:
             win_mask = (win_j[None, :] <= i)[:, None, None, :]  # [1,1,1,W]
 
             def layer(carry, inputs):
-                (x,), (lp, kv, wk, wv) = carry, inputs
+                (x, win_k, win_v), (lp, l, kv) = carry, inputs
                 q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, b)
-                # stash this step's K/V in the window buffer (small, in-place)
-                wk = jax.lax.dynamic_update_index_in_dim(wk, k[:, 0], i, 0)
-                wv = jax.lax.dynamic_update_index_in_dim(wv, v[:, 0], i, 0)
+                # stash this step's K/V: row (l, i) of the carried buffer
+                win_k = jax.lax.dynamic_update_slice(
+                    win_k, k[:, 0][None, None], (l, i, 0, 0, 0))
+                win_v = jax.lax.dynamic_update_slice(
+                    win_v, v[:, 0][None, None], (l, i, 0, 0, 0))
+                # the layer's [W, B, Hkv, D] slabs, each read by one product
+                wk = jax.lax.dynamic_index_in_dim(win_k, l, 0, keepdims=False)
+                wv = jax.lax.dynamic_index_in_dim(win_v, l, 0, keepdims=False)
                 qg = q.reshape(b, hkv, group, cfg.head_dim)
                 scale = cfg.head_dim ** -0.5
                 if use_kernel:
@@ -957,7 +982,7 @@ class DensePrograms:
                     # reduction order aside
                     with jax.named_scope("paged_attn"):
                         o_c, lse_c = paged_attn(
-                            qg, cache_k, cache_v, kv, tables, base_len)
+                            qg, cache_k, cache_v, l, tables, base_len)
                     with jax.named_scope("attn"):
                         s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
                         s_w = jnp.where(win_mask, s_w,
@@ -991,10 +1016,11 @@ class DensePrograms:
                                     probs[..., kv_span:])
                         attn = (jnp.einsum("bhgk,bkhd->bhgd", p_c, lv)
                                 + jnp.einsum("bhgj,jbhd->bhgd", p_w, wv))
-                return (_layer_tail(x, attn, lp, cfg),), (wk, wv)
+                return (_layer_tail(x, attn, lp, cfg), win_k, win_v), None
 
-            (x,), (win_k, win_v), states = _layer_passes(
-                params, cfg, layer, (x,), (layer_kv, win_k, win_v))
+            (x, win_k, win_v), _, states = _layer_passes(
+                params, cfg, layer, (x, win_k, win_v),
+                (jnp.arange(cfg.cache_layers), layer_kv))
             x, exit_step = _output_rows(params, cfg, x, states)
             with jax.named_scope("lm_head"):
                 logits = qmatmul(x, head, cfg.dtype,

@@ -337,14 +337,18 @@ _NO_BUFFER = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
 
 
 def _pool_sized_ops(text: str, layer_elems: int, layers: int,
-                    dtype: str = None):
+                    dtype: str = None, loops_only: bool = False,
+                    skip_dims=()):
     """Instructions of compiled HLO ``text`` whose result is 1..``layers``
     whole layers of a pool leaf (``layer_elems`` elements a layer) and
     (of element type ``dtype``, an HLO name such as ``f32``, where given)
     that materialise it: everything outside fused computations except the
     plumbing of _NO_BUFFER and the scatters (and the fusions around them;
     a one-block scatter compiles to a dynamic-update-slice) that update
-    the pool in place."""
+    the pool in place.  ``loops_only``: look inside the bodies of the
+    program's loops alone; ``skip_dims``: shapes of something else of the
+    same size (a layer's weight matrix), told in any order of their dims
+    and with or without dims of 1."""
     bodies = dict(re.findall(
         r"^(?:ENTRY )?%?([\w.\-]+) \([^\n]*\) -> [^\n]* \{\n(.*?)^\}", text,
         re.M | re.S))
@@ -353,6 +357,9 @@ def _pool_sized_ops(text: str, layer_elems: int, layers: int,
     fused = set(re.findall(r" fusion\(.*calls=%?([\w.\-]+)", text))
     applied = set(re.findall(r"to_apply=%?([\w.\-]+)", text))
     sizes = {layer_elems * k for k in range(1, layers + 1)}
+    loops = set(re.findall(r" while\(.*body=%?([\w.\-]+)", text))
+    plain = lambda dims: tuple(sorted(d for d in dims if d > 1))
+    skip_dims = {plain(dims) for dims in skip_dims}
 
     def updates_in_place(computation: str) -> bool:
         """The computation, or a fusion nested in it, is the scatter."""
@@ -365,6 +372,8 @@ def _pool_sized_ops(text: str, layer_elems: int, layers: int,
     for name, body in bodies.items():
         if name in fused or name in applied:
             continue
+        if loops_only and name not in loops:
+            continue
         for line in body.splitlines():
             m = _HLO_LINE.match(line)
             if not m or m.group(4) in _NO_BUFFER:
@@ -372,7 +381,7 @@ def _pool_sized_ops(text: str, layer_elems: int, layers: int,
             if dtype is not None and m.group(2) != dtype:
                 continue
             dims = [int(x) for x in m.group(3).split(",") if x]
-            if math.prod(dims) not in sizes:
+            if math.prod(dims) not in sizes or plain(dims) in skip_dims:
                 continue
             if m.group(4) == "scatter":
                 continue
@@ -404,9 +413,10 @@ def engine_programs(one_chip, real_lowering):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     @functools.lru_cache(maxsize=None)
-    def compile_program(geometry: str, program: str, blocks: int):
+    def compile_program(geometry: str, program: str, blocks: int,
+                        layers: int = ENGINE_LAYERS):
         g = ENGINES[geometry]
-        cfg = LlamaConfig(num_layers=ENGINE_LAYERS, max_seq_len=g["max_len"],
+        cfg = LlamaConfig(num_layers=layers, max_seq_len=g["max_len"],
                           **g["cfg"])
         b, bs = g["batch"], 32
         engine = InferenceEngine(
@@ -417,15 +427,16 @@ def engine_programs(one_chip, real_lowering):
             lambda: init_params(jax.random.PRNGKey(0), cfg)))
         pool = sds(tuple(engine._state))
         leaf = jax.tree.leaves(pool)[0]
-        layer_elems = leaf.size // ENGINE_LAYERS
+        layer_elems = leaf.size // layers
         for weight in jax.tree.leaves(params):
             assert weight.size % layer_elems or \
-                weight.size // layer_elems > ENGINE_LAYERS, (
+                weight.size // layer_elems > layers, (
                     "a weight is as large as k layers of the pool: pick "
                     "another number of blocks", weight.shape)
         i32, f32 = jnp.int32, jnp.float32
-        if program == "decode_w64":
-            fn = engine._decode_window_program(64, False, g["kb"])
+        if program.startswith("decode_w"):
+            fn = engine._decode_window_program(
+                int(program[len("decode_w"):]), False, g["kb"])
             args = (params, arg(i32, b), arg(i32, b), arg(jnp.bool_, b),
                     *pool, arg(f32, b), arg(f32, b), arg(i32, b),
                     arg(i32, b, g["kb"]), arg(jnp.uint32, 2))
@@ -654,3 +665,70 @@ def test_looped_program_fits_and_stays_a_loop(looped_programs, program):
     # around them in the decode program), not 192 copies of it
     assert len(re.findall(r"^\s*(?:ROOT )?%?[\w.\-]+ = .* while\(", text,
                           re.M)) <= 6
+
+
+# -- the decode window buffer -------------------------------------------------
+#
+# The W rows a decode window produces are a scan CARRY of ``serving/dense.py``
+# (PR 34): the compiled step loop, layer loop and pass loop may write a row of
+# the buffer in place and read a layer's slab inside the fusion that consumes
+# it, and must not slice, stack or re-lay k whole cache layers of it.  What
+# the window's END does with the buffer (fold the heads into the pool's lanes,
+# order the rows for the scatter) runs once in 64 steps and is left to the
+# bound on the temporaries.
+
+#: the dense cells at their own depth (the fixture's three layers make a
+#: buffer small enough to live in fast memory, where nothing shows)
+WINDOW_LAYERS = {"mha-d64": 24, "gqa-d128": 16}
+
+
+@pytest.mark.parametrize("geometry", ["mha-d64", "gqa-d128", "looped"])
+def test_decode_window_buffer_is_updated_in_place(request, geometry):
+    """``decode_w64`` of both dense geometries and of the looped cell: no
+    instruction of a loop's body yields k whole cache layers of a window
+    buffer but the row update (a ``dynamic-update-slice`` in place), and
+    the program's temporaries hold two window buffers, K's and V's, beside
+    what the same program holds at a window of 8 (the chat geometry, 0.4 GB
+    a buffer as stored; the looped cell: under 3 GB, where a scanned buffer
+    compiled to 4.2-4.4; the batch geometry's 34 MB buffers are too small
+    to tell from the rest).  One exception, by
+    what the products are: with grouped queries they are matrix products,
+    and the compiler hands a matrix product its sliced operand as a buffer
+    (as it does a layer's weights): ONE layer's slab each for K and V, by
+    a loop fusion, never a ``copy`` and never more than a layer."""
+    if geometry == "looped":
+        compiled, _ = request.getfixturevalue("looped_programs")(
+            "decode_w64")
+        layers, slots, hkv, d, group = 192, 8, 16, 128, 1
+        weights = [(2048, 2048), (2048, 5632)]
+        assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    else:
+        programs = request.getfixturevalue("engine_programs")
+        g, layers = ENGINES[geometry], WINDOW_LAYERS[geometry]
+        blocks = g["blocks"][1]
+        compiled, _, _ = programs(geometry, "decode_w64", blocks, layers)
+        slots, hkv, d = g["batch"], g["cfg"]["num_kv_heads"], \
+            g["cfg"]["head_dim"]
+        group = g["cfg"]["num_heads"] // hkv
+        h, f = g["cfg"]["hidden_size"], g["cfg"]["intermediate_size"]
+        weights = [(h, h), (h, hkv * d), (h, f)]
+        # a buffer as the chip stores it: bf16, a head's values in lanes
+        # of their own, so a 64-wide head takes a 128-lane tile
+        stored = layers * 64 * slots * hkv * max(d, 128) * 2
+        if stored > 100e6:  # the other's buffers are too small to tell
+            short, _, _ = programs(geometry, "decode_w8", blocks, layers)
+            grown = (compiled.memory_analysis().temp_size_in_bytes
+                     - short.memory_analysis().temp_size_in_bytes)
+            assert grown <= 2.1 * stored, grown
+    # a layer's slab of the buffer can be exactly as large as a weight
+    # matrix (64 x 32 slots x 2048 lanes = 2048 x 2048): tell them by shape
+    found = _pool_sized_ops(compiled.as_text(), 64 * slots * hkv * d, layers,
+                            "bf16", loops_only=True, skip_dims=weights)
+    if group > 1:
+        reads = [line for line in found
+                 if "dynamic-slice" in line.split(" = ")[0]
+                 and f" = bf16[1,64,{slots},{hkv},{d}]" in line
+                 and " fusion(" in line]
+        assert len(reads) <= 2, reads
+        found = [line for line in found if line not in reads]
+    assert found == [], found
