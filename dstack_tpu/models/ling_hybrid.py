@@ -33,14 +33,18 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 
+from dstack_tpu.models.experts import (  # noqa: F401  (LOAD_FIELDS: re-export)
+    LOAD_FIELDS,
+    expert_load,
+    held_experts,
+    swiglu as _swiglu,
+)
 from dstack_tpu.ops import kda, mla
 from dstack_tpu.ops.rmsnorm import rms_norm
 from dstack_tpu.ops.rotary import apply_rope, rope_frequencies
 
 Params = dict[str, Any]
 LANE = 128
-#: length of the expert-load vector :func:`moe_ffn` returns
-LOAD_FIELDS = 5
 
 
 @dataclasses.dataclass(frozen=True)
@@ -227,10 +231,6 @@ def init_params(rng: jax.Array, cfg: LingHybridConfig) -> Params:
 
 # -- feed-forward --------------------------------------------------------------
 
-def _swiglu(h, w_gate, w_up, w_down):
-    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
-
-
 @jax.named_scope("moe_route")
 def route(h, lp, cfg: LingHybridConfig):
     """Experts and weights of every token of ``h`` [T, D]: ``(ids [T, k],
@@ -253,36 +253,6 @@ def route(h, lp, cfg: LingHybridConfig):
     return ids, weights * cfg.routed_scaling_factor
 
 
-@jax.named_scope("moe_experts")
-def held_experts(h, ids, weights, lp, cfg: LingHybridConfig, token_mask):
-    """What this chip's experts add for the tokens routed to them: ``(y
-    [T, D], counts [experts_held])``.  The token-expert pairs are sorted by
-    expert and go through one grouped product a matrix; pairs of absent
-    experts and of masked tokens sort last, are computed in the last
-    expert's group (so that every row of the product is defined) and carry
-    weight 0."""
-    t, k = ids.shape
-    e = cfg.experts_held
-    local = ids - cfg.expert_offset
-    here = (local >= 0) & (local < e)
-    if token_mask is not None:
-        here = here & token_mask[:, None]
-    key = jnp.where(here, local, e).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    counts = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)
-    group_sizes = counts[:e].at[e - 1].add(counts[e])
-    rows = h[order // k]                                     # [T*k, D]
-    gated = (jax.nn.silu(jax.lax.ragged_dot(rows, lp["we_gate"], group_sizes))
-             * jax.lax.ragged_dot(rows, lp["we_up"], group_sizes))
-    out = jax.lax.ragged_dot(gated, lp["we_down"], group_sizes)
-    # back to [T, k] by the inverse permutation (a gather, not a scatter-add)
-    inverse = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
-    out = out[inverse].reshape(t, k, -1)
-    w = jnp.where(here, weights, 0.0)
-    y = jnp.einsum("tkd,tk->td", out, w, preferred_element_type=jnp.float32)
-    return y.astype(h.dtype), counts[:e]
-
-
 def moe_ffn(h, lp, cfg: LingHybridConfig, token_mask=None):
     """Routed experts held here + the shared expert, for ``h`` [T, D].
     Returns ``(y, load)``; ``load`` = float32 [held pairs, absent pairs,
@@ -294,11 +264,7 @@ def moe_ffn(h, lp, cfg: LingHybridConfig, token_mask=None):
         y = y + _swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
     tokens = (h.shape[0] if token_mask is None
               else token_mask.sum().astype(jnp.float32))
-    held = counts.sum().astype(jnp.float32)
-    load = jnp.stack([held, tokens * cfg.num_experts_per_tok - held,
-                      counts.max().astype(jnp.float32),
-                      held / cfg.experts_held,
-                      (counts > 0).sum().astype(jnp.float32)])
+    load = expert_load(counts, tokens, cfg)
     return y, load
 
 

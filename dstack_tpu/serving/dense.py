@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 from typing import Any, Callable, Optional
 
 import jax
@@ -35,6 +34,7 @@ from dstack_tpu.models.llama import (
 from dstack_tpu.ops.pool import scatter_rows as _scatter_rows
 from dstack_tpu.ops.rmsnorm import rms_norm
 from dstack_tpu.ops.rotary import apply_rope, rope_frequencies
+from dstack_tpu.serving import paged_window
 from dstack_tpu.serving.quant import (
     dequantize_kv,
     dequantize_kv4,
@@ -46,19 +46,6 @@ from dstack_tpu.serving.quant import (
 from dstack_tpu.utils.jax_runtime import named_jit
 
 logger = logging.getLogger(__name__)
-
-
-def _paged_kernel_default() -> bool:
-    """Whether paged decode attention should run the Pallas block-table
-    kernel (ops/flash_attention.py paged_decode_attention) instead of the
-    XLA gather path.  ``DSTACK_TPU_PAGED_ATTN_KERNEL``: "auto" (default —
-    on for a real TPU backend, off for CPU/interpret where the XLA path
-    wins), "1"/"0" to force.  Whichever is chosen is the only path: a
-    kernel the compiler refuses fails the decode, nothing falls back."""
-    v = os.environ.get("DSTACK_TPU_PAGED_ATTN_KERNEL", "auto")
-    if v == "auto":
-        return jax.default_backend() == "tpu"
-    return v not in ("0", "false", "off")
 
 
 # Device-side regions carry a jax.named_scope so that a profiler trace and an
@@ -111,7 +98,7 @@ def _layer_kv(params, cfg: LlamaConfig, x, positions, inv_freqs, length,
     def layer(carry, inputs):
         (x,), (lp,) = carry, inputs
         q, k, v = _decode_qkv(x, lp, cfg, positions, inv_freqs, b, s)
-        attn = _masked_attention(q, k, v, positions, positions)
+        attn = paged_window.masked_attention(q, k, v, positions, positions)
         return (_layer_tail(x, attn, lp, cfg, token_mask),), (k, v)
 
     (x,), (ks, vs), states = _layer_passes(params, cfg, layer, (x,), (),
@@ -319,23 +306,8 @@ def _suffix_layer(x, lp, cfg: LlamaConfig, positions, inv_freqs, kv_pos,
         layer_v = _kv_map(layer_v, v, insert, lanes)
     kv_k = _kv_mat(gather(layer_k), cfg.dtype)
     kv_v = _kv_mat(gather(layer_v), cfg.dtype)
-    attn = _masked_attention(q, kv_k, kv_v, positions, kv_pos)
+    attn = paged_window.masked_attention(q, kv_k, kv_v, positions, kv_pos)
     return _layer_tail(x, attn, lp, cfg, token_mask), layer_k, layer_v
-
-
-@jax.named_scope("attn")
-def _masked_attention(q, k, v, q_pos, kv_pos):
-    """Causal GQA attention with explicit position masks (prefill)."""
-    b, s, hq, d = q.shape
-    hkv = k.shape[2]
-    group = hq // hkv
-    q = q.reshape(b, s, hkv, group, d)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) / (d ** 0.5)
-    mask = (kv_pos[:, None, :] <= q_pos[:, :, None])[:, None, None, :, :]
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, s, hq, d)
 
 
 
@@ -372,7 +344,7 @@ class DensePrograms:
         self.kv_quantize = kv_quantize
         self.kv_quant = kv_quantize is not None
         #: Pallas block-table decode kernel (resolved once at init)
-        self._paged_kernel = _paged_kernel_default()
+        self._paged_kernel = paged_window.paged_kernel_default()
         self.mesh = mesh
         self._policy = None
         t = 1  # tensor-parallel degree
@@ -651,8 +623,7 @@ class DensePrograms:
         """
         cfg = self.cfg
         bs = self.block_size
-        bps = self.blocks_per_slot
-        kv_span = bps * bs
+        kv_span = self.blocks_per_slot * bs
 
         def fn(params, suffix_tokens, suffix_len, prefix_len,
                cache_k, cache_v, tables_row):
@@ -661,12 +632,8 @@ class DensePrograms:
                 cfg.head_dim, cfg.rope_theta, cfg.rope_scaling))
             x = params["embed"].astype(cfg.dtype)[suffix_tokens][None, :, :]
             kv_pos = jnp.arange(kv_span)[None, :]
-            idx = prefix_len + jnp.arange(sbucket)
-            # padding rows past the span write to the NULL block
-            safe = idx < kv_span
-            blk = jnp.where(
-                safe, tables_row[jnp.clip(idx // bs, 0, bps - 1)], 0)
-            off = idx % bs
+            blk, off = paged_window.chunk_pages(
+                prefix_len, sbucket, tables_row, bs, kv_span)
             # MoE: padding must not claim expert capacity
             token_mask = (jnp.arange(sbucket) < suffix_len)[None, :]
 
@@ -680,11 +647,11 @@ class DensePrograms:
                 x, pool_k, pool_v = carry
                 lp, l = inputs
                 scatter = lambda leaf, rows: _scatter_rows(
-                    leaf, (l * nb + blk) * bs + off, rows[0])
+                    leaf, paged_window.flat_rows(l, blk, off, nb, bs),
+                    rows[0])
                 gather = lambda pool: _split_heads(jax.tree.map(
-                    lambda a: a.reshape((-1,) + a.shape[2:])[
-                        l * nb + tables_row].reshape(
-                            1, kv_span, a.shape[-1]), pool),
+                    lambda a: paged_window.slot_span(
+                        a, l, tables_row, nb, lead=(1,)), pool),
                     cfg.num_kv_heads)
                 x, pool_k, pool_v = _suffix_layer(
                     x, lp, cfg, positions, inv_freqs, kv_pos, token_mask,
@@ -974,48 +941,19 @@ class DensePrograms:
                 wk = jax.lax.dynamic_index_in_dim(win_k, l, 0, keepdims=False)
                 wv = jax.lax.dynamic_index_in_dim(win_v, l, 0, keepdims=False)
                 qg = q.reshape(b, hkv, group, cfg.head_dim)
-                scale = cfg.head_dim ** -0.5
                 if use_kernel:
-                    # cache half straight off the block table (normalized
-                    # o + logsumexp per slot), window half in XLA, merged
-                    # by logsumexp — numerically the same attention set,
-                    # reduction order aside
-                    with jax.named_scope("paged_attn"):
-                        o_c, lse_c = paged_attn(
-                            qg, cache_k, cache_v, l, tables, base_len)
-                    with jax.named_scope("attn"):
-                        s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
-                        s_w = jnp.where(win_mask, s_w,
-                                        -1e30).astype(jnp.float32)
-                        m_w = jnp.max(s_w, axis=-1)
-                        p_w = jnp.exp(s_w - m_w[..., None])
-                        l_w = jnp.sum(p_w, axis=-1)
-                        o_w = jnp.einsum(
-                            "bhgj,jbhd->bhgd", p_w.astype(x.dtype), wv
-                        ).astype(jnp.float32) / l_w[..., None]
-                        lse_w = m_w + jnp.log(l_w)
-                        # empty-cache slots have lse_c = -inf; the window
-                        # half always has column 0 visible, so lse is finite
-                        lse = jnp.logaddexp(lse_c, lse_w)
-                        attn = (o_c * jnp.exp(lse_c - lse)[..., None]
-                                + o_w * jnp.exp(lse_w - lse)[..., None]
-                                ).astype(x.dtype)
+                    # cache half straight off the block table, window half
+                    # in XLA, merged by logsumexp
+                    attn = paged_window.attend_pages_and_window(
+                        paged_attn, qg, cache_k, cache_v, l, tables,
+                        base_len, wk, wv, win_mask, x.dtype)
                 else:
                     with jax.named_scope("attn"):
                         # quantized dequant fuses in
                         lk = _kv_mat(kv[0], x.dtype)
                         lv = _kv_mat(kv[1], x.dtype)
-                        s_c = jnp.einsum("bhgd,bkhd->bhgk", qg, lk) * scale
-                        s_c = jnp.where(cache_mask, s_c, -1e30)
-                        s_w = jnp.einsum("bhgd,jbhd->bhgj", qg, wk) * scale
-                        s_w = jnp.where(win_mask, s_w, -1e30)
-                        s = jnp.concatenate([s_c, s_w], axis=-1)
-                        probs = jax.nn.softmax(
-                            s.astype(jnp.float32), axis=-1).astype(x.dtype)
-                        p_c, p_w = (probs[..., :kv_span],
-                                    probs[..., kv_span:])
-                        attn = (jnp.einsum("bhgk,bkhd->bhgd", p_c, lv)
-                                + jnp.einsum("bhgj,jbhd->bhgd", p_w, wv))
+                    attn = paged_window.attend_view_and_window(
+                        qg, lk, lv, cache_mask, wk, wv, win_mask, x.dtype)
                 return (_layer_tail(x, attn, lp, cfg), win_k, win_v), None
 
             (x, win_k, win_v), _, states = _layer_passes(
@@ -1050,24 +988,11 @@ class DensePrograms:
 
         if self.paged:
             # row-wise scatter of the W new rows into each slot's blocks
-            # (positions base_len + j; overshoot past the span lands in the
-            # NULL block like the classic path's clamped writes)
-            bs = self.block_size
-            pos = base_len[:, None] + win_j[None, :]            # [B, W]
-            # inactive slots (released, or mid-chunked-prefill) must not
-            # write: their window rows are junk and a chunked prefill may
-            # be filling those cache rows concurrently
-            safe = (pos < kv_span) & active[:, None]
-            blk_col = jnp.clip(pos // bs, 0, nbk - 1)
-            phys = jnp.where(
-                safe, jnp.take_along_axis(tables, blk_col, axis=1), 0)
-            off = pos % bs
-
-            # win: [L, W, B, ...] -> rows of the pool by flat index, per
-            # (l, b, j); masked rows collide in the NULL blocks, so the
-            # indices are not unique
-            idx = ((jnp.arange(cfg.cache_layers)[:, None, None]
-                    * self.num_blocks + phys[None]) * bs + off[None])
+            # (positions base_len + j): rows [L, W, B, ...] by flat index,
+            # per (l, b, j)
+            idx = paged_window.window_rows(
+                cfg.cache_layers, base_len, active, tables, win_j,
+                self.block_size, self.num_blocks)
 
             @jax.named_scope("kv_window_write")
             def scatter(cache, win):

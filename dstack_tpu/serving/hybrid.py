@@ -42,6 +42,7 @@ from dstack_tpu.models import ling_hybrid as model
 from dstack_tpu.ops import mla
 from dstack_tpu.ops.pool import scatter_rows
 from dstack_tpu.ops.rmsnorm import rms_norm
+from dstack_tpu.serving import paged_window
 
 
 class HybridPrograms:
@@ -130,10 +131,6 @@ class HybridPrograms:
         return pages, jnp.int32(slot_id)
 
     @staticmethod
-    def _slot(rec, slot):
-        return jax.tree.map(lambda a: a[:, slot], rec)
-
-    @staticmethod
     def _put_slot(rec, slot, state, tail):
         return {"state": rec["state"].at[:, slot].set(state),
                 "tail": rec["tail"].at[:, slot].set(
@@ -147,7 +144,8 @@ class HybridPrograms:
 
         def fn(params, tokens, length, pool, rec, target):
             bids, slot = target
-            zero = jax.tree.map(jnp.zeros_like, self._slot(rec, slot))
+            zero = jax.tree.map(jnp.zeros_like,
+                                paged_window.slot_rows(rec, slot))
 
             def attend(m, rows):
                 nonlocal pool
@@ -169,29 +167,26 @@ class HybridPrograms:
         into the slot's pages and the chunk attends the slot's whole span;
         the recurrent state comes from the slot (zeros at ``prefix_len`` 0)
         and goes back to it."""
-        cfg, bs, bps = self.cfg, self.block_size, self.blocks_per_slot
-        nb, span = self.num_blocks, self.blocks_per_slot * self.block_size
+        cfg, bs, nb = self.cfg, self.block_size, self.num_blocks
+        span = self.blocks_per_slot * self.block_size
 
         def fn(params, tokens, chunk_len, prefix_len, pool, rec, target):
             tables_row, slot = target
-            mine = self._slot(rec, slot)
+            mine = paged_window.slot_rows(rec, slot)
             fresh = prefix_len == 0
             mine = jax.tree.map(
                 lambda a: jnp.where(fresh, jnp.zeros_like(a), a), mine)
-            idx = prefix_len + jnp.arange(cbucket)
-            # padding rows past the span write to the NULL block
-            blk = jnp.where(idx < span,
-                            tables_row[jnp.clip(idx // bs, 0, bps - 1)], 0)
-            off = idx % bs
+            blk, off = paged_window.chunk_pages(prefix_len, cbucket,
+                                                tables_row, bs, span)
 
             def attend(m, rows):
                 nonlocal pool
                 with jax.named_scope("kv_insert"):
-                    pool = scatter_rows(pool, (m * nb + blk) * bs + off,
-                                         rows)
-                mine_rows = pool.reshape((-1,) + pool.shape[2:])[
-                    m * nb + tables_row].reshape(span, pool.shape[-1])
-                return mine_rows, jnp.arange(span)
+                    pool = scatter_rows(
+                        pool, paged_window.flat_rows(m, blk, off, nb, bs),
+                        rows)
+                return (paged_window.slot_span(pool, m, tables_row, nb),
+                        jnp.arange(span))
 
             logits, state, tail = model.sequence_forward(
                 params, cfg, tokens, chunk_len, prefix_len, mine["state"],
@@ -270,15 +265,10 @@ class HybridPrograms:
                     (jnp.arange(w), jax.random.split(rng, w)))
 
             # the window's rows into each slot's pages (positions base_len +
-            # j); overshoot past the span and slots that are not active land
-            # in the NULL block
-            pos = base_len[:, None] + win_j[None, :]             # [B, W]
-            safe = (pos < span) & active[:, None]
-            col = jnp.clip(pos // bs, 0, nbk - 1)
-            phys = jnp.where(safe, jnp.take_along_axis(tables, col, axis=1),
-                             0)
-            idx = ((jnp.arange(cfg.mla_layers)[:, None, None]
-                    * self.num_blocks + phys[None]) * bs + (pos % bs)[None])
+            # j)
+            idx = paged_window.window_rows(cfg.mla_layers, base_len, active,
+                                           tables, win_j, bs,
+                                           self.num_blocks)
             with jax.named_scope("kv_window_write"):
                 pool = scatter_rows(pool, idx, jnp.moveaxis(win, 1, 2))
             return (tokens_all, last, new_lengths, pool,

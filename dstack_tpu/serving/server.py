@@ -21,6 +21,7 @@ from typing import Optional
 
 from aiohttp import web
 
+from dstack_tpu.models.lfm2 import Lfm2MoeConfig
 from dstack_tpu.models.ling_hybrid import LingHybridConfig
 from dstack_tpu.models.ouro import OuroConfig
 from dstack_tpu.models.llama import LlamaConfig
@@ -72,6 +73,10 @@ CONFIGS = {
     # every pass), served by the Llama family's programs
     "ouro-tiny": OuroConfig.tiny,
     "ouro-2.6b": OuroConfig.ouro_2_6b,
+    # the LFM2-MoE family (short convolutions + GQA + routed experts);
+    # needs --paged.  The 9-layer cut is the benchmark's pipeline stage
+    "lfm2-tiny": Lfm2MoeConfig.tiny,
+    "lfm2-24b-a2b-9l": Lfm2MoeConfig.lfm2_24b_a2b_9l,
 }
 
 

@@ -1,6 +1,6 @@
 """The seam between the scheduler (``serving/engine.py``) and a family's
-provider (``serving/dense.py``, ``serving/hybrid.py``): both providers answer
-the same calls, the engine drives each through admission, chunked prefill
+provider (``serving/dense.py``, ``serving/hybrid.py``, ``serving/lfm2.py``):
+the providers answer the same calls, the engine drives each through admission, chunked prefill
 and decode windows to the tokens of the family's plain forward, the state
 trees are the size the provider says, each provider refuses what its model
 is not served with, and the engine's source names no model.  CPU, toy
@@ -21,6 +21,7 @@ from dstack_tpu.serving.dense import DensePrograms
 from dstack_tpu.serving.engine import InferenceEngine
 from dstack_tpu.serving.families import programs_for
 from dstack_tpu.serving.hybrid import HybridPrograms
+from dstack_tpu.serving.lfm2 import Lfm2Programs
 
 ROOT = Path(__file__).resolve().parents[2]
 SERVING = ROOT / "dstack_tpu" / "serving"
@@ -70,6 +71,18 @@ def _hybrid():
         weights, sizes, np.asarray(seq), 0, len(seq), config=uncut)
 
 
+def _lfm2():
+    from benchmarks.harness.sizes import program_config, sizes_of
+    from benchmarks.references import lfm2_moe as ref
+
+    toy = json.loads((ROOT / "tests/benchmark/fixture_lfm2/cells/configs"
+                      / "tiny-lfm2.json").read_text())
+    sizes = sizes_of(toy)
+    weights = ref.init_weights(sizes, 5, config=toy)
+    return program_config(toy), weights, lambda seq: ref.logits(
+        weights, sizes, np.asarray(seq), 0, len(seq), config=toy)
+
+
 def _looped():
     from benchmarks.harness.sizes import program_config, sizes_of
     from benchmarks.references import ouro_looped as ref
@@ -89,6 +102,7 @@ FAMILIES = {
     "llama-paged": (_llama, PAGED, DensePrograms),
     "routed-mlp-paged": (_routed_mlp, PAGED, DensePrograms),
     "hybrid-paged": (_hybrid, PAGED, HybridPrograms),
+    "lfm2-paged": (_lfm2, PAGED, Lfm2Programs),
 }
 
 
@@ -125,11 +139,12 @@ def test_engine_drives_the_provider_to_the_plain_forward_s_tokens(family):
     assert gaps.max() < 1e-4, gaps
 
 
-def test_both_providers_are_built_with_the_same_keywords():
+def test_the_providers_are_built_with_the_same_keywords():
     keywords = lambda cls: [
         (p.name, p.kind) for p in
         inspect.signature(cls.__init__).parameters.values()]
-    assert keywords(DensePrograms) == keywords(HybridPrograms)
+    assert keywords(DensePrograms) == keywords(HybridPrograms) == \
+        keywords(Lfm2Programs)
     assert {name for name, _ in keywords(DensePrograms)[2:]} == \
         set(BUILT_WITH)
 
@@ -227,8 +242,52 @@ def test_the_scheduler_names_no_model():
     name_a_model = {
         path.name for path in SERVING.glob("*.py")
         if any(m.startswith("dstack_tpu.models") for m in imported(path)[1])}
-    assert name_a_model == {"dense.py", "hybrid.py", "families.py",
-                            "server.py"}
+    assert name_a_model == {"dense.py", "hybrid.py", "lfm2.py",
+                            "families.py", "server.py"}
     families = ast.parse((SERVING / "families.py").read_text())
     assert [node.name for node in ast.walk(families)
             if isinstance(node, ast.FunctionDef)] == ["programs_for"]
+
+
+def test_families_is_a_table_and_a_subclass_goes_to_its_base_s_provider():
+    from dstack_tpu.models.lfm2 import Lfm2MoeConfig
+    from dstack_tpu.models.ling_hybrid import LingHybridConfig
+    from dstack_tpu.models.llama import LlamaConfig
+    from dstack_tpu.models.moe import MoEConfig
+    from dstack_tpu.models.ouro import OuroConfig
+    from dstack_tpu.serving.families import PROVIDERS
+
+    assert PROVIDERS == {LlamaConfig: DensePrograms,
+                         LingHybridConfig: HybridPrograms,
+                         Lfm2MoeConfig: Lfm2Programs}
+    for make, provider in ((MoEConfig.tiny_moe, DensePrograms),
+                           (OuroConfig.tiny, DensePrograms),
+                           (Lfm2MoeConfig.tiny, Lfm2Programs)):
+        assert type(programs_for(make(), **BUILT_WITH)) is provider
+    with pytest.raises(TypeError, match="no provider serves"):
+        programs_for(object(), **BUILT_WITH)
+
+
+def test_what_the_families_share_exists_once():
+    """The sorted grouped expert product, the logsumexp merge of the cache
+    half with the window half and the page arithmetic of the end-of-window
+    scatter are each defined in one file of the tree and called from the
+    others."""
+    sources = {path: path.read_text()
+               for path in (ROOT / "dstack_tpu").rglob("*.py")}
+    where = lambda needle: sorted(
+        str(path.relative_to(ROOT / "dstack_tpu"))
+        for path, text in sources.items() if needle in text)
+    assert where("jax.lax.ragged_dot(") == ["models/experts.py"]
+    assert where("jnp.logaddexp(lse_c") == ["serving/paged_window.py"]
+    assert where("jnp.take_along_axis(tables") == ["serving/paged_window.py"]
+    assert where("def masked_attention(") == ["serving/paged_window.py"]
+    for caller in ("serving/dense.py", "serving/lfm2.py"):
+        assert caller in where("paged_window.attend_pages_and_window(")
+        assert caller in where("paged_window.attend_view_and_window(")
+    for caller in ("serving/dense.py", "serving/hybrid.py",
+                   "serving/lfm2.py"):
+        assert caller in where("paged_window.window_rows(")
+        assert caller in where("paged_window.chunk_pages(")
+    for caller in ("models/ling_hybrid.py", "models/lfm2.py"):
+        assert caller in where("held_experts(")

@@ -358,19 +358,23 @@ def test_server_presets_build_the_looped_configs():
 
 
 def test_one_copy_of_the_decode_window_serves_both():
-    """The looped decoder brought no second decode window: the attention
-    merge, the window buffer and the end-of-window scatter each stand once
-    under ``serving/``, in the Llama family's provider, and the model file
+    """The looped decoder brought no second decode window: the window
+    buffer and the end-of-window scatter each stand once under ``serving/``,
+    in the Llama family's provider, the attention merge once in what the
+    providers share (``paged_window.py``, since PR 35), and the model file
     holds no program."""
     from pathlib import Path
 
     serving = Path(ouro.__file__).resolve().parents[1] / "serving"
     text = {p.name: p.read_text() for p in serving.glob("*.py")}
-    for mark in ("jnp.logaddexp(lse_c, lse_w)", "win_shape = (",
-                 "def _decode_window_fn_buffered(",
-                 "@jax.named_scope(\"kv_window_write\")\n            def"):
+    for mark, home in (
+            ("jnp.logaddexp(lse_c, lse_w)", "paged_window.py"),
+            ("win_shape = (", "dense.py"),
+            ("def _decode_window_fn_buffered(", "dense.py"),
+            ("@jax.named_scope(\"kv_window_write\")\n            def",
+             "dense.py")):
         assert [name for name, t in text.items() if mark in t] == \
-            ["dense.py"] and text["dense.py"].count(mark) == 1, mark
+            [home] and text[home].count(mark) == 1, mark
     model = Path(ouro.__file__).read_text()
     assert "lax.scan" not in model and "def decode" not in model
     assert "ouro" not in text["engine.py"].lower()

@@ -667,6 +667,99 @@ def test_looped_program_fits_and_stays_a_loop(looped_programs, program):
                           re.M)) <= 6
 
 
+#: the LFM2 cell of BENCHMARK.json as its files size it: every width, 9
+#: layers, all 64 experts, 256 slots, the cell's own pool; ``decode_w64`` at
+#: the widest table bucket (128 columns)
+LFM2_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_prefix_b512"]
+
+
+@pytest.fixture(scope="module")
+def lfm2_programs(one_chip, real_lowering):
+    """``compile_program(program)`` -> (compiled, state tree shapes): the
+    engine's own builders for the LFM2-MoE decoder at the cell's real
+    sizes, with the block-table kernel, weights never made."""
+    import json
+    from pathlib import Path
+
+    from benchmarks.harness.sizes import load_config, program_config
+    from dstack_tpu.models.lfm2 import init_params
+    from dstack_tpu.serving.engine import InferenceEngine
+
+    env = pytest.MonkeyPatch()
+    env.setenv("DSTACK_TPU_PAGED_ATTN_KERNEL", "1")  # read at engine init
+    root = Path(__file__).resolve().parents[2] / "benchmarks"
+    cfg = program_config(load_config(
+        root / "configs" / "lfm2-24b-a2b-9l.json"))
+    load = json.loads((root / "workloads"
+                       / "lfm2-24b-a2b-9l.reason.json").read_text())
+    args = dict(load["engine"], prefill_chunk=512)
+    engine = InferenceEngine(cfg, params={"layers": {}}, **args)
+    env.undo()
+    b, bs = args["batch_size"], args["kv_block_size"]
+    kb = args["max_len"] // bs
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = sds(tuple(engine._state))
+    i32, f32 = jnp.int32, jnp.float32
+
+    @functools.lru_cache(maxsize=None)
+    def compile_program(program: str):
+        if program == "decode_w64":
+            fn = engine._decode_window_program(64, False, kb)
+            args = (params, arg(i32, b), arg(i32, b), arg(jnp.bool_, b),
+                    *state, arg(f32, b), arg(f32, b), arg(i32, b),
+                    arg(i32, b, kb), arg(jnp.uint32, 2))
+        elif program == "prefill_paged_b512":
+            fn = engine._prefill_program(512)
+            args = (params, arg(i32, 512), arg(i32), *state,
+                    (arg(i32, 512 // bs), arg(i32)))
+        else:
+            assert program == "prefill_prefix_b512", program
+            fn = engine._chunk_program(512)
+            args = (params, arg(i32, 512), arg(i32), arg(i32), *state,
+                    (arg(i32, kb), arg(i32)))
+        return fn.lower(*args).compile(), state
+
+    return compile_program
+
+
+@pytest.mark.parametrize("program", LFM2_PROGRAMS)
+def test_lfm2_program_fits_and_copies_no_pool(lfm2_programs, program):
+    """Each program of the LFM2 cell fits the chip beside its 10.36 GB of
+    weights at the cell's own pool size (2 x [2, 16384, 32, 512]); the pool
+    and the tails are donated and addressed in place (nothing yields a whole
+    layer of the K or V pool); the decode window calls the block-table
+    kernel once an attention layer and XLA's grouped product three times an
+    expert layer."""
+    compiled, (pool, rec) = lfm2_programs(program)
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(program, "held", held, "temp", mem.temp_size_in_bytes,
+          "args", mem.argument_size_in_bytes)
+    assert held < V5E_USABLE_BYTES, held
+    assert pool["k"].shape == (2, 16384, 32, 512)
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, rec)))
+    assert mem.alias_size_in_bytes >= donated
+    text = compiled.as_text()
+    assert _pool_sized_ops(text, pool["k"].size // 2, 2, "bf16") == []
+    # (XLA's grouped product is a tpu_custom_call too: tell the kernel by
+    # its name, as benchmarks/layer_metrics/paged_attn_roofline.lfm2.py does)
+    kernels = len(re.findall(r"%paged_decode_attention[\w.\-]* = ", text))
+    assert kernels == (2 if program.startswith("decode") else 0)
+    assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ",
+                          text)) == 3 * 8
+
+
 # -- the decode window buffer -------------------------------------------------
 #
 # The W rows a decode window produces are a scan CARRY of ``serving/dense.py``
