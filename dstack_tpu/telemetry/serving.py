@@ -312,13 +312,16 @@ class EngineTelemetry:
 
     def record_expert_load(self, held: float, absent: float,
                            load_max: float, load_mean: float,
-                           touched: float) -> None:
+                           touched: float, rows_computed: float) -> None:
         """One drained decode window of a model with routed experts, from
         the sums its program returned: token-expert pairs that fell on
         experts ``held`` on this chip and on ``absent`` ones, and, summed
         over the window's expert layer-steps, the largest and the mean
         number of pairs one held expert got and the number of held experts
-        that got any (``touched``: their weights are what the step read)."""
+        that got any (``touched``: their weights are what the step read)
+        and the rows the grouped product computed for them
+        (``rows_computed``: row tiles visited x the tile's rows; ``held``
+        over it is the share of the product that is not padding)."""
         r = self.recorder
         r.counter(PREFIX + "moe_pairs_total",
                   labels={"where": "held"}).inc(held)
@@ -327,6 +330,7 @@ class EngineTelemetry:
         r.counter(PREFIX + "moe_expert_load_max_sum").inc(load_max)
         r.counter(PREFIX + "moe_expert_load_mean_sum").inc(load_mean)
         r.counter(PREFIX + "moe_experts_touched_sum").inc(touched)
+        r.counter(PREFIX + "moe_rows_computed_total").inc(rows_computed)
 
     def record_loop_passes(self, passes: float, exit_tokens) -> None:
         """One drained decode window of a looped decoder, from the sums its

@@ -185,7 +185,7 @@ async def check_serving_metrics() -> int:
     tel.record_prefill_backlog(512)
     tel.record_preemption("kv_blocks_exhausted")
     tel.record_program_built("decode")
-    tel.record_expert_load(200.0, 600.0, 9.0, 2.0, 90.0)
+    tel.record_expert_load(200.0, 600.0, 9.0, 2.0, 90.0, 1024.0)
     tel.record_recurrent_state_bytes(1 << 20)
     tel.record_loop_passes(256.0, [0.0, 3.0, 0.0, 500.0])
     tel.record_kv_geometry(192, 1572864)
@@ -248,6 +248,7 @@ async def check_serving_metrics() -> int:
             "dstack_serving_moe_expert_load_max_sum",
             "dstack_serving_moe_expert_load_mean_sum",
             "dstack_serving_moe_experts_touched_sum",
+            "dstack_serving_moe_rows_computed_total",
             "dstack_serving_recurrent_state_bytes",
             "dstack_serving_loop_passes_total",
             "dstack_serving_loop_exit_tokens_total",

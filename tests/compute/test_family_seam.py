@@ -278,7 +278,16 @@ def test_what_the_families_share_exists_once():
     where = lambda needle: sorted(
         str(path.relative_to(ROOT / "dstack_tpu"))
         for path, text in sources.items() if needle in text)
-    assert where("jax.lax.ragged_dot(") == ["models/experts.py"]
+    # the grouped product is the repo's own kernel: one pallas_call under its
+    # name, reached through held_experts alone; XLA's ragged_dot is what
+    # held_experts keeps for the CPU backend (the tests' reference at toy
+    # sizes: no backend a cell runs on takes it) and is nowhere else
+    assert where("ragged_dot(") == ["models/experts.py"]
+    kernel = sources[ROOT / "dstack_tpu" / "ops" / "grouped_matmul.py"]
+    assert kernel.count("pl.pallas_call(") == 1
+    assert where("grouped_swiglu(") == ["models/experts.py",
+                                        "ops/grouped_matmul.py"]
+    assert where("grouped_matmul(") == ["ops/grouped_matmul.py"]
     assert where("jnp.logaddexp(lse_c") == ["serving/paged_window.py"]
     assert where("jnp.take_along_axis(tables") == ["serving/paged_window.py"]
     assert where("def masked_attention(") == ["serving/paged_window.py"]
@@ -289,5 +298,5 @@ def test_what_the_families_share_exists_once():
                    "serving/lfm2.py"):
         assert caller in where("paged_window.window_rows(")
         assert caller in where("paged_window.chunk_pages(")
-    for caller in ("models/ling_hybrid.py", "models/lfm2.py"):
-        assert caller in where("held_experts(")
+    assert where("held_experts(") == ["models/experts.py", "models/lfm2.py",
+                                      "models/ling_hybrid.py"]

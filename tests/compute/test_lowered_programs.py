@@ -12,7 +12,10 @@ One entry is the tree's AFTER it: the Ling decode window's text differs from
 the parent's in the PLACE of one operation (the end-of-window scatter's
 ``pos % block_size`` is now computed before the flat index's first product,
 where ``serving/dense.py`` always had it; same operations, same operands:
-the compiled CPU program is the same multiset of instructions).
+the compiled CPU program is the same multiset of instructions).  PR 36
+rewrote the three Ling entries: the expert-load vector the programs return
+has a sixth field (``models/experts.py`` ``LOAD_FIELDS``); the grouped
+product itself is the parent's on the CPU backend (``ragged_dot``).
 A change that is meant to alter one of these programs rewrites the file:
 
     JAX_PLATFORMS=cpu python tests/compute/test_lowered_programs.py --write

@@ -489,6 +489,20 @@ def test_engine_program_temporaries_ignore_pool(engine_programs, geometry,
         grown, large_pool - small_pool)
 
 
+def _experts_stream_in_place(text: str, expert_layers: int, experts: int,
+                             hidden: int, width: int) -> None:
+    """The compiled program runs the experts through the repo's grouped
+    product alone (``ops/grouped_matmul.py``: gate and up in one call, down
+    in a second; the decode window's sit in its step loop, once), XLA's
+    ``ragged-dot`` is gone, and nothing copies or re-lays a layer's stack of
+    expert matrices in front of the kernel (ROADMAP S6 is what that would
+    look like)."""
+    assert not re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ", text)
+    assert len(re.findall(r"%grouped_matmul[\w.\-]* = ",
+                          text)) == 2 * expert_layers
+    assert _pool_sized_ops(text, experts * hidden * width, 1, "bf16") == []
+
+
 #: the hybrid cell of BENCHMARK.json as its files size it: every width, all
 #: seven layers, 128 slots, 8,192 blocks.  ``decode_w64`` at the widest table
 #: bucket (128 columns): its gathered view is twice a layer of the pool, so
@@ -573,10 +587,8 @@ def test_hybrid_program_fits_and_copies_no_state(hybrid_programs, program):
     assert _pool_sized_ops(text, state.size // layers, layers, "f32") == []
     assert _pool_sized_ops(text, pool.size // pool.shape[0], pool.shape[0],
                            "bf16") == []
-    # the grouped expert product is XLA's own kernel, three an expert layer
-    # (the decode window's sit in its step loop, once)
-    assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ",
-                          text)) == 3 * 6
+    _experts_stream_in_place(text, expert_layers=6, experts=128,
+                             hidden=2560, width=768)
 
 
 #: the looped decoder's cell of BENCHMARK.json as its files size it: every
@@ -737,8 +749,8 @@ def test_lfm2_program_fits_and_copies_no_pool(lfm2_programs, program):
     weights at the cell's own pool size (2 x [2, 16384, 32, 512]); the pool
     and the tails are donated and addressed in place (nothing yields a whole
     layer of the K or V pool); the decode window calls the block-table
-    kernel once an attention layer and XLA's grouped product three times an
-    expert layer."""
+    kernel once an attention layer and the grouped product's kernel twice
+    an expert layer, on the experts' matrices where they lie."""
     compiled, (pool, rec) = lfm2_programs(program)
     mem = compiled.memory_analysis()
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -752,12 +764,13 @@ def test_lfm2_program_fits_and_copies_no_pool(lfm2_programs, program):
     assert mem.alias_size_in_bytes >= donated
     text = compiled.as_text()
     assert _pool_sized_ops(text, pool["k"].size // 2, 2, "bf16") == []
-    # (XLA's grouped product is a tpu_custom_call too: tell the kernel by
-    # its name, as benchmarks/layer_metrics/paged_attn_roofline.lfm2.py does)
+    # (the grouped product is a tpu_custom_call too: tell the kernels by
+    # their names, as benchmarks/layer_metrics/paged_attn_roofline.lfm2.py
+    # does)
     kernels = len(re.findall(r"%paged_decode_attention[\w.\-]* = ", text))
     assert kernels == (2 if program.startswith("decode") else 0)
-    assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ",
-                          text)) == 3 * 8
+    _experts_stream_in_place(text, expert_layers=8, experts=64, hidden=2048,
+                             width=1536)
 
 
 # -- the decode window buffer -------------------------------------------------
