@@ -112,6 +112,46 @@ class Request:
         self.cancelled = True
 
 
+class _Phase:
+    """One phase of the engine's loop, as a context manager: the
+    ``engine.<phase>`` span on the profiler's clock (the device trace's
+    own; ``args`` say whose span it is and are formatted only while a
+    trace is taken) and, with telemetry on, the phase's count and its SELF
+    seconds on ``time.perf_counter`` (``EngineTelemetry.record_phase``).
+
+    A phase opened inside another pauses it.  The engine's stack holds one
+    float a phase that is open: the seconds of self time so far while the
+    phase is paused, and ``now`` less those seconds while it runs, so a
+    boundary is one clock reading that ends one phase's time and starts
+    the other's.  Single writer (the engine's thread): no lock."""
+
+    __slots__ = ("_span", "_telemetry", "_stack", "_phase")
+
+    def __init__(self, engine: "InferenceEngine", phase: str, args: dict):
+        self._span = jax.profiler.TraceAnnotation("engine." + phase, **args)
+        self._telemetry = engine.telemetry
+        self._stack = engine._phase_stack
+        self._phase = phase
+
+    def __enter__(self) -> None:
+        if self._telemetry is not None:
+            now = time.perf_counter()
+            stack = self._stack
+            if stack:
+                stack[-1] = now - stack[-1]     # pause the parent
+            stack.append(now)
+        self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        if self._telemetry is not None:
+            now = time.perf_counter()
+            stack = self._stack
+            self._telemetry.record_phase(self._phase, now - stack.pop())
+            if stack:
+                stack[-1] = now - stack[-1]     # the parent runs again
+
+
 class InferenceEngine:
     """Slot-based continuous batching over one model replica.
 
@@ -344,6 +384,15 @@ class InferenceEngine:
         self._watchdog_s = float(os.environ.get(
             "DSTACK_TPU_ENGINE_WATCHDOG_S", "300"))
         self._step_started_at: Optional[float] = None
+        #: the open phases' clocks, innermost last (see :class:`_Phase`)
+        self._phase_stack: List[float] = []
+        #: decode windows dispatched so far: the ``window`` argument that
+        #: joins a window's dispatch, pull and emit spans
+        self._window_seq = 0
+
+    def _phase(self, phase: str, **args) -> _Phase:
+        """The context of one phase of the loop (``telemetry.PHASES``)."""
+        return _Phase(self, phase, args)
 
     def _reset_device_state(self) -> None:
         """(Re-)allocate the family's device state and the slot state.
@@ -414,7 +463,7 @@ class InferenceEngine:
         while not self._stop:
             if not self.has_work():
                 try:
-                    with jax.profiler.TraceAnnotation("engine.wait_for_work"):
+                    with self._phase("wait_for_work"):
                         req = self._queue.get(timeout=0.05)
                     self._queue.put(req)
                 except queue.Empty:
@@ -528,15 +577,19 @@ class InferenceEngine:
         # chunks this step may still put ahead of its window
         budget = self.batch_size
         if self._pending is not None:
-            nxt = None
+            nxt, broke = None, "admission"
             if not self._can_admit():
                 # chunks chain on the donated cache behind the in-flight
                 # window, and ahead of nxt
                 spent, completed = self._advance_chunks(budget)
                 budget -= spent
+                broke = "prompt_completed"
                 if not completed:
                     nxt = self._dispatch_window(
                         self._pending["remaining_after"])
+                    broke = None if nxt is not None else "drained"
+            if self.telemetry is not None:
+                self.telemetry.record_window_chain(broke)
             self._drain_window()
             self._finish_chunked()
             self._pending = nxt
@@ -580,7 +633,8 @@ class InferenceEngine:
                         if self.telemetry is not None:
                             self.telemetry.record_finished(req)
                     break
-                with jax.profiler.TraceAnnotation("engine.chunk"):
+                with self._phase("chunk", slot=slot_id, tokens=min(
+                        self.prefill_chunk, len(st["tokens"]) - st["done"])):
                     self._dispatch_chunk(slot_id, st)
                 spent += 1
                 completed = completed or "logits" in st
@@ -628,9 +682,11 @@ class InferenceEngine:
                 for i, bkey in enumerate(self._slot_prefix[slot_id][1]):
                     if (i + 1) * self._block_size <= n and i < len(blocks):
                         self._alloc.register(bkey, blocks[i])
-            with jax.profiler.TraceAnnotation("engine.chunk"):
-                self._activate(slot_id, req, n,
-                               self._sample_first(st["logits"], req))
+            # the span that activates a completed prompt: no chunk goes
+            # out under it
+            with self._phase("chunk", slot=slot_id, tokens=0):
+                self._activate(slot_id, req, n, self._sample_first(
+                    st["logits"], req, slot_id, n))
 
     def _can_admit(self) -> bool:
         """A waiting request could take a free slot."""
@@ -642,7 +698,7 @@ class InferenceEngine:
         ``engine.admit`` span per call that has both."""
         if not self._can_admit():
             return
-        with jax.profiler.TraceAnnotation("engine.admit"):
+        with self._phase("admit"):
             self._admit_into_free_slots()
 
     def _admit_into_free_slots(self) -> None:
@@ -696,11 +752,12 @@ class InferenceEngine:
                     self.telemetry.record_kv_utilization(
                         self._kv_used_fraction())
                 try:
+                    n = self._prompt_len(req)
                     if req.prefill is not None:
-                        with jax.profiler.TraceAnnotation("engine.prefill"):
+                        with self._phase("prefill", slot=slot_id, tokens=n):
                             self._insert_prefilled(slot_id, req)
                     elif (self.prefill_chunk is not None
-                          and self._prompt_len(req) > self.prefill_chunk):
+                          and n > self.prefill_chunk):
                         # long prompt: claim the slot now, prefill in chunks
                         # on the steps' budget (interleaved with decode
                         # windows); the slot stays inactive until the last
@@ -717,7 +774,7 @@ class InferenceEngine:
                         self._chunking[slot_id] = {"tokens": tokens,
                                                    "done": done}
                     else:
-                        with jax.profiler.TraceAnnotation("engine.prefill"):
+                        with self._phase("prefill", slot=slot_id, tokens=n):
                             self._prefill(slot_id, req)
                 except Exception:
                     # claim the slot so the crash handler (run_forever)
@@ -814,7 +871,7 @@ class InferenceEngine:
         fn = table.get(key)
         if fn is not None:
             return fn(*args)
-        with jax.profiler.TraceAnnotation("engine.build_program"):
+        with self._phase("build_program"):
             fn = table[key] = make()
             if self.telemetry is not None:
                 self.telemetry.record_program_built(
@@ -890,7 +947,8 @@ class InferenceEngine:
             # (prefix reuse prefills only the suffix)
             self.telemetry.record_prefill(n - prefix_len,
                                           self._bucket(n - prefix_len))
-        self._activate(slot_id, req, n, self._sample_first(logits, req))
+        self._activate(slot_id, req, n,
+                       self._sample_first(logits, req, slot_id, n))
 
     def _activate(self, slot_id: int, req: Request, n: int,
                   first: int) -> None:
@@ -956,7 +1014,8 @@ class InferenceEngine:
         if p.get("logits") is not None:
             # request-aware first token (temperature/top_p/top_k honored;
             # PD-wire logits arrive as numpy — asarray is host->device)
-            first = self._sample_first(jnp.asarray(p["logits"]), req)
+            first = self._sample_first(jnp.asarray(p["logits"]), req,
+                                       slot_id, n)
         else:
             first = int(p["first_token"])
         self._activate(slot_id, req, n, first)
@@ -1084,7 +1143,8 @@ class InferenceEngine:
         window = self._pick_window(remaining)
         sampling = any(
             req is not None and req.temperature > 0.0 for req in self._slots)
-        with jax.profiler.TraceAnnotation("engine.dispatch_window"):
+        self._window_seq += 1
+        with self._phase("dispatch_window", window=self._window_seq):
             return self._dispatch_window_program(remaining, window, sampling)
 
     def _decode_window_program(self, window: int, sampling: bool,
@@ -1152,10 +1212,11 @@ class InferenceEngine:
             slot_id for slot_id, req in enumerate(self._slots)
             if req is not None and slot_id not in self._chunking)
         pending = {"tokens": tokens_all, "window": window,
+                   "seq": self._window_seq,
                    "remaining_after": remaining - window,
                    "decoding": decoding, "window_counts": window_counts}
         if self.telemetry is not None:
-            self._record_dispatch(decoding, pending, nbk)
+            self._record_dispatch(decoding, pending)
         return pending
 
     def _kv_used_fraction(self) -> float:
@@ -1168,25 +1229,14 @@ class InferenceEngine:
         return (float(self._host_lengths.sum())
                 / max(self.batch_size * self.max_len, 1))
 
-    def _record_dispatch(self, decoding, pending: dict,
-                         nbk: Optional[int]) -> None:
-        """Per-window telemetry at dispatch time (batch occupancy, the
-        pages the window's table walk covers and those that are live, KV
+    def _record_dispatch(self, decoding, pending: dict) -> None:
+        """Per-window telemetry at dispatch time (batch occupancy, KV
         utilization, queue depth) + the monotonic stamp the drain uses
         for inter-token latency.  Only called when telemetry is on."""
         t = self.telemetry
         if t is None:  # callers gate too; cheap belt for new call sites
             return
-        live = walked = 0
-        if nbk is not None:
-            # rows the cache holds for a slot as this window starts: the
-            # host's count lags by a window still in flight (as in
-            # _ragged_blocks, which sized nbk to cover them)
-            held = (self._host_lengths[sorted(decoding)]
-                    + self._inflight_steps())
-            live = int(np.minimum(-(-held // self._block_size), nbk).sum())
-            walked = self.batch_size * nbk
-        t.record_window(len(decoding), self.batch_size, live, walked)
+        t.record_window(len(decoding), self.batch_size)
         t.record_kv_utilization(self._kv_used_fraction())
         t.record_queue_depth(self._queue.qsize())
         t.record_prefill_backlog(self._chunk_backlog())
@@ -1200,16 +1250,20 @@ class InferenceEngine:
             for st in self._chunking.values() if "logits" not in st)
 
     def _drain_window(self) -> None:
-        """Pull the in-flight window's tokens to the host and emit them —
-        the ONE device->host sync per window."""
+        """Pull the in-flight window's tokens (and what it counted about
+        itself) to the host and emit them — the ONE device->host sync per
+        window."""
         p = self._pending
         if p is None:
             return
         self._pending = None
-        with jax.profiler.TraceAnnotation("engine.pull"):
+        window_counts = None
+        with self._phase("pull", window=p["seq"]):
             tokens_np = np.asarray(p["tokens"])
+            if self.telemetry is not None and p.get("window_counts"):
+                window_counts = np.asarray(p["window_counts"][0])
         emitted = 0
-        with jax.profiler.TraceAnnotation("engine.emit"):
+        with self._phase("emit", window=p["seq"]):
             for step in range(p["window"]):
                 for slot_id, req in enumerate(self._slots):
                     if req is None or slot_id not in p["decoding"]:
@@ -1225,13 +1279,17 @@ class InferenceEngine:
             self.telemetry.record_drain(
                 emitted, time.perf_counter() - p["t0"], len(p["decoding"]),
                 steps=p["window"], batch_size=self.batch_size)
-            if p.get("window_counts"):
-                self._programs.record_window_counts(
-                    self.telemetry, np.asarray(p["window_counts"][0]))
+            if window_counts is not None:
+                self._programs.record_window_counts(self.telemetry,
+                                                    window_counts)
 
-    def _sample_first(self, logits, req: Request) -> int:
+    def _sample_first(self, logits, req: Request, slot_id: int,
+                      n: int) -> int:
         """Sample a request's FIRST token with the same fused on-device
-        sampler the decode windows use (:meth:`_sample_on_device`).
+        sampler the decode windows use (:meth:`_sample_on_device`), under
+        an ``engine.first_token`` phase: the program call and the pull of
+        its token, the one device->host sync a request (the wait for the
+        prompt's program; ``n`` its tokens, for the span).
 
         This replaced a host-side numpy softmax/top-p sampler that pulled
         the full [V] logits vector to the host per admission — the last
@@ -1250,11 +1308,12 @@ class InferenceEngine:
             self._rng_key, sub = jax.random.split(self._rng_key)
         else:
             sub = self._rng_key  # greedy ignores it; don't burn entropy
-        return int(self._run_program(
-            self._prefill_jit, "first_token",
-            lambda: self._jit_cached(fn, "first_token_sample"),
-            jnp.asarray(logits), jnp.float32(req.temperature),
-            jnp.float32(req.top_p), jnp.int32(req.top_k or 0), sub))
+        with self._phase("first_token", slot=slot_id, tokens=n):
+            return int(self._run_program(
+                self._prefill_jit, "first_token",
+                lambda: self._jit_cached(fn, "first_token_sample"),
+                jnp.asarray(logits), jnp.float32(req.temperature),
+                jnp.float32(req.top_p), jnp.int32(req.top_k or 0), sub))
 
     def _emit(self, slot_id: int, req: Request, token: int) -> None:
         if (not req.cancelled and req.deadline is not None

@@ -33,12 +33,26 @@ republished with project/run/job/replica labels):
   ``decode_tokens_total`` over ``decode_slot_steps_total`` is the share of
   that work that became a token; steps over
   ``batch_occupancy_count{phase=decode}`` is the mean window size
-- ``paged_walk_pages_total{kind}`` counter — per decode window of a paged
-  engine, at dispatch: ``walked`` = ``batch_size`` x the window's table
-  bucket, the pages a step's walk of the block tables covers;
-  ``live`` = the pages that hold the decoding slots' rows.  Live over
-  walked is the share of the walk that reads anything: what bounding the
-  walk further (a list of live blocks only) could still save
+- ``engine_phase_seconds_total{phase}`` / ``engine_phases_total{phase}``
+  counters — the engine thread's time by the phase of its loop
+  (``PHASES``: the ``engine.<phase>`` profiler spans of
+  ``serving/engine.py``, one count and its seconds a span, on
+  ``time.perf_counter``).  SELF time: a phase opened inside another
+  (``prefill`` in ``admit``, ``first_token`` in ``prefill`` or ``chunk``,
+  ``build_program`` in any) pauses its parent, so no second is counted
+  twice and the phases' sum is at most the thread's wall time.  ``pull``
+  and ``first_token`` hold every device->host transfer of the thread (the
+  wait for a decode window, for a prompt's program), ``wait_for_work`` the
+  idle loop: the rest is the host's own work.  A phase still open when the
+  counters are read has not been added yet
+- ``windows_dispatched_ahead_total`` counter — decode windows enqueued
+  before their predecessor's tokens were pulled (over
+  ``batch_occupancy_count{phase=decode}``, all windows: the share of the
+  window chain that is pipelined); ``window_chain_breaks_total{reason}``
+  counts the scheduling steps that had a window in flight and dispatched
+  none behind it: ``admission`` (a waiting request could take a free
+  slot), ``prompt_completed`` (the step's chunks ended a prompt, which
+  joins the next window), ``drained`` (nothing left to decode)
 - ``prefill_chunks_total`` / ``prefill_chunk_steps_total`` counters —
   chunked-prefill programs dispatched, and scheduling steps that
   dispatched any: their ratio is chunks per step (a step may spend up to
@@ -70,6 +84,15 @@ from dstack_tpu.telemetry.recorder import (
 from dstack_tpu.serving.wire import LOAD_HEADER_PREFIX
 
 PREFIX = "dstack_serving_"
+
+#: the phases of the engine's loop (``serving/engine.py``): each is an
+#: ``engine.<phase>`` profiler span and a label value of the two
+#: ``engine_phase*`` counter families
+PHASES = ("wait_for_work", "admit", "prefill", "chunk", "first_token",
+          "dispatch_window", "pull", "emit", "build_program")
+#: why a scheduling step with a decode window in flight dispatched none
+#: behind it (``window_chain_breaks_total{reason}``)
+CHAIN_BREAKS = ("admission", "prompt_completed", "drained")
 
 #: response-header prefix the serving server uses to piggyback its load
 #: snapshot on every proxied response (the gateway's passive load feed —
@@ -160,15 +183,23 @@ class EngineTelemetry:
         self.decode_steps = r.counter(PREFIX + "decode_steps_total")
         self.decode_slot_steps = r.counter(
             PREFIX + "decode_slot_steps_total")
-        self.walk_pages_live = r.counter(
-            PREFIX + "paged_walk_pages_total", labels={"kind": "live"})
-        self.walk_pages_walked = r.counter(
-            PREFIX + "paged_walk_pages_total", labels={"kind": "walked"})
         self.prefill_chunks = r.counter(PREFIX + "prefill_chunks_total")
         self.prefill_chunk_steps = r.counter(
             PREFIX + "prefill_chunk_steps_total")
         self.prefill_budget_exhausted = r.counter(
             PREFIX + "prefill_budget_exhausted_total")
+        self.windows_ahead = r.counter(
+            PREFIX + "windows_dispatched_ahead_total")
+        self._chain_breaks = {
+            reason: r.counter(PREFIX + "window_chain_breaks_total",
+                              labels={"reason": reason})
+            for reason in CHAIN_BREAKS}
+        self._phases = {
+            phase: (r.counter(PREFIX + "engine_phase_seconds_total",
+                              labels={"phase": phase}),
+                    r.counter(PREFIX + "engine_phases_total",
+                              labels={"phase": phase}))
+            for phase in PHASES}
         self._started_at = time.time()
 
     # -- engine-thread recording hooks ----------------------------------
@@ -253,17 +284,27 @@ class EngineTelemetry:
         if budget_exhausted:
             self.prefill_budget_exhausted.inc()
 
-    def record_window(self, decoding: int, batch_size: int,
-                      live_pages: int = 0, walked_pages: int = 0) -> None:
-        """One decode window at dispatch.  A paged engine passes the pages
-        its decoding slots' rows lie in (``live_pages``) and the pages a
-        step's walk covers, every slot's table at the window's bucket
-        (``walked_pages``)."""
+    def record_window(self, decoding: int, batch_size: int) -> None:
+        """One decode window at dispatch."""
         self.active_slots.set(decoding)
         if batch_size > 0:
             self.decode_occupancy.observe(min(decoding / batch_size, 1.0))
-        self.walk_pages_live.inc(live_pages)
-        self.walk_pages_walked.inc(walked_pages)
+
+    def record_window_chain(self, broke: Optional[str]) -> None:
+        """One scheduling step that began with a decode window in flight:
+        it enqueued the next window ahead of that one's drain (``broke``
+        None) or broke the chain for the reason given (``CHAIN_BREAKS``)."""
+        if broke is None:
+            self.windows_ahead.inc()
+        else:
+            self._chain_breaks[broke].inc()
+
+    def record_phase(self, phase: str, self_seconds: float) -> None:
+        """One ``engine.<phase>`` span of the engine's loop closed, with
+        the time it spent outside the phases opened inside it."""
+        seconds, count = self._phases[phase]
+        seconds.inc(self_seconds)
+        count.inc()
 
     def record_drain(self, tokens_emitted: int, wall: float,
                      decoding: int = 1, steps: int = 0,
@@ -403,7 +444,8 @@ def make_engine_telemetry(env: Optional[dict] = None,
     return EngineTelemetry(tracer=make_tracer(env))
 
 
-__all__ = ["EngineTelemetry", "make_engine_telemetry", "PREFIX",
+__all__ = ["EngineTelemetry", "make_engine_telemetry", "PREFIX", "PHASES",
+           "CHAIN_BREAKS",
            "LATENCY_BUCKETS", "RATIO_BUCKETS",
            "LOAD_HEADER_PREFIX", "LOAD_HEADER_FIELDS",
            "load_headers", "parse_load_headers"]

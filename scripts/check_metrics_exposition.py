@@ -180,6 +180,9 @@ async def check_serving_metrics() -> int:
     tel.record_first_token(0.04, trace_id=trace_id)
     tel.record_prefill(100, 128)
     tel.record_window(6, 8)
+    tel.record_window_chain(None)
+    tel.record_window_chain("admission")
+    tel.record_phase("pull", 0.4)
     tel.record_drain(64, 0.5, steps=64, batch_size=8)
     tel.record_kv_utilization(0.4)
     tel.record_prefill_backlog(512)
@@ -241,7 +244,10 @@ async def check_serving_metrics() -> int:
             "dstack_serving_decode_tokens_total",
             "dstack_serving_decode_steps_total",
             "dstack_serving_decode_slot_steps_total",
-            "dstack_serving_paged_walk_pages_total",
+            "dstack_serving_engine_phase_seconds_total",
+            "dstack_serving_engine_phases_total",
+            "dstack_serving_windows_dispatched_ahead_total",
+            "dstack_serving_window_chain_breaks_total",
             "dstack_serving_programs_built_total",
             "dstack_serving_preemptions_total",
             "dstack_serving_moe_pairs_total",

@@ -283,6 +283,14 @@ def _real_replica_app(name):
     return engine, serving, worker
 
 
+def _stop_replica(engine, worker):
+    """Stop a ``_real_replica_app`` loop and wait for its thread: a loop
+    left running idles in the worker's later test files too."""
+    engine.stop()
+    worker.join(timeout=30)
+    assert not worker.is_alive()
+
+
 async def test_standalone_drain_is_reversible(tmp_path):
     """`{"draining": false}` undoes a maintenance drain — without it a
     stray drain would shun a healthy replica until a process restart."""
@@ -409,7 +417,7 @@ async def test_drain_race_after_admission_check_still_503(tmp_path):
     top-of-handler draining check (handlers await the body / tokenize in
     between) must still surface as the documented 503 + Retry-After, not
     an unhandled EngineDraining 500."""
-    eng, serving, _ = _real_replica_app("rep-race")
+    eng, serving, worker = _real_replica_app("rep-race")
     c = TestClient(TestServer(serving.make_app()))
     await c.start_server()
     try:
@@ -425,7 +433,7 @@ async def test_drain_race_after_admission_check_still_503(tmp_path):
             assert r.status == 503, await r.text()
             assert r.headers.get("Retry-After")
     finally:
-        eng.stop()
+        _stop_replica(eng, worker)
         await c.close()
 
 
@@ -438,9 +446,10 @@ async def test_replica_kill_mid_decode_stream_completes(tmp_path):
     engines = []
     clients = []
     try:
-        eng_a, app_a, _ = _real_replica_app("rep-a")
-        eng_b, app_b, _ = _real_replica_app("rep-b")
-        engines += [eng_a, eng_b]
+        eng_a, app_a, worker_a = _real_replica_app("rep-a")
+        engines.append((eng_a, worker_a))
+        eng_b, app_b, worker_b = _real_replica_app("rep-b")
+        engines.append((eng_b, worker_b))
         for serving in (app_a, app_b):
             c = TestClient(TestServer(serving.make_app()))
             await c.start_server()
@@ -498,7 +507,7 @@ async def test_replica_kill_mid_decode_stream_completes(tmp_path):
             await asyncio.sleep(0.1)
         assert "a" not in reps and "b" in reps
     finally:
-        for eng in engines:
-            eng.stop()
+        for eng, worker in engines:
+            _stop_replica(eng, worker)
         for c in clients:
             await c.close()

@@ -1,8 +1,9 @@
 """What the engine says about itself: its programs carry their compile-cache
 tags as names, the phases of its scheduling step are ``engine.*`` spans on
-the profiler's clock (on the engine's own thread), its decode counters add
-up, and request stamps never run backwards.  CPU, toy size: names and
-counts only, no device number."""
+the profiler's clock (on the engine's own thread) that say whose they are
+and are counted, with their self time, by the telemetry; the window chain
+and the decode counters add up, and request stamps never run backwards.
+CPU, toy size: names and counts only, no device number."""
 
 import threading
 import time
@@ -14,8 +15,8 @@ ENGINE_ARGS = dict(batch_size=4, max_len=128, paged=True, kv_block_size=16,
                    total_kv_blocks=40, prefill_chunk=64)
 #: the span names of ``InferenceEngine``'s loop (docs/concepts/observability.md)
 SPANS = ("engine.wait_for_work", "engine.admit", "engine.prefill",
-         "engine.chunk", "engine.dispatch_window", "engine.pull",
-         "engine.emit", "engine.build_program")
+         "engine.chunk", "engine.first_token", "engine.dispatch_window",
+         "engine.pull", "engine.emit", "engine.build_program")
 
 
 @pytest.fixture(scope="module")
@@ -49,7 +50,9 @@ def _counters(engine) -> dict:
 @pytest.fixture(scope="module")
 def traced(model, tmp_path_factory):
     """One short run of the serving loop under the profiler: a warm-up
-    request outside the trace, then short and chunked prompts inside it."""
+    request outside the trace, then the loop's whole life inside it (short
+    and chunked prompts, then idle), so that every phase the counters
+    count between ``before`` and ``after`` is a span of the profile."""
     import jax
     from jax.profiler import ProfileData
 
@@ -60,12 +63,13 @@ def traced(model, tmp_path_factory):
     before = _counters(engine)
     loop = threading.Thread(target=engine.run_forever, name="engine",
                             daemon=True)
-    loop.start()
     trace_dir = tmp_path_factory.mktemp("trace")
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 2
     jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    started = time.perf_counter()
+    loop.start()
     try:
         with jax.profiler.TraceAnnotation("test.main_thread"):
             requests = [engine.submit(Request(tokens=list(range(1, n + 1)),
@@ -75,15 +79,26 @@ def traced(model, tmp_path_factory):
                 assert r.done.wait(120)
         time.sleep(0.15)            # the loop goes idle: wait_for_work
     finally:
-        jax.profiler.stop_trace()
         engine.stop()
         loop.join(timeout=30)
+        wall = time.perf_counter() - started
+        jax.profiler.stop_trace()
+    assert not loop.is_alive()
     path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
-    lines = [[e.name for e in line.events]
-             for plane in ProfileData.from_file(str(path)).planes
-             if plane.name.startswith("/host:") for line in plane.lines]
+    host = [line for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:") for line in plane.lines]
+    lines = [[e.name for e in line.events] for line in host]
+    # the engine's spans with the arguments that say whose they are
+    spans = [(e.name, dict(e.stats)) for line in host for e in line.events
+             if e.name.startswith("engine.")]
     return {"engine": engine, "requests": requests, "lines": lines,
+            "spans": spans, "loop_wall_s": wall,
             "before": before, "after": _counters(engine)}
+
+
+def _delta(traced, name):
+    return traced["after"]["dstack_serving_" + name] - traced["before"].get(
+        "dstack_serving_" + name, 0.0)
 
 
 @pytest.mark.parametrize("span", SPANS)
@@ -105,6 +120,192 @@ def test_no_span_encloses_the_step_or_a_token(traced):
     assert set(names) <= set(SPANS)
     tokens = sum(len(r.output) for r in traced["requests"])
     assert names.count("engine.emit") == names.count("engine.pull") < tokens
+
+
+@pytest.mark.parametrize("span", SPANS)
+def test_phase_counter_counts_the_spans_of_its_name(traced, span):
+    """``engine_phases_total{phase}`` grows by one a span: the counter and
+    the span are opened by the same context."""
+    phase = span[len("engine."):]
+    in_profile = sum(1 for name, _ in traced["spans"] if name == span)
+    assert in_profile > 0
+    assert _delta(traced, f"engine_phases_total{{phase={phase}}}") == \
+        in_profile
+    assert _delta(traced, f"engine_phase_seconds_total{{phase={phase}}}") > 0
+
+
+def test_phase_seconds_are_self_time_within_the_loops_wall_time(traced):
+    """``admit`` holds ``prefill`` holds ``first_token`` (holds
+    ``build_program``) in the fixture's run: counted as whole spans they
+    would pass the thread's wall time between them; as self time all the
+    phases together stay under it."""
+    from dstack_tpu.telemetry.serving import PHASES
+
+    assert {"engine." + phase for phase in PHASES} == set(SPANS)
+    total = sum(_delta(traced, f"engine_phase_seconds_total{{phase={p}}}")
+                for p in PHASES)
+    assert 0 < total <= traced["loop_wall_s"]
+    assert traced["engine"]._phase_stack == []      # every phase closed
+
+
+def test_self_time_by_hand_on_a_stepped_clock(model, monkeypatch):
+    """admit 0-15 s holding prefill 1-10 s holding first_token 3-6 s:
+    1 + 5, 2 + 4 and 3 s of self time, 15 s in all, no second twice."""
+    engine = _engine(model)
+    clock = iter([0.0, 1.0, 3.0, 6.0, 10.0, 15.0])
+    monkeypatch.setattr(time, "perf_counter", lambda: next(clock))
+    with engine._phase("admit"):
+        with engine._phase("prefill", slot=0, tokens=40):
+            with engine._phase("first_token", slot=0, tokens=40):
+                pass
+    monkeypatch.undo()
+    counters = _counters(engine)
+    seconds = {p: counters[
+        f"dstack_serving_engine_phase_seconds_total{{phase={p}}}"]
+        for p in ("admit", "prefill", "first_token", "pull")}
+    assert seconds == {"admit": 6.0, "prefill": 6.0, "first_token": 3.0,
+                       "pull": 0.0}
+    assert counters["dstack_serving_engine_phases_total{phase=prefill}"] == 1
+
+
+def test_every_request_pulls_one_first_token(traced):
+    """The one device->host sync a request is the ``engine.first_token``
+    phase, whole-prompt and chunked alike; a window's is ``engine.pull``."""
+    tel = traced["engine"].telemetry
+    # less the warm-up request's, before the traced part
+    assert _delta(traced, "engine_phases_total{phase=first_token}") == \
+        len(traced["requests"]) == tel.queue_wait.count - 1
+    windows = tel.decode_occupancy.count - 1
+    assert _delta(traced, "engine_phases_total{phase=pull}") == windows
+
+
+def test_spans_say_whose_they_are(traced):
+    """A window's dispatch, pull and emit spans carry its sequence number;
+    a prompt's spans its slot and the tokens they put to the device."""
+    by_window = {}
+    for name, args in traced["spans"]:
+        if name in ("engine.dispatch_window", "engine.pull", "engine.emit"):
+            by_window.setdefault(args["window"], []).append(name)
+    # window 1 was the warm-up request's, outside the trace
+    windows = traced["engine"].telemetry.decode_occupancy.count
+    assert sorted(by_window) == list(range(2, windows + 1))
+    for names in by_window.values():
+        assert sorted(names) == ["engine.dispatch_window", "engine.emit",
+                                 "engine.pull"]
+    prompts = [(name, args["slot"], args["tokens"])
+               for name, args in traced["spans"] if name in (
+                   "engine.prefill", "engine.chunk", "engine.first_token")]
+    slots = {slot for _, slot, _ in prompts}
+    assert len(slots) == 3 and slots <= set(range(4))
+    assert sorted(t for n, _, t in prompts if n == "engine.prefill") == \
+        [40, 40]
+    # the 100-token prompt: chunks of 64 and 36, then its activation
+    assert [t for n, _, t in prompts if n == "engine.chunk"] == [64, 36, 0]
+    assert sorted(t for n, _, t in prompts if n == "engine.first_token") \
+        == [40, 40, 100]
+
+
+def test_span_arguments_are_formatted_only_under_a_trace(model):
+    """With no trace being taken a phase's arguments are never turned
+    into text: the cost of saying whose a span is falls on traced runs."""
+    class Loud:
+        shown = 0
+
+        def __str__(self):
+            Loud.shown += 1
+            return "loud"
+
+    engine = _engine(model)
+    with engine._phase("pull", window=Loud()):
+        pass
+    assert Loud.shown == 0
+
+
+def _chain(engine) -> dict:
+    c = _counters(engine)
+    out = {"ahead": c["dstack_serving_windows_dispatched_ahead_total"]}
+    for reason in ("admission", "prompt_completed", "drained"):
+        out[reason] = c[
+            f"dstack_serving_window_chain_breaks_total{{reason={reason}}}"]
+    return out
+
+
+def test_window_chain_by_hand_for_one_request_alone(model):
+    """24 tokens behind the prefill's first, in windows of 8: the first
+    window is dispatched with nothing in flight, the two behind it each
+    ahead of its predecessor's drain, and the step after the last finds
+    nothing left to decode."""
+    engine = _engine(model)
+    engine.DECODE_WINDOWS = (8,)
+    assert _chain(engine) == {"ahead": 0, "admission": 0,
+                              "prompt_completed": 0, "drained": 0}
+    req = engine.generate(list(range(1, 41)), max_new_tokens=25)
+    assert len(req.output) == 25
+    windows = engine.telemetry.decode_occupancy.count
+    assert windows == 3
+    assert _chain(engine) == {"ahead": windows - 1, "admission": 0,
+                              "prompt_completed": 0, "drained": 1}
+
+
+def _drive(engine, requests) -> None:
+    for _ in range(200):
+        if all(r.done.is_set() for r in requests):
+            return
+        engine.step()
+    raise AssertionError("requests did not finish in 200 steps")
+
+
+def test_window_chain_breaks_to_admit_a_waiting_request(model):
+    """More requests than slots, one of them short: its slot comes free
+    with a window in flight and a request waiting, and the next step
+    breaks the chain to admit."""
+    from dstack_tpu.serving.engine import Request
+
+    engine = _engine(model)
+    engine.DECODE_WINDOWS = (8,)
+    requests = [engine.submit(Request(tokens=list(range(1, 41)),
+                                      max_new_tokens=new))
+                for new in (9, 25, 25, 25, 9, 9)]
+    _drive(engine, requests)
+    assert [len(r.output) for r in requests] == [9, 25, 25, 25, 9, 9]
+    chain = _chain(engine)
+    assert chain["admission"] >= 1 and chain["prompt_completed"] == 0
+    # a step with a window in flight is counted once, under one name
+    windows = engine.telemetry.decode_occupancy.count
+    assert 0 < chain["ahead"] < windows
+    assert sum(chain.values()) <= windows
+
+
+def test_window_chain_breaks_where_a_chunked_prompt_completes(model):
+    """A prompt of seven chunks beside a decoding request: four go out
+    with the first window (a step's budget), the last three behind it,
+    and the step that completes the prompt dispatches no window ahead, so
+    that the new slot decodes in the next one."""
+    from dstack_tpu.serving.engine import Request
+
+    engine = _engine(model, prefill_chunk=16)
+    engine.DECODE_WINDOWS = (8,)
+    requests = [engine.submit(Request(tokens=list(range(1, n + 1)),
+                                      max_new_tokens=new))
+                for n, new in ((10, 25), (100, 9))]
+    _drive(engine, requests)
+    assert [len(r.output) for r in requests] == [25, 9]
+    chain = _chain(engine)
+    assert chain["prompt_completed"] == 1 and chain["admission"] == 0
+
+
+def test_engine_without_telemetry_serves_the_same_tokens(model):
+    from dstack_tpu.serving.engine import InferenceEngine
+
+    cfg, params = model
+    plain = InferenceEngine(cfg, params=params, telemetry=None,
+                            **ENGINE_ARGS)
+    counted = _engine(model)
+    for prompt, new in ((40, 12), (100, 9)):
+        tokens = list(range(1, prompt + 1))
+        assert plain.generate(tokens, max_new_tokens=new).output == \
+            counted.generate(tokens, max_new_tokens=new).output
+    assert plain._phase_stack == []          # nothing is kept without it
 
 
 def test_programs_are_named_by_their_compile_cache_tags(traced):
@@ -235,79 +436,29 @@ def test_decode_counters_add_up(traced):
     """Slot-steps are steps x all slots (what the device computes), tokens
     handed over never exceed them, and a window's steps are counted where
     it is dispatched."""
-    engine, after, before = (traced["engine"], traced["after"],
-                             traced["before"])
-
-    def delta(name):
-        return after["dstack_serving_" + name] - before.get(
-            "dstack_serving_" + name, 0.0)
-
-    steps, slot_steps = delta("decode_steps_total"), delta(
-        "decode_slot_steps_total")
+    engine = traced["engine"]
+    steps, slot_steps = _delta(traced, "decode_steps_total"), _delta(
+        traced, "decode_slot_steps_total")
     assert steps > 0 and steps % 8 == 0            # whole 8-step windows
     assert slot_steps == steps * engine.batch_size
-    assert 0 < delta("decode_tokens_total") <= slot_steps
+    assert 0 < _delta(traced, "decode_tokens_total") <= slot_steps
     # every request of the traced part: one first token from prefill, the
     # rest from decode windows
     tokens = sum(len(r.output) for r in traced["requests"])
-    assert delta("decode_tokens_total") == tokens - len(traced["requests"])
+    assert _delta(traced, "decode_tokens_total") == \
+        tokens - len(traced["requests"])
     windows = engine.telemetry.decode_occupancy.count
-    assert after["dstack_serving_decode_steps_total"] == 8 * windows
-
-
-def test_paged_walk_pages_by_hand(model):
-    """``paged_walk_pages_total``: per decode window, the pages a step's
-    table walk covers (all 4 slots x the window's bucket of columns) and
-    the pages the decoding slots' rows lie in, worked out by hand for one
-    request at a time over pages of 16 rows and windows of 8 steps."""
-    engine = _engine(model)
-
-    def walk():
-        c = _counters(engine)
-        return (c["dstack_serving_paged_walk_pages_total{kind=live}"],
-                c["dstack_serving_paged_walk_pages_total{kind=walked}"])
-
-    assert walk() == (0, 0)
-    # 40 rows lie in 3 pages; the window ends at 48 rows: 3 columns, in a
-    # bucket of 4, for each of the 4 slots.  One window: 8 tokens follow
-    # the prefill's first
-    engine.generate(list(range(1, 41)), max_new_tokens=9)
-    assert walk() == (3, 4 * 4)
-    # 100 rows (7 pages), two windows: 108 rows end in column 7 and 116
-    # in column 8, a bucket of 8 both; the second starts from 108 rows,
-    # still 7 pages
-    engine.generate(list(range(1, 101)), max_new_tokens=17)
-    live, walked = walk()
-    assert (live, walked) == (3 + 7 + 7, 16 + 2 * 4 * 8)
-    assert live <= walked
-    assert engine.telemetry.decode_occupancy.count == 3  # one inc a window
-
-
-def test_paged_walk_pages_are_zero_for_a_rows_cache(model):
-    """An engine that is not paged walks no table: both series stay 0
-    (and are there, so a dashboard's ratio has its terms)."""
-    engine = _engine(model, paged=False, total_kv_blocks=None,
-                     prefill_chunk=32)
-    engine.generate(list(range(1, 41)), max_new_tokens=9)
-    counters = _counters(engine)
-    assert engine.telemetry.decode_occupancy.count >= 1
-    assert counters["dstack_serving_paged_walk_pages_total{kind=live}"] == 0
-    assert counters["dstack_serving_paged_walk_pages_total{kind=walked}"] == 0
+    assert traced["after"]["dstack_serving_decode_steps_total"] == \
+        8 * windows
 
 
 def test_every_chunk_has_its_span(traced):
     """The 100-token prompt goes out as two chunks of one scheduling step
     (a budget of ``batch_size`` = 4): one ``engine.chunk`` span a chunk,
     and one more where the completed prompt is activated."""
-    after, before = traced["after"], traced["before"]
-
-    def delta(name):
-        return after["dstack_serving_" + name] - before.get(
-            "dstack_serving_" + name, 0.0)
-
-    assert delta("prefill_chunks_total") == 2
-    assert delta("prefill_chunk_steps_total") == 1
-    assert delta("prefill_budget_exhausted_total") == 0
+    assert _delta(traced, "prefill_chunks_total") == 2
+    assert _delta(traced, "prefill_chunk_steps_total") == 1
+    assert _delta(traced, "prefill_budget_exhausted_total") == 0
     names = [n for line in traced["lines"] for n in line]
     assert names.count("engine.chunk") == 2 + 1
 

@@ -738,6 +738,14 @@ def _serving_app(cfg, params):
     return app
 
 
+def _stop_serving_app(app):
+    """Stop the loop ``_serving_app`` started and wait for its thread: a
+    loop left running idles in the worker's later test files too."""
+    app.engine.stop()
+    app._thread.join(timeout=30)
+    assert not app._thread.is_alive()
+
+
 async def test_stop_sequences_clip_completion(setup):
     """OpenAI `stop`: generation halts at the first stop-string match and
     the response text excludes it."""
@@ -763,6 +771,7 @@ async def test_stop_sequences_clip_completion(setup):
         assert stop not in clipped
         assert body["choices"][0]["finish_reason"] == "stop"
     finally:
+        _stop_serving_app(app)
         await client.close()
 
 
@@ -797,6 +806,7 @@ async def test_stop_sequences_clip_stream(setup):
         streamed = "".join(texts)
         assert streamed == full[:full.find(stop)]
     finally:
+        _stop_serving_app(app)
         await client.close()
 
 

@@ -3,6 +3,8 @@ ring + tail sampler, histogram exemplars, engine span derivation, the
 serving server's /traces endpoints + trace middleware, and the sim-based
 overhead pin (<2% on the p95 TTFT proxy)."""
 
+import threading
+
 import pytest
 
 
@@ -304,11 +306,9 @@ async def test_server_traces_endpoints_and_header(setup):
     cfg, params = setup
     engine = _traced_engine(cfg, params)
     client, app = await _serving_client(engine)
+    worker = threading.Thread(target=engine.run_forever, daemon=True)
+    worker.start()
     try:
-        import threading
-
-        worker = threading.Thread(target=engine.run_forever, daemon=True)
-        worker.start()
         tid, sid = new_trace_id(), new_span_id()
         resp = await client.post(
             "/v1/completions",
@@ -340,6 +340,8 @@ async def test_server_traces_endpoints_and_header(setup):
         assert resp.status == 404
     finally:
         engine.stop()
+        worker.join(timeout=10)
+        assert not worker.is_alive()
         await client.close()
 
 
@@ -368,11 +370,9 @@ async def test_stream_carries_trace_header_and_completes_span(setup):
     cfg, params = setup
     engine = _traced_engine(cfg, params)
     client, app = await _serving_client(engine)
+    worker = threading.Thread(target=engine.run_forever, daemon=True)
+    worker.start()
     try:
-        import threading
-
-        worker = threading.Thread(target=engine.run_forever, daemon=True)
-        worker.start()
         resp = await client.post(
             "/v1/completions",
             json={"prompt": "hello", "max_tokens": 4, "stream": True})
@@ -394,6 +394,8 @@ async def test_stream_carries_trace_header_and_completes_span(setup):
                 >= decode[0]["start"] + decode[0]["duration"] - 1e-6)
     finally:
         engine.stop()
+        worker.join(timeout=10)
+        assert not worker.is_alive()
         await client.close()
 
 

@@ -423,6 +423,8 @@ async def test_live_gateway_replica_trace_has_full_span_set(tmp_path):
         assert any(e[0] == tid for e in exemplars)
     finally:
         engine.stop()
+        worker.join(timeout=15)
+        assert not worker.is_alive()
         await gw.close()
         await replica.close()
 

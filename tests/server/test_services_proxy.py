@@ -623,8 +623,8 @@ async def test_pd_router_with_real_serving_replicas(db=None):
         app.start_engine()
         return engine, app
 
-    _, prefill_app = make_replica()
-    _, decode_app = make_replica()
+    replicas = [make_replica(), make_replica()]
+    (_, prefill_app), (_, decode_app) = replicas
     prefill_srv = RawServer(prefill_app.make_app())
     decode_srv = RawServer(decode_app.make_app())
     await prefill_srv.start_server()
@@ -701,6 +701,11 @@ async def test_pd_router_with_real_serving_replicas(db=None):
         await client.close()
         await prefill_srv.close()
         await decode_srv.close()
+        # a loop left running idles in the worker's later test files too
+        for engine, replica in replicas:
+            engine.stop()
+            replica._thread.join(timeout=30)
+            assert not replica._thread.is_alive()
 
 
 async def test_client_cannot_smuggle_pd_phase_header(db=None):
