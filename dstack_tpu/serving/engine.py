@@ -42,6 +42,11 @@ from dstack_tpu.utils.jax_runtime import named_jit
 
 PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096)
 
+#: flags of a pending slot update (``InferenceEngine._flush_slot_updates``):
+#: the slot decodes; its last token is written; with the token the host
+#: gives, not the one the sampler left on the device
+_ACTIVE, _TOKEN, _TOKEN_FROM_HOST = 1, 2, 4
+
 logger = logging.getLogger(__name__)
 
 
@@ -163,7 +168,10 @@ class InferenceEngine:
     Streaming callbacks therefore arrive in bursts of up to
     `DECODE_WINDOWS[-1]` tokens, and a queued prompt waits at most one
     window for a free slot — the price of amortizing the host round-trip
-    across the window.
+    across the window.  The first tokens of one admission pass arrive
+    together at its end for the same reason: the pass sends every prompt's
+    program back to back and pulls their first tokens once (see
+    :meth:`_hand_over_first_tokens`).
     """
 
     #: Chunked-prefill sweep winner (PR 18): chunk=512 held background
@@ -400,6 +408,15 @@ class InferenceEngine:
         jit donates the state, so a raise mid-execution leaves it
         deleted)."""
         b = self.batch_size
+        # host-only records first: they must not outlive the requests the
+        # crash handler failed, even when the allocations below raise
+        #: slot -> [length, flags, token]: the net state of every slot
+        #: written since the last flush (see _flush_slot_updates)
+        self._slot_updates: dict = {}
+        #: (slot, request, token or None) in admission order: requests
+        #: whose first token is still on the device (None: in
+        #: ``_first_tokens``) or came over the PD wire
+        self._first_pending: List[tuple] = []
         #: the two donated trees every program takes and returns: what they
         #: hold is the provider's (a K and a V cache; a latent pool and a
         #: recurrent state)
@@ -419,6 +436,9 @@ class InferenceEngine:
         self._host_lengths = np.zeros((b,), np.int64)
         self._last_token = jnp.zeros((b,), jnp.int32)
         self._active = jnp.zeros((b,), jnp.bool_)
+        #: where the sampler leaves a request's first token until its pass
+        #: ends (see _sample_first)
+        self._first_tokens = jnp.zeros((b,), jnp.int32)
 
     # -- public API --------------------------------------------------------
 
@@ -565,7 +585,11 @@ class InferenceEngine:
         a prefill writes cache rows that an in-flight window's end-of-window
         bulk insert could clobber.  The overlap chain therefore breaks
         whenever a queued request could take a free slot, costing one
-        non-overlapped window at request boundaries.
+        non-overlapped window at request boundaries.  An admission pass
+        sends every prompt's program and sampler back to back and waits
+        for the device once, at its end (:meth:`_hand_over_first_tokens`);
+        what the pass and a drain change in the slots' device state goes
+        out as one program each (:meth:`_flush_slot_updates`).
 
         Chunked prompts are advanced ahead of the step's window, on a
         budget (see :meth:`_advance_chunks`).  The chain also breaks on a
@@ -666,27 +690,32 @@ class InferenceEngine:
     def _finish_chunked(self) -> None:
         """Activate slots whose final prefill chunk has completed: sample
         the first token from the chunk's logits and open the slot for
-        decode windows (it joins the next dispatched window)."""
-        for slot_id, st in list(self._chunking.items()):
-            if "logits" not in st:
-                continue
-            del self._chunking[slot_id]
-            req = self._slots[slot_id]
-            if req is None:
-                continue
-            n = st["n"]
-            if self.prefix_cache:
-                # publish the completed prompt's full blocks for future
-                # prefix reuse (mirrors _prefill's publication)
-                blocks = self._slot_blocks[slot_id]
-                for i, bkey in enumerate(self._slot_prefix[slot_id][1]):
-                    if (i + 1) * self._block_size <= n and i < len(blocks):
-                        self._alloc.register(bkey, blocks[i])
-            # the span that activates a completed prompt: no chunk goes
-            # out under it
-            with self._phase("chunk", slot=slot_id, tokens=0):
-                self._activate(slot_id, req, n, self._sample_first(
-                    st["logits"], req, slot_id, n))
+        decode windows (it joins the next dispatched window).  The prompts
+        one step completed are activated together: their samplers go out
+        back to back and their first tokens come over in one pull."""
+        completed = [slot_id for slot_id, st in self._chunking.items()
+                     if "logits" in st]
+        if not completed:
+            return
+        # the span that activates the completed prompts: no chunk goes
+        # out under it
+        with self._phase("chunk", requests=len(completed), tokens=0):
+            for slot_id in completed:
+                st = self._chunking.pop(slot_id)
+                req = self._slots[slot_id]
+                if req is None:
+                    continue
+                n = st["n"]
+                if self.prefix_cache:
+                    # publish the completed prompt's full blocks for future
+                    # prefix reuse (mirrors _prefill's publication)
+                    blocks = self._slot_blocks[slot_id]
+                    for i, bkey in enumerate(self._slot_prefix[slot_id][1]):
+                        if ((i + 1) * self._block_size <= n
+                                and i < len(blocks)):
+                            self._alloc.register(bkey, blocks[i])
+                self._activate(slot_id, req, n, st["logits"])
+            self._hand_over_first_tokens()
 
     def _can_admit(self) -> bool:
         """A waiting request could take a free slot."""
@@ -695,11 +724,19 @@ class InferenceEngine:
 
     def _admit(self) -> None:
         """Admit queued requests into free slots, under one
-        ``engine.admit`` span per call that has both."""
+        ``engine.admit`` span per call that has both.  The pass sends every
+        prompt's program and sampler back to back; its slot updates and
+        its one wait for the device close it, under an ``engine.prefill``
+        span of no tokens (the closing span shows in a trace whose edge
+        dropped the pass's long ``engine.admit``)."""
         if not self._can_admit():
             return
         with self._phase("admit"):
             self._admit_into_free_slots()
+            if self._first_pending:
+                with self._phase("prefill",
+                                 requests=len(self._first_pending), tokens=0):
+                    self._hand_over_first_tokens()
 
     def _admit_into_free_slots(self) -> None:
         for slot_id in range(self.batch_size):
@@ -947,20 +984,92 @@ class InferenceEngine:
             # (prefix reuse prefills only the suffix)
             self.telemetry.record_prefill(n - prefix_len,
                                           self._bucket(n - prefix_len))
-        self._activate(slot_id, req, n,
-                       self._sample_first(logits, req, slot_id, n))
+        self._activate(slot_id, req, n, logits)
 
-    def _activate(self, slot_id: int, req: Request, n: int,
-                  first: int) -> None:
-        """Open a slot whose prompt (``n`` tokens) is in the device state
-        for decode windows, and hand over its first token."""
+    def _activate(self, slot_id: int, req: Request, n: int, logits,
+                  first: Optional[int] = None) -> None:
+        """Claim a slot whose prompt's program (``n`` tokens) has been
+        sent, and queue what opens it for decode windows.  Nothing here
+        waits for the device or writes its slot state: the first token is
+        sampled from ``logits`` into ``_first_tokens`` by a program of its
+        own (``first`` instead where the PD wire brought the token and no
+        logits), the slot's length, activity and last token go out with the
+        next :meth:`_flush_slot_updates`, and the request learns its token
+        in :meth:`_hand_over_first_tokens`.  The slot is claimed HERE so
+        that ``run_forever``'s crash handler fails the request and frees
+        its blocks when a device error surfaces at that later pull."""
         self._slots[slot_id] = req
         self._slots_gen += 1
-        self._lengths = self._lengths.at[slot_id].set(n)
         self._host_lengths[slot_id] = n
-        self._last_token = self._last_token.at[slot_id].set(first)
-        self._active = self._active.at[slot_id].set(True)
-        self._emit(slot_id, req, first)
+        if first is None:
+            self._sample_first(logits, req, slot_id)
+            self._slot_updates[slot_id] = [n, _ACTIVE | _TOKEN, 0]
+        else:
+            self._slot_updates[slot_id] = [
+                n, _ACTIVE | _TOKEN | _TOKEN_FROM_HOST, first]
+        self._first_pending.append((slot_id, req, first))
+
+    def _flush_slot_updates(self) -> None:
+        """Write the slots' pending device state (``_lengths``, ``_active``,
+        ``_last_token``) in ONE program of fixed shapes, whatever the
+        number of slots activated and released since the last flush: the
+        net state of each (a slot activated and released in between ends
+        inactive with its first token as last token, as two updates in a
+        row left it).  Called before a decode window is dispatched and at
+        the end of an admission pass and of a drain: a drain behind a
+        window in flight queues one program behind it."""
+        updates = self._slot_updates
+        if not updates:
+            return
+        b = self.batch_size
+        # rows: slot ids (``b``, out of range, where there is none: the
+        # program drops those writes), lengths, flags, tokens the host knew
+        host = np.zeros((4, b), np.int32)
+        host[0] = b
+        host[0, :len(updates)] = list(updates)
+        host[1:, :len(updates)] = np.transpose(list(updates.values()))
+        if self.telemetry is not None:
+            self.telemetry.record_slot_update(len(updates))
+        updates.clear()
+        self._lengths, self._last_token, self._active = self._run_program(
+            self._prefill_jit, "slot_update", self._slot_update_program,
+            self._lengths, self._last_token, self._active,
+            self._first_tokens, jnp.asarray(host))
+
+    def _slot_update_program(self):
+        """The jitted program that writes a flush's slot updates (see
+        :meth:`_flush_slot_updates` for the rows of ``update``)."""
+        b = self.batch_size
+
+        def fn(lengths, last_token, active, first_tokens, update):
+            slots, new_lengths, flags, tokens = update
+            lengths = lengths.at[slots].set(new_lengths, mode="drop")
+            active = active.at[slots].set((flags & _ACTIVE) > 0, mode="drop")
+            tokens = jnp.where((flags & _TOKEN_FROM_HOST) > 0, tokens,
+                               first_tokens[jnp.minimum(slots, b - 1)])
+            last_token = last_token.at[
+                jnp.where((flags & _TOKEN) > 0, slots, b)].set(
+                    tokens, mode="drop")
+            return lengths, last_token, active
+
+        return self._jit_cached(fn, "slot_update", donate_argnums=(0, 1, 2))
+
+    def _hand_over_first_tokens(self) -> None:
+        """End of an admission pass (or of a step's completed chunked
+        prompts): flush the slot updates, then bring every pending first
+        token over in ONE device->host transfer, the pass's only wait for
+        the device (the ``engine.first_token`` phase: it lasts until the
+        last prompt's program has run), and emit them in admission order:
+        first-token stamps, ``on_token``, a request that ends at once."""
+        pending, self._first_pending = self._first_pending, []
+        if not pending:
+            return
+        self._flush_slot_updates()
+        with self._phase("first_token", requests=len(pending)):
+            tokens = np.asarray(self._first_tokens)
+        for slot_id, req, first in pending:
+            self._emit(slot_id, req,
+                       int(tokens[slot_id]) if first is None else first)
 
     def prefill_export(self, tokens: List[int],
                        max_new_tokens: int = 128) -> dict:
@@ -1013,12 +1122,11 @@ class InferenceEngine:
             *self._state, p, n, self._programs.slot_target(slot_id, pages))
         if p.get("logits") is not None:
             # request-aware first token (temperature/top_p/top_k honored;
-            # PD-wire logits arrive as numpy — asarray is host->device)
-            first = self._sample_first(jnp.asarray(p["logits"]), req,
-                                       slot_id, n)
+            # PD-wire logits arrive as numpy)
+            self._activate(slot_id, req, n, p["logits"])
         else:
-            first = int(p["first_token"])
-        self._activate(slot_id, req, n, first)
+            self._activate(slot_id, req, n, None,
+                           first=int(p["first_token"]))
 
     @jax.named_scope("sample")
     def _sample_on_device(self, logits, temps, top_ps, top_ks, rng):
@@ -1061,6 +1169,8 @@ class InferenceEngine:
     #: overshoot on short tails.  Trade-off: streaming callbacks burst up
     #: to 64 tokens and a queued prompt waits up to one window for a slot —
     #: latency-sensitive deployments can override this class attribute.
+    #: (The first tokens of one admission pass arrive together too, at the
+    #: pass's end: it waits for the device once, not once a request.)
     DECODE_WINDOWS = (8, 32, 64)
 
     #: fixed per-window dispatch overhead expressed in decode steps (host
@@ -1162,6 +1272,8 @@ class InferenceEngine:
         """Build the window's tables and per-slot constants, enqueue the
         program."""
         nbk = self._ragged_blocks(window) if self.paged else None
+        # the window reads the slots' state as the host last left it
+        self._flush_slot_updates()
 
         # Host->device transfers per WINDOW must be near zero, so
         # everything below is cached against the current slot assignment
@@ -1252,7 +1364,9 @@ class InferenceEngine:
     def _drain_window(self) -> None:
         """Pull the in-flight window's tokens (and what it counted about
         itself) to the host and emit them — the ONE device->host sync per
-        window."""
+        window.  The slots the emit released go out as one program at its
+        end (:meth:`_flush_slot_updates`): behind a window dispatched
+        ahead that is one program queued, and the thread does not wait."""
         p = self._pending
         if p is None:
             return
@@ -1275,6 +1389,7 @@ class InferenceEngine:
                     self._host_lengths[slot_id] += 1  # mirrors device
                     emitted += 1
                     self._emit(slot_id, req, int(tokens_np[step, slot_id]))
+            self._flush_slot_updates()
         if self.telemetry is not None and "t0" in p:
             self.telemetry.record_drain(
                 emitted, time.perf_counter() - p["t0"], len(p["decoding"]),
@@ -1283,37 +1398,47 @@ class InferenceEngine:
                 self._programs.record_window_counts(self.telemetry,
                                                     window_counts)
 
-    def _sample_first(self, logits, req: Request, slot_id: int,
-                      n: int) -> int:
+    def _sample_first(self, logits, req: Request, slot_id: int) -> None:
         """Sample a request's FIRST token with the same fused on-device
-        sampler the decode windows use (:meth:`_sample_on_device`), under
-        an ``engine.first_token`` phase: the program call and the pull of
-        its token, the one device->host sync a request (the wait for the
-        prompt's program; ``n`` its tokens, for the span).
+        sampler the decode windows use (:meth:`_sample_on_device`) into
+        ``_first_tokens[slot_id]``.  The call is asynchronous: the token
+        stays on the device until the pass that sent the prompt ends, and
+        one pull brings every first token of the pass over
+        (:meth:`_hand_over_first_tokens`).
 
-        This replaced a host-side numpy softmax/top-p sampler that pulled
-        the full [V] logits vector to the host per admission — the last
-        logits-sized device->host transfer outside the decode loop.  Now
-        one int32 crosses the wire (the slot bookkeeping genuinely needs
-        the token id on the host).  Greedy (temp<=0) is argmax on both
-        the old and the fused path, so greedy first tokens are
-        bit-identical; sampled ones are seed-deterministic through the
-        engine's threaded ``jax.random`` key."""
-        def fn(lg, temp, top_p, top_k, rng):
-            return self._sample_on_device(
-                lg[None, :], temp[None], top_p[None], top_k[None],
-                rng)[0]
-
+        No logits-sized transfer and no wait a request: a pull of each
+        token as it was sampled left the device dry between one prompt's
+        program and the next.  Greedy (temp<=0) is argmax, so greedy first
+        tokens are bit-identical to a full forward's; sampled ones are
+        seed-deterministic through the engine's threaded ``jax.random``
+        key, split here on the host in admission order."""
         if req.temperature > 0.0:
             self._rng_key, sub = jax.random.split(self._rng_key)
         else:
             sub = self._rng_key  # greedy ignores it; don't burn entropy
-        with self._phase("first_token", slot=slot_id, tokens=n):
-            return int(self._run_program(
-                self._prefill_jit, "first_token",
-                lambda: self._jit_cached(fn, "first_token_sample"),
-                jnp.asarray(logits), jnp.float32(req.temperature),
-                jnp.float32(req.top_p), jnp.int32(req.top_k or 0), sub))
+        # the request's constants and its slot as ONE host array: one
+        # transfer, no conversion program a scalar.  Both integers are
+        # exact in float32 (a top-k past the sampler's 1,024-wide prefilter
+        # keeps every rank, as any larger one does)
+        consts = jnp.asarray(np.array(
+            [req.temperature, req.top_p, min(req.top_k or 0, 1024), slot_id],
+            np.float32))
+        self._first_tokens = self._run_program(
+            self._prefill_jit, "first_token", self._first_token_program,
+            jnp.asarray(logits), consts, sub, self._first_tokens)
+
+    def _first_token_program(self):
+        """The jitted sampler of one request's first token: writes it into
+        the donated ``[batch_size]`` vector at the slot."""
+        def fn(logits, consts, rng, first_tokens):
+            temp, top_p, top_k, slot = consts
+            token = self._sample_on_device(
+                logits[None, :], temp[None], top_p[None],
+                top_k.astype(jnp.int32)[None], rng)[0]
+            return first_tokens.at[slot.astype(jnp.int32)].set(token)
+
+        return self._jit_cached(fn, "first_token_sample",
+                                donate_argnums=(3,))
 
     def _emit(self, slot_id: int, req: Request, token: int) -> None:
         if (not req.cancelled and req.deadline is not None
@@ -1356,9 +1481,13 @@ class InferenceEngine:
                 self.telemetry.record_finished(req)
 
     def _release(self, slot_id: int) -> None:
+        """Free a slot; its device state (inactive, no length) goes out
+        with the next :meth:`_flush_slot_updates`.  A slot released before
+        its activation was flushed keeps that update's last token."""
         self._release_host(slot_id)
-        self._active = self._active.at[slot_id].set(False)
-        self._lengths = self._lengths.at[slot_id].set(0)
+        update = self._slot_updates.setdefault(slot_id, [0, 0, 0])
+        update[0] = 0
+        update[1] &= ~_ACTIVE
 
     def _release_host(self, slot_id: int) -> None:
         """Host-side half of release: safe to call when the device runtime

@@ -42,9 +42,17 @@ republished with project/run/job/replica labels):
   ``build_program`` in any) pauses its parent, so no second is counted
   twice and the phases' sum is at most the thread's wall time.  ``pull``
   and ``first_token`` hold every device->host transfer of the thread (the
-  wait for a decode window, for a prompt's program), ``wait_for_work`` the
-  idle loop: the rest is the host's own work.  A phase still open when the
-  counters are read has not been added yet
+  wait for a decode window; for the programs of an admission pass, or of
+  the chunked prompts a step completed: ONE ``first_token`` a pass, so its
+  count is pulls, not requests), ``wait_for_work`` the idle loop: the rest
+  is the host's own work.  A phase still open when the counters are read
+  has not been added yet
+- ``engine_slot_update_programs_total`` / ``engine_slot_updates_total``
+  counters — programs that wrote the slots' device state (length,
+  activity, last token: one at the end of an admission pass, one at the
+  end of a drain, one before a window where something is still pending)
+  and slots they wrote: their ratio is how many activations and releases
+  one program stands for
 - ``windows_dispatched_ahead_total`` counter — decode windows enqueued
   before their predecessor's tokens were pulled (over
   ``batch_occupancy_count{phase=decode}``, all windows: the share of the
@@ -190,6 +198,9 @@ class EngineTelemetry:
             PREFIX + "prefill_budget_exhausted_total")
         self.windows_ahead = r.counter(
             PREFIX + "windows_dispatched_ahead_total")
+        self.slot_update_programs = r.counter(
+            PREFIX + "engine_slot_update_programs_total")
+        self.slot_updates = r.counter(PREFIX + "engine_slot_updates_total")
         self._chain_breaks = {
             reason: r.counter(PREFIX + "window_chain_breaks_total",
                               labels={"reason": reason})
@@ -298,6 +309,11 @@ class EngineTelemetry:
             self.windows_ahead.inc()
         else:
             self._chain_breaks[broke].inc()
+
+    def record_slot_update(self, slots: int) -> None:
+        """One program wrote the device state of ``slots`` slots."""
+        self.slot_update_programs.inc()
+        self.slot_updates.inc(slots)
 
     def record_phase(self, phase: str, self_seconds: float) -> None:
         """One ``engine.<phase>`` span of the engine's loop closed, with

@@ -247,6 +247,8 @@ async def check_serving_metrics() -> int:
             "dstack_serving_engine_phase_seconds_total",
             "dstack_serving_engine_phases_total",
             "dstack_serving_windows_dispatched_ahead_total",
+            "dstack_serving_engine_slot_update_programs_total",
+            "dstack_serving_engine_slot_updates_total",
             "dstack_serving_window_chain_breaks_total",
             "dstack_serving_programs_built_total",
             "dstack_serving_preemptions_total",

@@ -69,12 +69,13 @@ def traced(model, tmp_path_factory):
     options.host_tracer_level = 2
     jax.profiler.start_trace(str(trace_dir), profiler_options=options)
     started = time.perf_counter()
-    loop.start()
     try:
         with jax.profiler.TraceAnnotation("test.main_thread"):
+            # all three wait when the loop starts: one admission pass
             requests = [engine.submit(Request(tokens=list(range(1, n + 1)),
                                               max_new_tokens=9))
                         for n in (40, 40, 100)]
+            loop.start()
             for r in requests:
                 assert r.done.wait(120)
         time.sleep(0.15)            # the loop goes idle: wait_for_work
@@ -91,8 +92,12 @@ def traced(model, tmp_path_factory):
     # the engine's spans with the arguments that say whose they are
     spans = [(e.name, dict(e.stats)) for line in host for e in line.events
              if e.name.startswith("engine.")]
+    # the engine's thread: every event with its interval
+    timed = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events] for line in host
+             if any(e.name == "engine.dispatch_window" for e in line.events)]
     return {"engine": engine, "requests": requests, "lines": lines,
-            "spans": spans, "loop_wall_s": wall,
+            "spans": spans, "timed": timed, "loop_wall_s": wall,
             "before": before, "after": _counters(engine)}
 
 
@@ -168,15 +173,58 @@ def test_self_time_by_hand_on_a_stepped_clock(model, monkeypatch):
     assert counters["dstack_serving_engine_phases_total{phase=prefill}"] == 1
 
 
-def test_every_request_pulls_one_first_token(traced):
-    """The one device->host sync a request is the ``engine.first_token``
-    phase, whole-prompt and chunked alike; a window's is ``engine.pull``."""
+def test_an_admission_pass_pulls_once_and_a_steps_completed_prompts_once(
+        traced):
+    """The device->host syncs of the loop: one ``engine.first_token`` for
+    the admission pass of three requests (two whole prompts), one for the
+    chunked prompt the next step completed, one ``engine.pull`` a window."""
     tel = traced["engine"].telemetry
     # less the warm-up request's, before the traced part
-    assert _delta(traced, "engine_phases_total{phase=first_token}") == \
-        len(traced["requests"]) == tel.queue_wait.count - 1
+    assert len(traced["requests"]) == tel.queue_wait.count - 1 == 3
+    assert _delta(traced, "engine_phases_total{phase=first_token}") == 2
+    handed_over = sorted(args["requests"] for name, args in traced["spans"]
+                         if name == "engine.first_token")
+    assert handed_over == [1, 2]
     windows = tel.decode_occupancy.count - 1
     assert _delta(traced, "engine_phases_total{phase=pull}") == windows
+
+
+def _calls_inside(traced, span: str) -> list:
+    """For each span of the given name, the jitted calls that began inside
+    it (``PjitFunction(<name>)`` on the engine's thread: the engine's
+    programs by their tags, an eager operation by its primitive's).  The
+    profile shows a call twice, one event inside the other."""
+    (events,) = traced["timed"]
+    calls, last_end = [], {}
+    for name, start, end in sorted(events, key=lambda e: e[1]):
+        if name.startswith("PjitFunction(") and start >= last_end.get(name, 0):
+            last_end[name] = end
+            calls.append((name[len("PjitFunction("):-1], start))
+    return [[n for n, start in calls if lo <= start < hi]
+            for name, lo, hi in events if name == span]
+
+
+def test_a_pass_and_a_drain_send_one_slot_update_program_each(traced):
+    """No eager ``x.at[i].set(v)`` (a ``scatter`` or a
+    ``dynamic_update_slice`` program of its own) runs inside
+    ``engine.admit`` or ``engine.emit``: the pass's activations and the
+    drain's releases are one ``slot_update`` program each, counted with the
+    slots they wrote."""
+    (admit,) = _calls_inside(traced, "engine.admit")
+    assert admit.count("slot_update") == 1
+    assert admit.count("first_token_sample") == 2       # the whole prompts
+    assert admit.count("prefill_paged_b64") == 2
+    emits = _calls_inside(traced, "engine.emit")
+    # the three requests end together: one drain releases them all
+    assert sorted(emits) == [[]] * (len(emits) - 1) + [["slot_update"]]
+    chunks = _calls_inside(traced, "engine.chunk")
+    assert chunks[-1] == ["first_token_sample", "slot_update"]  # activation
+    for calls in (admit, *chunks, *emits):
+        assert not [n for n in calls
+                    if "scatter" in n or "dynamic" in n], calls
+    # the pass's two, the chunked prompt's activation, the drain's three
+    assert _delta(traced, "engine_slot_update_programs_total") == 3
+    assert _delta(traced, "engine_slot_updates_total") == 2 + 1 + 3
 
 
 def test_spans_say_whose_they_are(traced):
@@ -193,16 +241,22 @@ def test_spans_say_whose_they_are(traced):
         assert sorted(names) == ["engine.dispatch_window", "engine.emit",
                                  "engine.pull"]
     prompts = [(name, args["slot"], args["tokens"])
-               for name, args in traced["spans"] if name in (
-                   "engine.prefill", "engine.chunk", "engine.first_token")]
+               for name, args in traced["spans"] if "slot" in args]
+    assert {name for name, _, _ in prompts} == {"engine.prefill",
+                                                "engine.chunk"}
     slots = {slot for _, slot, _ in prompts}
     assert len(slots) == 3 and slots <= set(range(4))
-    assert sorted(t for n, _, t in prompts if n == "engine.prefill") == \
-        [40, 40]
-    # the 100-token prompt: chunks of 64 and 36, then its activation
-    assert [t for n, _, t in prompts if n == "engine.chunk"] == [64, 36, 0]
-    assert sorted(t for n, _, t in prompts if n == "engine.first_token") \
-        == [40, 40, 100]
+    # the 100-token prompt: chunks of 64 and 36
+    assert sorted((n, t) for n, _, t in prompts) == [
+        ("engine.chunk", 36), ("engine.chunk", 64),
+        ("engine.prefill", 40), ("engine.prefill", 40)]
+    # what closes the pass and what activates the chunked prompt send no
+    # prompt tokens and say how many requests they hand over
+    closing = sorted((name, args["requests"], args.get("tokens"))
+                     for name, args in traced["spans"] if "requests" in args)
+    assert closing == [("engine.chunk", 1, 0), ("engine.first_token", 1, None),
+                       ("engine.first_token", 2, None),
+                       ("engine.prefill", 2, 0)]
 
 
 def test_span_arguments_are_formatted_only_under_a_trace(model):
@@ -314,7 +368,7 @@ def test_programs_are_named_by_their_compile_cache_tags(traced):
     prefill = {fn.__name__ for fn in engine._prefill_jit.values()}
     assert decode and all(n.startswith("decode_w8_s0_kb") for n in decode)
     assert prefill == {"prefill_paged_b64", "prefill_prefix_b64",
-                       "first_token_sample"}
+                       "first_token_sample", "slot_update"}
     # the CPU trace has no XLA Modules line; its host line names each call
     called = {n for line in traced["lines"] for n in line
               if n.startswith("PjitFunction(")}
@@ -331,7 +385,7 @@ def test_programs_are_named_by_their_compile_cache_tags(traced):
 @pytest.mark.parametrize("kwargs,tags", [
     (dict(paged=False, total_kv_blocks=None, prefill_chunk=32),
      {"prefill_b32", "prefill_chunk_b32", "first_token_sample",
-      "decode_w8_s0"}),
+      "slot_update", "decode_w8_s0"}),
 ], ids=["dense-chunked"])
 def test_every_program_kind_lowers_under_its_tag(model, kwargs, tags):
     engine = _engine(model, **kwargs)

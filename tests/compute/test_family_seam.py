@@ -132,7 +132,7 @@ def test_engine_drives_the_provider_to_the_plain_forward_s_tokens(family):
     assert {fn.__name__.rsplit("_b", 1)[0]
             for fn in engine._prefill_jit.values()} == {
         "prefill_prefix" if engine.paged else "prefill_chunk",
-        "first_token_sample"}
+        "first_token_sample", "slot_update"}
     scores = np.asarray(plain_logits(prompt + served[:-1]))[len(prompt) - 1:]
     assert scores.shape[0] == 17 and scores.std() > 0.1
     gaps = scores.max(-1) - scores[np.arange(17), served]

@@ -489,6 +489,47 @@ def test_engine_program_temporaries_ignore_pool(engine_programs, geometry,
         grown, large_pool - small_pool)
 
 
+@pytest.mark.parametrize("program", ["slot_update", "first_token_sample"])
+def test_scheduler_program_compiles_and_updates_in_place(one_chip,
+                                                          real_lowering,
+                                                          program):
+    """The scheduler's own two programs at the widest cell's shape (256
+    slots, a vocabulary of 65,536): the slot-update program writes the
+    slots' three vectors and the sampler the first-token vector INTO their
+    donated arguments (every donated buffer aliased to an output), and
+    neither holds a copy."""
+    from dstack_tpu.models.llama import LlamaConfig
+    from dstack_tpu.serving.engine import InferenceEngine
+
+    slots, vocab = 256, 65536
+    cfg = LlamaConfig(num_layers=1, max_seq_len=256, vocab_size=vocab,
+                      hidden_size=128, intermediate_size=256, num_heads=2,
+                      num_kv_heads=2, head_dim=64)
+    engine = InferenceEngine(cfg, params={"layers": {}}, batch_size=slots,
+                             max_len=256, paged=True, kv_block_size=32,
+                             total_kv_blocks=16)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    if program == "slot_update":
+        fn, donated = engine._slot_update_program(), 3
+        args = (arg(i32, slots), arg(i32, slots), arg(jnp.bool_, slots),
+                arg(i32, slots), arg(i32, 4, slots))
+    else:
+        fn, donated = engine._first_token_program(), 1
+        args = (arg(jnp.float32, vocab), arg(jnp.float32, 4),
+                arg(jnp.uint32, 2), arg(i32, slots))
+    assert fn.__name__ == program
+    text = fn.lower(*args).compile().as_text()
+    assert f"HloModule jit_{program}" in text
+    aliases = re.search(r"input_output_alias=\{([^\n]*?)\}, entry", text)
+    assert aliases and aliases.group(1).count("may-alias") + \
+        aliases.group(1).count("must-alias") == donated, text[:400]
+    assert " copy(" not in text
+
+
 def _experts_stream_in_place(text: str, expert_layers: int, experts: int,
                              hidden: int, width: int) -> None:
     """The compiled program runs the experts through the repo's grouped
