@@ -34,6 +34,7 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from dstack_tpu.models import experts
 from dstack_tpu.models.experts import (  # noqa: F401  (LOAD_FIELDS: re-export)
     LOAD_FIELDS,
     expert_load,
@@ -231,19 +232,14 @@ def init_params(rng: jax.Array, cfg: Lfm2MoeConfig) -> Params:
 
 # -- feed-forward -------------------------------------------------------------
 
-@jax.named_scope("moe_route")
 def route(h, lp, cfg: Lfm2MoeConfig):
     """Experts and weights of every token of ``h`` [T, D]: ``(ids [T, k],
     weights [T, k] float32)`` over all ``num_experts``; the selection bias
-    picks, the plain scores weigh."""
-    scores = jax.nn.sigmoid(jnp.matmul(
-        h.astype(jnp.float32), lp["router"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    ids = jax.lax.top_k(scores + lp["router_bias"],
-                        cfg.num_experts_per_tok)[1]
-    weights = jnp.take_along_axis(scores, ids, axis=1)
-    weights = weights / (weights.sum(-1, keepdims=True) + 1e-6)
-    return ids, weights * cfg.routed_scaling_factor
+    picks, the plain scores weigh (``experts.route``; ``1e-6`` in the
+    weights' sum)."""
+    return experts.route(
+        h, lp["router"], lp["router_bias"], top_k=cfg.num_experts_per_tok,
+        scale=cfg.routed_scaling_factor, eps=1e-6)
 
 
 def moe_ffn(h, lp, cfg: Lfm2MoeConfig, token_mask=None):

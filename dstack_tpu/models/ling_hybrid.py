@@ -33,6 +33,7 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 
+from dstack_tpu.models import experts
 from dstack_tpu.models.experts import (  # noqa: F401  (LOAD_FIELDS: re-export)
     LOAD_FIELDS,
     expert_load,
@@ -231,26 +232,14 @@ def init_params(rng: jax.Array, cfg: LingHybridConfig) -> Params:
 
 # -- feed-forward --------------------------------------------------------------
 
-@jax.named_scope("moe_route")
 def route(h, lp, cfg: LingHybridConfig):
     """Experts and weights of every token of ``h`` [T, D]: ``(ids [T, k],
-    weights [T, k] float32)`` over ALL ``num_experts``."""
-    scores = jax.nn.sigmoid(jnp.matmul(
-        h.astype(jnp.float32), lp["router"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    choose = scores + lp["router_bias"]
-    t = h.shape[0]
-    per_group = cfg.num_experts // cfg.n_group
-    grouped = choose.reshape(t, cfg.n_group, per_group)
-    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)
-    kept = jax.lax.top_k(group_score, cfg.topk_group)[1]          # [T, g]
-    keep = jnp.zeros((t, cfg.n_group), jnp.bool_).at[
-        jnp.arange(t)[:, None], kept].set(True)
-    choose = jnp.where(jnp.repeat(keep, per_group, axis=1), choose, -jnp.inf)
-    ids = jax.lax.top_k(choose, cfg.num_experts_per_tok)[1]
-    picked = jnp.take_along_axis(scores, ids, axis=1)
-    weights = picked / picked.sum(-1, keepdims=True)
-    return ids, weights * cfg.routed_scaling_factor
+    weights [T, k] float32)`` over ALL ``num_experts``, chosen inside the
+    ``topk_group`` best of ``n_group`` groups (``experts.route``)."""
+    return experts.route(
+        h, lp["router"], lp["router_bias"], top_k=cfg.num_experts_per_tok,
+        scale=cfg.routed_scaling_factor, n_group=cfg.n_group,
+        topk_group=cfg.topk_group)
 
 
 def moe_ffn(h, lp, cfg: LingHybridConfig, token_mask=None):

@@ -131,7 +131,7 @@ def plan(counts, tiles: int) -> tuple:
 
 def _kernel(expert_ref, out_tile_ref, x_tile_ref, lead_ref, next_ref,
             starts_ref, ends_ref, items_ref, x_ref, *refs, matrices: int,
-            columns: int):
+            columns: int, transposed: bool):
     w_hbm, o_ref = refs[:matrices], refs[matrices]
     buffers, sem, turn = refs[matrices + 1:-2], refs[-2], refs[-1]
     j, w = pl.program_id(0), pl.program_id(1)
@@ -142,11 +142,12 @@ def _kernel(expert_ref, out_tile_ref, x_tile_ref, lead_ref, next_ref,
 
     def copies(expert, column_block, slot):
         """The copies of one expert's column block into buffer ``slot``."""
-        lanes = pl.ds(pl.multiple_of(column_block * columns, columns),
+        block = pl.ds(pl.multiple_of(column_block * columns, columns),
                       columns)
-        return [pltpu.make_async_copy(hbm.at[expert, :, lanes], buf.at[slot],
-                                      sem.at[slot, i])
-                for i, (hbm, buf) in enumerate(zip(w_hbm, buffers))]
+        return [pltpu.make_async_copy(
+            hbm.at[expert, block, :] if transposed
+            else hbm.at[expert, :, block], buf.at[slot], sem.at[slot, i])
+            for i, (hbm, buf) in enumerate(zip(w_hbm, buffers))]
 
     @pl.when(computing & (lead > 0))
     def _fetch():   # an expert's first item
@@ -178,10 +179,17 @@ def _kernel(expert_ref, out_tile_ref, x_tile_ref, lead_ref, next_ref,
             jnp.int32, (ROW_TILE, 1), 0)
         mine = (row >= starts_ref[e]) & (row < ends_ref[e])
         x = x_ref[...]
-        y = jnp.dot(x, buffers[0][slot], preferred_element_type=jnp.float32)
+
+        def product(block):
+            if transposed:      # the block is [columns, K]: x @ block.T
+                return jax.lax.dot_general(
+                    x, block, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            return jnp.dot(x, block, preferred_element_type=jnp.float32)
+
+        y = product(buffers[0][slot])
         if matrices == 2:     # gate and up: silu(gate) * up, in float32
-            y = jax.nn.silu(y) * jnp.dot(
-                x, buffers[1][slot], preferred_element_type=jnp.float32)
+            y = jax.nn.silu(y) * product(buffers[1][slot])
         y = y.astype(o_ref.dtype)
 
         @pl.when(opens)
@@ -212,19 +220,23 @@ def _column_block(k: int, n: int, itemsize: int, matrices: int,
 
 
 def grouped_matmul(x, weights, counts=None, *, work=None,
-                   block_bytes: int = BLOCK_BYTES):
+                   block_bytes: int = BLOCK_BYTES, transposed: bool = False):
     """``x [M, K]`` (rows sorted by expert) against the experts' matrices:
     ``out [M, N]`` in ``x``'s dtype, row ``r`` of expert ``e`` (the
     ``counts[e]`` rows after those of the experts before it) being ``x[r] @
     w[e]`` accumulated in float32 and rounded once; the rows past
     ``counts.sum()`` are zeros.  ``weights`` is one matrix stack ``[E, K,
     N]`` or a ``(gate, up)`` pair of them, which gives ``silu(x @ gate[e])
-    * (x @ up[e])`` formed in float32.  ``work`` is :func:`plan`'s result
-    for these ``counts`` where two calls share it."""
+    * (x @ up[e])`` formed in float32.  ``transposed``: the stack is ``[E,
+    N, K]`` (an expert's matrix as ``nn.Linear`` keeps it) and row ``r`` is
+    ``x[r] @ w[e].T``; an ``N`` that is no whole number of 128-lane tiles
+    (1856) then lies on the stack's second-minor dim, where the chip stores
+    it without padding and the kernel copies it in one block.  ``work`` is
+    :func:`plan`'s result for these ``counts`` where two calls share it."""
     weights = tuple(weights) if isinstance(weights, (tuple, list)) else (
         weights,)
     m, k = x.shape
-    e, _, n = weights[0].shape
+    e, n = weights[0].shape[0], weights[0].shape[1 if transposed else 2]
     tiles = pl.cdiv(m, ROW_TILE)
     if work is None:
         work = plan(counts, tiles)
@@ -245,7 +257,8 @@ def grouped_matmul(x, weights, counts=None, *, work=None,
                  + ROW_TILE * tn * x.dtype.itemsize)
             + (len(weights) + 2) * ROW_TILE * tn * 4 + (8 << 20))
     out = pl.pallas_call(
-        functools.partial(_kernel, matrices=len(weights), columns=tn),
+        functools.partial(_kernel, matrices=len(weights), columns=tn,
+                          transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(work),
             grid=(n // tn, tiles + e - 1),
@@ -254,7 +267,8 @@ def grouped_matmul(x, weights, counts=None, *, work=None,
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(weights),
             out_specs=pl.BlockSpec((ROW_TILE, tn), o_block,
                                    memory_space=pltpu.VMEM),
-            scratch_shapes=[pltpu.VMEM((2, k, tn), weights[0].dtype)
+            scratch_shapes=[pltpu.VMEM((2, tn, k) if transposed
+                                       else (2, k, tn), weights[0].dtype)
                             for _ in weights] + [
                 pltpu.SemaphoreType.DMA((2, len(weights))),
                 pltpu.SMEM((2,), jnp.int32),    # next buffer, this expert's
@@ -277,3 +291,13 @@ def grouped_swiglu(rows, w_gate, w_up, w_down, counts):
     work = plan(counts, pl.cdiv(rows.shape[0], ROW_TILE))
     gated = grouped_matmul(rows, (w_gate, w_up), work=work)
     return grouped_matmul(gated, w_down, work=work)
+
+
+def grouped_relu2(rows, w_up, w_down, counts):
+    """The experts' two-matrix MLP over rows sorted by expert: ``relu(rows @
+    up[e].T)^2 @ down[e]``, both stacks ``[E, width, hidden]`` (up through
+    the kernel's ``transposed`` mode), in two calls that share one plan;
+    rows past ``counts.sum()`` come out as zeros."""
+    work = plan(counts, pl.cdiv(rows.shape[0], ROW_TILE))
+    up = grouped_matmul(rows, w_up, work=work, transposed=True)
+    return grouped_matmul(jnp.square(jax.nn.relu(up)), w_down, work=work)

@@ -570,6 +570,11 @@ class DensePrograms:
         passes, *exit_tokens = counts.tolist()
         telemetry.record_loop_passes(passes, exit_tokens)
 
+    @staticmethod
+    def record_prompt_program(telemetry, bucket: int) -> None:
+        """A prefill or chunk program of ``bucket`` positions ran: nothing
+        this family counts (``serving/nemotron_h.py`` counts its scans)."""
+
     def slot_target(self, slot_id, pages):
         """Where a prefill or chunk program writes: the slot's ``pages``
         (block ids or its table row) when paged, its row of the cache

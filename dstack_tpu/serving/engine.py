@@ -680,6 +680,7 @@ class InferenceEngine:
         st["done"] = done + len(chunk)
         if self.telemetry is not None:
             self.telemetry.record_prefill(len(chunk), cbucket)
+            self._programs.record_prompt_program(self.telemetry, cbucket)
             # keep the backlog gauge fresh even when every slot is
             # chunking (no decode window dispatches then)
             self.telemetry.record_prefill_backlog(self._chunk_backlog())
@@ -982,8 +983,9 @@ class InferenceEngine:
         if self.telemetry is not None:
             # occupancy over the bucket the executed program was padded to
             # (prefix reuse prefills only the suffix)
-            self.telemetry.record_prefill(n - prefix_len,
-                                          self._bucket(n - prefix_len))
+            ran = self._bucket(n - prefix_len)
+            self.telemetry.record_prefill(n - prefix_len, ran)
+            self._programs.record_prompt_program(self.telemetry, ran)
         self._activate(slot_id, req, n, logits)
 
     def _activate(self, slot_id: int, req: Request, n: int, logits,

@@ -8,7 +8,10 @@ a model file, a programs file and one line of ``PROVIDERS``.
 * ``LingHybridConfig``: ``serving/hybrid.py``, a paged pool of MLA's latent
   rows beside the KDA layers' recurrent state;
 * ``Lfm2MoeConfig``: ``serving/lfm2.py``, a paged K and V pool over the
-  attention layers beside the convolution layers' tails.
+  attention layers beside the convolution layers' tails;
+* ``NemotronHConfig``: ``serving/nemotron_h.py``, a paged K and V pool over
+  the attention layers beside the Mamba-2 layers' state-space states and
+  convolution tails.
 
 What the families' programs share of the paged pool and of the decode
 window is ``serving/paged_window.py``; the sorted grouped expert product is
@@ -20,15 +23,18 @@ from __future__ import annotations
 from dstack_tpu.models.lfm2 import Lfm2MoeConfig
 from dstack_tpu.models.ling_hybrid import LingHybridConfig
 from dstack_tpu.models.llama import LlamaConfig
+from dstack_tpu.models.nemotron_h import NemotronHConfig
 from dstack_tpu.serving.dense import DensePrograms
 from dstack_tpu.serving.hybrid import HybridPrograms
 from dstack_tpu.serving.lfm2 import Lfm2Programs
+from dstack_tpu.serving.nemotron_h import NemotronHPrograms
 
 #: config class -> provider; a subclass is served by its nearest base's
 PROVIDERS = {
     LlamaConfig: DensePrograms,
     LingHybridConfig: HybridPrograms,
     Lfm2MoeConfig: Lfm2Programs,
+    NemotronHConfig: NemotronHPrograms,
 }
 
 

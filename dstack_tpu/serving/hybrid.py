@@ -125,6 +125,11 @@ class HybridPrograms:
         telemetry.record_expert_load(*counts.tolist())
 
     @staticmethod
+    def record_prompt_program(telemetry, bucket: int) -> None:
+        """A prefill or chunk program of ``bucket`` positions ran: nothing
+        this family counts (``serving/nemotron_h.py`` counts its scans)."""
+
+    @staticmethod
     def slot_target(slot_id: int, pages):
         """Where a prefill or chunk program writes: the slot's pages and
         the slot, whose recurrent state it starts or carries."""

@@ -1,8 +1,9 @@
-"""The LFM2-MoE decoder (``models/lfm2.py``) behind the engine's seam: a
-mixed stack whose attention layers keep K and V rows in the Llama family's
-paged pool and whose convolution layers keep a fixed tail per slot.
-``serving/dense.py`` and ``serving/hybrid.py`` answer the same calls for
-their families; ``serving/families.py`` picks among them.
+"""The Nemotron-H decoder (``models/nemotron_h.py``) behind the engine's
+seam: a stack whose Mamba-2 layers keep a state-space state and a
+convolution tail per slot, whose attention layers keep K and V rows in the
+Llama family's paged pool, and whose expert blocks keep nothing.
+``serving/dense.py``, ``serving/hybrid.py`` and ``serving/lfm2.py`` answer
+the same calls for their families; ``serving/families.py`` picks among them.
 
 The engine's two donated trees of device state are
 
@@ -10,20 +11,26 @@ The engine's two donated trees of device state are
   Hkv * head_dim]: the paged pool in the form ``serving/dense.py`` stores
   it (kv heads folded into the lanes), over the ATTENTION layers only,
   addressed through the same block tables and the same allocator;
-* ``rec`` [conv layers, slots, conv_L_cache - 1, hidden]: the convolutions'
-  tails, the last rows of ``B * X`` of each slot, whatever its length.
+* ``rec`` = {"ssm": [[slots, heads, head_dim, state] float32, ...], "tail":
+  [[slots, conv_kernel - 1, conv_dim], ...]}: one array a Mamba layer (not
+  one stacked array: a layer's state is 2 MiB a slot, and a decode step
+  then rewrites each layer's buffer where it lies, with no slice of a
+  larger one to read or to write back).
 
-A slot's tail is never reset by a program of its own: the prefill that
+A slot's state is never reset by a program of its own: the prefill that
 admits a request starts from zeros and overwrites the slot, and a prompt's
 first chunk (``prefix_len == 0``) starts from zeros instead of reading it;
 later chunks read and write their slot.  A decode window leaves slots that
-are not ``active`` (free, or mid-chunk) untouched.
+are not ``active`` (free, or mid-chunk) untouched: their step is 0, so
+their decay is 1 and their input nothing (``ops/ssd.py``).
 
 The decode window is the engine's buffered one (``serving/dense.py``
 documents it): the pool read-only, the window's K and V rows in a buffer
 carried through the step scan, the cache half read by the block-table
 kernel on a TPU and through one gathered view elsewhere, one scatter at the
-end (``serving/paged_window.py`` holds what the families share of it).
+end (``serving/paged_window.py`` holds what the families share of it).  The
+states ride the same scan as a carry and are donated, so the largest
+program holds them once.
 """
 
 from __future__ import annotations
@@ -33,19 +40,30 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from dstack_tpu.models import lfm2 as model
+from dstack_tpu.models import nemotron_h as model
 from dstack_tpu.ops.pool import scatter_rows
 from dstack_tpu.serving import paged_window
 
 
-class Lfm2Programs:
+def _slot(rec, slot):
+    """One slot's part of ``rec``."""
+    return jax.tree.map(lambda a: a[slot], rec)
+
+
+def _with_slot(rec, slot, mine):
+    """``rec`` with the slot's part replaced by ``mine``."""
+    return jax.tree.map(lambda a, m: a.at[slot].set(m.astype(a.dtype)),
+                        rec, mine)
+
+
+class NemotronHPrograms:
     #: why prefill/decode disaggregation is refused
     pd_refusal = (
         "prefill/decode disaggregation is not served for this "
         "model: the wire carries K and V rows of every layer, not "
-        "convolution tails beside the rows of some")
+        "state-space states and convolution tails beside the rows of some")
 
-    def __init__(self, cfg: model.Lfm2MoeConfig, *, batch_size: int,
+    def __init__(self, cfg: model.NemotronHConfig, *, batch_size: int,
                  max_len: int, paged: bool, block_size: int, num_blocks: int,
                  prefix_cache: bool, quantize: Optional[str],
                  kv_quantize: Optional[str], mesh: Optional[Any],
@@ -56,14 +74,15 @@ class Lfm2Programs:
             (not paged, "paged=False: the attention layers' rows live in "
              "the paged pool, a dense row per slot is not written"),
             (prefix_cache, "prefix_cache: a cached block would need a "
-             "snapshot of the convolution tails at its boundary"),
+             "snapshot of the state-space states at its boundary (2 MiB a "
+             "layer a block)"),
             (kv_quantize, "kv_quantize: the window buffer and the "
              "end-of-window scatter write plain rows, not packed ones "
              "with scales"),
             (quantize, "quantize: the grouped expert product would need "
              "int8 forms of the expert stacks"),
             (mesh is not None, "a mesh: it would need the expert "
-             "exchange and sharding rules for the tails"),
+             "exchange and sharding rules for the states"),
         ):
             if refused:
                 raise ValueError(
@@ -90,18 +109,23 @@ class Lfm2Programs:
     # -- state ---------------------------------------------------------------
     def init_state(self):
         """``(pool, rec)``, all zeros."""
-        cfg = self.cfg
+        cfg, b = self.cfg, self.batch_size
         leaf = (cfg.attention_layers, self.num_blocks, self.block_size,
                 cfg.kv_lanes)
         pool = {"k": jnp.zeros(leaf, cfg.dtype),
                 "v": jnp.zeros(leaf, cfg.dtype)}
-        rec = jnp.zeros((cfg.conv_layers, self.batch_size, cfg.conv_reach,
-                         cfg.hidden_size), cfg.dtype)
+        rec = {
+            "ssm": [jnp.zeros((b, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                               cfg.ssm_state_size), jnp.float32)
+                    for _ in range(cfg.mamba_layers)],
+            "tail": [jnp.zeros((b, cfg.conv_reach, cfg.conv_dim), cfg.dtype)
+                     for _ in range(cfg.mamba_layers)],
+        }
         return pool, rec
 
     def recurrent_state_bytes(self) -> int:
-        """Bytes of ``rec``: the tails the slots hold whatever their
-        lengths (the ``recurrent_state_bytes`` gauge)."""
+        """Bytes of ``rec``: the states and tails the slots hold whatever
+        their lengths (the ``recurrent_state_bytes`` gauge)."""
         return self.cfg.recurrent_state_bytes(self.batch_size)
 
     def kv_geometry(self) -> tuple:
@@ -114,20 +138,25 @@ class Lfm2Programs:
 
     @staticmethod
     def record_window_counts(telemetry, counts) -> None:
-        """A drained window's last output: its expert load."""
+        """A drained window's last output: its expert load, then the
+        slot-layer-steps its state-space updates ran."""
         if telemetry is None:
             return
-        telemetry.record_expert_load(*counts.tolist())
+        *load, ssm_steps = counts.tolist()
+        telemetry.record_expert_load(*load)
+        telemetry.record_ssm_steps(ssm_steps)
 
-    @staticmethod
-    def record_prompt_program(telemetry, bucket: int) -> None:
-        """A prefill or chunk program of ``bucket`` positions ran: nothing
-        this family counts (``serving/nemotron_h.py`` counts its scans)."""
+    def record_prompt_program(self, telemetry, bucket: int) -> None:
+        """A prefill or chunk program of ``bucket`` positions ran: the
+        blocks that went through the chunked scan in its Mamba layers."""
+        if telemetry is not None:
+            chunks = -(-bucket // min(self.cfg.chunk_size, bucket))
+            telemetry.record_ssm_scan_chunks(self.cfg.mamba_layers * chunks)
 
     @staticmethod
     def slot_target(slot_id: int, pages):
         """Where a prefill or chunk program writes: the slot's pages and
-        the slot, whose tails it starts or carries."""
+        the slot, whose state it starts or carries."""
         return pages, jnp.int32(slot_id)
 
     # -- prefill -------------------------------------------------------------
@@ -149,10 +178,10 @@ class Lfm2Programs:
                 return paged_window.masked_attention(
                     q[None], k[None], v[None], positions, positions)[0]
 
-            logits, tails = model.sequence_forward(
-                params, cfg, tokens, length, 0,
-                jnp.zeros_like(paged_window.slot_rows(rec, slot)), attend)
-            return logits, pool, rec.at[:, slot].set(tails.astype(rec.dtype))
+            zeros = jax.tree.map(jnp.zeros_like, _slot(rec, slot))
+            logits, mine = model.sequence_forward(
+                params, cfg, tokens, length, zeros, attend)
+            return logits, pool, _with_slot(rec, slot, mine)
 
         return fn
 
@@ -160,16 +189,17 @@ class Lfm2Programs:
         """One chunk of a long prompt: ``fn(params, tokens [cbucket],
         chunk_len, prefix_len, pool, rec, (table row, slot))``.  The K and V
         rows go into the slot's pages and the chunk attends the slot's whole
-        span; the tails come from the slot (zeros at ``prefix_len`` 0) and
-        go back to it."""
+        span; the state and the tail come from the slot (zeros at
+        ``prefix_len`` 0) and go back to it."""
         cfg, bs, nb = self.cfg, self.block_size, self.num_blocks
         span = self.blocks_per_slot * self.block_size
         heads = (span, cfg.num_key_value_heads, cfg.head_dim)
 
         def fn(params, tokens, chunk_len, prefix_len, pool, rec, target):
             tables_row, slot = target
-            mine = paged_window.slot_rows(rec, slot)
-            mine = jnp.where(prefix_len == 0, jnp.zeros_like(mine), mine)
+            mine = jax.tree.map(
+                lambda a: jnp.where(prefix_len == 0, jnp.zeros_like(a), a),
+                _slot(rec, slot))
             blk, off = paged_window.chunk_pages(prefix_len, cbucket,
                                                 tables_row, bs, span)
             positions = prefix_len + jnp.arange(cbucket)[None, :]
@@ -190,9 +220,9 @@ class Lfm2Programs:
                     q[None], mine_k[None], mine_v[None], positions,
                     kv_pos)[0]
 
-            logits, tails = model.sequence_forward(
-                params, cfg, tokens, chunk_len, prefix_len, mine, attend)
-            return logits, pool, rec.at[:, slot].set(tails.astype(rec.dtype))
+            logits, mine = model.sequence_forward(
+                params, cfg, tokens, chunk_len, mine, attend)
+            return logits, pool, _with_slot(rec, slot, mine)
 
         return fn
 
@@ -208,9 +238,11 @@ class Lfm2Programs:
                          kv_blocks: Optional[int]):
         """``window`` tokens for every active slot in one program: the
         engine's buffered window over the attention layers' pool, with the
-        tails carried through the steps in place.  Returns what the Llama
-        window returns and, last, the window's expert load (float32
-        [``model.LOAD_FIELDS``], :func:`model.moe_ffn`) for the telemetry."""
+        states and tails carried through the steps in place.  Returns what
+        the Llama window returns and, last, the window's counts for the
+        telemetry: its expert load (float32 [``model.LOAD_FIELDS``],
+        :func:`model.moe_block`) and the slot-layer-steps of its
+        state-space updates (live slots x Mamba layers a step)."""
         cfg, b, w, bs = self.cfg, self.batch_size, window, self.block_size
         layers = cfg.attention_layers
         hkv, hd = cfg.num_key_value_heads, cfg.head_dim
@@ -239,9 +271,8 @@ class Lfm2Programs:
             win_j = jnp.arange(w)
 
             def one_step(carry, inputs):
-                last_token, step_lengths, win_k, win_v, tails, load = carry
+                last_token, win_k, win_v, rec, load = carry
                 i, step_rng = inputs
-                positions = jnp.minimum(step_lengths, max_len - 1)
                 x = params["embed"].astype(cfg.dtype)[last_token]
                 win_mask = (win_j[None, :] <= i)[:, None, None, :]
 
@@ -263,8 +294,8 @@ class Lfm2Programs:
                             win_v[m], win_mask, x.dtype)
                     return o.reshape(b, hkv * group, hd)
 
-                x, tails, step_load = model.decode_step(
-                    params, cfg, x, positions, active, tails, attend)
+                x, rec, step_load = model.decode_step(
+                    params, cfg, x, active, rec, attend)
                 logits = model.output_logits(params, cfg, x)
                 if sampling:
                     tokens = self._sample(logits, temps, top_ps, top_ks,
@@ -272,17 +303,16 @@ class Lfm2Programs:
                 else:
                     with jax.named_scope("sample"):
                         tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                new_lengths = jnp.where(active, step_lengths + 1,
-                                        step_lengths)
-                return (tokens, new_lengths, win_k, win_v, tails,
-                        load + step_load), tokens
+                return (tokens, win_k, win_v, rec, load + step_load), tokens
 
-            (last, new_lengths, win_k, win_v, tails, load), tokens_all = \
-                jax.lax.scan(
-                    one_step,
-                    (last_token, lengths, win0, win0, rec,
-                     jnp.zeros((model.LOAD_FIELDS,), jnp.float32)),
-                    (jnp.arange(w), jax.random.split(rng, w)))
+            (last, win_k, win_v, rec, load), tokens_all = jax.lax.scan(
+                one_step,
+                (last_token, win0, win0, rec,
+                 jnp.zeros((model.LOAD_FIELDS,), jnp.float32)),
+                (jnp.arange(w), jax.random.split(rng, w)))
+            new_lengths = jnp.where(active, lengths + w, lengths)
+            ssm_steps = (active.sum().astype(jnp.float32)
+                         * (w * cfg.mamba_layers))
 
             # the window's rows into each slot's pages (positions base_len +
             # j)
@@ -293,6 +323,7 @@ class Lfm2Programs:
                                           jnp.moveaxis(win_k, 1, 2)),
                         "v": scatter_rows(pool["v"], idx,
                                           jnp.moveaxis(win_v, 1, 2))}
-            return tokens_all, last, new_lengths, pool, tails, load
+            return (tokens_all, last, new_lengths, pool, rec,
+                    jnp.concatenate([load, ssm_steps[None]]))
 
         return fn

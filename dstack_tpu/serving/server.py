@@ -22,6 +22,7 @@ from typing import Optional
 from aiohttp import web
 
 from dstack_tpu.models.lfm2 import Lfm2MoeConfig
+from dstack_tpu.models.nemotron_h import NemotronHConfig
 from dstack_tpu.models.ling_hybrid import LingHybridConfig
 from dstack_tpu.models.ouro import OuroConfig
 from dstack_tpu.models.llama import LlamaConfig
@@ -77,6 +78,12 @@ CONFIGS = {
     # needs --paged.  The 9-layer cut is the benchmark's pipeline stage
     "lfm2-tiny": Lfm2MoeConfig.tiny,
     "lfm2-24b-a2b-9l": Lfm2MoeConfig.lfm2_24b_a2b_9l,
+    # the Nemotron-H family (Mamba-2 + routed relu^2 experts + GQA without
+    # positions); needs --paged.  The 9-block cut is the benchmark's chip
+    # of EP 2
+    "nemotron-h-tiny": NemotronHConfig.tiny,
+    "nemotron-3-nano-30b-a3b-9l-ep2":
+        NemotronHConfig.nemotron_3_nano_30b_a3b_9l_ep2,
 }
 
 

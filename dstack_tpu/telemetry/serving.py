@@ -71,6 +71,12 @@ republished with project/run/job/replica labels):
   and added where a window is drained: passes over the layer stack its
   steps ran (over ``decode_steps_total``: passes a step), and decoded
   tokens by the pass the exit gate took their logits from
+- ``ssm_slot_layer_steps_total`` / ``ssm_scan_chunks_total`` counters — a
+  state-space decoder's work on its recurrent state: decode updates (live
+  slots x state-space layers a step, counted inside the window's program
+  and added where it is drained: each reads and writes one slot's state of
+  one layer), and blocks of tokens through the chunked scan (state-space
+  layers x blocks of the padded bucket, a prefill or chunk program)
 - ``kv_cache_layers`` / ``kv_bytes_per_token`` gauges — layers of K/V a
   token holds and its bytes over all of them: what a pool is sized from
 - ``programs_built_total{kind}`` counter — engine programs built (compiled
@@ -400,6 +406,17 @@ class EngineTelemetry:
             r.counter(PREFIX + "loop_exit_tokens_total",
                       labels={"step": str(step)}).inc(tokens)
 
+    def record_ssm_steps(self, slot_layer_steps: float) -> None:
+        """One drained decode window of a state-space decoder: the updates
+        of one live slot's state in one layer its steps ran."""
+        self.recorder.counter(
+            PREFIX + "ssm_slot_layer_steps_total").inc(slot_layer_steps)
+
+    def record_ssm_scan_chunks(self, chunks: int) -> None:
+        """One prefill or chunk program of a state-space decoder: the
+        blocks of tokens its layers' chunked scans ran."""
+        self.recorder.counter(PREFIX + "ssm_scan_chunks_total").inc(chunks)
+
     def record_kv_geometry(self, cache_layers: int,
                            bytes_per_token: int) -> None:
         """What a KV pool is sized from: the layers of cache a token holds
@@ -410,8 +427,9 @@ class EngineTelemetry:
             bytes_per_token)
 
     def record_recurrent_state_bytes(self, nbytes: int) -> None:
-        """Bytes of per-slot recurrent state (linear-attention layers) the
-        engine holds beside the paged pool."""
+        """Bytes of per-slot recurrent state (linear-attention states,
+        convolution tails, state-space states) the engine holds beside the
+        paged pool."""
         self.recorder.gauge(PREFIX + "recurrent_state_bytes").set(nbytes)
 
     # -- read side -------------------------------------------------------

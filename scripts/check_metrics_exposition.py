@@ -191,6 +191,8 @@ async def check_serving_metrics() -> int:
     tel.record_expert_load(200.0, 600.0, 9.0, 2.0, 90.0, 1024.0)
     tel.record_recurrent_state_bytes(1 << 20)
     tel.record_loop_passes(256.0, [0.0, 3.0, 0.0, 500.0])
+    tel.record_ssm_steps(64 * 4 * 300.0)
+    tel.record_ssm_scan_chunks(4 * 4)
     tel.record_kv_geometry(192, 1572864)
 
     class _Req:
@@ -260,6 +262,8 @@ async def check_serving_metrics() -> int:
             "dstack_serving_recurrent_state_bytes",
             "dstack_serving_loop_passes_total",
             "dstack_serving_loop_exit_tokens_total",
+            "dstack_serving_ssm_slot_layer_steps_total",
+            "dstack_serving_ssm_scan_chunks_total",
             "dstack_serving_kv_cache_layers",
             "dstack_serving_kv_bytes_per_token",
             "dstack_serving_requests_total",

@@ -1,5 +1,6 @@
 """The seam between the scheduler (``serving/engine.py``) and a family's
-provider (``serving/dense.py``, ``serving/hybrid.py``, ``serving/lfm2.py``):
+provider (``serving/dense.py``, ``serving/hybrid.py``, ``serving/lfm2.py``,
+``serving/nemotron_h.py``):
 the providers answer the same calls, the engine drives each through admission, chunked prefill
 and decode windows to the tokens of the family's plain forward, the state
 trees are the size the provider says, each provider refuses what its model
@@ -22,14 +23,15 @@ from dstack_tpu.serving.engine import InferenceEngine
 from dstack_tpu.serving.families import programs_for
 from dstack_tpu.serving.hybrid import HybridPrograms
 from dstack_tpu.serving.lfm2 import Lfm2Programs
+from dstack_tpu.serving.nemotron_h import NemotronHPrograms
 
 ROOT = Path(__file__).resolve().parents[2]
 SERVING = ROOT / "dstack_tpu" / "serving"
 #: the calls of the seam and how many arguments each takes
 SEAM = {"prepare_params": 2, "init_state": 0, "recurrent_state_bytes": 0,
         "kv_geometry": 0, "slot_target": 2, "prefill_fn": 1, "chunk_fn": 1,
-        "decode_window_fn": 3, "record_window_counts": 2, "export_fn": 1,
-        "insert_rows": 5}
+        "decode_window_fn": 3, "record_window_counts": 2,
+        "record_prompt_program": 2, "export_fn": 1, "insert_rows": 5}
 PAGED = dict(paged=True, kv_block_size=16, total_kv_blocks=20)
 BUILT_WITH = dict(batch_size=2, max_len=64, paged=True, block_size=16,
                   num_blocks=9, prefix_cache=False, quantize=None,
@@ -83,6 +85,18 @@ def _lfm2():
         weights, sizes, np.asarray(seq), 0, len(seq), config=toy)
 
 
+def _nemotron():
+    from benchmarks.harness.sizes import program_config, sizes_of
+    from benchmarks.references import nemotron_h as ref
+
+    toy = json.loads((ROOT / "tests/benchmark/fixture_nemotron/cells/configs"
+                      / "tiny-nemotron.json").read_text())
+    sizes = sizes_of(toy)
+    weights = ref.init_weights(sizes, 5, config=toy)
+    return program_config(toy), weights, lambda seq: ref.logits(
+        weights, sizes, np.asarray(seq), 0, len(seq), config=toy)
+
+
 def _looped():
     from benchmarks.harness.sizes import program_config, sizes_of
     from benchmarks.references import ouro_looped as ref
@@ -103,6 +117,7 @@ FAMILIES = {
     "routed-mlp-paged": (_routed_mlp, PAGED, DensePrograms),
     "hybrid-paged": (_hybrid, PAGED, HybridPrograms),
     "lfm2-paged": (_lfm2, PAGED, Lfm2Programs),
+    "nemotron-paged": (_nemotron, PAGED, NemotronHPrograms),
 }
 
 
@@ -144,7 +159,7 @@ def test_the_providers_are_built_with_the_same_keywords():
         (p.name, p.kind) for p in
         inspect.signature(cls.__init__).parameters.values()]
     assert keywords(DensePrograms) == keywords(HybridPrograms) == \
-        keywords(Lfm2Programs)
+        keywords(Lfm2Programs) == keywords(NemotronHPrograms)
     assert {name for name, _ in keywords(DensePrograms)[2:]} == \
         set(BUILT_WITH)
 
@@ -243,7 +258,7 @@ def test_the_scheduler_names_no_model():
         path.name for path in SERVING.glob("*.py")
         if any(m.startswith("dstack_tpu.models") for m in imported(path)[1])}
     assert name_a_model == {"dense.py", "hybrid.py", "lfm2.py",
-                            "families.py", "server.py"}
+                            "nemotron_h.py", "families.py", "server.py"}
     families = ast.parse((SERVING / "families.py").read_text())
     assert [node.name for node in ast.walk(families)
             if isinstance(node, ast.FunctionDef)] == ["programs_for"]
@@ -254,15 +269,18 @@ def test_families_is_a_table_and_a_subclass_goes_to_its_base_s_provider():
     from dstack_tpu.models.ling_hybrid import LingHybridConfig
     from dstack_tpu.models.llama import LlamaConfig
     from dstack_tpu.models.moe import MoEConfig
+    from dstack_tpu.models.nemotron_h import NemotronHConfig
     from dstack_tpu.models.ouro import OuroConfig
     from dstack_tpu.serving.families import PROVIDERS
 
     assert PROVIDERS == {LlamaConfig: DensePrograms,
                          LingHybridConfig: HybridPrograms,
-                         Lfm2MoeConfig: Lfm2Programs}
+                         Lfm2MoeConfig: Lfm2Programs,
+                         NemotronHConfig: NemotronHPrograms}
     for make, provider in ((MoEConfig.tiny_moe, DensePrograms),
                            (OuroConfig.tiny, DensePrograms),
-                           (Lfm2MoeConfig.tiny, Lfm2Programs)):
+                           (Lfm2MoeConfig.tiny, Lfm2Programs),
+                           (NemotronHConfig.tiny, NemotronHPrograms)):
         assert type(programs_for(make(), **BUILT_WITH)) is provider
     with pytest.raises(TypeError, match="no provider serves"):
         programs_for(object(), **BUILT_WITH)
@@ -287,16 +305,25 @@ def test_what_the_families_share_exists_once():
     assert kernel.count("pl.pallas_call(") == 1
     assert where("grouped_swiglu(") == ["models/experts.py",
                                         "ops/grouped_matmul.py"]
+    assert where("grouped_relu2(") == ["models/experts.py",
+                                       "ops/grouped_matmul.py"]
     assert where("grouped_matmul(") == ["ops/grouped_matmul.py"]
+    # one router for the three dropless families
+    assert where("jax.nn.sigmoid(jnp.matmul(") == ["models/experts.py"]
+    assert where("experts.route(") == ["models/lfm2.py",
+                                       "models/ling_hybrid.py",
+                                       "models/nemotron_h.py"]
     assert where("jnp.logaddexp(lse_c") == ["serving/paged_window.py"]
     assert where("jnp.take_along_axis(tables") == ["serving/paged_window.py"]
     assert where("def masked_attention(") == ["serving/paged_window.py"]
-    for caller in ("serving/dense.py", "serving/lfm2.py"):
+    for caller in ("serving/dense.py", "serving/lfm2.py",
+                   "serving/nemotron_h.py"):
         assert caller in where("paged_window.attend_pages_and_window(")
         assert caller in where("paged_window.attend_view_and_window(")
     for caller in ("serving/dense.py", "serving/hybrid.py",
-                   "serving/lfm2.py"):
+                   "serving/lfm2.py", "serving/nemotron_h.py"):
         assert caller in where("paged_window.window_rows(")
         assert caller in where("paged_window.chunk_pages(")
     assert where("held_experts(") == ["models/experts.py", "models/lfm2.py",
-                                      "models/ling_hybrid.py"]
+                                      "models/ling_hybrid.py",
+                                      "models/nemotron_h.py"]

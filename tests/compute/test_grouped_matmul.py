@@ -31,6 +31,7 @@ PATTERNS = {
                                           [2, 0, 3, 1, 0, 0, 2, 4] * 4),
     "lfm2-width-ratio": (256, 128, 96, [16, 26, 9, 13] * 4),      # 2048:1536
     "ling-width-ratio": (256, 160, 48, [2, 0, 1, 5] * 8),         # 2560:768
+    "nemotron-width-ratio": (256, 168, 116, [9, 0, 2, 25] * 4),   # 2688:1856
     "last-expert-ends-on-a-tile-edge": (256, 64, 32, [28, 100, 128]),
 }
 
@@ -90,6 +91,32 @@ def test_grouped_product_is_the_loop_over_experts(pattern, product, dtype):
     want = _loop(x.at[live:].set(0), weights, counts, dtype)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("pattern", ["nemotron-width-ratio",
+                                     "a-group-spans-three-tiles",
+                                     "rows-no-multiple-of-the-tile",
+                                     "every-row-in-the-dead-tail",
+                                     "fewer-rows-than-a-tile"])
+def test_transposed_stack_is_the_same_product(pattern, dtype):
+    """``transposed=True`` takes the stack ``[E, N, K]`` (an expert's matrix
+    as ``nn.Linear`` keeps it) and gives ``x @ w[e].T``: the loop's result
+    on the stack's transpose, in whole-width and in 128-row blocks."""
+    x, (w, _), counts = _case(pattern, dtype)
+    stack = jnp.swapaxes(w, 1, 2)                         # [E, N, K]
+    live = int(counts.sum())
+    want = _loop(x.at[live:].set(0), (w,), counts, dtype)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for block_bytes in (gm.BLOCK_BYTES, 1):
+        got = jax.jit(lambda x, w, c: gm.grouped_matmul(
+            x, w, c, transposed=True, block_bytes=block_bytes))(
+                x, stack, counts)
+        assert got.shape == (x.shape[0], w.shape[2]) and got.dtype == x.dtype
+        got = np.asarray(got.astype(jnp.float32))
+        assert np.isfinite(got).all() and (got[live:] == 0).all()
+        np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
 
 
 @pytest.mark.parametrize("pattern", ["a-group-straddles-a-row-tile",
@@ -155,20 +182,25 @@ def test_column_blocks_follow_the_shapes():
     assert block(2560, 768, 2, 2) == 768        # Ling gate and up: whole
     assert block(768, 2560, 2, 1) == 2560       # Ling down: whole
     assert block(64, 32, 4, 2) == 32            # a toy: whole
+    assert block(2688, 1856, 2, 1) == 1856      # Nemotron up: no lane tiles
+    assert block(1856, 2688, 2, 1) == 896       # Nemotron down: thirds
     assert block(4096, 14336, 2, 2) == 512      # wide experts: 4 lane tiles
     assert gm._column_block(64, 96, 4, 1, 1) == 96    # no lane tiles: whole
     assert gm._column_block(64, 256, 4, 1, 1) == 128  # never under a tile
 
 
+@pytest.mark.parametrize("form", ["swiglu", "relu2"])
 @pytest.mark.parametrize("held", ["every-expert-held", "a-quarter-held"])
 @pytest.mark.parametrize("masked", ["no-mask", "half-the-tokens-masked"])
 def test_held_experts_is_the_same_through_the_kernel(monkeypatch, held,
-                                                     masked):
+                                                     masked, form):
     """``models/experts.py`` ``held_experts`` through the kernel (what every
     backend but the CPU's runs; interpreted here) against its CPU path
     (XLA's ``ragged_dot``, the absent and masked pairs computed in the last
-    expert's group): the same sums, the same counts, and the load vector's
-    sixth field counts the kernel's rows."""
+    expert's group), in both of the experts' forms (gate, up, down; up,
+    relu squared, down): the same sums, the same counts, and the load
+    vector's sixth field counts the kernel's rows.  The two-matrix form
+    against a plain loop over the experts besides."""
     routed, k, d, f, t = 16, 4, 64, 32, 37
     cfg = SimpleNamespace(
         num_experts_per_tok=k,
@@ -184,12 +216,28 @@ def test_held_experts_is_the_same_through_the_kernel(monkeypatch, held,
         jax.random.split(keys[4], t)).astype(jnp.int32)
     weights = jax.nn.softmax(jax.random.normal(keys[5], (t, k)))
     mask = None if masked == "no-mask" else jnp.arange(t) % 2 == 0
+    if form == "relu2":     # two stacks, both [experts, width, hidden]
+        del lp["we_gate"]
+        lp["we_up"] = jnp.swapaxes(lp["we_up"], 1, 2)
     plain, plain_counts = jax.jit(
-        lambda *a: experts.held_experts(*a, lp, cfg, mask))(h, ids, weights)
+        lambda *a: experts.held_experts(*a, lp, cfg, mask, form))(
+            h, ids, weights)
     assert not experts._on_chip()
     monkeypatch.setattr(experts, "_on_chip", lambda: True)
     ours, counts = jax.jit(
-        lambda *a: experts.held_experts(*a, lp, cfg, mask))(h, ids, weights)
+        lambda *a: experts.held_experts(*a, lp, cfg, mask, form))(
+            h, ids, weights)
+    if form == "relu2":
+        local = np.asarray(ids) - cfg.expert_offset
+        want = np.zeros((t, d), np.float32)
+        for i in range(t):
+            for j in range(k):
+                if 0 <= local[i, j] < e and (mask is None or bool(mask[i])):
+                    want[i] += float(weights[i, j]) * np.asarray(
+                        experts.relu2(h[i], lp["we_up"][local[i, j]].T,
+                                      lp["we_down"][local[i, j]]))
+        np.testing.assert_allclose(np.asarray(ours), want, atol=2e-5,
+                                   rtol=2e-5)
     assert (np.asarray(counts) == np.asarray(plain_counts)).all()
     assert 0 < int(counts.sum()) <= (t if mask is None else (t + 1) // 2) * k
     np.testing.assert_allclose(np.asarray(ours), np.asarray(plain),

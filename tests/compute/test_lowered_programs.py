@@ -16,6 +16,11 @@ the compiled CPU program is the same multiset of instructions).  PR 36
 rewrote the three Ling entries: the expert-load vector the programs return
 has a sixth field (``models/experts.py`` ``LOAD_FIELDS``); the grouped
 product itself is the parent's on the CPU backend (``ragged_dot``).
+PR 39 added ``nemotron-paged`` (the fourth provider's three programs, so that
+the lift of the providers' shared scaffolding, ROADMAP D13, has all four
+families under it) and left the 18 hashes before it as they were: the
+experts' form and the shared router (``models/experts.py``) lower the Ling
+programs to the same text.
 A change that is meant to alter one of these programs rewrites the file:
 
     JAX_PLATFORMS=cpu python tests/compute/test_lowered_programs.py --write
@@ -51,6 +56,12 @@ def _ouro():
     return OuroConfig.tiny()
 
 
+def _nemotron():
+    from dstack_tpu.models.nemotron_h import NemotronHConfig
+
+    return NemotronHConfig.tiny()
+
+
 def _ling():
     from dstack_tpu.models.ling_hybrid import LingHybridConfig
 
@@ -65,6 +76,7 @@ CASES = {
     "llama-rows": (_llama, {}, False),
     "ouro-paged": (_ouro, PAGED, False),
     "ling-paged": (_ling, PAGED, False),
+    "nemotron-paged": (_nemotron, PAGED, False),
 }
 
 

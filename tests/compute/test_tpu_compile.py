@@ -814,6 +814,118 @@ def test_lfm2_program_fits_and_copies_no_pool(lfm2_programs, program):
                              width=1536)
 
 
+#: the Nemotron-H cell of BENCHMARK.json as its files size it: every width,
+#: all nine blocks, 384 slots, 24,576 blocks of 32 rows.  ``decode_w64`` at
+#: the widest table bucket (128 columns)
+NEMOTRON_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_prefix_b512"]
+
+
+@pytest.fixture(scope="module")
+def nemotron_programs(one_chip, real_lowering):
+    """``compile_program(program)`` -> (compiled, state tree shapes): the
+    engine's own builders for the Nemotron-H decoder at the cell's real
+    sizes, with the block-table kernel, weights never made."""
+    import json
+    from pathlib import Path
+
+    from benchmarks.harness.sizes import load_config, program_config
+    from dstack_tpu.models.nemotron_h import init_params
+    from dstack_tpu.serving.engine import InferenceEngine
+
+    env = pytest.MonkeyPatch()
+    env.setenv("DSTACK_TPU_PAGED_ATTN_KERNEL", "1")  # read at engine init
+    root = Path(__file__).resolve().parents[2] / "benchmarks"
+    cell = "nemotron-3-nano-30b-a3b-9l-ep2"
+    cfg = program_config(load_config(root / "configs" / f"{cell}.json"))
+    load = json.loads((root / "workloads"
+                       / f"{cell}.reason.json").read_text())
+    args = dict(load["engine"], prefill_chunk=512)
+    b, bs = args["batch_size"], args["kv_block_size"]
+    kb = args["max_len"] // bs
+    # an engine of one slot, its provider then told the cell's slots and
+    # pool: the 4.1 GB of states and pages are shapes only
+    engine = InferenceEngine(cfg, params={"layers": {}}, **dict(
+        args, batch_size=1, total_kv_blocks=kb + 1))
+    env.undo()
+    engine._programs.batch_size = b
+    engine._programs.num_blocks = args["total_kv_blocks"]
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = sds(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = sds(jax.eval_shape(engine._programs.init_state))
+    i32, f32 = jnp.int32, jnp.float32
+
+    @functools.lru_cache(maxsize=None)
+    def compile_program(program: str):
+        if program == "decode_w64":
+            fn = engine._decode_window_program(64, False, kb)
+            args = (params, arg(i32, b), arg(i32, b), arg(jnp.bool_, b),
+                    *state, arg(f32, b), arg(f32, b), arg(i32, b),
+                    arg(i32, b, kb), arg(jnp.uint32, 2))
+        elif program == "prefill_paged_b512":
+            fn = engine._prefill_program(512)
+            args = (params, arg(i32, 512), arg(i32), *state,
+                    (arg(i32, 512 // bs), arg(i32)))
+        else:
+            assert program == "prefill_prefix_b512", program
+            fn = engine._chunk_program(512)
+            args = (params, arg(i32, 512), arg(i32), arg(i32), *state,
+                    (arg(i32, kb), arg(i32)))
+        return fn.lower(*args).compile(), state
+
+    return compile_program
+
+
+@pytest.mark.parametrize("program", NEMOTRON_PROGRAMS)
+def test_nemotron_program_fits_and_holds_the_state_once(nemotron_programs,
+                                                        program):
+    """Each program of the Nemotron-H cell fits the chip beside its 6.33 GB
+    of weights at the cell's own sizes (384 slots: 3.28 GB of state-space
+    states and tails; a pool of 2 x [1, 24576, 32, 256]); the pool, the
+    states and the tails are donated and the program holds them once (its
+    temporaries are far below ONE layer's states); the decode window calls
+    the block-table kernel once (one attention layer), the grouped
+    product's kernel twice an expert layer (up, down: no gate) on the
+    experts' matrices where they lie (no copy or re-lay of a stack), and reads
+    and writes a Mamba layer's state in ONE fusion a step."""
+    compiled, (pool, rec) = nemotron_programs(program)
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    print(program, "held", held, "temp", mem.temp_size_in_bytes,
+          "args", mem.argument_size_in_bytes)
+    assert held < V5E_USABLE_BYTES, held
+    assert pool["k"].shape == (1, 24576, 32, 256)
+    assert [a.shape for a in rec["ssm"]] == [(384, 64, 64, 128)] * 4
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves((pool, rec)))
+    assert mem.alias_size_in_bytes >= donated
+    one_layer = rec["ssm"][0].size * 4
+    assert mem.temp_size_in_bytes < one_layer, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    kernels = len(re.findall(r"%paged_decode_attention[\w.\-]* = ", text))
+    assert kernels == (1 if program.startswith("decode") else 0)
+    assert not re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ", text)
+    assert len(re.findall(r"%grouped_matmul[\w.\-]* = ", text)) == 2 * 4
+    assert _pool_sized_ops(text, 64 * 2688 * 1856, 1, "bf16") == []
+    if program.startswith("decode"):
+        # every operation that yields a whole layer's states is the one
+        # fusion of ``ops/ssd.py`` ``ssm_step`` (the update and the read by
+        # C in one pass), four a step
+        whole = [line for line in text.splitlines()
+                 if re.search(r" = \(?[^=]*f32\[384,64,64,128\]", line)
+                 and " fusion(" in line]
+        assert len(whole) == 4, [line[:200] for line in whole]
+        assert all("jit(ssm_step)" in line for line in whole)
+
+
 # -- the decode window buffer -------------------------------------------------
 #
 # The W rows a decode window produces are a scan CARRY of ``serving/dense.py``
