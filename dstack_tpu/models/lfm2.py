@@ -20,8 +20,9 @@ Pre-norm residual blocks, ``x += mixer(norm(x)); x += ffn(norm(x))``, with
 
 Layers differ in kind, so the weights are a LIST of per-layer dicts and the
 stack is a Python loop (as ``models/ling_hybrid.py``).  The functions work
-on one sequence [T, D] (prefill, a chunk of it) or on one token of every
-slot [B, D] (a decode step); what the attention layers see of the cache is
+on one sequence [T, D] (a chunk of a prompt), on a group of whole prompts
+[P, T] (prefill) or on one token of every slot [B, D] (a decode step); what
+the attention layers see of the cache is
 the caller's (``attend``): the engine's programs (``serving/lfm2.py``) hold
 the K/V pool and the convolution tails around them.
 """
@@ -361,6 +362,39 @@ def sequence_forward(params: Params, cfg: Lfm2MoeConfig, tokens, length,
         x, _ = _ffn(x + y, lp, cfg, live)
     logits = output_logits(params, cfg, x[length - 1])
     return logits, (jnp.stack(new_tails) if new_tails else tails)
+
+
+def prompts_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
+                    attend: Callable):
+    """A group of whole prompts through the stack in one pass, each from
+    zeros: ``tokens`` [P, T], of which ``lengths`` [P] are real (0: an
+    unused entry, which routes no pair to an expert).  The convolutions run
+    per prompt; everything a token does alone (norms, projections, the
+    router, the experts' grouped product) runs on the P * T rows at once,
+    so a layer streams each expert's matrices once for the group.
+    ``attend(m, q, k, v)`` takes and returns those rows ([P * T, ...],
+    prompt p's at [p * T, (p + 1) * T)).  Returns ``(logits [P, V] float32
+    at each prompt's last real token, tails [conv layers, P, conv_L_cache
+    - 1, D])``."""
+    p, t = tokens.shape
+    positions = jnp.tile(jnp.arange(t), p)
+    live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(-1)
+    x = params["embed"].astype(cfg.dtype)[tokens.reshape(-1)]
+    zeros = jnp.zeros((cfg.conv_reach, cfg.hidden_size), cfg.dtype)
+    tails = []
+    for i, (kind, lp) in enumerate(zip(cfg.layer_types, params["layers"])):
+        if kind == "conv":
+            y, tail = jax.vmap(
+                lambda rows, n, lp=lp: conv_sequence(rows, lp, cfg, n, zeros)
+            )(x.reshape(p, t, -1), lengths)
+            y = y.reshape(p * t, -1)
+            tails.append(tail)
+        else:
+            q, k, v = attention_project(x, lp, cfg, positions)
+            y = attend(i - len(tails), q, k, v).reshape(p * t, -1) @ lp["wo"]
+        x, _ = _ffn(x + y, lp, cfg, live)
+    last = x.reshape(p, t, -1)[jnp.arange(p), lengths - 1]
+    return output_logits(params, cfg, last), jnp.stack(tails)
 
 
 def decode_step(params: Params, cfg: Lfm2MoeConfig, x, positions, live,

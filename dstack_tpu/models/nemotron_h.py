@@ -27,9 +27,9 @@ A block is ONE mixer behind one norm, ``x += mixer(norm(x))``, of the kind
 
 Layers differ in kind, so the weights are a LIST of per-layer dicts and the
 stack is a Python loop (as ``models/lfm2.py``).  The functions work on one
-sequence [T, D] (prefill, a chunk of it) or on one token of every slot
-[B, D] (a decode step); what the attention layers see of the cache is the
-caller's (``attend``): the engine's programs (``serving/nemotron_h.py``)
+sequence [T, D] (a chunk of a prompt), on a group of whole prompts [P, T]
+(prefill) or on one token of every slot [B, D] (a decode step); what the
+attention layers see of the cache is the caller's (``attend``): the engine's programs (``serving/nemotron_h.py``)
 hold the K/V pool, the states and the tails around them.
 """
 
@@ -462,6 +462,41 @@ def sequence_forward(params: Params, cfg: NemotronHConfig, tokens, length,
         lambda h, lp, state, tail: mamba_sequence(h, lp, cfg, length, state,
                                                   tail))
     return output_logits(params, cfg, x[length - 1]), rec
+
+
+def prompts_forward(params: Params, cfg: NemotronHConfig, tokens, lengths,
+                    attend: Callable):
+    """A group of whole prompts through the stack in one pass, each from
+    zeros: ``tokens`` [P, T], of which ``lengths`` [P] are real (0: an
+    unused entry, which routes no pair to an expert).  The Mamba-2 mixers
+    run per prompt (the chunked scan batched over the prompts as over the
+    heads); everything a token does alone (norms, projections, the router,
+    the experts' grouped product, the shared expert) runs on the P * T rows
+    at once, so a block streams each expert's matrices once for the group.
+    ``attend(m, q, k, v)`` takes and returns those rows ([P * T, ...],
+    prompt p's at [p * T, (p + 1) * T)).  Returns ``(logits [P, V] float32
+    at each prompt's last real token, rec)``: ``rec`` as
+    :func:`decode_step`'s, with a prompt axis in front of every entry."""
+    p, t = tokens.shape
+    live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(-1)
+    x = params["embed"].astype(cfg.dtype)[tokens.reshape(-1)]
+    zeros = {
+        "ssm": [jnp.zeros((p, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                           cfg.ssm_state_size), jnp.float32)
+                for _ in range(cfg.mamba_layers)],
+        "tail": [jnp.zeros((p, cfg.conv_reach, cfg.conv_dim), cfg.dtype)
+                 for _ in range(cfg.mamba_layers)],
+    }
+
+    def mamba(h, lp, state, tail):
+        y, state, tail = jax.vmap(
+            lambda rows, n, s, tl: mamba_sequence(rows, lp, cfg, n, s, tl)
+        )(h.reshape(p, t, -1), lengths, state, tail)
+        return y.reshape(p * t, -1), state, tail
+
+    x, rec, _ = _stack(params, cfg, x, live, zeros, attend, mamba)
+    last = x.reshape(p, t, -1)[jnp.arange(p), lengths - 1]
+    return output_logits(params, cfg, last), rec
 
 
 def decode_step(params: Params, cfg: NemotronHConfig, x, live, rec,

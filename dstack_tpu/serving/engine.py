@@ -180,6 +180,13 @@ class InferenceEngine:
     #: a deliberate re-sweep, not drift.
     TUNED_PREFILL_CHUNK = 512
 
+    #: positions of one batched whole-prompt program (a provider with
+    #: ``prefill_batch_fn``): a bucket's program takes ``PREFILL_BATCH_TOKENS
+    #: // bucket`` prompts (at least one, at most ``batch_size``), so a
+    #: group of short prompts streams the held experts once, not once a
+    #: prompt (PR 40)
+    PREFILL_BATCH_TOKENS = 2048
+
     def __init__(
         self,
         cfg: Any,
@@ -311,6 +318,9 @@ class InferenceEngine:
             prefix_cache=prefix_cache, quantize=quantize,
             kv_quantize=kv_quantize, mesh=mesh,
             sharding_policy=sharding_policy, sample=self._sample_on_device)
+        #: whole prompts of an admission pass go out a group a bucket, in
+        #: one program each, where the provider has that program
+        self._batched_prefill = hasattr(self._programs, "prefill_batch_fn")
         cache_layers, token_bytes = self._programs.kv_geometry()
         if telemetry is not None:
             telemetry.record_kv_geometry(cache_layers, token_bytes)
@@ -417,6 +427,9 @@ class InferenceEngine:
         #: whose first token is still on the device (None: in
         #: ``_first_tokens``) or came over the PD wire
         self._first_pending: List[tuple] = []
+        #: bucket -> [(slot, request, tokens, sampler key)]: the whole
+        #: prompts of the pass not yet sent (see _gather_prompt)
+        self._prompt_groups: dict = {}
         #: the two donated trees every program takes and returns: what they
         #: hold is the provider's (a K and a V cache; a latent pool and a
         #: recurrent state)
@@ -726,7 +739,9 @@ class InferenceEngine:
     def _admit(self) -> None:
         """Admit queued requests into free slots, under one
         ``engine.admit`` span per call that has both.  The pass sends every
-        prompt's program and sampler back to back; its slot updates and
+        prompt's program and sampler back to back (a group of whole prompts
+        a program, sampler included, where the provider has the batched
+        form: :meth:`_gather_prompt`); its slot updates and
         its one wait for the device close it, under an ``engine.prefill``
         span of no tokens (the closing span shows in a trace whose edge
         dropped the pass's long ``engine.admit``)."""
@@ -740,6 +755,15 @@ class InferenceEngine:
                     self._hand_over_first_tokens()
 
     def _admit_into_free_slots(self) -> None:
+        """Claim a free slot for each waiting request the pool has room
+        for, and send every prompt program of the pass: each gathered group
+        of whole prompts goes out once it is full or, at the latest, here at
+        the pass's end (however the claiming stopped)."""
+        self._claim_free_slots()
+        for bucket in list(self._prompt_groups):
+            self._send_prompts(bucket)
+
+    def _claim_free_slots(self) -> None:
         for slot_id in range(self.batch_size):
             if self._slots[slot_id] is not None:
                 continue
@@ -811,6 +835,8 @@ class InferenceEngine:
                         self._mark_admitted(req)
                         self._chunking[slot_id] = {"tokens": tokens,
                                                    "done": done}
+                    elif self._batched_prefill:
+                        self._gather_prompt(slot_id, req)
                     else:
                         with self._phase("prefill", slot=slot_id, tokens=n):
                             self._prefill(slot_id, req)
@@ -917,10 +943,31 @@ class InferenceEngine:
             return fn(*args)
 
     def _prefill_program(self, bucket: int):
-        """The jitted whole-prompt prefill of one bucket."""
-        return self._jit_cached(self._programs.prefill_fn(bucket),
-                                f"{self._prefill_tag}{bucket}",
-                                donate_argnums=(3, 4))
+        """The jitted whole-prompt prefill of one bucket: of one prompt, or
+        where the provider has ``prefill_batch_fn`` of a group of them,
+        ``fn(params, tokens [P, bucket], lengths [P], pool, rec, block ids
+        [P, bucket / block_size], slots [P], sampler constants [P, 3]
+        (temperature, top-p, top-k), keys (P of them), first_tokens)``,
+        which samples every prompt's first token into the donated
+        ``first_tokens`` and returns ``(pool, rec, first_tokens)``."""
+        tag = f"{self._prefill_tag}{bucket}"
+        if not self._batched_prefill:
+            return self._jit_cached(self._programs.prefill_fn(bucket), tag,
+                                    donate_argnums=(3, 4))
+        forward = self._programs.prefill_batch_fn(bucket)
+
+        def fn(params, tokens, lengths, pool, rec, bids, slots, consts, keys,
+               first_tokens):
+            logits, pool, rec = forward(params, tokens, lengths, pool, rec,
+                                        bids, slots)
+            temps, top_ps, top_ks = consts.T
+            firsts = jax.vmap(lambda *one: self._sample_on_device(
+                *(a[None] for a in one[:4]), one[4])[0])(
+                    logits, temps, top_ps, top_ks.astype(jnp.int32),
+                    jnp.stack(keys))
+            return pool, rec, first_tokens.at[slots].set(firsts, mode="drop")
+
+        return self._jit_cached(fn, tag, donate_argnums=(3, 4, 9))
 
     def _chunk_program(self, cbucket: int):
         """The jitted program of one bucket that prefills a chunk of a long
@@ -946,6 +993,68 @@ class InferenceEngine:
             # peer's compile-cache entries still hit
             self._programs.slot_target(jnp.int32(slot_id), pages))
         return logits
+
+    def _prompts_per_program(self, bucket: int) -> int:
+        """Prompts of one batched whole-prompt program of ``bucket``."""
+        return min(self.batch_size,
+                   max(1, self.PREFILL_BATCH_TOKENS // bucket))
+
+    def _gather_prompt(self, slot_id: int, req: Request) -> None:
+        """Put a whole prompt into its bucket's group, claiming the slot
+        (so that the crash handler fails the request and frees its blocks
+        if its group's program raises), and send the group once it is
+        full.  The sampler's key is split here, in admission order, as
+        :meth:`_sample_first` splits it."""
+        self._mark_admitted(req)
+        self._slots[slot_id] = req
+        self._slots_gen += 1
+        tokens = self._prompt_tokens(req.tokens, req.max_new_tokens)
+        bucket = self._bucket(len(tokens))
+        group = self._prompt_groups.setdefault(bucket, [])
+        group.append((slot_id, req, tokens, self._first_key(req)))
+        if len(group) == self._prompts_per_program(bucket):
+            self._send_prompts(bucket)
+
+    def _send_prompts(self, bucket: int) -> None:
+        """Send the group of whole prompts gathered for ``bucket`` as ONE
+        program (:meth:`_prefill_program`), its unused entries of length 0
+        at slot ``batch_size`` and past the pool's blocks, then queue each
+        prompt's activation in admission order."""
+        group = self._prompt_groups.pop(bucket)
+        p = self._prompts_per_program(bucket)
+        padded = np.zeros((p, bucket), np.int32)
+        lengths = np.zeros((p,), np.int32)
+        bids = np.full((p, bucket // self._block_size), self._alloc.num_blocks,
+                       np.int32)
+        slots = np.full((p,), self.batch_size, np.int32)
+        consts = np.zeros((p, 3), np.float32)
+        keys = [self._rng_key] * p
+        for i, (slot_id, req, tokens, key) in enumerate(group):
+            n = len(tokens)
+            padded[i, :n] = tokens
+            lengths[i] = n
+            bids[i] = self._slot_blocks[slot_id][:bids.shape[1]]
+            slots[i] = slot_id
+            # a top-k past the sampler's 1,024-wide prefilter keeps every
+            # rank, as any larger one does (see _sample_first)
+            consts[i] = (req.temperature, req.top_p,
+                         min(req.top_k or 0, 1024))
+            keys[i] = key
+        with self._phase("prefill", requests=len(group),
+                         tokens=int(lengths.sum())):
+            *self._state, self._first_tokens = self._run_program(
+                self._prefill_jit, ("prefill", bucket),
+                functools.partial(self._prefill_program, bucket),
+                self.params, jnp.asarray(padded), jnp.asarray(lengths),
+                *self._state, jnp.asarray(bids), jnp.asarray(slots),
+                jnp.asarray(consts), tuple(keys), self._first_tokens)
+        if self.telemetry is not None:
+            self.telemetry.record_prefill_batch(len(group))
+        for slot_id, req, tokens, _ in group:
+            if self.telemetry is not None:
+                self.telemetry.record_prefill(len(tokens), bucket)
+                self._programs.record_prompt_program(self.telemetry, bucket)
+            self._activate(slot_id, req, len(tokens), None)
 
     def _prefill(self, slot_id: int, req: Request) -> None:
         # keep the newest prompt tokens so generation fits the cache
@@ -995,7 +1104,8 @@ class InferenceEngine:
         waits for the device or writes its slot state: the first token is
         sampled from ``logits`` into ``_first_tokens`` by a program of its
         own (``first`` instead where the PD wire brought the token and no
-        logits), the slot's length, activity and last token go out with the
+        logits; neither where the prompt's own program sampled it there),
+        the slot's length, activity and last token go out with the
         next :meth:`_flush_slot_updates`, and the request learns its token
         in :meth:`_hand_over_first_tokens`.  The slot is claimed HERE so
         that ``run_forever``'s crash handler fails the request and frees
@@ -1004,7 +1114,8 @@ class InferenceEngine:
         self._slots_gen += 1
         self._host_lengths[slot_id] = n
         if first is None:
-            self._sample_first(logits, req, slot_id)
+            if logits is not None:
+                self._sample_first(logits, req, slot_id)
             self._slot_updates[slot_id] = [n, _ACTIVE | _TOKEN, 0]
         else:
             self._slot_updates[slot_id] = [
@@ -1414,10 +1525,7 @@ class InferenceEngine:
         tokens are bit-identical to a full forward's; sampled ones are
         seed-deterministic through the engine's threaded ``jax.random``
         key, split here on the host in admission order."""
-        if req.temperature > 0.0:
-            self._rng_key, sub = jax.random.split(self._rng_key)
-        else:
-            sub = self._rng_key  # greedy ignores it; don't burn entropy
+        sub = self._first_key(req)
         # the request's constants and its slot as ONE host array: one
         # transfer, no conversion program a scalar.  Both integers are
         # exact in float32 (a top-k past the sampler's 1,024-wide prefilter
@@ -1428,6 +1536,14 @@ class InferenceEngine:
         self._first_tokens = self._run_program(
             self._prefill_jit, "first_token", self._first_token_program,
             jnp.asarray(logits), consts, sub, self._first_tokens)
+
+    def _first_key(self, req: Request):
+        """The key a request's first token is sampled with: split from the
+        engine's on the host, in admission order."""
+        if req.temperature <= 0.0:
+            return self._rng_key  # greedy ignores it; don't burn entropy
+        self._rng_key, sub = jax.random.split(self._rng_key)
+        return sub
 
     def _first_token_program(self):
         """The jitted sampler of one request's first token: writes it into

@@ -13,9 +13,22 @@ a model file, a programs file and one line of ``PROVIDERS``.
   the attention layers beside the Mamba-2 layers' state-space states and
   convolution tails.
 
-What the families' programs share of the paged pool and of the decode
-window is ``serving/paged_window.py``; the sorted grouped expert product is
-``models/experts.py``.
+A provider answers the calls ``tests/compute/test_family_seam.py`` lists
+(``DensePrograms`` documents each).  One is optional: ``prefill_batch_fn
+(bucket)`` in place of ``prefill_fn(bucket)``, a group of whole prompts in
+one program, ``fn(params, tokens [P, bucket], lengths [P], pool, rec, block
+ids [P, bucket / block_size], slots [P]) -> (logits [P, V], pool, rec)``,
+where an unused entry (length 0, slot ``batch_size``, block ids past the
+pool) writes nothing and routes no pair to an expert.  The engine asks
+whether the provider has it, and nothing else: with it, an admission
+pass's whole prompts go out a group a bucket
+(``InferenceEngine.PREFILL_BATCH_TOKENS``), which the families whose every
+prompt routes pairs to every held expert offer (``serving/lfm2.py``,
+``serving/nemotron_h.py``); without it, one program a prompt.
+
+What the families' programs share of the paged pool, of a group of whole
+prompts and of the decode window is ``serving/paged_window.py``; the sorted
+grouped expert product is ``models/experts.py``.
 """
 
 from __future__ import annotations

@@ -18,11 +18,13 @@ The engine's two donated trees of device state are
   larger one to read or to write back).
 
 A slot's state is never reset by a program of its own: the prefill that
-admits a request starts from zeros and overwrites the slot, and a prompt's
-first chunk (``prefix_len == 0``) starts from zeros instead of reading it;
-later chunks read and write their slot.  A decode window leaves slots that
-are not ``active`` (free, or mid-chunk) untouched: their step is 0, so
-their decay is 1 and their input nothing (``ops/ssd.py``).
+admits a request (one program for a group of whole prompts, which streams
+the held experts once for all of them) starts from zeros and overwrites the
+slot, and a prompt's first chunk (``prefix_len == 0``) starts from zeros
+instead of reading it; later chunks read and write their slot.  A decode
+window leaves slots that are not ``active`` (free, or mid-chunk)
+untouched: their step is 0, so their decay is 1 and their input nothing
+(``ops/ssd.py``).
 
 The decode window is the engine's buffered one (``serving/dense.py``
 documents it): the pool read-only, the window's K and V rows in a buffer
@@ -51,7 +53,9 @@ def _slot(rec, slot):
 
 
 def _with_slot(rec, slot, mine):
-    """``rec`` with the slot's part replaced by ``mine``."""
+    """``rec`` with the slot's part replaced by ``mine`` (or, for a vector
+    of slots, each one's by its row of ``mine``; a slot past the last one
+    is written nowhere: the scatter drops it)."""
     return jax.tree.map(lambda a, m: a.at[slot].set(m.astype(a.dtype)),
                         rec, mine)
 
@@ -160,28 +164,24 @@ class NemotronHPrograms:
         return pages, jnp.int32(slot_id)
 
     # -- prefill -------------------------------------------------------------
-    def prefill_fn(self, bucket: int):
-        """A whole prompt into an empty slot: ``fn(params, tokens [bucket],
-        length, pool, rec, (block ids [bucket / block_size], slot))``."""
-        cfg, bs = self.cfg, self.block_size
+    def prefill_batch_fn(self, bucket: int):
+        """A group of whole prompts into empty slots, in one program:
+        ``fn(params, tokens [P, bucket], lengths [P], pool, rec, block ids
+        [P, bucket / block_size], slots [P])`` returns ``(logits [P, V],
+        pool, rec)``.  An unused entry (length 0, slot ``batch_size``, block
+        ids past the pool) writes no page and no slot."""
+        cfg = self.cfg
 
-        def fn(params, tokens, length, pool, rec, target):
-            bids, slot = target
-            positions = jnp.arange(bucket)[None, :]
-
+        def fn(params, tokens, lengths, pool, rec, bids, slots):
             def attend(m, q, k, v):
                 nonlocal pool
-                with jax.named_scope("kv_insert"):
-                    pool = {key: pool[key].at[m, bids].set(
-                        rows.reshape(-1, bs, cfg.kv_lanes))
-                        for key, rows in (("k", k), ("v", v))}
-                return paged_window.masked_attention(
-                    q[None], k[None], v[None], positions, positions)[0]
+                pool, out = paged_window.attend_prompts(pool, m, bids, q, k,
+                                                        v)
+                return out
 
-            zeros = jax.tree.map(jnp.zeros_like, _slot(rec, slot))
-            logits, mine = model.sequence_forward(
-                params, cfg, tokens, length, zeros, attend)
-            return logits, pool, _with_slot(rec, slot, mine)
+            logits, mine = model.prompts_forward(params, cfg, tokens,
+                                                 lengths, attend)
+            return logits, pool, _with_slot(rec, slots, mine)
 
         return fn
 

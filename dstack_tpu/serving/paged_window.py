@@ -1,7 +1,9 @@
 """What the providers' programs share (``serving/dense.py``,
-``serving/hybrid.py``, ``serving/lfm2.py``): the page arithmetic of a paged
-pool leaf [cache layers, blocks, block rows, lanes] under the engine's block
-tables, and the decode window's attention over (cache ⧺ window buffer).
+``serving/hybrid.py``, ``serving/lfm2.py``, ``serving/nemotron_h.py``): the
+page arithmetic of a paged pool leaf [cache layers, blocks, block rows,
+lanes] under the engine's block tables, a group of whole prompts' page
+insert and attention, and the decode window's attention over (cache ⧺
+window buffer).
 
 A pool leaf is addressed by FLAT row, ``(layer * blocks + block) * rows +
 offset`` (``ops/pool.py`` ``scatter_rows`` says why); block 0 is the NULL
@@ -47,6 +49,29 @@ def masked_attention(q, k, v, q_pos, kv_pos):
 def slot_rows(rec, slot):
     """One slot's part of a per-slot state tree [layers, slots, ...]."""
     return jax.tree.map(lambda a: a[:, slot], rec)
+
+
+# -- a group of whole prompts -------------------------------------------------
+
+def attend_prompts(pool, layer, bids, q, k, v):
+    """One attention layer of a group of whole prompts (each from position
+    0, in its own pages): ``q`` [P * T, H, d], ``k``, ``v`` [P * T, Hkv, d],
+    the rows of prompt p at [p * T, (p + 1) * T).  Writes every prompt's K
+    and V rows into its pages ``bids`` [P, T / block rows] of ``layer``
+    (an id past the pool's blocks, an unused entry's, writes nothing) and
+    returns ``(pool, out [P * T, H, d])``: each prompt's causal attention
+    over its own rows."""
+    p, n = bids.shape[0], q.shape[0]
+    t = n // p
+    rows, lanes = pool["k"].shape[2:]
+    with jax.named_scope("kv_insert"):
+        pool = {key: pool[key].at[layer, bids.reshape(-1)].set(
+            new.reshape(-1, rows, lanes), mode="drop")
+            for key, new in (("k", k), ("v", v))}
+    positions = jnp.broadcast_to(jnp.arange(t), (p, t))
+    split = lambda a: a.reshape((p, t) + a.shape[1:])
+    out = masked_attention(split(q), split(k), split(v), positions, positions)
+    return pool, out.reshape(q.shape)
 
 
 # -- a chunk of a prompt ------------------------------------------------------

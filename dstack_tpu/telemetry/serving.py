@@ -66,6 +66,11 @@ republished with project/run/job/replica labels):
   dispatched any: their ratio is chunks per step (a step may spend up to
   ``batch_size``); ``prefill_budget_exhausted_total`` counts the steps
   whose budget ran out with a chunk still waiting
+- ``prefill_batches_total`` / ``prefill_batch_prompts_total`` counters
+  — batched whole-prompt programs sent (a provider with
+  ``prefill_batch_fn``: one program a bucket for a group of an admission
+  pass's whole prompts) and the live prompts in them: their ratio is
+  prompts a program, the batching's engagement
 - ``loop_passes_total{phase}`` / ``loop_exit_tokens_total{step}``
   counters — a looped decoder's decode windows, counted inside the program
   and added where a window is drained: passes over the layer stack its
@@ -202,6 +207,9 @@ class EngineTelemetry:
             PREFIX + "prefill_chunk_steps_total")
         self.prefill_budget_exhausted = r.counter(
             PREFIX + "prefill_budget_exhausted_total")
+        self.prefill_batches = r.counter(PREFIX + "prefill_batches_total")
+        self.prefill_batch_prompts = r.counter(
+            PREFIX + "prefill_batch_prompts_total")
         self.windows_ahead = r.counter(
             PREFIX + "windows_dispatched_ahead_total")
         self.slot_update_programs = r.counter(
@@ -300,6 +308,12 @@ class EngineTelemetry:
             self.prefill_chunk_steps.inc()
         if budget_exhausted:
             self.prefill_budget_exhausted.inc()
+
+    def record_prefill_batch(self, prompts: int) -> None:
+        """One batched whole-prompt program sent, with ``prompts`` live
+        prompts."""
+        self.prefill_batches.inc()
+        self.prefill_batch_prompts.inc(prompts)
 
     def record_window(self, decoding: int, batch_size: int) -> None:
         """One decode window at dispatch."""

@@ -179,6 +179,7 @@ async def check_serving_metrics() -> int:
     tel.record_admitted(0.002, trace_id=trace_id)
     tel.record_first_token(0.04, trace_id=trace_id)
     tel.record_prefill(100, 128)
+    tel.record_prefill_batch(5)
     tel.record_window(6, 8)
     tel.record_window_chain(None)
     tel.record_window_chain("admission")
@@ -243,6 +244,8 @@ async def check_serving_metrics() -> int:
             "dstack_serving_prefill_chunks_total",
             "dstack_serving_prefill_chunk_steps_total",
             "dstack_serving_prefill_budget_exhausted_total",
+            "dstack_serving_prefill_batches_total",
+            "dstack_serving_prefill_batch_prompts_total",
             "dstack_serving_decode_tokens_total",
             "dstack_serving_decode_steps_total",
             "dstack_serving_decode_slot_steps_total",

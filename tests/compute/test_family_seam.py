@@ -133,7 +133,13 @@ def test_engine_drives_the_provider_to_the_plain_forward_s_tokens(family):
                              prefill_chunk=16, telemetry=EngineTelemetry(),
                              **kwargs)
     assert type(engine._programs) is provider
+    # the one optional call stands in for the one-prompt prefill
+    batched = hasattr(engine._programs, "prefill_batch_fn")
+    assert batched is (provider in (Lfm2Programs, NemotronHPrograms))
+    assert not (batched and hasattr(engine._programs, "prefill_fn"))
     for name, arity in SEAM.items():
+        if name == "prefill_fn" and batched:
+            name = "prefill_batch_fn"
         params = inspect.signature(getattr(engine._programs, name)).parameters
         assert len(params) == arity, (name, tuple(params))
         assert all(p.kind is p.POSITIONAL_OR_KEYWORD

@@ -28,6 +28,7 @@ from benchmarks.references import lfm2_moe as ref
 from dstack_tpu.models import lfm2 as model
 from dstack_tpu.serving.engine import InferenceEngine, Request
 from dstack_tpu.serving.lfm2 import Lfm2Programs
+from tests.compute.test_prompt_groups import send_group
 
 ROOT = Path(__file__).resolve().parents[2]
 TOY = json.loads((ROOT / "tests/benchmark/fixture_lfm2/cells/configs"
@@ -211,14 +212,19 @@ def test_prefill_logits_are_the_reference_s(toy):
     sizes, weights, cfg = toy
     engine = _engine(cfg, weights)
     prompt = _prompt(50)
-    padded = np.zeros((64,), np.int32)
-    padded[:50] = prompt
-    logits, pool, rec = engine._prefill_program(64)(
-        engine.params, jnp.asarray(padded), jnp.int32(50), *engine._state,
-        (jnp.arange(1, 5, dtype=jnp.int32), jnp.int32(2)))
+    padded = np.zeros((1, 64), np.int32)
+    padded[0, :50] = prompt
+    bids = jnp.arange(1, 5, dtype=jnp.int32)
+    # the provider's group of one prompt gives the logits; the engine's
+    # program (a group of one live prompt) samples its first token from them
+    logits, _, _ = jax.jit(engine._programs.prefill_batch_fn(64))(
+        engine.params, jnp.asarray(padded), jnp.asarray([50], jnp.int32),
+        *engine._state, bids[None], jnp.asarray([2], jnp.int32))
     want = _reference(weights, sizes, prompt, 49, 1)[0]
-    np.testing.assert_allclose(logits, want, atol=ATOL)
+    np.testing.assert_allclose(logits[0], want, atol=ATOL)
     assert want.std() > 0.5
+    pool, rec, first = send_group(engine, 64, [(prompt, 2, bids)])
+    assert int(first[2]) == int(np.argmax(logits[0]))
     # the slot's tails and pages are written, the others' are not
     assert float(jnp.abs(rec[:, 2]).max()) > 0
     assert float(jnp.abs(rec[:, :2]).max()) == 0
@@ -250,11 +256,8 @@ def test_chunked_prefill_logits_are_the_reference_s(toy, prompt_len):
     np.testing.assert_allclose(logits, want, atol=ATOL)
     whole = _engine(cfg, weights, prefill_chunk=None)
     bucket = whole._bucket(prompt_len)
-    padded = np.zeros((bucket,), np.int32)
-    padded[:prompt_len] = prompt
-    _, pool, rec = whole._prefill_program(bucket)(
-        whole.params, jnp.asarray(padded), jnp.int32(prompt_len),
-        *whole._state, (tables[:bucket // 16], jnp.int32(1)))
+    pool, rec, _ = send_group(whole, bucket,
+                              [(prompt, 1, tables[:bucket // 16])])
     np.testing.assert_allclose(state[1][:, 1], rec[:, 1], atol=2e-5)
     for key in ("k", "v"):
         rows = lambda p: p[key][:, 1:9].reshape(2, -1, p[key].shape[-1])

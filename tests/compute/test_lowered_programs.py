@@ -21,6 +21,9 @@ the lift of the providers' shared scaffolding, ROADMAP D13, has all four
 families under it) and left the 18 hashes before it as they were: the
 experts' form and the shared router (``models/experts.py``) lower the Ling
 programs to the same text.
+PR 40 rewrote ``nemotron-paged``'s prefill alone: the family's whole prompts
+go out a group a bucket (``prefill_batch_fn``), and the entry is that
+program, a group of two.
 A change that is meant to alter one of these programs rewrites the file:
 
     JAX_PLATFORMS=cpu python tests/compute/test_lowered_programs.py --write
@@ -101,10 +104,17 @@ def _texts(case: str) -> dict:
     nbk = 2 if paged else None
     tables = (jnp.ones((b, nbk), i32) if paged
               else jnp.zeros((b, 1), i32))
+    if engine._batched_prefill:
+        p = engine._prompts_per_program(32)
+        prefill = (engine.params, jnp.zeros((p, 32), i32),
+                   jnp.full((p,), 20, i32), *state, jnp.ones((p, 2), i32),
+                   jnp.arange(p, dtype=i32), jnp.zeros((p, 3), f32),
+                   (jax.random.PRNGKey(0),) * p, jnp.zeros((b,), i32))
+    else:
+        prefill = (engine.params, jnp.zeros((32,), i32), i32(20), *state,
+                   target(1, bids))
     programs = {
-        "prefill": (engine._prefill_program(32), (
-            engine.params, jnp.zeros((32,), i32), i32(20), *state,
-            target(1, bids))),
+        "prefill": (engine._prefill_program(32), prefill),
         "chunk": (engine._chunk_program(16), (
             engine.params, jnp.zeros((16,), i32), i32(9), i32(16), *state,
             target(i32(1), row))),

@@ -720,10 +720,30 @@ def test_looped_program_fits_and_stays_a_loop(looped_programs, program):
                           re.M)) <= 6
 
 
+def _held(compiled) -> int:
+    """Bytes a compiled program holds on the device while it runs."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+
+
+def _prompt_group(engine, bucket: int, params, state, arg):
+    """The batched whole-prompt program of ``bucket`` (a provider with
+    ``prefill_batch_fn``) and its arguments' shapes: a group of
+    ``engine._prompts_per_program(bucket)`` prompts."""
+    p, i32 = engine._prompts_per_program(bucket), jnp.int32
+    return engine._prefill_program(bucket), (
+        params, arg(i32, p, bucket), arg(i32, p), *state,
+        arg(i32, p, bucket // engine._block_size), arg(i32, p),
+        arg(jnp.float32, p, 3), (arg(jnp.uint32, 2),) * p,
+        arg(i32, engine.batch_size))
+
+
 #: the LFM2 cell of BENCHMARK.json as its files size it: every width, 9
 #: layers, all 64 experts, 256 slots, the cell's own pool; ``decode_w64`` at
 #: the widest table bucket (128 columns)
-LFM2_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_prefix_b512"]
+LFM2_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_paged_b64",
+                 "prefill_prefix_b512"]
 
 
 @pytest.fixture(scope="module")
@@ -770,10 +790,9 @@ def lfm2_programs(one_chip, real_lowering):
             args = (params, arg(i32, b), arg(i32, b), arg(jnp.bool_, b),
                     *state, arg(f32, b), arg(f32, b), arg(i32, b),
                     arg(i32, b, kb), arg(jnp.uint32, 2))
-        elif program == "prefill_paged_b512":
-            fn = engine._prefill_program(512)
-            args = (params, arg(i32, 512), arg(i32), *state,
-                    (arg(i32, 512 // bs), arg(i32)))
+        elif program.startswith("prefill_paged_b"):
+            fn, args = _prompt_group(engine, int(program[15:]), params, state,
+                                     arg)
         else:
             assert program == "prefill_prefix_b512", program
             fn = engine._chunk_program(512)
@@ -791,14 +810,18 @@ def test_lfm2_program_fits_and_copies_no_pool(lfm2_programs, program):
     and the tails are donated and addressed in place (nothing yields a whole
     layer of the K or V pool); the decode window calls the block-table
     kernel once an attention layer and the grouped product's kernel twice
-    an expert layer, on the experts' matrices where they lie."""
+    an expert layer, on the experts' matrices where they lie: so does a
+    group of whole prompts (4 prompts at 512, 32 at 64), twice an expert
+    layer for the group, not twice a prompt, within the bytes of the
+    widest decode window."""
     compiled, (pool, rec) = lfm2_programs(program)
-    mem = compiled.memory_analysis()
-    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
-    print(program, "held", held, "temp", mem.temp_size_in_bytes,
-          "args", mem.argument_size_in_bytes)
+    held = _held(compiled)
+    print(program, "held", held, "temp",
+          compiled.memory_analysis().temp_size_in_bytes)
     assert held < V5E_USABLE_BYTES, held
+    if program.startswith("prefill_paged"):
+        assert held < _held(lfm2_programs("decode_w64")[0])
+    mem = compiled.memory_analysis()
     assert pool["k"].shape == (2, 16384, 32, 512)
     donated = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves((pool, rec)))
@@ -817,7 +840,8 @@ def test_lfm2_program_fits_and_copies_no_pool(lfm2_programs, program):
 #: the Nemotron-H cell of BENCHMARK.json as its files size it: every width,
 #: all nine blocks, 384 slots, 24,576 blocks of 32 rows.  ``decode_w64`` at
 #: the widest table bucket (128 columns)
-NEMOTRON_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_prefix_b512"]
+NEMOTRON_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_paged_b64",
+                     "prefill_prefix_b512"]
 
 
 @pytest.fixture(scope="module")
@@ -847,7 +871,7 @@ def nemotron_programs(one_chip, real_lowering):
     engine = InferenceEngine(cfg, params={"layers": {}}, **dict(
         args, batch_size=1, total_kv_blocks=kb + 1))
     env.undo()
-    engine._programs.batch_size = b
+    engine.batch_size = engine._programs.batch_size = b
     engine._programs.num_blocks = args["total_kv_blocks"]
 
     def sds(tree):
@@ -869,10 +893,9 @@ def nemotron_programs(one_chip, real_lowering):
             args = (params, arg(i32, b), arg(i32, b), arg(jnp.bool_, b),
                     *state, arg(f32, b), arg(f32, b), arg(i32, b),
                     arg(i32, b, kb), arg(jnp.uint32, 2))
-        elif program == "prefill_paged_b512":
-            fn = engine._prefill_program(512)
-            args = (params, arg(i32, 512), arg(i32), *state,
-                    (arg(i32, 512 // bs), arg(i32)))
+        elif program.startswith("prefill_paged_b"):
+            fn, args = _prompt_group(engine, int(program[15:]), params, state,
+                                     arg)
         else:
             assert program == "prefill_prefix_b512", program
             fn = engine._chunk_program(512)
@@ -894,14 +917,22 @@ def test_nemotron_program_fits_and_holds_the_state_once(nemotron_programs,
     the block-table kernel once (one attention layer), the grouped
     product's kernel twice an expert layer (up, down: no gate) on the
     experts' matrices where they lie (no copy or re-lay of a stack), and reads
-    and writes a Mamba layer's state in ONE fusion a step."""
+    and writes a Mamba layer's state in ONE fusion a step.  A group of
+    whole prompts (4 at 512, 32 at 64) calls the kernel twice an expert
+    layer for the group, not twice a prompt.  Its bytes pass the widest
+    decode window's (10.49 GB: the window holds the states once and keeps
+    0.07 GB of temporaries, less than any other program of the cell; the
+    512-token chunk program held 10.57 at the parent) by 0.30 GB at 512 and
+    0.35 at 64 (PR 40: a group's float32 states, [prompts, 64, 64, 128] a
+    Mamba layer, and its causal scores), and are held to half a GB over."""
     compiled, (pool, rec) = nemotron_programs(program)
     mem = compiled.memory_analysis()
-    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    held = _held(compiled)
     print(program, "held", held, "temp", mem.temp_size_in_bytes,
           "args", mem.argument_size_in_bytes)
     assert held < V5E_USABLE_BYTES, held
+    if program.startswith("prefill_paged"):
+        assert held < _held(nemotron_programs("decode_w64")[0]) + 0.5e9
     assert pool["k"].shape == (1, 24576, 32, 256)
     assert [a.shape for a in rec["ssm"]] == [(384, 64, 64, 128)] * 4
     donated = sum(a.size * a.dtype.itemsize
