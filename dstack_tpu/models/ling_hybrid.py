@@ -313,18 +313,32 @@ def kda_sequence(x, lp, cfg: LingHybridConfig, length, state, tail):
     return _kda_output(o, h, lp, cfg), state, tail
 
 
-def kda_token(x, lp, cfg: LingHybridConfig, live, state, tail):
-    """The KDA mixer for one token of every slot: ``x`` [B, D], ``state``
-    [B, H, d_k, d_v], ``tail`` [B, kernel - 1, 3 * kda_dim].  Slots that are
-    not ``live`` keep state and tail."""
+def _on_chip() -> bool:
+    """Whether the decode step's recurrence is the Pallas kernel
+    (``ops/kda.py`` ``kda_step_into``): every backend but the CPU's, as for
+    the grouped product (``models/experts.py``); the CPU backend keeps
+    :func:`kda.kda_step`, the form the tests hold the kernel to."""
+    return experts._on_chip()
+
+
+def kda_token(x, lp, cfg: LingHybridConfig, live, states, layer: int, tail):
+    """The KDA mixer for one token of every slot: ``x`` [B, D], ``states``
+    the stack [L_kda, B, H, d_k, d_v] of which this is KDA layer ``layer``,
+    ``tail`` [B, kernel - 1, 3 * kda_dim].  Returns ``(y, states, tail)``
+    with layer ``layer`` of the stack updated (in place, on the chip).
+    Slots that are not ``live`` keep state and tail."""
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     proj = h @ lp["w_qkv"]
     window = jnp.concatenate([tail.astype(proj.dtype), proj[:, None]], axis=1)
     qkv = jnp.einsum("bjc,jc->bc", window, lp["conv_w"])
     tail = jnp.where(live[:, None, None], window[:, 1:], tail)
     q, k, v, g, beta = _kda_inputs(h, qkv, lp, cfg, live)
-    o, state = kda.kda_step(state, q, k, v, g, beta)
-    return _kda_output(o, h, lp, cfg), state, tail
+    if _on_chip():
+        o, states = kda.kda_step_into(states, layer, q, k, v, g, beta)
+    else:
+        o, s = kda.kda_step(states[layer], q, k, v, g, beta)
+        states = states.at[layer].set(s)
+    return _kda_output(o, h, lp, cfg), states, tail
 
 
 def _mla_project(x, lp, cfg: LingHybridConfig, positions):
@@ -398,8 +412,7 @@ def decode_step(params: Params, cfg: LingHybridConfig, x, positions, live,
     j = 0
     for i, (kind, lp) in enumerate(zip(cfg.layer_types, params["layers"])):
         if kind == "kda":
-            y, s, tl = kda_token(x, lp, cfg, live, states[j], tails[j])
-            states = states.at[j].set(s)
+            y, states, tl = kda_token(x, lp, cfg, live, states, j, tails[j])
             tails = tails.at[j].set(tl)
             j += 1
         else:

@@ -24,6 +24,11 @@ programs to the same text.
 PR 40 rewrote ``nemotron-paged``'s prefill alone: the family's whole prompts
 go out a group a bucket (``prefill_batch_fn``), and the entry is that
 program, a group of two.
+The Ling decode window's entry was rewritten when its KDA mixer came to
+take the whole stacked state (``models/ling_hybrid.py`` ``kda_token``, so
+that the chip's step kernel can update a layer in place): on the CPU the
+same operations, with two ``where`` helpers numbered two lower because the
+layer is now sliced inside the mixer.
 A change that is meant to alter one of these programs rewrites the file:
 
     JAX_PLATFORMS=cpu python tests/compute/test_lowered_programs.py --write

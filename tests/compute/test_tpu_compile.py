@@ -630,6 +630,23 @@ def test_hybrid_program_fits_and_copies_no_state(hybrid_programs, program):
                            "bf16") == []
     _experts_stream_in_place(text, expert_layers=6, experts=128,
                              hidden=2560, width=768)
+    if program == "decode_w64":
+        _kda_state_in_place(text, state.shape)
+
+
+def _kda_state_in_place(text: str, stack: tuple) -> None:
+    """The decode window's step updates the KDA state through the repo's
+    step kernel alone (``ops/kda.py`` ``kda_step_into``: one call a KDA
+    layer in the step loop, the stack aliased in and out), and no fusion
+    yields a whole layer of the state or the whole stack: XLA's form read a
+    layer three times and wrote it back by a dynamic-update-slice fusion of
+    the stack."""
+    assert len(re.findall(r"%kda_step[\w.\-]* = ", text)) == stack[0]
+    whole = [f"f32[{','.join(map(str, dims))}]" for dims in (stack,
+                                                            stack[1:])]
+    for result in re.findall(r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) fusion\(",
+                             text, re.M):
+        assert not any(shape in result for shape in whole), result[:160]
 
 
 #: the looped decoder's cell of BENCHMARK.json as its files size it: every
