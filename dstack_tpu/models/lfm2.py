@@ -20,11 +20,11 @@ Pre-norm residual blocks, ``x += mixer(norm(x)); x += ffn(norm(x))``, with
 
 Layers differ in kind, so the weights are a LIST of per-layer dicts and the
 stack is a Python loop (as ``models/ling_hybrid.py``).  The functions work
-on one sequence [T, D] (a chunk of a prompt), on a group of whole prompts
-[P, T] (prefill) or on one token of every slot [B, D] (a decode step); what
-the attention layers see of the cache is
-the caller's (``attend``): the engine's programs (``serving/lfm2.py``) hold
-the K/V pool and the convolution tails around them.
+on a group of sequences [G, T] (whole prompts, or chunks of prompts of
+different slots) or on one token of every slot [B, D] (a decode step);
+what the attention layers see of the cache is the caller's (``attend``):
+the engine's programs (``serving/lfm2.py``) hold the K/V pool and the
+convolution tails around them.
 """
 
 from __future__ import annotations
@@ -335,66 +335,60 @@ def output_logits(params: Params, cfg: Lfm2MoeConfig, x):
                           preferred_element_type=jnp.float32)
 
 
-def sequence_forward(params: Params, cfg: Lfm2MoeConfig, tokens, length,
-                     start, tails, attend: Callable):
-    """One sequence (a whole prompt, or one chunk of it) through the stack.
+def sequence_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
+                     starts, tails, attend: Callable):
+    """A group of sequences (whole prompts, or chunks of prompts of
+    different slots) through the stack in one pass.
 
-    ``tokens`` [T] of which ``length`` are real, the first at position
-    ``start``; ``tails`` [conv layers, conv_L_cache - 1, D] are the
-    convolutions' state before the first token.  ``attend(m, q, k, v)``
-    stores the new ``k`` and ``v`` rows of attention layer ``m`` and returns
-    the layer's output [T, H, d] over whatever the caller keeps of the
-    cache.  Returns ``(logits [V] float32 at the last real token, tails)``.
-    """
-    t = tokens.shape[0]
-    positions = start + jnp.arange(t)
-    live = jnp.arange(t) < length
-    x = params["embed"].astype(cfg.dtype)[tokens]
+    ``tokens`` [G, T], of which ``lengths`` [G] are real (0: an unused
+    entry, which routes no pair to an expert), entry g's first at position
+    ``starts[g]``; ``tails`` [conv layers, G, conv_L_cache - 1, D] are the
+    convolutions' state before each entry's first token (``starts`` and
+    ``tails`` None: position 0 and zeros, a prompt's start).  The
+    convolutions run per entry; everything a token does alone (norms,
+    projections, the router, the experts' grouped product) runs on the
+    G * T rows at once, so a layer streams each expert's matrices once for
+    the group.  ``attend(m, q, k, v)`` stores the new ``k`` and ``v`` rows
+    of attention layer ``m`` and returns the layer's output over whatever
+    the caller keeps of the cache; it takes and returns the rows [G * T,
+    ...], entry g's at [g * T, (g + 1) * T).  Returns ``(logits [G, V]
+    float32 at each entry's last real token, tails)``."""
+    g, t = tokens.shape
+    positions = (jnp.tile(jnp.arange(t), g) if starts is None
+                 else (starts[:, None] + jnp.arange(t)).reshape(-1))
+    live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(-1)
+    x = params["embed"].astype(cfg.dtype)[tokens.reshape(-1)]
+    zeros = jnp.zeros((cfg.conv_reach, cfg.hidden_size), cfg.dtype)
     new_tails = []
     for i, (kind, lp) in enumerate(zip(cfg.layer_types, params["layers"])):
         if kind == "conv":
-            y, tail = conv_sequence(x, lp, cfg, length,
-                                    tails[len(new_tails)])
+            if tails is None:
+                y, tail = jax.vmap(
+                    lambda rows, n, lp=lp: conv_sequence(rows, lp, cfg, n,
+                                                         zeros)
+                )(x.reshape(g, t, -1), lengths)
+            else:
+                y, tail = jax.vmap(
+                    lambda rows, n, before, lp=lp: conv_sequence(
+                        rows, lp, cfg, n, before)
+                )(x.reshape(g, t, -1), lengths, tails[len(new_tails)])
+            y = y.reshape(g * t, -1)
             new_tails.append(tail)
         else:
             q, k, v = attention_project(x, lp, cfg, positions)
-            y = attend(i - len(new_tails), q, k, v).reshape(t, -1) @ lp["wo"]
+            y = attend(i - len(new_tails), q, k,
+                       v).reshape(g * t, -1) @ lp["wo"]
         x, _ = _ffn(x + y, lp, cfg, live)
-    logits = output_logits(params, cfg, x[length - 1])
-    return logits, (jnp.stack(new_tails) if new_tails else tails)
+    last = x.reshape(g, t, -1)[jnp.arange(g), lengths - 1]
+    return output_logits(params, cfg, last), (jnp.stack(new_tails)
+                                              if new_tails else tails)
 
 
 def prompts_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
                     attend: Callable):
-    """A group of whole prompts through the stack in one pass, each from
-    zeros: ``tokens`` [P, T], of which ``lengths`` [P] are real (0: an
-    unused entry, which routes no pair to an expert).  The convolutions run
-    per prompt; everything a token does alone (norms, projections, the
-    router, the experts' grouped product) runs on the P * T rows at once,
-    so a layer streams each expert's matrices once for the group.
-    ``attend(m, q, k, v)`` takes and returns those rows ([P * T, ...],
-    prompt p's at [p * T, (p + 1) * T)).  Returns ``(logits [P, V] float32
-    at each prompt's last real token, tails [conv layers, P, conv_L_cache
-    - 1, D])``."""
-    p, t = tokens.shape
-    positions = jnp.tile(jnp.arange(t), p)
-    live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(-1)
-    x = params["embed"].astype(cfg.dtype)[tokens.reshape(-1)]
-    zeros = jnp.zeros((cfg.conv_reach, cfg.hidden_size), cfg.dtype)
-    tails = []
-    for i, (kind, lp) in enumerate(zip(cfg.layer_types, params["layers"])):
-        if kind == "conv":
-            y, tail = jax.vmap(
-                lambda rows, n, lp=lp: conv_sequence(rows, lp, cfg, n, zeros)
-            )(x.reshape(p, t, -1), lengths)
-            y = y.reshape(p * t, -1)
-            tails.append(tail)
-        else:
-            q, k, v = attention_project(x, lp, cfg, positions)
-            y = attend(i - len(tails), q, k, v).reshape(p * t, -1) @ lp["wo"]
-        x, _ = _ffn(x + y, lp, cfg, live)
-    last = x.reshape(p, t, -1)[jnp.arange(p), lengths - 1]
-    return output_logits(params, cfg, last), jnp.stack(tails)
+    """A group of whole prompts: :func:`sequence_forward` from position 0
+    and zero tails."""
+    return sequence_forward(params, cfg, tokens, lengths, None, None, attend)
 
 
 def decode_step(params: Params, cfg: Lfm2MoeConfig, x, positions, live,

@@ -26,11 +26,12 @@ A block is ONE mixer behind one norm, ``x += mixer(norm(x))``, of the kind
   V row a token.
 
 Layers differ in kind, so the weights are a LIST of per-layer dicts and the
-stack is a Python loop (as ``models/lfm2.py``).  The functions work on one
-sequence [T, D] (a chunk of a prompt), on a group of whole prompts [P, T]
-(prefill) or on one token of every slot [B, D] (a decode step); what the
-attention layers see of the cache is the caller's (``attend``): the engine's programs (``serving/nemotron_h.py``)
-hold the K/V pool, the states and the tails around them.
+stack is a Python loop (as ``models/lfm2.py``).  The functions work on a
+group of sequences [G, T] (whole prompts, or chunks of prompts of
+different slots) or on one token of every slot [B, D] (a decode step);
+what the attention layers see of the cache is the caller's (``attend``):
+the engine's programs (``serving/nemotron_h.py``) hold the K/V pool, the
+states and the tails around them.
 """
 
 from __future__ import annotations
@@ -444,59 +445,52 @@ def _stack(params: Params, cfg: NemotronHConfig, x, live, rec, attend,
     return x, {"ssm": states, "tail": tails}, load
 
 
-def sequence_forward(params: Params, cfg: NemotronHConfig, tokens, length,
+def sequence_forward(params: Params, cfg: NemotronHConfig, tokens, lengths,
                      rec, attend: Callable):
-    """One sequence (a whole prompt, or one chunk of it) through the stack.
+    """A group of sequences (whole prompts, or chunks of prompts of
+    different slots) through the stack in one pass.
 
-    ``tokens`` [T] of which ``length`` are real; ``rec`` is the Mamba
-    layers' memory before the first token: {"ssm": [[H, P, N] float32, ...],
-    "tail": [[conv_kernel - 1, conv_dim], ...]}, one entry a Mamba layer.
+    ``tokens`` [G, T], of which ``lengths`` [G] are real (0: an unused
+    entry, which routes no pair to an expert); ``rec`` is the Mamba layers'
+    memory before each entry's first token, as :func:`decode_step`'s with
+    an entry axis in front of every entry (None: zeros, a prompt's
+    start).  The Mamba-2 mixers run per
+    entry (the chunked scan batched over the entries as over the heads);
+    everything a token does alone (norms, projections, the router, the
+    experts' grouped product, the shared expert) runs on the G * T rows at
+    once, so a block streams each expert's matrices once for the group.
     ``attend(m, q, k, v)`` stores the new ``k`` and ``v`` rows of attention
-    layer ``m`` and returns the layer's output [T, H, d] over whatever the
-    caller keeps of the cache.  Returns ``(logits [V] float32 at the last
-    real token, rec)``."""
-    live = jnp.arange(tokens.shape[0]) < length
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    x, rec, _ = _stack(
-        params, cfg, x, live, rec, attend,
-        lambda h, lp, state, tail: mamba_sequence(h, lp, cfg, length, state,
-                                                  tail))
-    return output_logits(params, cfg, x[length - 1]), rec
-
-
-def prompts_forward(params: Params, cfg: NemotronHConfig, tokens, lengths,
-                    attend: Callable):
-    """A group of whole prompts through the stack in one pass, each from
-    zeros: ``tokens`` [P, T], of which ``lengths`` [P] are real (0: an
-    unused entry, which routes no pair to an expert).  The Mamba-2 mixers
-    run per prompt (the chunked scan batched over the prompts as over the
-    heads); everything a token does alone (norms, projections, the router,
-    the experts' grouped product, the shared expert) runs on the P * T rows
-    at once, so a block streams each expert's matrices once for the group.
-    ``attend(m, q, k, v)`` takes and returns those rows ([P * T, ...],
-    prompt p's at [p * T, (p + 1) * T)).  Returns ``(logits [P, V] float32
-    at each prompt's last real token, rec)``: ``rec`` as
-    :func:`decode_step`'s, with a prompt axis in front of every entry."""
-    p, t = tokens.shape
+    layer ``m`` and returns the layer's output over whatever the caller
+    keeps of the cache; it takes and returns the rows [G * T, ...], entry
+    g's at [g * T, (g + 1) * T).  Returns ``(logits [G, V] float32 at each
+    entry's last real token, rec)``."""
+    g, t = tokens.shape
     live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(-1)
     x = params["embed"].astype(cfg.dtype)[tokens.reshape(-1)]
-    zeros = {
-        "ssm": [jnp.zeros((p, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                           cfg.ssm_state_size), jnp.float32)
-                for _ in range(cfg.mamba_layers)],
-        "tail": [jnp.zeros((p, cfg.conv_reach, cfg.conv_dim), cfg.dtype)
-                 for _ in range(cfg.mamba_layers)],
-    }
+    if rec is None:
+        rec = {
+            "ssm": [jnp.zeros((g, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                               cfg.ssm_state_size), jnp.float32)
+                    for _ in range(cfg.mamba_layers)],
+            "tail": [jnp.zeros((g, cfg.conv_reach, cfg.conv_dim), cfg.dtype)
+                     for _ in range(cfg.mamba_layers)],
+        }
 
     def mamba(h, lp, state, tail):
         y, state, tail = jax.vmap(
             lambda rows, n, s, tl: mamba_sequence(rows, lp, cfg, n, s, tl)
-        )(h.reshape(p, t, -1), lengths, state, tail)
-        return y.reshape(p * t, -1), state, tail
+        )(h.reshape(g, t, -1), lengths, state, tail)
+        return y.reshape(g * t, -1), state, tail
 
-    x, rec, _ = _stack(params, cfg, x, live, zeros, attend, mamba)
-    last = x.reshape(p, t, -1)[jnp.arange(p), lengths - 1]
+    x, rec, _ = _stack(params, cfg, x, live, rec, attend, mamba)
+    last = x.reshape(g, t, -1)[jnp.arange(g), lengths - 1]
     return output_logits(params, cfg, last), rec
+
+
+def prompts_forward(params: Params, cfg: NemotronHConfig, tokens, lengths,
+                    attend: Callable):
+    """A group of whole prompts: :func:`sequence_forward` from zeros."""
+    return sequence_forward(params, cfg, tokens, lengths, None, attend)
 
 
 def decode_step(params: Params, cfg: NemotronHConfig, x, live, rec,

@@ -321,6 +321,9 @@ class InferenceEngine:
         #: whole prompts of an admission pass go out a group a bucket, in
         #: one program each, where the provider has that program
         self._batched_prefill = hasattr(self._programs, "prefill_batch_fn")
+        #: and the chunk queue's chunks a group of different slots a
+        #: program, in rounds (see _advance_chunks)
+        self._batched_chunks = hasattr(self._programs, "chunk_batch_fn")
         cache_layers, token_bytes = self._programs.kv_geometry()
         if telemetry is not None:
             telemetry.record_kv_geometry(cache_layers, token_bytes)
@@ -644,43 +647,166 @@ class InferenceEngine:
             self._pending = self._dispatch_window(remaining)
 
     def _advance_chunks(self, budget: int) -> tuple:
-        """Dispatch prefill chunks, the oldest-admitted prompt's first and
-        each prompt to its end before the next one starts (a finished
-        prompt is a slot that decodes), until no mid-chunking slot has a
-        chunk left or ``budget`` chunks went out.  The budget bounds the
-        stall one scheduling step can put ahead of its decode window: a
-        step starts with ``batch_size`` chunks and its second call gets
-        what the first left.  Returns (chunks dispatched, whether some
-        prompt's last chunk was among them)."""
+        """Dispatch prefill chunks until no mid-chunking slot has a chunk
+        left or ``budget`` chunks went out.  The budget bounds the stall one
+        scheduling step can put ahead of its decode window: a step starts
+        with ``batch_size`` chunks and its second call gets what the first
+        left.  Where the provider groups chunks (``chunk_batch_fn``), the
+        chunks go out in rounds, several slots' a program
+        (:meth:`_send_chunk_rounds`); elsewhere one program a chunk, the
+        oldest-admitted prompt's first and each prompt to its end before the
+        next one starts (a finished prompt is a slot that decodes).  Returns
+        (chunks dispatched, whether some prompt's last chunk was among
+        them)."""
         if budget <= 0:  # spent by the step's first call, which counted that
             return 0, False
-        spent, completed = 0, False
-        # dict order is admission order: a slot is keyed when it is claimed
-        for slot_id, st in list(self._chunking.items()):
-            # a state with logits is complete: _finish_chunked's
-            while "logits" not in st and spent < budget:
-                req = self._slots[slot_id]
-                if req is None or req.cancelled:
-                    del self._chunking[slot_id]
-                    if req is not None:
-                        self._release(slot_id)
-                        req.finish_reason = req.finish_reason or "cancelled"
-                        req.finished_at = req.now()
-                        req.done.set()
-                        if self.telemetry is not None:
-                            self.telemetry.record_finished(req)
-                    break
-                with self._phase("chunk", slot=slot_id, tokens=min(
-                        self.prefill_chunk, len(st["tokens"]) - st["done"])):
-                    self._dispatch_chunk(slot_id, st)
-                spent += 1
-                completed = completed or "logits" in st
+        if self._batched_chunks:
+            spent, programs, completed = self._send_chunk_rounds(budget)
+        else:
+            spent, completed = 0, False
+            # dict order is admission order: a slot is keyed when it is
+            # claimed
+            for slot_id, st in list(self._chunking.items()):
+                # a state with logits is complete: _finish_chunked's
+                while "logits" not in st and spent < budget:
+                    if self._drop_if_cancelled(slot_id):
+                        break
+                    with self._phase("chunk", slot=slot_id, tokens=min(
+                            self.prefill_chunk,
+                            len(st["tokens"]) - st["done"])):
+                        self._dispatch_chunk(slot_id, st)
+                    spent += 1
+                    completed = completed or "logits" in st
+            programs = spent
         if self.telemetry is not None and spent:
             self.telemetry.record_prefill_chunks(
-                spent, first_of_step=budget == self.batch_size,
+                spent, programs, first_of_step=budget == self.batch_size,
                 budget_exhausted=(spent == budget
                                   and self._chunk_backlog() > 0))
         return spent, completed
+
+    def _drop_if_cancelled(self, slot_id: int) -> bool:
+        """Take a mid-chunking slot whose request is gone or cancelled out
+        of the chunk queue (finishing the request, freeing the slot)."""
+        req = self._slots[slot_id]
+        if req is not None and not req.cancelled:
+            return False
+        del self._chunking[slot_id]
+        if req is not None:
+            self._release(slot_id)
+            req.finish_reason = req.finish_reason or "cancelled"
+            req.finished_at = req.now()
+            req.done.set()
+            if self.telemetry is not None:
+                self.telemetry.record_finished(req)
+        return True
+
+    def _chunk_rounds(self, budget: int) -> List[list]:
+        """The chunks one call of a grouping provider's chunk queue sends,
+        ``(slot, start, tokens)`` each, in rounds: a round is the next chunk
+        of every mid-chunking slot in admission order, and rounds follow
+        until no slot has a chunk left or ``budget`` chunks are taken."""
+        at = {slot_id: st["done"]
+              for slot_id, st in list(self._chunking.items())
+              if "logits" not in st and not self._drop_if_cancelled(slot_id)}
+        rounds, spent = [], 0
+        while at and spent < budget:
+            chunks = []
+            for slot_id, done in list(at.items())[:budget - spent]:
+                total = len(self._chunking[slot_id]["tokens"])
+                n = min(self.prefill_chunk, total - done)
+                chunks.append((slot_id, done, n))
+                if done + n < total:
+                    at[slot_id] = done + n
+                else:
+                    del at[slot_id]
+            spent += len(chunks)
+            rounds.append(chunks)
+        return rounds
+
+    def _chunk_groups(self, chunks: list) -> list:
+        """A round's chunks as ``(bucket, chunks)`` groups of one program
+        each, the largest bucket first: a group takes
+        :meth:`_prompts_per_program` of its first chunk's bucket, the
+        smaller chunks behind it padded to that bucket (a chunk's own program
+        would stream the held experts once more)."""
+        groups = []
+        for chunk in sorted(chunks, key=lambda c: -self._bucket(c[2])):
+            if groups and len(groups[-1][1]) < self._prompts_per_program(
+                    groups[-1][0]):
+                groups[-1][1].append(chunk)
+            else:
+                groups.append((self._bucket(chunk[2]), [chunk]))
+        return groups
+
+    def _send_chunk_rounds(self, budget: int) -> tuple:
+        """Send :meth:`_chunk_rounds`' chunks a group a program
+        (:meth:`_send_chunks`), round after round.  A group's chunk that
+        ends its prompt samples the prompt's first token inside the program,
+        with a key split here in admission order, as :meth:`_finish_chunked`
+        splits it behind one-chunk programs.  Returns (chunks, programs,
+        whether some prompt's last chunk was among them)."""
+        rounds = self._chunk_rounds(budget)
+        ends = {slot_id for chunks in rounds for slot_id, done, n in chunks
+                if done + n == len(self._chunking[slot_id]["tokens"])}
+        keys = {slot_id: self._first_key(self._slots[slot_id])
+                for slot_id in self._chunking if slot_id in ends}
+        programs = 0
+        for chunks in rounds:
+            for bucket, group in self._chunk_groups(chunks):
+                self._send_chunks(bucket, group, keys)
+                programs += 1
+        return sum(map(len, rounds)), programs, bool(ends)
+
+    def _send_chunks(self, bucket: int, group: list, keys: dict) -> None:
+        """Send one group of chunks of different slots as ONE program
+        (:meth:`_chunk_program`), its unused entries of length 0 at slot
+        ``batch_size`` with table rows past the pool's blocks; a chunk that
+        ends its prompt writes the prompt's first token at its slot of
+        ``_first_tokens`` (``keys`` holds its sampler key)."""
+        g = self._prompts_per_program(bucket)
+        tokens = np.zeros((g, bucket), np.int32)
+        lengths = np.zeros((g,), np.int32)
+        starts = np.zeros((g,), np.int32)
+        rows = np.full((g, self._blocks_per_slot), self._alloc.num_blocks,
+                       np.int32)
+        slots = np.full((g,), self.batch_size, np.int32)
+        first_to = np.full((g,), self.batch_size, np.int32)
+        consts = np.zeros((g, 3), np.float32)
+        first_keys = [self._rng_key] * g
+        for i, (slot_id, done, n) in enumerate(group):
+            tokens[i, :n] = self._chunking[slot_id]["tokens"][done:done + n]
+            lengths[i], starts[i], slots[i] = n, done, slot_id
+            rows[i] = self._tables_host[slot_id]
+            if slot_id in keys:
+                req = self._slots[slot_id]
+                first_to[i] = slot_id
+                # a top-k past the sampler's 1,024-wide prefilter keeps
+                # every rank, as any larger one does (see _sample_first)
+                consts[i] = (req.temperature, req.top_p,
+                             min(req.top_k or 0, 1024))
+                first_keys[i] = keys[slot_id]
+        with self._phase("chunk", requests=len(group),
+                         tokens=int(lengths.sum())):
+            *self._state, self._first_tokens = self._run_program(
+                self._prefill_jit, ("chunk", bucket),
+                functools.partial(self._chunk_program, bucket),
+                self.params, jnp.asarray(tokens), jnp.asarray(lengths),
+                jnp.asarray(starts), *self._state,
+                (jnp.asarray(rows), jnp.asarray(slots)),
+                jnp.asarray(consts), tuple(first_keys),
+                jnp.asarray(first_to), self._first_tokens)
+        for slot_id, done, n in group:
+            st = self._chunking[slot_id]
+            st["done"] = done + n
+            if self.telemetry is not None:
+                self.telemetry.record_prefill(n, bucket)
+                self._programs.record_prompt_program(self.telemetry, bucket)
+            if slot_id in keys:
+                # complete, its first token already sampled on the device
+                st["logits"], st["n"] = None, len(st["tokens"])
+        if self.telemetry is not None:
+            self.telemetry.record_prefill_backlog(self._chunk_backlog())
 
     def _dispatch_chunk(self, slot_id: int, st: dict) -> None:
         """Dispatch the next prefill chunk of one mid-chunking slot."""
@@ -960,21 +1086,48 @@ class InferenceEngine:
                first_tokens):
             logits, pool, rec = forward(params, tokens, lengths, pool, rec,
                                         bids, slots)
-            temps, top_ps, top_ks = consts.T
-            firsts = jax.vmap(lambda *one: self._sample_on_device(
-                *(a[None] for a in one[:4]), one[4])[0])(
-                    logits, temps, top_ps, top_ks.astype(jnp.int32),
-                    jnp.stack(keys))
-            return pool, rec, first_tokens.at[slots].set(firsts, mode="drop")
+            return pool, rec, self._sample_firsts(first_tokens, slots,
+                                                  logits, consts, keys)
 
         return self._jit_cached(fn, tag, donate_argnums=(3, 4, 9))
 
+    def _sample_firsts(self, first_tokens, slots, logits, consts, keys):
+        """Inside a group's program: every entry's first token sampled from
+        its ``logits`` with its constants (temperature, top-p, top-k) and
+        key, written into ``first_tokens`` at its slot (none for a slot
+        past the last)."""
+        temps, top_ps, top_ks = consts.T
+        firsts = jax.vmap(lambda *one: self._sample_on_device(
+            *(a[None] for a in one[:4]), one[4])[0])(
+                logits, temps, top_ps, top_ks.astype(jnp.int32),
+                jnp.stack(keys))
+        return first_tokens.at[slots].set(firsts, mode="drop")
+
     def _chunk_program(self, cbucket: int):
         """The jitted program of one bucket that prefills a chunk of a long
-        prompt, or a prompt's suffix behind a cached prefix."""
-        return self._jit_cached(self._programs.chunk_fn(cbucket),
-                                f"{self._chunk_tag}{cbucket}",
-                                donate_argnums=(4, 5))
+        prompt, or a prompt's suffix behind a cached prefix; where the
+        provider has ``chunk_batch_fn``, a group of chunks of different
+        slots, ``fn(params, tokens [G, cbucket], lengths [G], starts [G],
+        pool, rec, (table rows [G, blocks a slot], slots [G]), sampler
+        constants [G, 3], keys (G of them), slots of the first tokens [G],
+        first_tokens)``: an entry whose chunk ends its prompt samples the
+        prompt's first token into the donated ``first_tokens`` (the others
+        are given slot ``batch_size``); returns ``(pool, rec,
+        first_tokens)``."""
+        tag = f"{self._chunk_tag}{cbucket}"
+        if not self._batched_chunks:
+            return self._jit_cached(self._programs.chunk_fn(cbucket), tag,
+                                    donate_argnums=(4, 5))
+        forward = self._programs.chunk_batch_fn(cbucket)
+
+        def fn(params, tokens, lengths, starts, pool, rec, target, consts,
+               keys, first_to, first_tokens):
+            logits, pool, rec = forward(params, tokens, lengths, starts, pool,
+                                        rec, target)
+            return pool, rec, self._sample_firsts(first_tokens, first_to,
+                                                  logits, consts, keys)
+
+        return self._jit_cached(fn, tag, donate_argnums=(4, 5, 10))
 
     def _run_chunk(self, slot_id: int, padded, n: int, prefix_len: int):
         """Prefill ``n`` prompt tokens (``padded`` to a bucket) behind the
@@ -995,7 +1148,8 @@ class InferenceEngine:
         return logits
 
     def _prompts_per_program(self, bucket: int) -> int:
-        """Prompts of one batched whole-prompt program of ``bucket``."""
+        """Prompts of one batched whole-prompt program of ``bucket``, and
+        chunks of one group of chunks."""
         return min(self.batch_size,
                    max(1, self.PREFILL_BATCH_TOKENS // bucket))
 

@@ -24,10 +24,18 @@ whether the provider has it, and nothing else: with it, an admission
 pass's whole prompts go out a group a bucket
 (``InferenceEngine.PREFILL_BATCH_TOKENS``), which the families whose every
 prompt routes pairs to every held expert offer (``serving/lfm2.py``,
-``serving/nemotron_h.py``); without it, one program a prompt.
+``serving/nemotron_h.py``); without it, one program a prompt.  The same
+families offer ``chunk_batch_fn(cbucket)`` beside ``chunk_fn``, a group of
+chunks of different slots in one program, ``fn(params, tokens [G,
+cbucket], chunk_lens [G], prefix_lens [G], pool, rec, (table rows [G,
+blocks a slot], slots [G])) -> (logits [G, V], pool, rec)``, where an unused
+entry (length 0, slot ``batch_size``, a table row past the pool's blocks)
+writes nothing: with it the chunk queue sends its chunks in rounds, a group
+a program; without it, one program a chunk.
 
 What the families' programs share of the paged pool, of a group of whole
-prompts and of the decode window is ``serving/paged_window.py``; the sorted
+prompts or of chunks and of the decode window is
+``serving/paged_window.py``; the sorted
 grouped expert product is ``models/experts.py``.
 """
 

@@ -17,7 +17,8 @@ A slot's tail is never reset by a program of its own: the prefill that
 admits a request (one program for a group of whole prompts, which streams
 the held experts once for all of them) starts from zeros and overwrites the
 slot, and a prompt's first chunk (``prefix_len == 0``) starts from zeros
-instead of reading it; later chunks read and write their slot.  A decode
+instead of reading it; later chunks read and write their slot.  Chunks go
+out a group of different slots a program too (``chunk_batch_fn``).  A decode
 window leaves slots that are not ``active`` (free, or mid-chunk) untouched.
 
 The decode window is the engine's buffered one (``serving/dense.py``
@@ -159,7 +160,8 @@ class Lfm2Programs:
         chunk_len, prefix_len, pool, rec, (table row, slot))``.  The K and V
         rows go into the slot's pages and the chunk attends the slot's whole
         span; the tails come from the slot (zeros at ``prefix_len`` 0) and
-        go back to it."""
+        go back to it.  The engine sends :meth:`chunk_batch_fn`'s groups;
+        this form is what a group's entry must leave."""
         cfg, bs, nb = self.cfg, self.block_size, self.num_blocks
         span = self.blocks_per_slot * self.block_size
         heads = (span, cfg.num_key_value_heads, cfg.head_dim)
@@ -189,8 +191,41 @@ class Lfm2Programs:
                     kv_pos)[0]
 
             logits, tails = model.sequence_forward(
-                params, cfg, tokens, chunk_len, prefix_len, mine, attend)
-            return logits, pool, rec.at[:, slot].set(tails.astype(rec.dtype))
+                params, cfg, tokens[None], chunk_len[None], prefix_len[None],
+                mine[:, None], attend)
+            return logits[0], pool, rec.at[:, slot].set(
+                tails[:, 0].astype(rec.dtype))
+
+        return fn
+
+    def chunk_batch_fn(self, cbucket: int):
+        """A group of chunks, each of its own slot, in one program:
+        ``fn(params, tokens [G, cbucket], chunk_lens [G], prefix_lens [G],
+        pool, rec, (table rows [G, blocks a slot], slots [G]))`` returns
+        ``(logits [G, V], pool, rec)``.  Each entry is what
+        :meth:`chunk_fn` does with it: its rows into its slot's pages, its
+        attention over its slot's span (one entry at a time), its tails
+        from its slot (zeros at ``prefix_len`` 0) and back to it; the
+        experts' grouped product runs once for the group.  An unused entry
+        (length 0, slot ``batch_size``, a table row past the pool's blocks)
+        writes no page and no slot."""
+        cfg = self.cfg
+
+        def fn(params, tokens, chunk_lens, prefix_lens, pool, rec, target):
+            tables_rows, slots = target
+            mine = jnp.where((prefix_lens == 0)[:, None, None], 0,
+                             rec[:, slots])
+
+            def attend(m, q, k, v):
+                nonlocal pool
+                pool, out = paged_window.attend_chunks(
+                    pool, m, tables_rows, prefix_lens, chunk_lens, q, k, v)
+                return out
+
+            logits, tails = model.sequence_forward(
+                params, cfg, tokens, chunk_lens, prefix_lens, mine, attend)
+            return logits, pool, rec.at[:, slots].set(
+                tails.astype(rec.dtype), mode="drop")
 
         return fn
 

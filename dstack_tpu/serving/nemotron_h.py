@@ -21,8 +21,9 @@ A slot's state is never reset by a program of its own: the prefill that
 admits a request (one program for a group of whole prompts, which streams
 the held experts once for all of them) starts from zeros and overwrites the
 slot, and a prompt's first chunk (``prefix_len == 0``) starts from zeros
-instead of reading it; later chunks read and write their slot.  A decode
-window leaves slots that are not ``active`` (free, or mid-chunk)
+instead of reading it; later chunks read and write their slot.  Chunks go
+out a group of different slots a program too (``chunk_batch_fn``).  A
+decode window leaves slots that are not ``active`` (free, or mid-chunk)
 untouched: their step is 0, so their decay is 1 and their input nothing
 (``ops/ssd.py``).
 
@@ -190,7 +191,9 @@ class NemotronHPrograms:
         chunk_len, prefix_len, pool, rec, (table row, slot))``.  The K and V
         rows go into the slot's pages and the chunk attends the slot's whole
         span; the state and the tail come from the slot (zeros at
-        ``prefix_len`` 0) and go back to it."""
+        ``prefix_len`` 0) and go back to it.  The engine sends
+        :meth:`chunk_batch_fn`'s groups; this form is what a group's entry
+        must leave."""
         cfg, bs, nb = self.cfg, self.block_size, self.num_blocks
         span = self.blocks_per_slot * self.block_size
         heads = (span, cfg.num_key_value_heads, cfg.head_dim)
@@ -198,7 +201,8 @@ class NemotronHPrograms:
         def fn(params, tokens, chunk_len, prefix_len, pool, rec, target):
             tables_row, slot = target
             mine = jax.tree.map(
-                lambda a: jnp.where(prefix_len == 0, jnp.zeros_like(a), a),
+                lambda a: jnp.where(prefix_len == 0, jnp.zeros_like(a),
+                                    a)[None],
                 _slot(rec, slot))
             blk, off = paged_window.chunk_pages(prefix_len, cbucket,
                                                 tables_row, bs, span)
@@ -221,8 +225,42 @@ class NemotronHPrograms:
                     kv_pos)[0]
 
             logits, mine = model.sequence_forward(
-                params, cfg, tokens, chunk_len, mine, attend)
-            return logits, pool, _with_slot(rec, slot, mine)
+                params, cfg, tokens[None], chunk_len[None], mine, attend)
+            return logits[0], pool, _with_slot(
+                rec, slot, jax.tree.map(lambda a: a[0], mine))
+
+        return fn
+
+    def chunk_batch_fn(self, cbucket: int):
+        """A group of chunks, each of its own slot, in one program:
+        ``fn(params, tokens [G, cbucket], chunk_lens [G], prefix_lens [G],
+        pool, rec, (table rows [G, blocks a slot], slots [G]))`` returns
+        ``(logits [G, V], pool, rec)``.  Each entry is what
+        :meth:`chunk_fn` does with it: its rows into its slot's pages, its
+        attention over its slot's span (one entry at a time), its state and
+        tail from its slot (zeros at ``prefix_len`` 0) and back to it; the
+        experts' grouped product and the shared expert run once for the
+        group.  An unused entry (length 0, slot ``batch_size``, a table row
+        past the pool's blocks) writes no page and no slot."""
+        cfg = self.cfg
+
+        def fn(params, tokens, chunk_lens, prefix_lens, pool, rec, target):
+            tables_rows, slots = target
+            first = prefix_lens == 0
+            mine = jax.tree.map(
+                lambda a: jnp.where(
+                    first.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a[slots]),
+                rec)
+
+            def attend(m, q, k, v):
+                nonlocal pool
+                pool, out = paged_window.attend_chunks(
+                    pool, m, tables_rows, prefix_lens, chunk_lens, q, k, v)
+                return out
+
+            logits, mine = model.sequence_forward(
+                params, cfg, tokens, chunk_lens, mine, attend)
+            return logits, pool, _with_slot(rec, slots, mine)
 
         return fn
 

@@ -1,9 +1,9 @@
 """What the providers' programs share (``serving/dense.py``,
 ``serving/hybrid.py``, ``serving/lfm2.py``, ``serving/nemotron_h.py``): the
 page arithmetic of a paged pool leaf [cache layers, blocks, block rows,
-lanes] under the engine's block tables, a group of whole prompts' page
-insert and attention, and the decode window's attention over (cache ⧺
-window buffer).
+lanes] under the engine's block tables, a group of whole prompts' and a
+group of chunks' page insert and attention, and the decode window's
+attention over (cache ⧺ window buffer).
 
 A pool leaf is addressed by FLAT row, ``(layer * blocks + block) * rows +
 offset`` (``ops/pool.py`` ``scatter_rows`` says why); block 0 is the NULL
@@ -16,6 +16,8 @@ import os
 
 import jax
 import jax.numpy as jnp
+
+from dstack_tpu.ops.pool import scatter_rows
 
 
 def paged_kernel_default() -> bool:
@@ -102,6 +104,54 @@ def slot_span(leaf, layer, tables_row, num_blocks: int, lead: tuple = ()):
     rows = leaf.reshape((-1,) + leaf.shape[2:])[layer * num_blocks
                                                  + tables_row]
     return rows.reshape(lead + (-1, leaf.shape[-1]))
+
+
+# -- a group of chunks of different slots -------------------------------------
+
+def attend_chunks(pool, layer, tables_rows, prefix_lens, lengths, q, k, v):
+    """One attention layer of a group of chunks, each of its own slot:
+    ``q`` [G * T, H, d], ``k``, ``v`` [G * T, Hkv, d], the rows of entry g
+    at [g * T, (g + 1) * T), behind ``prefix_lens[g]`` rows of the slot
+    whose table row is ``tables_rows[g]``.  Writes every entry's K and V
+    rows into its slot's pages of ``layer`` (rows past the slot's span go to
+    the NULL block; an entry whose table row is past the pool's blocks, an
+    unused one's, writes nothing) and returns ``(pool, out [G * T, H,
+    d])``: each entry's causal attention over its slot's whole span, ONE
+    ENTRY AT A TIME (the scores are one chunk's), an entry of length 0
+    skipped."""
+    nb, bs = pool["k"].shape[1:3]
+    g, (n, hq, d) = tables_rows.shape[0], q.shape
+    t, hkv = n // g, k.shape[1]
+    span = tables_rows.shape[1] * bs
+    blk, off = jax.vmap(
+        lambda start, row: chunk_pages(start, t, row, bs, span))(
+            prefix_lens, tables_rows)
+    # a block id past the pool is an unused entry's: its rows land past the
+    # leaf's last one, where the scatter drops them
+    idx = jnp.where(blk < nb, flat_rows(layer, blk, off, nb, bs),
+                    pool["k"].size // pool["k"].shape[-1])
+    with jax.named_scope("kv_insert"):
+        # (scatter_rows folds the heads into the pool's lanes)
+        pool = {"k": scatter_rows(pool["k"], idx, k),
+                "v": scatter_rows(pool["v"], idx, v)}
+    kv_pos = jnp.arange(span)[None, :]
+
+    def one(entry):
+        qe, row, start, length = entry
+
+        def attend(qe):
+            mine_k, mine_v = (slot_span(pool[key], layer, row,
+                                        nb).reshape(span, hkv, d)
+                              for key in ("k", "v"))
+            return masked_attention(qe[None], mine_k[None], mine_v[None],
+                                    (start + jnp.arange(t))[None],
+                                    kv_pos)[0]
+
+        return jax.lax.cond(length > 0, attend, jnp.zeros_like, qe)
+
+    out = jax.lax.map(one, (q.reshape(g, t, hq, d), tables_rows, prefix_lens,
+                            lengths))
+    return pool, out.reshape(n, hq, d)
 
 
 # -- a decode window ----------------------------------------------------------

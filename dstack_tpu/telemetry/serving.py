@@ -62,10 +62,13 @@ republished with project/run/job/replica labels):
   slot), ``prompt_completed`` (the step's chunks ended a prompt, which
   joins the next window), ``drained`` (nothing left to decode)
 - ``prefill_chunks_total`` / ``prefill_chunk_steps_total`` counters —
-  chunked-prefill programs dispatched, and scheduling steps that
-  dispatched any: their ratio is chunks per step (a step may spend up to
-  ``batch_size``); ``prefill_budget_exhausted_total`` counts the steps
-  whose budget ran out with a chunk still waiting
+  prefill chunks dispatched, and scheduling steps that dispatched any:
+  their ratio is chunks per step (a step may spend up to ``batch_size``);
+  ``prefill_budget_exhausted_total`` counts the steps whose budget ran out
+  with a chunk still waiting; ``prefill_chunk_programs_total`` the programs
+  the chunks went out in (a provider with ``chunk_batch_fn`` sends a group
+  of chunks of different slots a program): chunks over it is chunks a
+  program, 1 where the provider does not group them
 - ``prefill_batches_total`` / ``prefill_batch_prompts_total`` counters
   — batched whole-prompt programs sent (a provider with
   ``prefill_batch_fn``: one program a bucket for a group of an admission
@@ -203,6 +206,8 @@ class EngineTelemetry:
         self.decode_slot_steps = r.counter(
             PREFIX + "decode_slot_steps_total")
         self.prefill_chunks = r.counter(PREFIX + "prefill_chunks_total")
+        self.prefill_chunk_programs = r.counter(
+            PREFIX + "prefill_chunk_programs_total")
         self.prefill_chunk_steps = r.counter(
             PREFIX + "prefill_chunk_steps_total")
         self.prefill_budget_exhausted = r.counter(
@@ -297,13 +302,17 @@ class EngineTelemetry:
         if bucket > 0:
             self.prefill_occupancy.observe(min(n_tokens / bucket, 1.0))
 
-    def record_prefill_chunks(self, chunks: int, first_of_step: bool,
+    def record_prefill_chunks(self, chunks: int, programs: int,
+                              first_of_step: bool,
                               budget_exhausted: bool) -> None:
-        """``chunks`` chunked-prefill programs dispatched by one call of the
-        scheduler's chunk queue; a scheduling step makes up to two calls
-        and is counted with its first chunks.  ``budget_exhausted``: the
-        step's budget ran out with a chunk still waiting."""
+        """``chunks`` prefill chunks dispatched by one call of the
+        scheduler's chunk queue, in ``programs`` programs (as many as chunks
+        where the provider does not group them); a scheduling step makes up
+        to two calls and is counted with its first chunks.
+        ``budget_exhausted``: the step's budget ran out with a chunk still
+        waiting."""
         self.prefill_chunks.inc(chunks)
+        self.prefill_chunk_programs.inc(programs)
         if first_of_step:
             self.prefill_chunk_steps.inc()
         if budget_exhausted:

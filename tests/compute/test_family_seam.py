@@ -137,7 +137,10 @@ def test_engine_drives_the_provider_to_the_plain_forward_s_tokens(family):
     batched = hasattr(engine._programs, "prefill_batch_fn")
     assert batched is (provider in (Lfm2Programs, NemotronHPrograms))
     assert not (batched and hasattr(engine._programs, "prefill_fn"))
-    for name, arity in SEAM.items():
+    # and so does the other for the chunk queue, beside the one-chunk form
+    assert hasattr(engine._programs, "chunk_batch_fn") is batched
+    for name, arity in dict(SEAM, **({"chunk_batch_fn": 1}
+                                     if batched else {})).items():
         if name == "prefill_fn" and batched:
             name = "prefill_batch_fn"
         params = inspect.signature(getattr(engine._programs, name)).parameters
@@ -150,10 +153,11 @@ def test_engine_drives_the_provider_to_the_plain_forward_s_tokens(family):
     counters = dict(engine.telemetry.recorder.summary()["counters"])
     assert counters["dstack_serving_prefill_chunks_total"] == 3
     assert counters["dstack_serving_decode_steps_total"] == 16
+    # (a group of chunks samples its prompt's first token itself)
     assert {fn.__name__.rsplit("_b", 1)[0]
             for fn in engine._prefill_jit.values()} == {
         "prefill_prefix" if engine.paged else "prefill_chunk",
-        "first_token_sample", "slot_update"}
+        "slot_update"} | (set() if batched else {"first_token_sample"})
     scores = np.asarray(plain_logits(prompt + served[:-1]))[len(prompt) - 1:]
     assert scores.shape[0] == 17 and scores.std() > 0.1
     gaps = scores.max(-1) - scores[np.arange(17), served]

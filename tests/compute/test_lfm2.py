@@ -249,7 +249,7 @@ def test_chunked_prefill_logits_are_the_reference_s(toy, prompt_len):
         piece = prompt[start:start + 16]
         padded = np.zeros((16,), np.int32)
         padded[:len(piece)] = piece
-        logits, *state = chunked._chunk_program(16)(
+        logits, *state = jax.jit(chunked._programs.chunk_fn(16))(
             chunked.params, jnp.asarray(padded), jnp.int32(len(piece)),
             jnp.int32(start), *state, (tables, jnp.int32(1)))
     want = _reference(weights, sizes, prompt, prompt_len - 1, 1)[0]

@@ -24,6 +24,10 @@ programs to the same text.
 PR 40 rewrote ``nemotron-paged``'s prefill alone: the family's whole prompts
 go out a group a bucket (``prefill_batch_fn``), and the entry is that
 program, a group of two.
+Where the two families send their chunks a group of different slots a
+program (``chunk_batch_fn``), ``nemotron-paged``'s chunk entry is that
+program, a group of two, and ``lfm2-paged`` holds the third provider's
+three programs the same way; the other 18 hashes stayed as they were.
 The Ling decode window's entry was rewritten when its KDA mixer came to
 take the whole stacked state (``models/ling_hybrid.py`` ``kda_token``, so
 that the chip's step kernel can update a layer in place): on the CPU the
@@ -70,6 +74,12 @@ def _nemotron():
     return NemotronHConfig.tiny()
 
 
+def _lfm2():
+    from dstack_tpu.models.lfm2 import Lfm2MoeConfig
+
+    return Lfm2MoeConfig.tiny()
+
+
 def _ling():
     from dstack_tpu.models.ling_hybrid import LingHybridConfig
 
@@ -85,6 +95,7 @@ CASES = {
     "ouro-paged": (_ouro, PAGED, False),
     "ling-paged": (_ling, PAGED, False),
     "nemotron-paged": (_nemotron, PAGED, False),
+    "lfm2-paged": (_lfm2, PAGED, False),
 }
 
 
@@ -118,11 +129,20 @@ def _texts(case: str) -> dict:
     else:
         prefill = (engine.params, jnp.zeros((32,), i32), i32(20), *state,
                    target(1, bids))
+    if engine._batched_chunks:
+        g = engine._prompts_per_program(16)
+        chunk = (engine.params, jnp.zeros((g, 16), i32),
+                 jnp.full((g,), 9, i32), jnp.full((g,), 16, i32), *state,
+                 (jnp.ones((g, engine._blocks_per_slot), i32),
+                  jnp.arange(g, dtype=i32)), jnp.zeros((g, 3), f32),
+                 (jax.random.PRNGKey(0),) * g, jnp.arange(g, dtype=i32),
+                 jnp.zeros((b,), i32))
+    else:
+        chunk = (engine.params, jnp.zeros((16,), i32), i32(9), i32(16),
+                 *state, target(i32(1), row))
     programs = {
         "prefill": (engine._prefill_program(32), prefill),
-        "chunk": (engine._chunk_program(16), (
-            engine.params, jnp.zeros((16,), i32), i32(9), i32(16), *state,
-            target(i32(1), row))),
+        "chunk": (engine._chunk_program(16), chunk),
         "decode": (engine._decode_window_program(8, False, nbk), (
             engine.params, jnp.zeros((b,), i32), jnp.full((b,), 5, i32),
             jnp.ones((b,), jnp.bool_), *state, jnp.zeros((b,), f32),

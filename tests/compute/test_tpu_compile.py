@@ -756,11 +756,23 @@ def _prompt_group(engine, bucket: int, params, state, arg):
         arg(i32, engine.batch_size))
 
 
+def _chunk_group(engine, cbucket: int, params, state, arg):
+    """The chunk program of ``cbucket`` of a provider with
+    ``chunk_batch_fn`` and its arguments' shapes: a group of
+    ``engine._prompts_per_program(cbucket)`` chunks of different slots."""
+    g, i32 = engine._prompts_per_program(cbucket), jnp.int32
+    return engine._chunk_program(cbucket), (
+        params, arg(i32, g, cbucket), arg(i32, g), arg(i32, g), *state,
+        (arg(i32, g, engine._blocks_per_slot), arg(i32, g)),
+        arg(jnp.float32, g, 3), (arg(jnp.uint32, 2),) * g, arg(i32, g),
+        arg(i32, engine.batch_size))
+
+
 #: the LFM2 cell of BENCHMARK.json as its files size it: every width, 9
 #: layers, all 64 experts, 256 slots, the cell's own pool; ``decode_w64`` at
 #: the widest table bucket (128 columns)
 LFM2_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_paged_b64",
-                 "prefill_prefix_b512"]
+                 "prefill_prefix_b512", "prefill_prefix_b64"]
 
 
 @pytest.fixture(scope="module")
@@ -811,10 +823,9 @@ def lfm2_programs(one_chip, real_lowering):
             fn, args = _prompt_group(engine, int(program[15:]), params, state,
                                      arg)
         else:
-            assert program == "prefill_prefix_b512", program
-            fn = engine._chunk_program(512)
-            args = (params, arg(i32, 512), arg(i32), arg(i32), *state,
-                    (arg(i32, kb), arg(i32)))
+            assert program.startswith("prefill_prefix_b"), program
+            fn, args = _chunk_group(engine, int(program[16:]), params, state,
+                                    arg)
         return fn.lower(*args).compile(), state
 
     return compile_program
@@ -830,7 +841,9 @@ def test_lfm2_program_fits_and_copies_no_pool(lfm2_programs, program):
     an expert layer, on the experts' matrices where they lie: so does a
     group of whole prompts (4 prompts at 512, 32 at 64), twice an expert
     layer for the group, not twice a prompt, within the bytes of the
-    widest decode window."""
+    widest decode window, and a group of chunks of different slots (4 at
+    512: 12.70 GB, where one chunk's program held 12.67; 32 at 64), each
+    attending its slot's span one entry at a time."""
     compiled, (pool, rec) = lfm2_programs(program)
     held = _held(compiled)
     print(program, "held", held, "temp",
@@ -858,7 +871,7 @@ def test_lfm2_program_fits_and_copies_no_pool(lfm2_programs, program):
 #: all nine blocks, 384 slots, 24,576 blocks of 32 rows.  ``decode_w64`` at
 #: the widest table bucket (128 columns)
 NEMOTRON_PROGRAMS = ["decode_w64", "prefill_paged_b512", "prefill_paged_b64",
-                     "prefill_prefix_b512"]
+                     "prefill_prefix_b512", "prefill_prefix_b64"]
 
 
 @pytest.fixture(scope="module")
@@ -914,10 +927,9 @@ def nemotron_programs(one_chip, real_lowering):
             fn, args = _prompt_group(engine, int(program[15:]), params, state,
                                      arg)
         else:
-            assert program == "prefill_prefix_b512", program
-            fn = engine._chunk_program(512)
-            args = (params, arg(i32, 512), arg(i32), arg(i32), *state,
-                    (arg(i32, kb), arg(i32)))
+            assert program.startswith("prefill_prefix_b"), program
+            fn, args = _chunk_group(engine, int(program[16:]), params, state,
+                                    arg)
         return fn.lower(*args).compile(), state
 
     return compile_program
@@ -935,8 +947,10 @@ def test_nemotron_program_fits_and_holds_the_state_once(nemotron_programs,
     product's kernel twice an expert layer (up, down: no gate) on the
     experts' matrices where they lie (no copy or re-lay of a stack), and reads
     and writes a Mamba layer's state in ONE fusion a step.  A group of
-    whole prompts (4 at 512, 32 at 64) calls the kernel twice an expert
-    layer for the group, not twice a prompt.  Its bytes pass the widest
+    whole prompts (4 at 512, 32 at 64), and a group of chunks of different
+    slots (4 at 512: 10.82 GB, where one chunk's program held 10.57; 32 at 64:
+    10.99), calls the kernel twice an expert layer for the group, not twice
+    a prompt or a chunk.  A group of whole prompts' bytes pass the widest
     decode window's (10.49 GB: the window holds the states once and keeps
     0.07 GB of temporaries, less than any other program of the cell; the
     512-token chunk program held 10.57 at the parent) by 0.30 GB at 512 and
