@@ -23,7 +23,7 @@ stack is a Python loop (as ``models/ling_hybrid.py``).  The functions work
 on a group of sequences [G, T] (whole prompts, or chunks of prompts of
 different slots) or on one token of every slot [B, D] (a decode step);
 what the attention layers see of the cache is the caller's (``attend``):
-the engine's programs (``serving/lfm2.py``) hold the K/V pool and the
+the engine's programs (``serving/mixed.py``) hold the K/V pool and the
 convolution tails around them.
 """
 
@@ -336,15 +336,15 @@ def output_logits(params: Params, cfg: Lfm2MoeConfig, x):
 
 
 def sequence_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
-                     starts, tails, attend: Callable):
+                     starts, rec, attend: Callable):
     """A group of sequences (whole prompts, or chunks of prompts of
     different slots) through the stack in one pass.
 
     ``tokens`` [G, T], of which ``lengths`` [G] are real (0: an unused
     entry, which routes no pair to an expert), entry g's first at position
-    ``starts[g]``; ``tails`` [conv layers, G, conv_L_cache - 1, D] are the
-    convolutions' state before each entry's first token (``starts`` and
-    ``tails`` None: position 0 and zeros, a prompt's start).  The
+    ``starts[g]``; ``rec`` [conv layers, G, conv_L_cache - 1, D] is the
+    convolutions' tails before each entry's first token (``starts`` and
+    ``rec`` None: position 0 and zeros, a prompt's start).  The
     convolutions run per entry; everything a token does alone (norms,
     projections, the router, the experts' grouped product) runs on the
     G * T rows at once, so a layer streams each expert's matrices once for
@@ -352,7 +352,7 @@ def sequence_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
     of attention layer ``m`` and returns the layer's output over whatever
     the caller keeps of the cache; it takes and returns the rows [G * T,
     ...], entry g's at [g * T, (g + 1) * T).  Returns ``(logits [G, V]
-    float32 at each entry's last real token, tails)``."""
+    float32 at each entry's last real token, rec)``."""
     g, t = tokens.shape
     positions = (jnp.tile(jnp.arange(t), g) if starts is None
                  else (starts[:, None] + jnp.arange(t)).reshape(-1))
@@ -362,7 +362,7 @@ def sequence_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
     new_tails = []
     for i, (kind, lp) in enumerate(zip(cfg.layer_types, params["layers"])):
         if kind == "conv":
-            if tails is None:
+            if rec is None:
                 y, tail = jax.vmap(
                     lambda rows, n, lp=lp: conv_sequence(rows, lp, cfg, n,
                                                          zeros)
@@ -371,7 +371,7 @@ def sequence_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
                 y, tail = jax.vmap(
                     lambda rows, n, before, lp=lp: conv_sequence(
                         rows, lp, cfg, n, before)
-                )(x.reshape(g, t, -1), lengths, tails[len(new_tails)])
+                )(x.reshape(g, t, -1), lengths, rec[len(new_tails)])
             y = y.reshape(g * t, -1)
             new_tails.append(tail)
         else:
@@ -381,7 +381,7 @@ def sequence_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
         x, _ = _ffn(x + y, lp, cfg, live)
     last = x.reshape(g, t, -1)[jnp.arange(g), lengths - 1]
     return output_logits(params, cfg, last), (jnp.stack(new_tails)
-                                              if new_tails else tails)
+                                              if new_tails else rec)
 
 
 def prompts_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
@@ -392,19 +392,19 @@ def prompts_forward(params: Params, cfg: Lfm2MoeConfig, tokens, lengths,
 
 
 def decode_step(params: Params, cfg: Lfm2MoeConfig, x, positions, live,
-                tails, attend: Callable):
+                rec, attend: Callable):
     """One token of every slot through the stack: ``x`` [B, D] at
-    ``positions`` [B]; ``tails`` [conv layers, B, conv_L_cache - 1, D].
+    ``positions`` [B]; ``rec`` [conv layers, B, conv_L_cache - 1, D].
     ``attend(m, q, k, v)`` is attention layer ``m`` over whatever the caller
     keeps of the cache, after it has taken this step's ``k`` and ``v``
-    [B, Hkv, d]; it returns [B, H, d].  Returns ``(hidden [B, D], tails,
+    [B, Hkv, d]; it returns [B, H, d].  Returns ``(hidden [B, D], rec,
     load)``; ``load`` sums :func:`moe_ffn`'s over the expert layers."""
     load = jnp.zeros((LOAD_FIELDS,), jnp.float32)
     j = 0
     for i, (kind, lp) in enumerate(zip(cfg.layer_types, params["layers"])):
         if kind == "conv":
-            y, tail = conv_token(x, lp, cfg, live, tails[j])
-            tails = tails.at[j].set(tail.astype(tails.dtype))
+            y, tail = conv_token(x, lp, cfg, live, rec[j])
+            rec = rec.at[j].set(tail.astype(rec.dtype))
             j += 1
         else:
             q, k, v = attention_project(x, lp, cfg, positions)
@@ -412,4 +412,33 @@ def decode_step(params: Params, cfg: Lfm2MoeConfig, x, positions, live,
         x, layer_load = _ffn(x + y, lp, cfg, live)
         if layer_load is not None:
             load = load + layer_load
-    return x, tails, load
+    return x, rec, load
+
+
+# -- a slot's state, and what a program counts (``serving/mixed.py``) --------
+
+def init_rec(cfg: Lfm2MoeConfig, slots: int):
+    """Zero tails of ``slots`` slots: [conv layers, slots, reach, D]."""
+    return jnp.zeros((cfg.conv_layers, slots, cfg.conv_reach,
+                      cfg.hidden_size), cfg.dtype)
+
+
+def read_slots(rec, slots, fresh):
+    """The tails of ``slots`` [G], zeros where ``fresh`` [G]."""
+    return jnp.where(fresh[:, None, None], 0, rec[:, slots])
+
+
+def write_slots(rec, slots, mine):
+    """``rec`` with ``slots`` [G] holding ``mine``, a slot past the last
+    dropped."""
+    return rec.at[:, slots].set(mine.astype(rec.dtype), mode="drop")
+
+
+def window_counts(cfg: Lfm2MoeConfig, load, live, steps: int):
+    """A decode window's counts: its expert ``load`` alone."""
+    return load
+
+
+def scan_chunks(cfg: Lfm2MoeConfig, bucket: int) -> int:
+    """A prompt program's blocks of chunked scan: none."""
+    return 0

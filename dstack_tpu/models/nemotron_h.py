@@ -30,7 +30,7 @@ stack is a Python loop (as ``models/lfm2.py``).  The functions work on a
 group of sequences [G, T] (whole prompts, or chunks of prompts of
 different slots) or on one token of every slot [B, D] (a decode step);
 what the attention layers see of the cache is the caller's (``attend``):
-the engine's programs (``serving/nemotron_h.py``) hold the K/V pool, the
+the engine's programs (``serving/mixed.py``) hold the K/V pool, the
 states and the tails around them.
 """
 
@@ -446,15 +446,16 @@ def _stack(params: Params, cfg: NemotronHConfig, x, live, rec, attend,
 
 
 def sequence_forward(params: Params, cfg: NemotronHConfig, tokens, lengths,
-                     rec, attend: Callable):
+                     starts, rec, attend: Callable):
     """A group of sequences (whole prompts, or chunks of prompts of
     different slots) through the stack in one pass.
 
     ``tokens`` [G, T], of which ``lengths`` [G] are real (0: an unused
-    entry, which routes no pair to an expert); ``rec`` is the Mamba layers'
-    memory before each entry's first token, as :func:`decode_step`'s with
-    an entry axis in front of every entry (None: zeros, a prompt's
-    start).  The Mamba-2 mixers run per
+    entry, which routes no pair to an expert; ``starts``, unread: no
+    position signal); ``rec`` is the Mamba layers' memory before each
+    entry's first token, as :func:`decode_step`'s with an entry axis in
+    front of every entry (None: zeros, a prompt's start).  The Mamba-2
+    mixers run per
     entry (the chunked scan batched over the entries as over the heads);
     everything a token does alone (norms, projections, the router, the
     experts' grouped product, the shared expert) runs on the G * T rows at
@@ -468,13 +469,7 @@ def sequence_forward(params: Params, cfg: NemotronHConfig, tokens, lengths,
     live = (jnp.arange(t)[None, :] < lengths[:, None]).reshape(-1)
     x = params["embed"].astype(cfg.dtype)[tokens.reshape(-1)]
     if rec is None:
-        rec = {
-            "ssm": [jnp.zeros((g, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                               cfg.ssm_state_size), jnp.float32)
-                    for _ in range(cfg.mamba_layers)],
-            "tail": [jnp.zeros((g, cfg.conv_reach, cfg.conv_dim), cfg.dtype)
-                     for _ in range(cfg.mamba_layers)],
-        }
+        rec = init_rec(cfg, g)
 
     def mamba(h, lp, state, tail):
         y, state, tail = jax.vmap(
@@ -490,13 +485,14 @@ def sequence_forward(params: Params, cfg: NemotronHConfig, tokens, lengths,
 def prompts_forward(params: Params, cfg: NemotronHConfig, tokens, lengths,
                     attend: Callable):
     """A group of whole prompts: :func:`sequence_forward` from zeros."""
-    return sequence_forward(params, cfg, tokens, lengths, None, attend)
+    return sequence_forward(params, cfg, tokens, lengths, None, None, attend)
 
 
-def decode_step(params: Params, cfg: NemotronHConfig, x, live, rec,
-                attend: Callable):
-    """One token of every slot through the stack: ``x`` [B, D]; ``rec`` as
-    :func:`sequence_forward`'s with a slot axis in front of every entry.
+def decode_step(params: Params, cfg: NemotronHConfig, x, positions, live,
+                rec, attend: Callable):
+    """One token of every slot through the stack: ``x`` [B, D] (``positions``
+    unread); ``rec`` as :func:`sequence_forward`'s with a slot axis in front
+    of every entry.
     ``attend(m, q, k, v)`` is attention layer ``m`` over whatever the caller
     keeps of the cache, after it has taken this step's ``k`` and ``v``
     [B, Hkv, d]; it returns [B, H, d].  Returns ``(hidden [B, D], rec,
@@ -504,3 +500,41 @@ def decode_step(params: Params, cfg: NemotronHConfig, x, live, rec,
     return _stack(
         params, cfg, x, live, rec, attend,
         lambda h, lp, state, tail: mamba_token(h, lp, cfg, live, state, tail))
+
+
+# -- a slot's state, and what a program counts (``serving/mixed.py``) --------
+
+def init_rec(cfg: NemotronHConfig, slots: int):
+    """Zero state of ``slots`` slots, one array a Mamba layer (a decode step
+    rewrites each layer's 2 MiB a slot where it lies, no slice of a stack)."""
+    return {
+        "ssm": [jnp.zeros((slots, cfg.mamba_num_heads, cfg.mamba_head_dim,
+                           cfg.ssm_state_size), jnp.float32)
+                for _ in range(cfg.mamba_layers)],
+        "tail": [jnp.zeros((slots, cfg.conv_reach, cfg.conv_dim), cfg.dtype)
+                 for _ in range(cfg.mamba_layers)],
+    }
+
+
+def read_slots(rec, slots, fresh):
+    """The state of ``slots`` [G], zeros where ``fresh`` [G]."""
+    return jax.tree.map(lambda a: jnp.where(
+        fresh.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a[slots]), rec)
+
+
+def write_slots(rec, slots, mine):
+    """``rec`` with ``slots`` [G] holding ``mine``, a slot past the last
+    dropped."""
+    return jax.tree.map(lambda a, m: a.at[slots].set(
+        m.astype(a.dtype), mode="drop"), rec, mine)
+
+
+def window_counts(cfg: NemotronHConfig, load, live, steps: int):
+    """A decode window's counts: its expert ``load``, its SSM updates."""
+    ssm_steps = live.sum().astype(jnp.float32) * (steps * cfg.mamba_layers)
+    return jnp.concatenate([load, ssm_steps[None]])
+
+
+def scan_chunks(cfg: NemotronHConfig, bucket: int) -> int:
+    """A prompt program's blocks of chunked scan, over the Mamba layers."""
+    return cfg.mamba_layers * -(-bucket // min(cfg.chunk_size, bucket))

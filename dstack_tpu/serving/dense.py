@@ -573,7 +573,8 @@ class DensePrograms:
     @staticmethod
     def record_prompt_program(telemetry, bucket: int) -> None:
         """A prefill or chunk program of ``bucket`` positions ran: nothing
-        this family counts (``serving/nemotron_h.py`` counts its scans)."""
+        this family counts (Nemotron-H's, ``serving/mixed.py``, counts its
+        scans)."""
 
     def slot_target(self, slot_id, pages):
         """Where a prefill or chunk program writes: the slot's ``pages``

@@ -1,8 +1,11 @@
 """The hybrid decoder (``models/ling_hybrid.py``) behind the engine's seam:
 the device state of a model whose memory is not K and V rows, and the
 programs the scheduler runs over it (whole-prompt prefill, one chunk, a
-decode window).  ``serving/dense.py`` answers the same calls for the Llama
-family; ``serving/families.py`` picks between them.
+decode window).  ``serving/dense.py`` and ``serving/mixed.py`` answer the
+same calls for their families; ``serving/families.py`` picks among them.
+What does not depend on the programs (the refusals, the weights, the
+telemetry's counts, the PD wire's refusal) is ``serving/mixed.py``
+``SlotStatePrograms``'s.
 
 The engine keeps two donated trees of device state and hands both to every
 program as they are.  For a Llama-family model they are the K and the V
@@ -33,7 +36,7 @@ length, but not its products: here every head reads the SAME row.)
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -43,53 +46,27 @@ from dstack_tpu.ops import mla
 from dstack_tpu.ops.pool import scatter_rows
 from dstack_tpu.ops.rmsnorm import rms_norm
 from dstack_tpu.serving import paged_window
+from dstack_tpu.serving.mixed import SlotStatePrograms
 
 
-class HybridPrograms:
-    #: why prefill/decode disaggregation is refused
+class HybridPrograms(SlotStatePrograms):
+    model = model
+    refusals = {
+        "paged=False": "the MLA layers' latent rows live in the paged "
+                       "pool, a dense latent cache is not written",
+        "prefix_cache": "a cached block would need a snapshot of the "
+                        "recurrent state at its boundary",
+        "kv_quantize": "latent pages would need scales and an absorbed "
+                       "product over quantized rows",
+        "quantize": "the grouped expert product would need int8 forms of "
+                    "the expert stacks",
+        "a mesh": "it would need the expert exchange and sharding rules "
+                  "for the recurrent state",
+    }
     pd_refusal = (
         "prefill/decode disaggregation is not served for this "
         "model: the wire carries K and V rows, not a recurrent "
         "state and latent rows")
-
-    def __init__(self, cfg: model.LingHybridConfig, *, batch_size: int,
-                 max_len: int, paged: bool, block_size: int, num_blocks: int,
-                 prefix_cache: bool, quantize: Optional[str],
-                 kv_quantize: Optional[str], mesh: Optional[Any],
-                 sharding_policy: Optional[Any], sample: Callable):
-        """What ``serving/dense.py`` ``DensePrograms`` takes, of which this
-        model is served with the paged pool alone."""
-        for refused, needs in (
-            (not paged, "paged=False: the MLA layers' latent rows live "
-             "in the paged pool, a dense latent cache is not written"),
-            (prefix_cache, "prefix_cache: a cached block would need a "
-             "snapshot of the recurrent state at its boundary"),
-            (kv_quantize, "kv_quantize: latent pages would need scales "
-             "and an absorbed product over quantized rows"),
-            (quantize, "quantize: the grouped expert product would need "
-             "int8 forms of the expert stacks"),
-            (mesh is not None, "a mesh: it would need the expert "
-             "exchange and sharding rules for the recurrent state"),
-        ):
-            if refused:
-                raise ValueError(
-                    f"{type(cfg).__name__} is not served with {needs}")
-        self.cfg = cfg
-        self.batch_size = batch_size
-        self.max_len = max_len
-        self.block_size = block_size
-        self.num_blocks = num_blocks
-        self.blocks_per_slot = max_len // block_size
-        #: the engine's on-device sampler (logits, temps, top_ps, top_ks, rng)
-        self._sample = sample
-
-    def prepare_params(self, params: Optional[model.Params], rng_seed: int):
-        """The weights, initialised from ``rng_seed`` when ``params`` is
-        None, committed to the device (what an uncommitted tree does to a
-        compile-cache key: ``DensePrograms.prepare_params``)."""
-        if params is None:
-            params = model.init_params(jax.random.PRNGKey(rng_seed), self.cfg)
-        return jax.device_put(params, jax.devices()[0])
 
     # -- state ---------------------------------------------------------------
     def init_state(self):
@@ -105,35 +82,12 @@ class HybridPrograms:
         }
         return pool, rec
 
-    def recurrent_state_bytes(self) -> int:
-        """Bytes of ``rec``: the state the slots hold whatever their
-        lengths (the ``recurrent_state_bytes`` gauge)."""
-        return self.cfg.recurrent_state_bytes(self.batch_size)
-
     def kv_geometry(self) -> tuple:
         """(cache layers, bytes one token holds over them): a latent row
         in each MLA layer."""
         cfg = self.cfg
         return cfg.mla_layers, (cfg.mla_layers * cfg.latent_lanes
                                 * jnp.dtype(cfg.dtype).itemsize)
-
-    @staticmethod
-    def record_window_counts(telemetry, counts) -> None:
-        """A drained window's last output: its expert load."""
-        if telemetry is None:
-            return
-        telemetry.record_expert_load(*counts.tolist())
-
-    @staticmethod
-    def record_prompt_program(telemetry, bucket: int) -> None:
-        """A prefill or chunk program of ``bucket`` positions ran: nothing
-        this family counts (``serving/nemotron_h.py`` counts its scans)."""
-
-    @staticmethod
-    def slot_target(slot_id: int, pages):
-        """Where a prefill or chunk program writes: the slot's pages and
-        the slot, whose recurrent state it starts or carries."""
-        return pages, jnp.int32(slot_id)
 
     @staticmethod
     def _put_slot(rec, slot, state, tail):
@@ -199,13 +153,6 @@ class HybridPrograms:
             return logits, pool, self._put_slot(rec, slot, state, tail)
 
         return fn
-
-    # -- the PD wire ---------------------------------------------------------
-    def export_fn(self, bucket: int):
-        raise ValueError(self.pd_refusal)
-
-    def insert_rows(self, pool, rec, prefill: dict, n: int, target):
-        raise ValueError(self.pd_refusal)
 
     # -- decode --------------------------------------------------------------
     def decode_window_fn(self, window: int, sampling: bool,

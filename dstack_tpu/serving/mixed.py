@@ -1,9 +1,10 @@
-"""The Nemotron-H decoder (``models/nemotron_h.py``) behind the engine's
-seam: a stack whose Mamba-2 layers keep a state-space state and a
-convolution tail per slot, whose attention layers keep K and V rows in the
-Llama family's paged pool, and whose expert blocks keep nothing.
-``serving/dense.py``, ``serving/hybrid.py`` and ``serving/lfm2.py`` answer
-the same calls for their families; ``serving/families.py`` picks among them.
+"""The decoders that keep K and V rows of their attention layers in the
+Llama family's paged pool beside a fixed state a slot over their other
+layers, behind the engine's seam: one provider, :class:`MixedPrograms`,
+built with the model module that computes the config (``serving/families.py``
+pairs them: ``models/lfm2.py``, short convolutions' tails;
+``models/nemotron_h.py``, Mamba-2 states and tails).  This module names no
+model; what differs between the families is their model module's.
 
 The engine's two donated trees of device state are
 
@@ -11,29 +12,21 @@ The engine's two donated trees of device state are
   Hkv * head_dim]: the paged pool in the form ``serving/dense.py`` stores
   it (kv heads folded into the lanes), over the ATTENTION layers only,
   addressed through the same block tables and the same allocator;
-* ``rec`` = {"ssm": [[slots, heads, head_dim, state] float32, ...], "tail":
-  [[slots, conv_kernel - 1, conv_dim], ...]}: one array a Mamba layer (not
-  one stacked array: a layer's state is 2 MiB a slot, and a decode step
-  then rewrites each layer's buffer where it lies, with no slice of a
-  larger one to read or to write back).
+* ``rec``: the model's state of every slot, in the form the model keeps it
+  (``init_rec``), read and written here only through the model's
+  ``read_slots`` and ``write_slots``.
 
 A slot's state is never reset by a program of its own: the prefill that
 admits a request (one program for a group of whole prompts, which streams
 the held experts once for all of them) starts from zeros and overwrites the
 slot, and a prompt's first chunk (``prefix_len == 0``) starts from zeros
 instead of reading it; later chunks read and write their slot.  Chunks go
-out a group of different slots a program too (``chunk_batch_fn``).  A
-decode window leaves slots that are not ``active`` (free, or mid-chunk)
-untouched: their step is 0, so their decay is 1 and their input nothing
-(``ops/ssd.py``).
+out a group of different slots a program too (``chunk_batch_fn``).  A decode
+window leaves slots that are not ``active`` (free, or mid-chunk) untouched.
 
 The decode window is the engine's buffered one (``serving/dense.py``
-documents it): the pool read-only, the window's K and V rows in a buffer
-carried through the step scan, the cache half read by the block-table
-kernel on a TPU and through one gathered view elsewhere, one scatter at the
-end (``serving/paged_window.py`` holds what the families share of it).  The
-states ride the same scan as a carry and are donated, so the largest
-program holds them once.
+documents it; ``serving/paged_window.py`` holds what the families share of
+it), with the per-slot state a donated carry of its step scan.
 """
 
 from __future__ import annotations
@@ -43,95 +36,126 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from dstack_tpu.models import nemotron_h as model
 from dstack_tpu.ops.pool import scatter_rows
 from dstack_tpu.serving import paged_window
 
 
-def _slot(rec, slot):
-    """One slot's part of ``rec``."""
-    return jax.tree.map(lambda a: a[slot], rec)
-
-
-def _with_slot(rec, slot, mine):
-    """``rec`` with the slot's part replaced by ``mine`` (or, for a vector
-    of slots, each one's by its row of ``mine``; a slot past the last one
-    is written nowhere: the scatter drops it)."""
-    return jax.tree.map(lambda a, m: a.at[slot].set(m.astype(a.dtype)),
-                        rec, mine)
-
-
-class NemotronHPrograms:
+class SlotStatePrograms:
+    """The seam's calls that do not depend on a family's programs, for a
+    model served with a paged pool beside a fixed state a slot."""
+    #: the family's model module (``init_params``, ``LOAD_FIELDS``)
+    model: Any
+    #: why each engine option the family is not served with is refused
+    refusals: dict
     #: why prefill/decode disaggregation is refused
-    pd_refusal = (
-        "prefill/decode disaggregation is not served for this "
-        "model: the wire carries K and V rows of every layer, not "
-        "state-space states and convolution tails beside the rows of some")
+    pd_refusal: str
 
-    def __init__(self, cfg: model.NemotronHConfig, *, batch_size: int,
-                 max_len: int, paged: bool, block_size: int, num_blocks: int,
-                 prefix_cache: bool, quantize: Optional[str],
-                 kv_quantize: Optional[str], mesh: Optional[Any],
-                 sharding_policy: Optional[Any], sample: Callable):
-        """What ``serving/dense.py`` ``DensePrograms`` takes, of which this
-        model is served with the paged pool alone."""
-        for refused, needs in (
-            (not paged, "paged=False: the attention layers' rows live in "
-             "the paged pool, a dense row per slot is not written"),
-            (prefix_cache, "prefix_cache: a cached block would need a "
-             "snapshot of the state-space states at its boundary (2 MiB a "
-             "layer a block)"),
-            (kv_quantize, "kv_quantize: the window buffer and the "
-             "end-of-window scatter write plain rows, not packed ones "
-             "with scales"),
-            (quantize, "quantize: the grouped expert product would need "
-             "int8 forms of the expert stacks"),
-            (mesh is not None, "a mesh: it would need the expert "
-             "exchange and sharding rules for the states"),
-        ):
+    def __init__(self, cfg, *, batch_size: int, max_len: int, paged: bool,
+                 block_size: int, num_blocks: int, prefix_cache: bool,
+                 quantize: Optional[str], kv_quantize: Optional[str],
+                 mesh: Optional[Any], sharding_policy: Optional[Any],
+                 sample: Callable):
+        """What ``serving/dense.py`` ``DensePrograms`` takes, of which these
+        models are served with the paged pool alone."""
+        asked = {"paged=False": not paged, "prefix_cache": prefix_cache,
+                 "kv_quantize": kv_quantize, "quantize": quantize,
+                 "a mesh": mesh is not None}
+        for option, refused in asked.items():
             if refused:
-                raise ValueError(
-                    f"{type(cfg).__name__} is not served with {needs}")
+                raise ValueError(f"{type(cfg).__name__} is not served with "
+                                 f"{option}: {self.refusals[option]}")
         self.cfg = cfg
         self.batch_size = batch_size
         self.max_len = max_len
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.blocks_per_slot = max_len // block_size
-        #: Pallas block-table decode kernel (resolved once at init)
-        self._paged_kernel = paged_window.paged_kernel_default()
         #: the engine's on-device sampler (logits, temps, top_ps, top_ks, rng)
         self._sample = sample
 
-    def prepare_params(self, params: Optional[model.Params], rng_seed: int):
+    def prepare_params(self, params, rng_seed: int):
         """The weights, initialised from ``rng_seed`` when ``params`` is
         None, committed to the device (what an uncommitted tree does to a
         compile-cache key: ``DensePrograms.prepare_params``)."""
         if params is None:
-            params = model.init_params(jax.random.PRNGKey(rng_seed), self.cfg)
+            params = self.model.init_params(jax.random.PRNGKey(rng_seed),
+                                            self.cfg)
         return jax.device_put(params, jax.devices()[0])
+
+    def recurrent_state_bytes(self) -> int:
+        """Bytes of ``rec``: what the slots hold whatever their lengths (the
+        ``recurrent_state_bytes`` gauge)."""
+        return self.cfg.recurrent_state_bytes(self.batch_size)
+
+    def record_window_counts(self, telemetry, counts) -> None:
+        """A drained window's last output: its expert load, then what the
+        model counts beside it (a state-space model: the slot-layer-steps
+        of its updates)."""
+        if telemetry is None:
+            return
+        counts, fields = counts.tolist(), self.model.LOAD_FIELDS
+        telemetry.record_expert_load(*counts[:fields])
+        if counts[fields:]:
+            telemetry.record_ssm_steps(*counts[fields:])
+
+    @staticmethod
+    def record_prompt_program(telemetry, bucket: int) -> None:
+        """A prefill or chunk program of ``bucket`` positions ran: nothing
+        the family counts (:class:`MixedPrograms` asks its model)."""
+
+    @staticmethod
+    def slot_target(slot_id: int, pages):
+        """Where a prefill or chunk program writes: the slot's pages and
+        the slot, whose state it starts or carries."""
+        return pages, jnp.int32(slot_id)
+
+    # -- the PD wire ---------------------------------------------------------
+    def export_fn(self, bucket: int):
+        raise ValueError(self.pd_refusal)
+
+    def insert_rows(self, pool, rec, prefill: dict, n: int, target):
+        raise ValueError(self.pd_refusal)
+
+
+class MixedPrograms(SlotStatePrograms):
+    """A paged K and V pool over the attention layers beside the model's
+    per-slot state; the model module answers the contract
+    ``serving/families.py`` states."""
+    refusals = {
+        "paged=False": "the attention layers' rows live in the paged "
+                       "pool, a dense row per slot is not written",
+        "prefix_cache": "a cached block would need a snapshot of the "
+                        "slots' state at its boundary (a state-space "
+                        "state is 2 MiB a layer a block)",
+        "kv_quantize": "the window buffer and the end-of-window scatter "
+                       "write plain rows, not packed ones with scales",
+        "quantize": "the grouped expert product would need int8 forms of "
+                    "the expert stacks",
+        "a mesh": "it would need the expert exchange and sharding rules "
+                  "for the slots' state",
+    }
+    pd_refusal = (
+        "prefill/decode disaggregation is not served for this "
+        "model: the wire carries K and V rows of every layer, not "
+        "a per-slot state beside the rows of some")
+
+    def __init__(self, cfg, *, model, **built_with):
+        """:class:`SlotStatePrograms`'s keywords, and ``model``: the module
+        that computes ``cfg`` (``serving/families.py`` names it)."""
+        super().__init__(cfg, **built_with)
+        self.model = model
+        #: Pallas block-table decode kernel (resolved once at init)
+        self._paged_kernel = paged_window.paged_kernel_default()
 
     # -- state ---------------------------------------------------------------
     def init_state(self):
         """``(pool, rec)``, all zeros."""
-        cfg, b = self.cfg, self.batch_size
+        cfg = self.cfg
         leaf = (cfg.attention_layers, self.num_blocks, self.block_size,
                 cfg.kv_lanes)
         pool = {"k": jnp.zeros(leaf, cfg.dtype),
                 "v": jnp.zeros(leaf, cfg.dtype)}
-        rec = {
-            "ssm": [jnp.zeros((b, cfg.mamba_num_heads, cfg.mamba_head_dim,
-                               cfg.ssm_state_size), jnp.float32)
-                    for _ in range(cfg.mamba_layers)],
-            "tail": [jnp.zeros((b, cfg.conv_reach, cfg.conv_dim), cfg.dtype)
-                     for _ in range(cfg.mamba_layers)],
-        }
-        return pool, rec
-
-    def recurrent_state_bytes(self) -> int:
-        """Bytes of ``rec``: the states and tails the slots hold whatever
-        their lengths (the ``recurrent_state_bytes`` gauge)."""
-        return self.cfg.recurrent_state_bytes(self.batch_size)
+        return pool, self.model.init_rec(cfg, self.batch_size)
 
     def kv_geometry(self) -> tuple:
         """(cache layers, bytes one token holds over them): a K and a V row
@@ -141,28 +165,13 @@ class NemotronHPrograms:
             2 * cfg.attention_layers * cfg.kv_lanes
             * jnp.dtype(cfg.dtype).itemsize)
 
-    @staticmethod
-    def record_window_counts(telemetry, counts) -> None:
-        """A drained window's last output: its expert load, then the
-        slot-layer-steps its state-space updates ran."""
-        if telemetry is None:
-            return
-        *load, ssm_steps = counts.tolist()
-        telemetry.record_expert_load(*load)
-        telemetry.record_ssm_steps(ssm_steps)
-
     def record_prompt_program(self, telemetry, bucket: int) -> None:
         """A prefill or chunk program of ``bucket`` positions ran: the
-        blocks that went through the chunked scan in its Mamba layers."""
-        if telemetry is not None:
-            chunks = -(-bucket // min(self.cfg.chunk_size, bucket))
-            telemetry.record_ssm_scan_chunks(self.cfg.mamba_layers * chunks)
-
-    @staticmethod
-    def slot_target(slot_id: int, pages):
-        """Where a prefill or chunk program writes: the slot's pages and
-        the slot, whose state it starts or carries."""
-        return pages, jnp.int32(slot_id)
+        blocks that went through the model's chunked scans, if it has
+        any."""
+        chunks = self.model.scan_chunks(self.cfg, bucket)
+        if telemetry is not None and chunks:
+            telemetry.record_ssm_scan_chunks(chunks)
 
     # -- prefill -------------------------------------------------------------
     def prefill_batch_fn(self, bucket: int):
@@ -171,7 +180,7 @@ class NemotronHPrograms:
         [P, bucket / block_size], slots [P])`` returns ``(logits [P, V],
         pool, rec)``.  An unused entry (length 0, slot ``batch_size``, block
         ids past the pool) writes no page and no slot."""
-        cfg = self.cfg
+        cfg, model = self.cfg, self.model
 
         def fn(params, tokens, lengths, pool, rec, bids, slots):
             def attend(m, q, k, v):
@@ -182,7 +191,7 @@ class NemotronHPrograms:
 
             logits, mine = model.prompts_forward(params, cfg, tokens,
                                                  lengths, attend)
-            return logits, pool, _with_slot(rec, slots, mine)
+            return logits, pool, model.write_slots(rec, slots, mine)
 
         return fn
 
@@ -190,20 +199,18 @@ class NemotronHPrograms:
         """One chunk of a long prompt: ``fn(params, tokens [cbucket],
         chunk_len, prefix_len, pool, rec, (table row, slot))``.  The K and V
         rows go into the slot's pages and the chunk attends the slot's whole
-        span; the state and the tail come from the slot (zeros at
-        ``prefix_len`` 0) and go back to it.  The engine sends
-        :meth:`chunk_batch_fn`'s groups; this form is what a group's entry
-        must leave."""
-        cfg, bs, nb = self.cfg, self.block_size, self.num_blocks
+        span; the state comes from the slot (zeros at ``prefix_len`` 0) and
+        goes back to it.  The engine sends :meth:`chunk_batch_fn`'s groups;
+        this form is what a group's entry must leave."""
+        cfg, model = self.cfg, self.model
+        bs, nb = self.block_size, self.num_blocks
         span = self.blocks_per_slot * self.block_size
         heads = (span, cfg.num_key_value_heads, cfg.head_dim)
 
         def fn(params, tokens, chunk_len, prefix_len, pool, rec, target):
             tables_row, slot = target
-            mine = jax.tree.map(
-                lambda a: jnp.where(prefix_len == 0, jnp.zeros_like(a),
-                                    a)[None],
-                _slot(rec, slot))
+            slots = slot[None]
+            mine = model.read_slots(rec, slots, (prefix_len == 0)[None])
             blk, off = paged_window.chunk_pages(prefix_len, cbucket,
                                                 tables_row, bs, span)
             positions = prefix_len + jnp.arange(cbucket)[None, :]
@@ -225,9 +232,9 @@ class NemotronHPrograms:
                     kv_pos)[0]
 
             logits, mine = model.sequence_forward(
-                params, cfg, tokens[None], chunk_len[None], mine, attend)
-            return logits[0], pool, _with_slot(
-                rec, slot, jax.tree.map(lambda a: a[0], mine))
+                params, cfg, tokens[None], chunk_len[None], prefix_len[None],
+                mine, attend)
+            return logits[0], pool, model.write_slots(rec, slots, mine)
 
         return fn
 
@@ -237,20 +244,16 @@ class NemotronHPrograms:
         pool, rec, (table rows [G, blocks a slot], slots [G]))`` returns
         ``(logits [G, V], pool, rec)``.  Each entry is what
         :meth:`chunk_fn` does with it: its rows into its slot's pages, its
-        attention over its slot's span (one entry at a time), its state and
-        tail from its slot (zeros at ``prefix_len`` 0) and back to it; the
-        experts' grouped product and the shared expert run once for the
-        group.  An unused entry (length 0, slot ``batch_size``, a table row
-        past the pool's blocks) writes no page and no slot."""
-        cfg = self.cfg
+        attention over its slot's span (one entry at a time), its state
+        from its slot (zeros at ``prefix_len`` 0) and back to it; the
+        experts' grouped product runs once for the group.  An unused entry
+        (length 0, slot ``batch_size``, a table row past the pool's blocks)
+        writes no page and no slot."""
+        cfg, model = self.cfg, self.model
 
         def fn(params, tokens, chunk_lens, prefix_lens, pool, rec, target):
             tables_rows, slots = target
-            first = prefix_lens == 0
-            mine = jax.tree.map(
-                lambda a: jnp.where(
-                    first.reshape((-1,) + (1,) * (a.ndim - 1)), 0, a[slots]),
-                rec)
+            mine = model.read_slots(rec, slots, prefix_lens == 0)
 
             def attend(m, q, k, v):
                 nonlocal pool
@@ -259,29 +262,22 @@ class NemotronHPrograms:
                 return out
 
             logits, mine = model.sequence_forward(
-                params, cfg, tokens, chunk_lens, mine, attend)
-            return logits, pool, _with_slot(rec, slots, mine)
+                params, cfg, tokens, chunk_lens, prefix_lens, mine, attend)
+            return logits, pool, model.write_slots(rec, slots, mine)
 
         return fn
-
-    # -- the PD wire ---------------------------------------------------------
-    def export_fn(self, bucket: int):
-        raise ValueError(self.pd_refusal)
-
-    def insert_rows(self, pool, rec, prefill: dict, n: int, target):
-        raise ValueError(self.pd_refusal)
 
     # -- decode --------------------------------------------------------------
     def decode_window_fn(self, window: int, sampling: bool,
                          kv_blocks: Optional[int]):
         """``window`` tokens for every active slot in one program: the
         engine's buffered window over the attention layers' pool, with the
-        states and tails carried through the steps in place.  Returns what
+        per-slot state carried through the steps in place.  Returns what
         the Llama window returns and, last, the window's counts for the
-        telemetry: its expert load (float32 [``model.LOAD_FIELDS``],
-        :func:`model.moe_block`) and the slot-layer-steps of its
-        state-space updates (live slots x Mamba layers a step)."""
-        cfg, b, w, bs = self.cfg, self.batch_size, window, self.block_size
+        telemetry (the model's ``window_counts``: its expert load, float32
+        [``LOAD_FIELDS``], first)."""
+        cfg, model = self.cfg, self.model
+        b, w, bs = self.batch_size, window, self.block_size
         layers = cfg.attention_layers
         hkv, hd = cfg.num_key_value_heads, cfg.head_dim
         group = cfg.num_attention_heads // hkv
@@ -309,8 +305,9 @@ class NemotronHPrograms:
             win_j = jnp.arange(w)
 
             def one_step(carry, inputs):
-                last_token, win_k, win_v, rec, load = carry
+                last_token, step_lengths, win_k, win_v, rec, load = carry
                 i, step_rng = inputs
+                positions = jnp.minimum(step_lengths, max_len - 1)
                 x = params["embed"].astype(cfg.dtype)[last_token]
                 win_mask = (win_j[None, :] <= i)[:, None, None, :]
 
@@ -333,7 +330,7 @@ class NemotronHPrograms:
                     return o.reshape(b, hkv * group, hd)
 
                 x, rec, step_load = model.decode_step(
-                    params, cfg, x, active, rec, attend)
+                    params, cfg, x, positions, active, rec, attend)
                 logits = model.output_logits(params, cfg, x)
                 if sampling:
                     tokens = self._sample(logits, temps, top_ps, top_ks,
@@ -341,16 +338,17 @@ class NemotronHPrograms:
                 else:
                     with jax.named_scope("sample"):
                         tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (tokens, win_k, win_v, rec, load + step_load), tokens
+                new_lengths = jnp.where(active, step_lengths + 1,
+                                        step_lengths)
+                return (tokens, new_lengths, win_k, win_v, rec,
+                        load + step_load), tokens
 
-            (last, win_k, win_v, rec, load), tokens_all = jax.lax.scan(
-                one_step,
-                (last_token, win0, win0, rec,
-                 jnp.zeros((model.LOAD_FIELDS,), jnp.float32)),
-                (jnp.arange(w), jax.random.split(rng, w)))
-            new_lengths = jnp.where(active, lengths + w, lengths)
-            ssm_steps = (active.sum().astype(jnp.float32)
-                         * (w * cfg.mamba_layers))
+            (last, new_lengths, win_k, win_v, rec, load), tokens_all = \
+                jax.lax.scan(
+                    one_step,
+                    (last_token, lengths, win0, win0, rec,
+                     jnp.zeros((model.LOAD_FIELDS,), jnp.float32)),
+                    (jnp.arange(w), jax.random.split(rng, w)))
 
             # the window's rows into each slot's pages (positions base_len +
             # j)
@@ -362,6 +360,6 @@ class NemotronHPrograms:
                         "v": scatter_rows(pool["v"], idx,
                                           jnp.moveaxis(win_v, 1, 2))}
             return (tokens_all, last, new_lengths, pool, rec,
-                    jnp.concatenate([load, ssm_steps[None]]))
+                    model.window_counts(cfg, load, active, w))
 
         return fn

@@ -1,5 +1,5 @@
 """What the providers' programs share (``serving/dense.py``,
-``serving/hybrid.py``, ``serving/lfm2.py``, ``serving/nemotron_h.py``): the
+``serving/hybrid.py``, ``serving/mixed.py``): the
 page arithmetic of a paged pool leaf [cache layers, blocks, block rows,
 lanes] under the engine's block tables, a group of whole prompts' and a
 group of chunks' page insert and attention, and the decode window's

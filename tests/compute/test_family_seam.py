@@ -1,6 +1,7 @@
 """The seam between the scheduler (``serving/engine.py``) and a family's
-provider (``serving/dense.py``, ``serving/hybrid.py``, ``serving/lfm2.py``,
-``serving/nemotron_h.py``):
+provider (``serving/dense.py``, ``serving/hybrid.py``, and
+``serving/mixed.py``, which serves LFM2 and Nemotron-H with the model module
+``serving/families.py`` pairs with the config):
 the providers answer the same calls, the engine drives each through admission, chunked prefill
 and decode windows to the tokens of the family's plain forward, the state
 trees are the size the provider says, each provider refuses what its model
@@ -22,8 +23,7 @@ from dstack_tpu.serving.dense import DensePrograms
 from dstack_tpu.serving.engine import InferenceEngine
 from dstack_tpu.serving.families import programs_for
 from dstack_tpu.serving.hybrid import HybridPrograms
-from dstack_tpu.serving.lfm2 import Lfm2Programs
-from dstack_tpu.serving.nemotron_h import NemotronHPrograms
+from dstack_tpu.serving.mixed import MixedPrograms, SlotStatePrograms
 
 ROOT = Path(__file__).resolve().parents[2]
 SERVING = ROOT / "dstack_tpu" / "serving"
@@ -116,8 +116,8 @@ FAMILIES = {
     "llama-paged": (_llama, PAGED, DensePrograms),
     "routed-mlp-paged": (_routed_mlp, PAGED, DensePrograms),
     "hybrid-paged": (_hybrid, PAGED, HybridPrograms),
-    "lfm2-paged": (_lfm2, PAGED, Lfm2Programs),
-    "nemotron-paged": (_nemotron, PAGED, NemotronHPrograms),
+    "lfm2-paged": (_lfm2, PAGED, MixedPrograms),
+    "nemotron-paged": (_nemotron, PAGED, MixedPrograms),
 }
 
 
@@ -135,7 +135,7 @@ def test_engine_drives_the_provider_to_the_plain_forward_s_tokens(family):
     assert type(engine._programs) is provider
     # the one optional call stands in for the one-prompt prefill
     batched = hasattr(engine._programs, "prefill_batch_fn")
-    assert batched is (provider in (Lfm2Programs, NemotronHPrograms))
+    assert batched is (provider is MixedPrograms)
     assert not (batched and hasattr(engine._programs, "prefill_fn"))
     # and so does the other for the chunk queue, beside the one-chunk form
     assert hasattr(engine._programs, "chunk_batch_fn") is batched
@@ -165,13 +165,19 @@ def test_engine_drives_the_provider_to_the_plain_forward_s_tokens(family):
 
 
 def test_the_providers_are_built_with_the_same_keywords():
+    """The merged provider takes the others' keywords through its base,
+    and beside them only the model module its table entry names."""
     keywords = lambda cls: [
         (p.name, p.kind) for p in
         inspect.signature(cls.__init__).parameters.values()]
     assert keywords(DensePrograms) == keywords(HybridPrograms) == \
-        keywords(Lfm2Programs) == keywords(NemotronHPrograms)
+        keywords(SlotStatePrograms)
     assert {name for name, _ in keywords(DensePrograms)[2:]} == \
         set(BUILT_WITH)
+    p = inspect.Parameter
+    assert keywords(MixedPrograms) == [
+        ("self", p.POSITIONAL_OR_KEYWORD), ("cfg", p.POSITIONAL_OR_KEYWORD),
+        ("model", p.KEYWORD_ONLY), ("built_with", p.VAR_KEYWORD)]
 
 
 @pytest.mark.parametrize("family", ["llama-paged-int8", "hybrid-paged"])
@@ -246,8 +252,10 @@ def test_the_hybrid_provider_refuses_the_pd_wire_itself():
 def test_the_scheduler_names_no_model():
     """``engine.py`` imports nothing of ``dstack_tpu.models`` or
     ``dstack_tpu.ops`` and asks no ``isinstance`` of its config; under
-    ``serving/`` only the providers, ``families.py`` (one function) and the
-    server's table of configs import a model."""
+    ``serving/`` only the providers of one family each, ``families.py`` (one
+    function) and the server's table of configs import a model; the merged
+    provider of two families imports none (its model module is handed to
+    it)."""
     def imported(path):
         tree = ast.parse(path.read_text())
         return tree, {
@@ -267,8 +275,10 @@ def test_the_scheduler_names_no_model():
     name_a_model = {
         path.name for path in SERVING.glob("*.py")
         if any(m.startswith("dstack_tpu.models") for m in imported(path)[1])}
-    assert name_a_model == {"dense.py", "hybrid.py", "lfm2.py",
-                            "nemotron_h.py", "families.py", "server.py"}
+    assert name_a_model == {"dense.py", "hybrid.py", "families.py",
+                            "server.py"}
+    assert not [m for m in imported(SERVING / "mixed.py")[1]
+                if m.startswith("dstack_tpu.models")]
     families = ast.parse((SERVING / "families.py").read_text())
     assert [node.name for node in ast.walk(families)
             if isinstance(node, ast.FunctionDef)] == ["programs_for"]
@@ -283,15 +293,26 @@ def test_families_is_a_table_and_a_subclass_goes_to_its_base_s_provider():
     from dstack_tpu.models.ouro import OuroConfig
     from dstack_tpu.serving.families import PROVIDERS
 
-    assert PROVIDERS == {LlamaConfig: DensePrograms,
-                         LingHybridConfig: HybridPrograms,
-                         Lfm2MoeConfig: Lfm2Programs,
-                         NemotronHConfig: NemotronHPrograms}
-    for make, provider in ((MoEConfig.tiny_moe, DensePrograms),
-                           (OuroConfig.tiny, DensePrograms),
-                           (Lfm2MoeConfig.tiny, Lfm2Programs),
-                           (NemotronHConfig.tiny, NemotronHPrograms)):
-        assert type(programs_for(make(), **BUILT_WITH)) is provider
+    from dstack_tpu.models import lfm2, nemotron_h
+
+    assert set(PROVIDERS) == {LlamaConfig, LingHybridConfig, Lfm2MoeConfig,
+                              NemotronHConfig}
+    assert PROVIDERS[LlamaConfig] is DensePrograms
+    assert PROVIDERS[LingHybridConfig] is HybridPrograms
+    # the two mixed families: one class, each with its model module
+    for cfg_cls, module in ((Lfm2MoeConfig, lfm2),
+                            (NemotronHConfig, nemotron_h)):
+        entry = PROVIDERS[cfg_cls]
+        assert (entry.func, entry.args, entry.keywords) == (
+            MixedPrograms, (), {"model": module})
+    for make, provider, module in (
+            (MoEConfig.tiny_moe, DensePrograms, None),
+            (OuroConfig.tiny, DensePrograms, None),
+            (Lfm2MoeConfig.tiny, MixedPrograms, lfm2),
+            (NemotronHConfig.tiny, MixedPrograms, nemotron_h)):
+        programs = programs_for(make(), **BUILT_WITH)
+        assert type(programs) is provider
+        assert getattr(programs, "model", None) is module
     with pytest.raises(TypeError, match="no provider serves"):
         programs_for(object(), **BUILT_WITH)
 
@@ -326,14 +347,20 @@ def test_what_the_families_share_exists_once():
     assert where("jnp.logaddexp(lse_c") == ["serving/paged_window.py"]
     assert where("jnp.take_along_axis(tables") == ["serving/paged_window.py"]
     assert where("def masked_attention(") == ["serving/paged_window.py"]
-    for caller in ("serving/dense.py", "serving/lfm2.py",
-                   "serving/nemotron_h.py"):
+    for caller in ("serving/dense.py", "serving/mixed.py"):
         assert caller in where("paged_window.attend_pages_and_window(")
         assert caller in where("paged_window.attend_view_and_window(")
     for caller in ("serving/dense.py", "serving/hybrid.py",
-                   "serving/lfm2.py", "serving/nemotron_h.py"):
+                   "serving/mixed.py"):
         assert caller in where("paged_window.window_rows(")
         assert caller in where("paged_window.chunk_pages(")
     assert where("held_experts(") == ["models/experts.py", "models/lfm2.py",
                                       "models/ling_hybrid.py",
                                       "models/nemotron_h.py"]
+    # the grouped programs, the PD wire's refusal and the expert-load record
+    # of the families of a pool beside a per-slot state are written once
+    for needle in ("def prefill_batch_fn(", "def chunk_batch_fn(",
+                   "raise ValueError(self.pd_refusal)"):
+        assert where(needle) == ["serving/mixed.py"], needle
+    assert where("def record_window_counts(") == ["serving/dense.py",
+                                                  "serving/mixed.py"]

@@ -33,6 +33,14 @@ take the whole stacked state (``models/ling_hybrid.py`` ``kda_token``, so
 that the chip's step kernel can update a layer in place): on the CPU the
 same operations, with two ``where`` helpers numbered two lower because the
 layer is now sliced inside the mixer.
+When one provider came to serve LFM2 and Nemotron-H (``serving/mixed.py``,
+ROADMAP D13), ``nemotron-paged``'s decode entry alone was rewritten: the
+window carries each slot's length through the step scan (LFM2's form, one
+more ``[slots]`` int32 carry and a ``where`` a step) where it added the
+window's steps once after the scan, and the state-space slot-layer-steps are
+summed after the end-of-window scatter, not before it (the model's
+``window_counts``); the positions the step now receives are read by nothing
+and lower to no operation.  The other 23 hashes stayed as they were.
 A change that is meant to alter one of these programs rewrites the file:
 
     JAX_PLATFORMS=cpu python tests/compute/test_lowered_programs.py --write

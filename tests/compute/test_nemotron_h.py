@@ -29,7 +29,7 @@ from benchmarks.harness.sizes import program_config, sizes_of
 from benchmarks.references import nemotron_h as ref
 from dstack_tpu.models import nemotron_h as model
 from dstack_tpu.serving.engine import InferenceEngine, Request
-from dstack_tpu.serving.nemotron_h import NemotronHPrograms
+from dstack_tpu.serving.mixed import MixedPrograms
 from tests.compute.test_prompt_groups import send_group
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -450,7 +450,8 @@ def test_engine_built_as_the_server_builds_it_serves_the_reference(toy):
         prefix_cache=False, kv_quantize=None,
         prefill_chunk=InferenceEngine.TUNED_PREFILL_CHUNK,
         telemetry=make_engine_telemetry(), compile_cache=None)
-    assert type(engine._programs) is NemotronHPrograms
+    assert type(engine._programs) is MixedPrograms
+    assert engine._programs.model is model
     prompt = _prompt(20)
     served = np.asarray(engine.generate(prompt.tolist(),
                                         max_new_tokens=9).output)
